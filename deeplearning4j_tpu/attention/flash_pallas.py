@@ -17,12 +17,14 @@ dQ iterates KV blocks per Q tile; dK/dV iterates Q tiles per KV block
 All dots run with bf16 operands (f32 accumulation via
 preferred_element_type) — the v5e MXU's native mode; softmax state is
 f32 in base-2 (exp2). Causal masking only runs on diagonal-crossing
-blocks; fully-visible blocks take a mask-free branch. Measured numbers
-and the amortized chained-scan timing protocol: BASELINE.md.
+blocks; fully-visible blocks take a mask-free branch.
 
 Falls back to `blockwise_attention` (forward AND backward) for
-tile-indivisible shapes; interpret mode covers CPU tests on the same
-kernel code path.
+tile-indivisible shapes — by design, but silently: a caller that needs
+the kernels checks the program (`chip_smoke.py`'s train leg does;
+docs/SERVING.md lists which prefill buckets take which lane).
+Interpret mode covers CPU tests on the same kernel code path; library
+code never picks it from the platform.
 """
 
 from __future__ import annotations
@@ -38,13 +40,6 @@ NEG_INF = -1e30
 LANES = 128  # Mosaic-aligned trailing dim for row vectors (lse, D)
 
 
-def _tpu_compiler_params(pltpu, **kw):
-    """pltpu.CompilerParams across the rename (TPUCompilerParams on
-    older jax releases)."""
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kw)
 LOG2E = 1.4426950408889634   # softmax state is kept in base-2 (exp2)
 LN2 = 0.6931471805599453     # converts base-2 LSE back to natural log
 
@@ -125,11 +120,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *rest, causal: bool, q_tile: int,
         # (scores pre-scaled by log2(e)/sqrt(d), exp2 instead of exp) so
         # the transcendental is a bare exp2 with no hidden multiply.
         # `group` batch rows (heads) are processed per grid step as a
-        # batched dot: the round-5 ablation measured the kernel
-        # MXU-dot + per-step-overhead bound (NOT VPU-softmax bound as
-        # round 4's broken-protocol ablation claimed), and halving the
-        # grid-step count amortizes that overhead (0.547 -> 0.462 ms at
-        # 4x8x2048x64 with group=2, q_tile=1024).
+        # batched dot, which halves the grid-step count (`_pick_group`).
         q = q_ref[...]  # (group, q_tile, d)
         k = k_ref[...]  # (group, block_k, d)
         v = v_ref[...]
@@ -189,6 +180,35 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *rest, causal: bool, q_tile: int,
             lse_ref[...] = jnp.broadcast_to(lse, (*lse.shape[:-1], LANES))
 
 
+def _pick_group(b: int, d: int, itemsize: int, q_tile: int,
+                block_k: int, want_lse: bool) -> int:
+    """Batch rows (heads) per forward grid step: 2 when the batch
+    divides and the step fits VMEM, else 1.
+
+    A (2, tile, d) batched dot halves the grid-step count, amortizing
+    the per-step overhead. The byte estimate below (f32 scores +
+    double-buffered q/k/v/o blocks + f32 acc scratch + the lse output
+    block on the vjp path) and its 11.5M threshold were set on an older
+    toolchain, where d=64 bf16 1024x1024 with lse was refused at 17.71M
+    against the 16M scoped-VMEM limit. With jax 0.9.0 / libtpu 0.0.34
+    every combination of group 1|2, d 64|128, f32|bf16, with and
+    without lse compiles for v5e at 1024x1024 tiles (PR 21), so the
+    gate is now narrower than the compiler. It is kept as it was
+    because which group is FASTER at d=128 or in f32 has not been
+    measured (ROADMAP S5); widening it is that item's change, not a
+    correctness one. At group=1 the forward compiles and matches
+    blockwise on the chip at every prefill bucket 128..2048 and the
+    backward at T=1024, d 64 and 128, f32 and bf16
+    (tests/test_tpu_lane.py)."""
+    if b % 2 or d > 64 or itemsize > 2:
+        return 1
+    scores = 2 * q_tile * block_k * 4
+    io = 2 * 2 * (q_tile + 2 * block_k + q_tile) * d * itemsize
+    acc = 2 * q_tile * d * 4
+    lse = 2 * 2 * q_tile * LANES * 4 if want_lse else 0
+    return 2 if scores + io + acc + lse <= 11.5 * 1024 * 1024 else 1
+
+
 def _flash_forward(q, k, v, causal: bool, q_tile: int, block_k: int,
                    interpret: bool, want_lse: bool = True):
     from jax.experimental import pallas as pl
@@ -196,33 +216,7 @@ def _flash_forward(q, k, v, causal: bool, q_tile: int, block_k: int,
 
     b, t_q, d = q.shape
     t_k = k.shape[1]
-    # Pair up batch rows (heads) when the batch divides and VMEM allows:
-    # a (2, tile, d) batched dot halves the grid-step count, amortizing
-    # the per-step overhead the round-5 ablation measured (0.547 ->
-    # 0.462 ms at 4x8x2048x64). VMEM estimate per grid step at group g:
-    # f32 scores (g*qt*bk*4) + double-buffered bf16 q/k/v/o blocks
-    # (d-scaled) + f32 acc scratch + the lse output block on the vjp
-    # path. The estimate undercounts Mosaic's internal buffers, so the
-    # threshold is CALIBRATED on d=64 1024x1024 measurements: the
-    # no-lse group=2 config (estimate 10.6M) compiles and runs; the lse
-    # group=2 config (estimate 12.7M) OOMs at 17.71M actual against the
-    # 16M scoped limit. 11.5M sits between them, erring conservative
-    # (larger d falls back to the always-safe group=1).
-    def vmem_est(g):
-        itemsize = q.dtype.itemsize  # kernel blocks stay in input dtype
-        scores = g * q_tile * block_k * 4
-        io = 2 * g * (q_tile + 2 * block_k + q_tile) * d * itemsize
-        acc = g * q_tile * d * 4
-        lse = 2 * g * q_tile * LANES * 4 if want_lse else 0
-        return scores + io + acc + lse
-
-    # group=2 only inside the envelope the 11.5M threshold was actually
-    # calibrated on (d <= 64, <= 2-byte operands): outside it the
-    # estimate's undercount of Mosaic's internal buffers is unvalidated,
-    # and a miss is a runtime Mosaic VMEM OOM rather than a graceful
-    # fallback — degrade to the always-safe group=1 instead
-    group = 2 if (b % 2 == 0 and d <= 64 and q.dtype.itemsize <= 2
-                  and vmem_est(2) <= 11.5 * 1024 * 1024) else 1
+    group = _pick_group(b, d, q.dtype.itemsize, q_tile, block_k, want_lse)
     grid = (b // group, t_q // q_tile, t_k // block_k)
     out_shape = [jax.ShapeDtypeStruct(q.shape, q.dtype)]
     out_specs = [pl.BlockSpec((group, q_tile, d),
@@ -256,13 +250,13 @@ def _flash_forward(q, k, v, causal: bool, q_tile: int, block_k: int,
             pltpu.VMEM((group, q_tile, 1), jnp.float32),   # running max
             pltpu.VMEM((group, q_tile, 1), jnp.float32),   # running sum
         ],
-        # batch and Q-tile grid dims carry no cross-step state — letting
-        # Mosaic treat them as parallel measured ~1.4x on v5e; only the
-        # KV accumulation dim is sequential
-        compiler_params=_tpu_compiler_params(
-            pltpu,
+        # batch and Q-tile grid dims carry no cross-step state, so
+        # Mosaic may treat them as parallel; only the KV accumulation
+        # dim is sequential
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return res if want_lse else (res, None)
 
@@ -276,15 +270,12 @@ def flash_attention(q, k, v, causal: bool = False, q_tile: int = 1024,
     with no 128-aligned divisor falls back to blockwise. Set
     interpret=True off-TPU.
 
-    Defaults tuned on v5e at (4x8)x2048x64 bf16 causal under the
-    amortized chained-scan protocol (see BASELINE.md). Round-5 ablation:
-    the kernel is MXU-dot + per-grid-step-overhead bound (dots-only on
-    the same grid: 0.50 ms of the 0.63 ms non-causal total; an empty
-    kernel body is 0.11 ms), so fewer/larger steps win: q_tile 1024 +
-    batch-pair grouping (see _flash_forward) moved 0.547 -> 0.462
-    ms/step causal. bf16 softmax, score prescaling, and a
-    double-buffered lookahead pipeline were all measured no-better
-    (scratch/flash_ablate3.py).
+    The 1024 defaults were tuned at (4x8)x2048x64 bf16 causal in an
+    earlier round, on a set-up that no longer exists: fewer, larger
+    grid steps won there (q_tile 1024 + batch-pair grouping, see
+    `_pick_group`). They have not been re-tuned at head_dim 128 or on
+    the current machine (ROADMAP S5); what PR 21 established is that
+    they compile and match blockwise there.
 
     NOTE: sequence length is axis -2 (NOT axis 1 — a 4-D (B, H, T, d)
     input's axis 1 is heads; reading it as T silently routed every 4-D
@@ -476,10 +467,10 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, q_tile: int,
         ],
         out_specs=at(lambda bi, qi, ki: (bi, qi, 0), q_spec),
         scratch_shapes=[pltpu.VMEM((q_tile, d), jnp.float32)],
-        compiler_params=_tpu_compiler_params(
-            pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, g, lse, dd)
 
     dk, dv = pl.pallas_call(
@@ -499,10 +490,10 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, q_tile: int,
                    at(lambda bi, ki, qi: (bi, ki, 0), k_spec)),
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=_tpu_compiler_params(
-            pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, g, lse, dd)
     return dq, dk, dv
 

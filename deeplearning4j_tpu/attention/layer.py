@@ -2,11 +2,12 @@
 
 Beyond-reference capability (the reference predates attention): a
 single-head self-attention block usable in a MultiLayerNetwork stack on
-(batch, T, d) inputs. The forward computes through `flash_attention` —
-the Pallas kernel on TPU for tile-aligned sequences, transparently the
-blockwise form elsewhere (same O(T) memory either way; the custom VJP
-recomputes through blockwise). With a mesh configured, callers can swap
-the inner call for `ring_attention` (sequence parallelism).
+(batch, T, d) inputs. On a TPU backend the forward computes through
+`flash_attention` (the Pallas kernels for tile-aligned sequences, the
+blockwise form for ragged ones); on any other backend it calls
+`blockwise_attention` directly — same O(T) memory, and never the
+Pallas interpreter. With a mesh configured, callers can swap the inner
+call for `ring_attention` (sequence parallelism).
 """
 
 from __future__ import annotations
@@ -16,6 +17,7 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from deeplearning4j_tpu.attention.blockwise import blockwise_attention
 from deeplearning4j_tpu.attention.flash_pallas import flash_attention
 from deeplearning4j_tpu.nn.layers import (BaseLayer, apply_dropout,
                                           register_layer)
@@ -64,11 +66,9 @@ class SelfAttentionLayer(BaseLayer):
             return proj.reshape(B, T, n_heads, d_head).transpose(0, 2, 1, 3)
 
         q, k, v = heads(params["Wq"]), heads(params["Wk"]), heads(params["Wv"])
-        # interpret mode off-TPU: the kernel path still runs (slowly) under
-        # the Pallas interpreter so tests exercise the same code path
-        on_tpu = jax.devices()[0].platform == "tpu"
-        out = flash_attention(q, k, v, causal=self.is_causal(),
-                              interpret=not on_tpu)
+        attend = (flash_attention if jax.default_backend() == "tpu"
+                  else blockwise_attention)
+        out = attend(q, k, v, causal=self.is_causal())
         out = out.transpose(0, 2, 1, 3).reshape(B, T, d_attn)
         out = out.astype(jnp.dtype(self.conf.dtype)) @ params["Wo"]
         return apply_dropout(rng, out, self.conf.dropout, training)

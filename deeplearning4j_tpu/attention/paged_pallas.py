@@ -27,9 +27,9 @@ portability pattern from `attention/flash_pallas.py`):
 
 `resolve_decode_kernel` is the lane selector behind the
 `kernel="pallas"|"gather"|"auto"` knob (`DecodeLoop`, `engine`,
-`cli serve`): `auto` takes the kernel only on TPU inside the calibrated
-envelope and NEVER silently runs interpret mode off-TPU (the
-`flash_pallas` group-gate precedent); explicit `pallas` off-TPU is an
+`cli serve`): `auto` takes the kernel only on TPU inside the envelope
+that has been run against the gather math on a chip, and NEVER
+silently runs interpret mode off-TPU; explicit `pallas` off-TPU is an
 error unless `cfg.interpret` is set (the CPU tier-1 test lane).
 """
 
@@ -40,8 +40,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from deeplearning4j_tpu.attention.flash_pallas import (LOG2E, NEG_INF,
-                                                       _tpu_compiler_params)
+from deeplearning4j_tpu.attention.flash_pallas import LOG2E, NEG_INF
 
 __all__ = ["paged_attention", "resolve_decode_kernel", "DECODE_KERNELS"]
 
@@ -53,7 +52,9 @@ def _decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     """One (slot, page) grid step. `pt_ref`/`len_ref` are the
     scalar-prefetch operands (the same arrays the BlockSpec index maps
     read); K/V refs already hold the PHYSICAL page the index map
-    selected for this step."""
+    selected for this step. The query block carries `rows` identical
+    copies of the slot's one query row (see `paged_attention`), so the
+    softmax state below is (H, rows, ·) with every row equal."""
     from jax.experimental import pallas as pl
 
     si = pl.program_id(0)
@@ -72,7 +73,7 @@ def _decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     # computes (pos >= 0), so the softmax sum is never empty
     @pl.when(j * page_size <= pos)
     def _tile():
-        q = q_ref[0]          # (H, hd)
+        q = q_ref[0]          # (H, rows, hd)
         k = k_ref[0]          # (H, ps, hd)
         v = v_ref[0]
         hd = q.shape[-1]
@@ -80,10 +81,11 @@ def _decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         # the transcendental is a bare exp2 (flash_pallas._kernel)
         scale2 = jnp.float32(LOG2E) / jnp.float32(hd) ** 0.5
         scores = jax.lax.dot_general(
-            q, k, (((1,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale2   # (H, ps)
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+            precision=_dot_precision(q.dtype)) * scale2   # (H, rows, ps)
         k_pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, scores.shape, 1)
+            jnp.int32, scores.shape, 2)
         mask = k_pos <= pos   # current token at `pos` IS visible
         scores = jnp.where(mask, scores, NEG_INF)
         m_prev, s_prev = m_ref[...], s_ref[...]
@@ -96,13 +98,22 @@ def _decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         # P in V's storage dtype for the MXU dot, f32 accumulation —
         # same rounding story as the flash forward
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+            precision=_dot_precision(v.dtype))
 
     @pl.when(j == n_j - 1)
     def _finalize():
         o_ref[0] = (acc_ref[...] /
                     jnp.maximum(s_ref[...], 1e-30)).astype(o_ref.dtype)
+
+
+def _dot_precision(dtype):
+    """f32 pools ask Mosaic for a full-precision contraction (the 1e-5
+    parity with the gather path's f32 softmax is pinned by tests);
+    narrower dtypes take the MXU's native single pass."""
+    return (jax.lax.Precision.HIGHEST
+            if jnp.dtype(dtype).itemsize >= 4 else None)
 
 
 def paged_attention(q, k_pool, v_pool, page_table, lengths, *,
@@ -119,44 +130,53 @@ def paged_attention(q, k_pool, v_pool, page_table, lengths, *,
 
     Returns (S, H, hd) in q.dtype. page_table/lengths are traced
     values: membership changes never recompile (the
-    `decode_step_programs() == 1` invariant)."""
+    `decode_step_programs() == 1` invariant).
+
+    The query row is replicated to one sublane tile of rows (8 for
+    4-byte, 16 for 2-byte dtypes) before the call: Mosaic's matmul
+    needs a free (non-contracting, non-batch) dimension on BOTH
+    operands, and a bare `(H, hd) x (H, ps, hd)` matrix-vector batch
+    has none on the left (it does not lower — the form this kernel had
+    before it ever met the compiler). The replicas cost `rows` x the
+    query/output bytes, which are ~1/page_count of the K/V bytes the
+    step streams; row 0 of the result is the answer."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     s, h, hd = q.shape
     ps = k_pool.shape[2]
     n_j = page_table.shape[1]
+    rows = 32 // jnp.dtype(q.dtype).itemsize   # one sublane tile
+    q_rows = jnp.broadcast_to(q[:, :, None, :], (s, h, rows, hd))
+    q_spec = pl.BlockSpec((1, h, rows, hd),
+                          lambda si, j, pt, ln: (si, 0, 0, 0),
+                          memory_space=pltpu.VMEM)
     kv_spec = pl.BlockSpec((1, h, ps, hd),
                            lambda si, j, pt, ln: (pt[si, j], 0, 0, 0),
                            memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(s, n_j),
-        in_specs=[
-            pl.BlockSpec((1, h, hd), lambda si, j, pt, ln: (si, 0, 0),
-                         memory_space=pltpu.VMEM),
-            kv_spec,
-            kv_spec,
-        ],
-        out_specs=pl.BlockSpec((1, h, hd),
-                               lambda si, j, pt, ln: (si, 0, 0),
-                               memory_space=pltpu.VMEM),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((h, hd), jnp.float32),   # acc
-            pltpu.VMEM((h, 1), jnp.float32),    # running max (base-2)
-            pltpu.VMEM((h, 1), jnp.float32),    # running sum
+            pltpu.VMEM((h, rows, hd), jnp.float32),   # acc
+            pltpu.VMEM((h, rows, 1), jnp.float32),    # running max (base-2)
+            pltpu.VMEM((h, rows, 1), jnp.float32),    # running sum
         ])
-    return pl.pallas_call(
+    out = pl.pallas_call(
         partial(_decode_kernel, page_size=ps),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s, h, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((s, h, rows, hd), q.dtype),
         # slots are independent (scratch init/finalize is per-row);
         # only the page sweep carries the online-softmax state
-        compiler_params=_tpu_compiler_params(
-            pltpu, dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_decode_attention",
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      q, k_pool, v_pool)
+      q_rows, k_pool, v_pool)
+    return out[:, :, 0, :]
 
 
 def resolve_decode_kernel(kernel: str, cfg, page_size: int) -> str:
@@ -168,9 +188,17 @@ def resolve_decode_kernel(kernel: str, cfg, page_size: int) -> str:
     - "pallas": the kernel; off-TPU this raises unless `cfg.interpret`
       is set (tests run the kernel code path through the interpreter —
       production must never fall into that silently).
-    - "auto": the kernel on TPU inside the calibrated envelope
-      (hd <= 128, <= 4-byte KV dtype, page_size >= 8 — lanes/sublane
-      padding stays bounded); everything else takes the gather path.
+    - "auto": the kernel on TPU for hd <= 128, a <= 4-byte KV dtype
+      and page_size >= 8; everything else takes the gather path.
+      That envelope is what has been CHECKED on a v5e (PR 21,
+      tests/test_tpu_lane.py): H8 x hd128 and H16 x hd64, f32 (1e-5
+      against a float64 dense reference) and bf16 (2e-2), page sizes 8
+      and 16, ragged cursors. The chip refused nothing in it. Outside
+      it the kernel also compiles (hd 16..256, page sizes 1..128 were
+      compiled for v5e, not run), but sub-tile pages pad every K/V
+      block to a full (8|16, 128) tile and nothing there has been
+      compared on a chip, so `auto` does not go there; widen it with a
+      tpu-lane case, not by argument.
       Off-TPU auto is ALWAYS gather, interpret or not: interpret mode
       is a test lane, not a production fallback."""
     if kernel not in DECODE_KERNELS:

@@ -24,20 +24,15 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 from deeplearning4j_tpu.attention.blockwise import NEG_INF
 
 
-def _ring_attention_local(q, k, v, axis_name: str, causal: bool,
-                          n_dev: int):
-    """Per-device body (inside shard_map). q/k/v: (..., T_local, d).
-    `n_dev` is the ring size, passed statically from the mesh (lax has no
-    stable in-trace axis-size query across the jax versions we span)."""
+def _ring_attention_local(q, k, v, axis_name: str, causal: bool):
+    """Per-device body (inside shard_map). q/k/v: (..., T_local, d)."""
     my_idx = lax.axis_index(axis_name)
+    n_dev = lax.axis_size(axis_name)   # static: the ring size
     t_local = q.shape[-2]
     d = q.shape[-1]
     scale = 1.0 / jnp.sqrt(d)
@@ -98,7 +93,7 @@ def _ring_attention_local(q, k, v, axis_name: str, causal: bool,
 
 
 def _ring_attention_local_flash(q, k, v, axis_name: str, causal: bool,
-                                interpret: bool, n_dev: int):
+                                interpret: bool):
     """Per-device ring body with the Pallas flash kernel computing each
     visiting shard's local attention on the MXU (bf16 operands, f32
     state), merged across ring steps in log-space via the kernel's
@@ -116,6 +111,7 @@ def _ring_attention_local_flash(q, k, v, axis_name: str, causal: bool,
         flash_attention_with_lse)
 
     my_idx = lax.axis_index(axis_name)
+    n_dev = lax.axis_size(axis_name)
     orig_dtype = q.dtype
 
     def local(k_cur, v_cur, is_causal):
@@ -205,32 +201,23 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str = "sp",
                          f"axis {batch_axis!r} size {mesh.shape[batch_axis]}")
     if local == "flash":
         body = partial(_ring_attention_local_flash, axis_name=axis,
-                       causal=causal, interpret=interpret, n_dev=n_dev)
+                       causal=causal, interpret=interpret)
     elif local == "einsum":
         body = partial(_ring_attention_local, axis_name=axis,
-                       causal=causal, n_dev=n_dev)
+                       causal=causal)
     else:
         raise ValueError(f"unknown local engine {local!r}; "
                          "expected 'einsum' or 'flash'")
 
     spec = P(batch_axis, axis, None)
-    kw = {}
-    if local == "flash":
-        # pallas_call's out_shape structs carry no vma annotations, so
-        # the new shard_map's varying-axes checker can't type them —
-        # use its escape hatch (check_vma; check_rep on older jax)
-        import inspect
-        params = inspect.signature(_shard_map).parameters
-        if "check_vma" in params:
-            kw["check_vma"] = False
-        elif "check_rep" in params:  # pre-rename jax
-            kw["check_rep"] = False
     fn = _shard_map(
         body,
         mesh=mesh,
         in_specs=(spec,) * 3,
         out_specs=spec,
-        **kw,
+        # pallas_call's out_shape structs carry no vma annotations, so
+        # shard_map's varying-axes checker can't type the flash engine
+        check_vma=local != "flash",
     )
     with mesh:
         return fn(q, k, v)

@@ -109,20 +109,29 @@ def _transformer_engine(spec: str):
 
 
 def _activate_compile_cache(spec: Optional[str],
-                            anchor: Optional[str]) -> Optional[str]:
+                            anchor: Optional[str],
+                            children_only: bool = False) -> Optional[str]:
     """`--compile-cache DIR|auto|off`: open the persistent AOT program
     cache BEFORE any engine/trainer jit is constructed (docs/WARMUP.md).
     `auto` co-locates the cache with `anchor` (the checkpoint/model
     dir) when one exists; with no flag at all the process still
     inherits `DL4J_TPU_COMPILE_CACHE` from a spawning parent lazily.
-    Returns the active cache dir (for the announce line) or None."""
+    Returns the active cache dir (for the announce line) or None.
+
+    `children_only` is for control-plane commands (fleet router,
+    elastic supervisor): the directory is exported to the children's
+    environment and NOT opened here, because opening it asks JAX for
+    the device and the children need that device."""
     from deeplearning4j_tpu import compilecache
 
-    if spec and spec != "off":
-        if spec == "auto":
-            if not anchor or not os.path.isdir(anchor):
-                return compilecache.active_dir()
-            spec = compilecache.default_dir_for_checkpoints(anchor)
+    if spec == "auto":
+        spec = (compilecache.default_dir_for_checkpoints(anchor)
+                if anchor and os.path.isdir(anchor) else None)
+    wanted = bool(spec) and spec != "off"
+    if children_only:
+        return (compilecache.export_dir(spec) if wanted
+                else os.environ.get(compilecache.CACHE_ENV))
+    if wanted:
         compilecache.activate(spec)
     return compilecache.active_dir()
 
@@ -139,7 +148,7 @@ class _Telemetry:
     optional standalone /metrics endpoint for the run's lifetime, and a
     Chrome-trace dump on exit."""
 
-    def __init__(self, args):
+    def __init__(self, args, control_plane: bool = False):
         self.metrics = None
         self.trace_path = getattr(args, "trace", None)
         port = getattr(args, "metrics_port", None)
@@ -147,7 +156,10 @@ class _Telemetry:
             from deeplearning4j_tpu.telemetry.exposition import \
                 start_metrics_server
 
-            self.metrics = start_metrics_server(port=port)
+            # a router/supervisor never samples device gauges: that
+            # would take the chip its children compute on
+            self.metrics = start_metrics_server(
+                port=port, device_gauges=not control_plane)
         if self.trace_path:
             from deeplearning4j_tpu.telemetry import start_tracing
 
@@ -178,7 +190,8 @@ def cmd_train(args) -> int:
             == "auto":
         os.makedirs(args.checkpoint_dir, exist_ok=True)
     _activate_compile_cache(getattr(args, "compile_cache", None),
-                            args.checkpoint_dir)
+                            args.checkpoint_dir,
+                            children_only=bool(args.elastic))
     if args.elastic:
         return _cmd_train_elastic(args)
     tele = _Telemetry(args)
@@ -322,6 +335,7 @@ def _cmd_train_elastic(args) -> int:
     from deeplearning4j_tpu.scaleout.registry import ConfigRegistry
     from deeplearning4j_tpu.scaleout.supervisor import (TrainingSupervisor,
                                                         WorkerSpawner)
+    from deeplearning4j_tpu.utils import jaxenv, procs
 
     if args.resume == "auto" and not args.checkpoint_dir:
         # same refusal as the non-elastic path: silently starting a
@@ -329,7 +343,11 @@ def _cmd_train_elastic(args) -> int:
         print("--resume auto needs --checkpoint-dir DIR to discover "
               "the latest committed step from", file=sys.stderr)
         return 2
-    tele = _Telemetry(args)
+    # the supervisor is control plane: what it computes itself (the
+    # net it builds to read the config, the final score) stays on the
+    # host CPU, and the accelerator belongs to the workers it spawns
+    jaxenv.keep_off_accelerator()
+    tele = _Telemetry(args, control_plane=True)
     if tele.metrics is not None:
         # announce BEFORE the run (cmd_train's contract): an
         # auto-assigned metrics port is useless once the run is over
@@ -364,7 +382,10 @@ def _cmd_train_elastic(args) -> int:
                              "NeuralNetWorkPerformer"),
             performer_conf={"conf_json": conf_json, "epochs": 1},
             n_workers=args.elastic, conf_json=conf_json,
-            spawner=WorkerSpawner(registry_root, run_name),
+            spawner=WorkerSpawner(
+                registry_root, run_name,
+                chips=(procs.ChipAllocator() if jaxenv.wants_tpu()
+                       else None)),
             checkpoint_dir=args.checkpoint_dir,
             resume=args.resume,
             max_respawns=args.max_respawns,
@@ -438,8 +459,10 @@ def cmd_predict(args) -> int:
 
 def cmd_serve(args) -> int:
     from deeplearning4j_tpu.serving.server import serve_network
+    from deeplearning4j_tpu.utils import jaxenv
 
     tele = _Telemetry(args)
+    jax_cache_entries = jaxenv.compile_cache_entries()
     try:
         # activate BEFORE model/engine construction so every jit the
         # serving stack builds goes through the AOT store
@@ -500,6 +523,11 @@ def cmd_serve(args) -> int:
     # page_size/... stay for older log parsers)
     loop = gen.decode_loop if gen is not None else None
     print(json.dumps({"serving": handle.url,
+                      # the device as JAX reports it, so a spawning
+                      # parent (fleet router, chip_smoke.py) can check
+                      # what this process computes on without touching
+                      # JAX itself
+                      "device": jaxenv.device_report(),
                       "role": args.role,
                       "model_id": args.model_id,
                       "replicas": len(handle.replicas.engines),
@@ -540,6 +568,9 @@ def cmd_serve(args) -> int:
                           },
                       },
                       "compile_cache": cache_dir,
+                      "jax_cache": {
+                          "dir": os.environ.get(jaxenv.CACHE_ENV),
+                          "entries_at_start": jax_cache_entries},
                       "warmup_plan": handle.warmup_plan_path,
                       "metrics": handle.url + "/metrics",
                       **tele.announce()}), flush=True)
@@ -603,7 +634,11 @@ def cmd_fleet(args) -> int:
                                                   ReplicaSpawner)
     from deeplearning4j_tpu.serving.router import (ReplicaClient,
                                                    serve_fleet)
+    from deeplearning4j_tpu.utils import jaxenv, procs
 
+    # on a TPU host every local replica gets its own chip; one
+    # allocator across all of this router's spawners
+    chips = procs.ChipAllocator() if jaxenv.wants_tpu() else None
     try:
         roles = _parse_roles(args.roles) if args.roles else {}
         models = _parse_models(args.models) if args.models else {}
@@ -625,11 +660,13 @@ def cmd_fleet(args) -> int:
         lo, _, hi = args.autoscale.partition(":")
         autoscaler = Autoscaler(min_replicas=int(lo),
                                 max_replicas=int(hi or lo))
-    # activate before the spawner snapshots its child environment: every
-    # replica (initial, autoscaled, respawned) inherits the warm cache
+    # export before the spawner snapshots its child environment: every
+    # replica (initial, autoscaled, respawned) inherits the warm cache.
+    # The router itself never opens it (that would take the device).
     _activate_compile_cache(
         getattr(args, "compile_cache", None),
-        args.model if args.model and os.path.isdir(args.model) else None)
+        args.model if args.model and os.path.isdir(args.model) else None,
+        children_only=True)
     spawner = None
     if not pooled and args.model \
             and (args.replicas > 0 or autoscaler is not None):
@@ -637,9 +674,9 @@ def cmd_fleet(args) -> int:
         # an explicit --serve-arg from the operator still wins (later
         # argparse occurrence overrides)
         spawner = ReplicaSpawner(
-            args.model,
+            args.model, chips=chips,
             serve_args=["--fleet-kv", args.fleet_kv] + args.serve_arg)
-    tele = _Telemetry(args)
+    tele = _Telemetry(args, control_plane=True)
     fleet = Fleet(spawner=spawner,
                   heartbeat_interval=args.heartbeat_interval,
                   heartbeat_timeout=args.heartbeat_timeout,
@@ -692,7 +729,7 @@ def cmd_fleet(args) -> int:
                             max_replicas=int(hi or lo))
                     fleet.add_pool(
                         model_id=mname, role=rname,
-                        spawner=ReplicaSpawner(mpath,
+                        spawner=ReplicaSpawner(mpath, chips=chips,
                                                serve_args=sargs),
                         autoscaler=pool_scaler)
                     have = sum(
@@ -1746,7 +1783,11 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv=None) -> int:
+    from deeplearning4j_tpu.utils import jaxenv
+
     args = build_parser().parse_args(argv)
+    # before any command can start a JAX back end or spawn a child
+    jaxenv.configure()
     return args.fn(args)
 
 

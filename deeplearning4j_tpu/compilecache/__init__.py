@@ -50,7 +50,8 @@ __all__ = [
     "ProgramStore", "AotCompiler", "AotDispatch",
     "config_digest", "key_digest", "runtime_fingerprint",
     "activate", "deactivate", "active_compiler", "active_dir",
-    "maybe_wrap", "export_env", "default_dir_for_checkpoints", "stats",
+    "maybe_wrap", "export_dir", "export_env",
+    "default_dir_for_checkpoints", "stats",
 ]
 
 log = logging.getLogger(__name__)
@@ -136,13 +137,28 @@ def maybe_wrap(jit_fn, key: Optional[str], *,
                        static_argnums=static_argnums)
 
 
+def export_dir(root: str) -> str:
+    """Hand the cache at `root` to FUTURE CHILDREN through the
+    environment without opening it here. Opening a store fingerprints
+    the device (`runtime_fingerprint` -> `jax.devices()`), and a
+    control-plane process — fleet router, elastic supervisor — must
+    stay off the accelerator its children need; they activate lazily
+    from the variable and take the fingerprint themselves."""
+    root = os.path.abspath(root)
+    os.environ[CACHE_ENV] = root
+    return root
+
+
 def export_env(env: dict) -> dict:
-    """Stamp the active cache dir into a child-process environment
-    (spawners call this; no-op when inactive or already set by the
-    caller). Returns `env` for chaining."""
-    comp = active_compiler()
-    if comp is not None and CACHE_ENV not in env:
-        env[CACHE_ENV] = comp.store.root
+    """Stamp this process's cache dir into a child-process environment
+    (spawners call this; no-op when there is none or the caller set
+    one). Never opens the store — see `export_dir`. Returns `env` for
+    chaining."""
+    with _lock:
+        root = (_compiler.store.root if _compiler is not None
+                else os.environ.get(CACHE_ENV))
+    if root and CACHE_ENV not in env:
+        env[CACHE_ENV] = root
     return env
 
 

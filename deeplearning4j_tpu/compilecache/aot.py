@@ -223,12 +223,10 @@ class AotDispatch:
           never invoked. Clearing costs recompiles for other live jits
           only if they re-trace, and this path runs at most once per
           poisoned program."""
-        try:
-            from jax._src import compilation_cache as jax_cc
-            from jax._src.config import enable_compilation_cache
-            from jax._src.interpreters import pxla
-        except Exception:
-            return None
+        from jax._src import compilation_cache as jax_cc
+        from jax._src.config import enable_compilation_cache
+        from jax._src.interpreters import pxla
+
         try:
             with enable_compilation_cache(False):
                 jax_cc.reset_cache()

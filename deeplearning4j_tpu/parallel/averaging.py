@@ -24,10 +24,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-try:  # jax>=0.4.35 exposes shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 from deeplearning4j_tpu.optimize.updater import NetworkGradientUpdater
 from deeplearning4j_tpu.parallel.mesh import DATA_AXIS, make_mesh

@@ -30,10 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.4.35 exposes shard_map at top level
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 EXPERT_AXIS = "expert"
 
@@ -236,7 +233,9 @@ def moe_grad_step(params, x, y, mesh: Mesh, axis: str = EXPERT_AXIS,
             out = moe_apply(p, x, mesh, axis, act, data_axis)
         return jnp.mean((out - y) ** 2)
 
-    loss, grads = jax.value_and_grad(loss_fn)(params)
+    # one compiled program: eagerly, every op inside the shard_map body
+    # is its own tiny multi-device dispatch
+    loss, grads = jax.jit(jax.value_and_grad(loss_fn))(params)
     params = jax.tree_util.tree_map(lambda p, g: p - lr * g, params, grads)
     return params, loss
 

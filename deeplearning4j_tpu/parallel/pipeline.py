@@ -26,10 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.4.35 exposes shard_map at top level
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 PIPE_AXIS = "pipe"
 
@@ -126,7 +123,9 @@ def pipeline_grad_step(params, xm, ym, mesh: Mesh, axis: str = PIPE_AXIS,
         out = pipeline_apply(p, xm, mesh, axis, act, data_axis)
         return jnp.mean((out - ym) ** 2)
 
-    loss, grads = jax.value_and_grad(loss_fn)(params)
+    # one compiled program: eagerly, every op inside the shard_map body
+    # is its own tiny multi-device dispatch
+    loss, grads = jax.jit(jax.value_and_grad(loss_fn))(params)
     params = jax.tree_util.tree_map(lambda p, g: p - lr * g, params, grads)
     return params, loss
 

@@ -1,9 +1,13 @@
 """ctypes loader for the native runtime library, with numpy fallbacks.
 
-Builds `libdl4j_native.so` from runtime/native/native.cpp on first use
-(g++ -O3 -shared -fPIC; ~1 s, cached next to the source). The CPython
-boundary is ctypes (pybind11 is not in the image — SURVEY environment
-notes), with buffer ownership handed to numpy via explicit free.
+`libdl4j_native.so` is a build product, never a tracked file: it is
+built from runtime/native/native.cpp on first use (g++ -O3 -shared
+-fPIC; ~1 s) and again whenever the source is newer, next to the
+source. Several processes may start at once on a fresh checkout (fleet
+replicas, elastic workers), so the build writes a private temporary
+file and renames it into place. The CPython boundary is ctypes
+(pybind11 is not in the image — SURVEY environment notes), with buffer
+ownership handed to numpy via explicit free.
 """
 
 from __future__ import annotations
@@ -31,13 +35,19 @@ _build_failed = False
 
 
 def _build() -> bool:
-    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", _SO,
+    tmp = f"{_SO}.{os.getpid()}.tmp"
+    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", tmp,
            "-pthread"]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        return True
+        os.replace(tmp, _SO)   # atomic: a concurrent loader never
+        return True            # maps a half-written library
     except (subprocess.SubprocessError, OSError) as e:
         log.warning("native build failed (%s); using numpy fallbacks", e)
+        try:
+            os.unlink(tmp)
+        except OSError:
+            pass
         return False
 
 

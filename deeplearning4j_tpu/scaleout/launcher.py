@@ -93,7 +93,9 @@ class MultiProcessMaster(DistributedRuntime):
             self.status_server = StatusServer(
                 self.tracker, runtime=self, host=host,
                 port=status_port, extra=status_extra,
-                health=status_health).start()
+                health=status_health,
+                # the workers are other processes and own the devices
+                device_gauges=False).start()
         run_conf = {
             TRACKER_ADDRESS: self.server.address,
             PERFORMER_CLASS: performer_class,

@@ -146,10 +146,14 @@ class StatusServer:
     def __init__(self, tracker, runtime=None, host: str = "127.0.0.1",
                  port: int = 0,
                  extra: Optional[Callable[[], Dict[str, Any]]] = None,
-                 health: Optional[Callable[[], Dict[str, Any]]] = None):
+                 health: Optional[Callable[[], Dict[str, Any]]] = None,
+                 device_gauges: bool = True):
         self.tracker = tracker
         self.runtime = runtime
         self.extra = extra
+        #: False in a process that supervises workers and must not take
+        #: their device (telemetry.exposition.metrics_payload)
+        self.device_gauges = device_gauges
         #: optional readiness verdict merged into /healthz: a dict whose
         #: "ok" key decides the status code (False -> 503). The training
         #: supervisor wires its quorum check here so a fleet scrape (or a
@@ -197,7 +201,8 @@ class StatusServer:
                             code = 200 if payload["ok"] else 503
                         else:
                             _, ctype, body = exposition.handle_metrics_get(
-                                self.path)
+                                self.path,
+                                device_gauges=outer.device_gauges)
                             code = 200
                     except Exception as e:
                         body = json.dumps({"error": repr(e)}).encode()

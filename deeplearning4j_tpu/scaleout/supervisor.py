@@ -138,9 +138,12 @@ class WorkerSpawner:
                  env_for: Optional[Callable[[str], dict]] = None,
                  python: Optional[str] = None,
                  heartbeat_interval: float = 0.05,
-                 reconnect_grace: float = 30.0):
+                 reconnect_grace: float = 30.0,
+                 chips: Optional[procs.ChipAllocator] = None):
         self.registry_root = str(registry_root)
         self.run_name = run_name
+        #: on a TPU host: confines each worker to its own chip
+        self.chips = chips
         self.reconnect_grace = float(reconnect_grace)
         base_env = dict(env) if env is not None else dict(os.environ)
         # the package must be importable in the child whatever cwd the
@@ -173,10 +176,11 @@ class WorkerSpawner:
         env = dict(self.env)
         if self.env_for is not None:
             env.update(self.env_for(worker_id) or {})
-        proc = subprocess.Popen(
-            self.command(worker_id), env=env, text=True,
-            stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL,
-            start_new_session=True)
+        kw = dict(text=True, stdout=subprocess.DEVNULL,
+                  stderr=subprocess.DEVNULL, start_new_session=True)
+        proc = (self.chips.popen(self.command(worker_id), env, **kw)
+                if self.chips is not None else
+                subprocess.Popen(self.command(worker_id), env=env, **kw))
         procs.register_spawned(proc)
         return proc
 

@@ -332,6 +332,8 @@ def main(argv=None) -> int:
                    help="initial reconnect backoff (doubles, capped)")
     args = p.parse_args(argv)
     logging.basicConfig(level=logging.INFO)
+    from deeplearning4j_tpu.utils import jaxenv
+    jaxenv.configure()
     performed = run_supervised_worker(
         registry_root=args.registry, run_name=args.run,
         worker_id=args.worker_id,

@@ -78,8 +78,8 @@ read inside the compiled step. "pallas" streams each slot's WRITTEN
 pages straight from the pool (`attention/paged_pallas.py` — per-step
 KV traffic O(written pages)); "gather" materializes the dense
 `S × max_len` window (the legacy path, O(reservation)). "auto"
-resolves ONCE at construction — the kernel on TPU inside its
-calibrated envelope, gather everywhere else (never a silent
+resolves ONCE at construction — the kernel on TPU inside the
+envelope checked on a chip, gather everywhere else (never a silent
 interpret-mode slowdown off-TPU) — so the step stays one compiled
 program either way. Both figures are exported every dispatch as
 dl4j_decode_kv_read_bytes{path="kernel"|"gather"} so the traffic win

@@ -15,7 +15,7 @@ params are NOT donated — they serve every request.
 
 Observability is first-class (`EngineStats`): requests, rows, batch
 occupancy, p50/p99 wall latency (each timed window ends with the D2H
-read of the result — the honest protocol from BASELINE.md), and the
+read of the result, so it covers the device work), and the
 program-cache counter that pins "ragged stream compiles <= one program
 per bucket" in tests and bench.
 """
@@ -49,8 +49,8 @@ class EngineStats:
     just the typed accessor — the same numbers appear in `/metrics`, in
     `/stats`, and here, with no second code path. Latency percentiles
     come from the histogram's bounded reservoir; each timed window ends
-    with the D2H read of the result (the honest protocol from
-    BASELINE.md). Note `telemetry.set_enabled(False)` blanks recording
+    with the D2H read of the result, so it covers the device work.
+    Note `telemetry.set_enabled(False)` blanks recording
     here too — the registry IS the storage.
     """
 

@@ -341,11 +341,15 @@ class ReplicaSpawner:
                  serve_args: Sequence[str] = (),
                  env: Optional[dict] = None,
                  python: Optional[str] = None,
-                 announce_timeout: float = 180.0):
+                 announce_timeout: float = 180.0,
+                 chips: Optional[procs.ChipAllocator] = None):
         self.model_path = str(model_path)
         self.host = host
         self.serve_args = list(serve_args)
         self.env = dict(env) if env is not None else dict(os.environ)
+        #: on a TPU host: confines each replica to its own chip
+        #: (shared by every spawner of one router)
+        self.chips = chips
         # replicas inherit the parent's AOT program cache so respawns
         # and autoscale spin-ups boot warm (docs/WARMUP.md)
         from deeplearning4j_tpu import compilecache
@@ -365,10 +369,11 @@ class ReplicaSpawner:
         replica announces fast (async warmup) — readiness is gated by
         its /readyz, not by this call. The process gets its own
         session/group and is registered for atexit orphan cleanup."""
-        proc = subprocess.Popen(
-            self.command(port), env=self.env, text=True,
-            stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-            start_new_session=True)
+        kw = dict(text=True, stdout=subprocess.PIPE,
+                  stderr=subprocess.STDOUT, start_new_session=True)
+        proc = (self.chips.popen(self.command(port), self.env, **kw)
+                if self.chips is not None else
+                subprocess.Popen(self.command(port), env=self.env, **kw))
         _register_spawned(proc)
         try:
             url = self._read_announce(proc)

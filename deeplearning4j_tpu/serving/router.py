@@ -379,7 +379,8 @@ def serve_fleet(fleet, host: str = "127.0.0.1",
                             time.time() - handle.started_at, 3),
                         "fleet": fleet.snapshot()})
                 elif (hit := exposition.handle_metrics_get(
-                        self.path)) is not None:
+                        self.path, device_gauges=False)) is not None:
+                    # the router holds no device; its replicas do
                     self._reply_raw(*hit)
                 else:
                     self._reply(404, {"error": f"no route {self.path}"})

@@ -20,7 +20,9 @@ module makes both one scrape away:
   "counter unavailable", never a fake 0.
 
 `install()` is idempotent and cheap; `exposition.metrics_payload` calls
-it so any /metrics mount gets device series without extra wiring.
+it so a /metrics mount in a process that computes gets device series
+without extra wiring. It starts the JAX back end if nothing has yet, so
+control-plane processes mount /metrics with `device_gauges=False`.
 """
 
 from __future__ import annotations
@@ -95,12 +97,11 @@ def install(registry: Optional[MetricsRegistry] = None) -> None:
     reg = registry if registry is not None else get_registry()
     if reg in _installed_on:
         return
-    try:
-        import jax
+    import jax
 
-        devices = jax.local_devices()
-    except Exception:
-        return  # no backend yet: try again at the next scrape
+    # a back end that refuses to start is the scrape's error to report,
+    # not something to render as "no devices"
+    devices = jax.local_devices()
     _installed_on.add(reg)
 
     reg.gauge("dl4j_device_count",

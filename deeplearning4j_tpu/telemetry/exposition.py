@@ -98,23 +98,30 @@ def snapshot(registry: Optional[MetricsRegistry] = None) -> dict:
     return reg.snapshot()
 
 
-def metrics_payload(registry: Optional[MetricsRegistry] = None):
+def metrics_payload(registry: Optional[MetricsRegistry] = None,
+                    device_gauges: bool = True):
     """(body_bytes, content_type) for a /metrics response. Samples the
     device gauges (telemetry.device) so HBM pressure and recompile
-    counters are one scrape away without a background sampler."""
-    from deeplearning4j_tpu.telemetry import device
+    counters are one scrape away without a background sampler.
 
-    device.install(registry)
+    `device_gauges=False` is for control-plane processes (fleet
+    router, elastic supervisor): sampling asks JAX for its devices,
+    which would take the chip their children compute on."""
+    if device_gauges:
+        from deeplearning4j_tpu.telemetry import device
+
+        device.install(registry)
     return render_prometheus(registry).encode(), CONTENT_TYPE
 
 
 def handle_metrics_get(path: str,
-                       registry: Optional[MetricsRegistry] = None):
+                       registry: Optional[MetricsRegistry] = None,
+                       device_gauges: bool = True):
     """Shared route logic for embedded servers: returns
     (code, content_type, body_bytes) for /metrics and /snapshot paths,
     or None when the path is not a telemetry route."""
     if path.startswith("/metrics"):
-        body, ctype = metrics_payload(registry)
+        body, ctype = metrics_payload(registry, device_gauges)
         return 200, ctype, body
     if path.startswith("/snapshot"):
         body = json.dumps(snapshot(registry)).encode()
@@ -123,11 +130,12 @@ def handle_metrics_get(path: str,
 
 
 def start_metrics_server(host: str = "127.0.0.1", port: int = 0,
-                         registry: Optional[MetricsRegistry] = None):
+                         registry: Optional[MetricsRegistry] = None,
+                         device_gauges: bool = True):
     """Standalone /metrics + /snapshot endpoint on the shared
     utils/httpd.py lifecycle (daemon thread, port-0 auto-assign,
     graceful close). Returns the ServerHandle; the caller owns
-    close()."""
+    close(). `device_gauges`: see `metrics_payload`."""
     from http.server import BaseHTTPRequestHandler
 
     from deeplearning4j_tpu.utils.httpd import start_http_server
@@ -138,7 +146,8 @@ def start_metrics_server(host: str = "127.0.0.1", port: int = 0,
 
         def do_GET(self):
             try:
-                hit = handle_metrics_get(self.path, registry)
+                hit = handle_metrics_get(self.path, registry,
+                                         device_gauges)
                 if hit is None:
                     code, ctype, body = 404, "text/plain", b"not found"
                 else:

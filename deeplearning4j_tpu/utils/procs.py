@@ -42,7 +42,7 @@ from typing import Optional, Tuple
 __all__ = ["register_spawned", "unregister_spawned", "release_spawned",
            "kill_spawned_orphans", "stop_process_group",
            "proc_start_time", "pid_matches", "classify_pid",
-           "AdoptedProc", "SPAWNED_PROCS"]
+           "AdoptedProc", "SPAWNED_PROCS", "ChipAllocator"]
 
 #: spawned session-leader processes still alive (shared registry)
 SPAWNED_PROCS: set = set()
@@ -62,6 +62,54 @@ def register_spawned(proc) -> None:
 def unregister_spawned(proc) -> None:
     with _lock:
         SPAWNED_PROCS.discard(proc)
+
+
+class ChipAllocator:
+    """One local TPU chip per spawned child, by index.
+
+    A chip belongs to one process at a time, and a child started with
+    the parent's environment unchanged asks libtpu for EVERY local chip
+    — so the second replica or worker on a host fails or hangs. The
+    control-plane command creates one allocator (when its platform
+    names a TPU) and hands it to its spawners; each spawn takes the
+    lowest index no live child holds and starts the child under
+    `utils.jaxenv.chip_env(index)`. The allocator does not know how
+    many chips the host has and does not ask (that would mean touching
+    JAX here): a child given an index past the last chip fails at
+    start-up with libtpu's own reason, which the spawner reports."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._held: dict = {}   # index -> proc (None while spawning)
+
+    def _acquire(self) -> int:
+        with self._lock:
+            for index, proc in list(self._held.items()):
+                if proc is not None and proc.poll() is not None:
+                    del self._held[index]
+            index = 0
+            while index in self._held:
+                index += 1
+            self._held[index] = None
+            return index
+
+    def popen(self, argv, env: dict, **kwargs):
+        """`subprocess.Popen(argv, env=..., **kwargs)` with the child
+        confined to the chip it was just given; the chip is free again
+        when the child exits (or at once if it never starts)."""
+        from deeplearning4j_tpu.utils import jaxenv
+
+        index = self._acquire()
+        try:
+            proc = subprocess.Popen(
+                argv, env={**env, **jaxenv.chip_env(index)}, **kwargs)
+        except BaseException:
+            with self._lock:
+                del self._held[index]
+            raise
+        with self._lock:
+            self._held[index] = proc
+        return proc
 
 
 def release_spawned(proc) -> None:

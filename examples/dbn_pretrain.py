@@ -9,6 +9,9 @@ import numpy as np
 from deeplearning4j_tpu.config import NeuralNetConfiguration
 from deeplearning4j_tpu.datasets.mnist import synthetic_mnist
 from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+from deeplearning4j_tpu.utils import jaxenv
+
+jaxenv.configure()  # compile cache + platform pin, before JAX starts
 
 FAST = os.environ.get("DL4J_TPU_EXAMPLE_FAST") == "1"
 

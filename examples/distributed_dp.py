@@ -16,6 +16,9 @@ from deeplearning4j_tpu.parallel import DataParallelTrainer
 from deeplearning4j_tpu.scaleout import (CollectionJobIterator,
                                          DistributedRuntime,
                                          NeuralNetWorkPerformer)
+from deeplearning4j_tpu.utils import jaxenv
+
+jaxenv.configure()  # compile cache + platform pin, before JAX starts
 
 conf = (NeuralNetConfiguration.builder()
         .lr(0.1).n_in(4).activation_function("tanh")

@@ -11,6 +11,9 @@ from deeplearning4j_tpu.attention.blockwise import blockwise_attention
 from deeplearning4j_tpu.attention.flash_pallas import flash_attention
 from deeplearning4j_tpu.attention.ring import ring_attention
 from deeplearning4j_tpu.parallel import make_mesh
+from deeplearning4j_tpu.utils import jaxenv
+
+jaxenv.configure()  # compile cache + platform pin, before JAX starts
 
 B, H, S, D = 2, 4, 1024, 64
 key = jax.random.PRNGKey(0)

@@ -14,6 +14,9 @@ from deeplearning4j_tpu.datasets.mnist import synthetic_mnist
 from deeplearning4j_tpu.eval import Evaluation
 from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
 from deeplearning4j_tpu.optimize import ScoreIterationListener
+from deeplearning4j_tpu.utils import jaxenv
+
+jaxenv.configure()  # compile cache + platform pin, before JAX starts
 
 conf = (NeuralNetConfiguration.builder()
         .lr(1.0)  # adagrad master step size (reference masterStepSize)

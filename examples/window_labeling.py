@@ -20,6 +20,9 @@ from deeplearning4j_tpu.nlp.windows import (annotate_windows,
                                             string_with_labels,
                                             window_as_vector)
 from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+from deeplearning4j_tpu.utils import jaxenv
+
+jaxenv.configure()  # compile cache + platform pin, before JAX starts
 
 # 1. span-labeled corpus (ContextLabel markup): the task is labeling
 #    each window as describing an ANIMAL or ROYAL context

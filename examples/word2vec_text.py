@@ -2,6 +2,9 @@
 classification bridge (reference Word2Vec + Word2VecDataSetIterator)."""
 from deeplearning4j_tpu.nlp import (LabelAwareSentenceIterator, Word2Vec,
                                     Word2VecDataSetIterator)
+from deeplearning4j_tpu.utils import jaxenv
+
+jaxenv.configure()  # compile cache + platform pin, before JAX starts
 
 corpus = ["the cat sat on the mat", "the dog sat on the rug",
           "the cat and the dog play in the yard",

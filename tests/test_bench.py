@@ -33,7 +33,7 @@ def run_main(monkeypatch, configs, env=None, platform="tpu"):
     lines = []
     monkeypatch.setattr("builtins.print",
                         lambda s, **kw: lines.append(str(s)))
-    bench.main()
+    run_main.exit_code = bench.main()
     return [json.loads(ln) for ln in lines]
 
 
@@ -62,12 +62,19 @@ class TestHistory:
 class TestMain:
     def test_pins_are_per_platform(self, hist_path, monkeypatch):
         cfg = {"mlp": lambda: {"value": 100.0, "unit": "u"}}
-        run_main(monkeypatch, cfg, platform="cpu")
+        cpu_lines = run_main(monkeypatch, cfg, platform="cpu")
         lines = run_main(monkeypatch, cfg, platform="tpu")
         hist = json.loads(hist_path.read_text())
         assert hist["baselines"]["cpu"]["mlp"] == 100.0
         assert hist["baselines"]["tpu"]["mlp"] == 100.0
         assert lines[-1]["vs_baseline"] == 1.0
+        # a run that did not measure the device says so FIRST, on every
+        # line; a TPU run carries no such key
+        assert all(next(iter(ln)) == "not_a_device_measurement"
+                   and "platform=cpu" in ln["not_a_device_measurement"]
+                   for ln in cpu_lines)
+        assert all("not_a_device_measurement" not in ln for ln in lines)
+        assert run_main.exit_code == 0
 
     def test_vs_baseline_lower_is_better(self, hist_path, monkeypatch):
         vals = iter([2.0, 1.0])
@@ -99,6 +106,25 @@ class TestMain:
         assert last["vs_baseline"] is None  # never 1.0 for a missing run
         assert "kaput" in json.dumps(last["extra"]) or "kaput" in str(last)
         assert last["extra"]["ok"]["value"] == 3.0
+        assert run_main.exit_code == 1  # an errored config fails the run
+
+    def test_child_spawning_configs_cannot_run_under_a_held_chip(
+            self, hist_path, monkeypatch):
+        """bench.py touches JAX, so on a TPU it holds the chip its
+        `cli serve` children would need: those configs are left out of
+        the default selection there and error when named."""
+        ran = []
+        cfg = {"mlp": lambda: {"value": 1.0, "unit": "u"},
+               "fleet": lambda: ran.append("fleet") or {"value": 2.0}}
+        lines = run_main(monkeypatch, cfg, platform="tpu")
+        assert "fleet" not in lines[-1]["extra"] and not ran
+        assert run_main.exit_code == 0
+        lines = run_main(monkeypatch, cfg, platform="tpu",
+                         env={"BENCH_CONFIGS": "mlp,fleet"})
+        assert "holds" in lines[-1]["extra"]["fleet"]["error"] and not ran
+        assert run_main.exit_code == 1
+        run_main(monkeypatch, cfg, platform="cpu")
+        assert ran == ["fleet"]
 
     def test_budget_skips_not_yet_started(self, hist_path, monkeypatch):
         cfg = {"mlp": lambda: {"value": 1.0, "unit": "u"},

@@ -206,9 +206,24 @@ def test_train_resume_auto_without_checkpoint_dir_refuses(
 
 
 @pytest.mark.elastic
-def test_train_elastic_smoke(tmp_path, iris_csv, capsys):
+def test_train_elastic_smoke(tmp_path, iris_csv, capsys, monkeypatch):
     """`train --elastic N` drives the TrainingSupervisor end to end
-    from the CLI: N spawned workers, every job folded, model saved."""
+    from the CLI: N spawned workers, every job folded, model saved.
+    The supervisor process is control plane: it pins itself to the
+    host CPU before it builds anything and never asks JAX for its
+    devices — on a TPU host they belong to the workers."""
+    import jax
+
+    from deeplearning4j_tpu.utils import jaxenv
+
+    def refuse(*a, **kw):
+        raise AssertionError("the supervisor asked JAX for its devices")
+
+    monkeypatch.setattr(jax, "devices", refuse)
+    monkeypatch.setattr(jax, "local_devices", refuse)
+    pinned = []
+    monkeypatch.setattr(jaxenv, "keep_off_accelerator",
+                        lambda: pinned.append(jax.config.jax_platforms))
     conf = (NeuralNetConfiguration.builder()
             .lr(0.1).n_in(4).activation_function("tanh")
             .optimization_algo("iteration_gradient_descent")
@@ -231,3 +246,4 @@ def test_train_elastic_smoke(tmp_path, iris_csv, capsys):
     assert summary["workers"] == 2
     assert summary["folded"] == summary["jobs"] == 3  # ceil(150/50)
     assert summary["respawns"] == 0
+    assert pinned, "the supervisor did not keep itself off the accelerator"

@@ -683,3 +683,49 @@ class TestCLIFleet:
 
         assert main(["fleet", "--replicas", "0"]) == 2
         assert "fleet needs" in capsys.readouterr().err
+
+    def test_router_process_never_asks_jax_for_its_devices(
+            self, monkeypatch, tmp_path, capsys):
+        """A chip belongs to one process, and the router's replicas
+        need it: `cli fleet --compile-cache` must hand the cache to its
+        children without opening it (opening fingerprints the device),
+        and a router `/metrics` scrape must not sample device gauges."""
+        import jax
+
+        from deeplearning4j_tpu import compilecache
+        from deeplearning4j_tpu.cli import main
+
+        handle = serve_network(_net(), n_replicas=1, max_delay_ms=1.0,
+                               warmup_shape=(4,))
+
+        def refuse(*a, **kw):
+            raise AssertionError("the router asked JAX for its devices")
+
+        monkeypatch.setattr(jax, "devices", refuse)
+        monkeypatch.setattr(jax, "local_devices", refuse)
+        cache = str(tmp_path / "programs")
+        fleet = Fleet(heartbeat_interval=0.1)
+        router = None
+        try:
+            assert main(["fleet", "--attach", handle.url, "--replicas",
+                         "0", "--smoke", "--compile-cache", cache,
+                         "--heartbeat-interval", "0.1"]) == 0
+            capsys.readouterr()
+            # exported for the replicas, not opened by the router
+            assert os.environ[compilecache.CACHE_ENV] == cache
+            assert not os.path.exists(cache)
+            spawner = ReplicaSpawner("model.ckpt")
+            assert spawner.env[compilecache.CACHE_ENV] == cache
+            fleet.attach(handle.url)
+            router = serve_fleet(fleet)
+            with urllib.request.urlopen(router.url + "/metrics",
+                                        timeout=30) as resp:
+                assert resp.status == 200
+                assert "dl4j_fleet" in resp.read().decode()
+        finally:
+            compilecache.deactivate()
+            if router is not None:
+                router.close()
+            else:
+                fleet.close()
+            handle.close()

@@ -107,7 +107,7 @@ class TestStatusServer:
         connection."""
         from deeplearning4j_tpu.scaleout import status as status_mod
 
-        def boom(path, registry=None):
+        def boom(path, registry=None, device_gauges=True):
             raise RuntimeError("render kaput")
 
         monkeypatch.setattr(status_mod.exposition, "handle_metrics_get",

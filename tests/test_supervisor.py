@@ -99,6 +99,43 @@ class TestWorkerSpawner:
         assert sp.env_for("w1") == {"X_DRILL": "w1"}
         assert sp.env_for("w1r1") == {}
 
+    def test_each_child_gets_its_own_chip_by_index(self, tmp_path,
+                                                   monkeypatch):
+        """On a TPU host a child started with the parent's environment
+        asks for every chip: with an allocator, spawn N confines worker
+        N to the lowest chip index no live child holds, and a dead
+        child's chip is handed out again."""
+        from deeplearning4j_tpu.scaleout import supervisor as sup_mod
+        from deeplearning4j_tpu.utils import procs
+
+        started = []
+
+        class FakeProc:
+            def __init__(self, cmd, env=None, **kw):
+                self.env, self.dead, self.pid = env, False, 0
+                started.append(self)
+
+            def poll(self):
+                return 0 if self.dead else None
+
+        monkeypatch.setattr(sup_mod.subprocess, "Popen", FakeProc)
+        monkeypatch.setattr(procs, "register_spawned", lambda p: None)
+        sp = WorkerSpawner(str(tmp_path), "run1",
+                           chips=procs.ChipAllocator())
+        for wid in ("w0", "w1", "w2"):
+            sp.spawn(wid)
+        assert [p.env["TPU_VISIBLE_CHIPS"] for p in started] == [
+            "0", "1", "2"]
+        assert all(p.env["TPU_PROCESS_BOUNDS"] == "1,1,1"
+                   and p.env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+                   for p in started)
+        started[1].dead = True
+        sp.spawn("w1r1")
+        assert started[-1].env["TPU_VISIBLE_CHIPS"] == "1"
+        # without an allocator (CPU hosts, tests) nothing is confined
+        WorkerSpawner(str(tmp_path), "run1").spawn("w9")
+        assert "TPU_VISIBLE_CHIPS" not in started[-1].env
+
 
 class TestProgressListener:
     def test_lines_drive_alive_and_progress_eof_drives_gone(self):

@@ -432,3 +432,52 @@ class TestInterop:
             interop.from_labeled_points([(5, [1.0])], num_labels=3)
         with pytest.raises(ValueError, match="no labeled points"):
             interop.from_labeled_points([], num_labels=3)
+
+
+class TestJaxEnv:
+    """utils/jaxenv.py: the compile-cache and platform rules every entry
+    point applies. Run in fresh interpreters — both rules are about
+    what is true BEFORE JAX starts."""
+
+    @staticmethod
+    def _run(code, **env):
+        import os
+        import subprocess
+        import sys
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        full = {k: v for k, v in os.environ.items()
+                if k not in ("JAX_COMPILATION_CACHE_DIR", "JAX_PLATFORMS")}
+        full.update(env)
+        out = subprocess.run([sys.executable, "-c", code], cwd=repo,
+                             env=full, capture_output=True, text=True,
+                             timeout=120)
+        assert out.returncode == 0, out.stderr
+        return out.stdout.strip().splitlines()
+
+    def test_cache_dir_is_taken_from_outside_or_fixed_in_the_checkout(self):
+        import os
+
+        code = ("import os, jax; from deeplearning4j_tpu.utils import jaxenv; "
+                "jaxenv.configure(); "
+                "print(os.environ['JAX_COMPILATION_CACHE_DIR']); "
+                "print(jax.config.jax_compilation_cache_dir); "
+                "print(os.environ['JAX_PLATFORMS'])")
+        # set from outside: used as is, nothing else set in code
+        assert self._run(code, JAX_COMPILATION_CACHE_DIR="/x",
+                         JAX_PLATFORMS="cpu") == ["/x", "/x", "cpu"]
+        # unset: one fixed path inside the checkout, exported for the
+        # children; and with libtpu installed the platform is pinned so
+        # a missing chip cannot become a quiet CPU run
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        fixed = os.path.join(repo, ".jax_program_cache")
+        assert self._run(code) == [fixed, fixed, "tpu,cpu"]
+
+    def test_control_plane_pin_keeps_a_process_off_the_accelerator(self):
+        """`tpu,cpu` fails loudly here (no chip), so reaching the CPU
+        devices proves the TPU back end was never tried."""
+        code = ("from deeplearning4j_tpu.utils import jaxenv; "
+                "jaxenv.keep_off_accelerator(); import jax, os; "
+                "print(jax.devices()[0].platform); "
+                "print(os.environ['JAX_PLATFORMS'])")
+        assert self._run(code, JAX_PLATFORMS="tpu,cpu") == ["cpu", "tpu,cpu"]
