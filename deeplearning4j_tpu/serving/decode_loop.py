@@ -135,6 +135,18 @@ dl4j_decode_tokens_streamed / dl4j_decode_admission_waits /
 dl4j_kv_prefix_{hits,misses,forks,evictions} /
 dl4j_decode_kv_read_bytes{path} counters, dl4j_decode_step_seconds
 histogram (docs/OBSERVABILITY.md).
+
+**Spans** (`telemetry.span`, names in `PHASES`): every scheduler pass
+is one `decode.tick` whose children are the phases of the pass, so each
+nanosecond of a pass lies in exactly one child or in the tick's self
+time. Their seconds and counts are always on
+(dl4j_decode_phase_seconds{loop,phase}, `snapshot()["phases"]`), the
+longest pass of each of the last `SLOW_TICKS_KEPT` intervals is kept
+with what it was made of (`snapshot()["slow_ticks"]`), and every span is
+a TraceMe of the same name, so a `jax.profiler` window holds the
+scheduler's phases on the device trace's clock. A request carries four
+stamps of its own life (`GenerationStream.timeline()`); the wait in the
+queue also feeds dl4j_decode_queue_wait_seconds.
 """
 
 from __future__ import annotations
@@ -174,6 +186,8 @@ from deeplearning4j_tpu.serving.paged_kv import (copy_page,
                                                  prompt_buckets)
 from deeplearning4j_tpu.serving.prefix_cache import PrefixIndex
 from deeplearning4j_tpu.serving.speculation import build_drafter
+from deeplearning4j_tpu.telemetry.trace import (PhaseTotals, active_tracer,
+                                               span)
 from deeplearning4j_tpu.testing import chaos
 from deeplearning4j_tpu.utils.jitcache import jit_cache_size
 
@@ -194,6 +208,23 @@ ROLE_UNIFIED = "unified"
 ROLE_PREFILL = "prefill"
 ROLE_DECODE = "decode"
 ROLES = (ROLE_UNIFIED, ROLE_PREFILL, ROLE_DECODE)
+
+#: the scheduler's spans. `decode.tick` is one pass; `decode.idle_wait`
+#: is the scheduler thread asleep between passes; `decode.prefill_dispatch`
+#: is a child of `decode.admit`; every other one is a child of the tick.
+TICK = "decode.tick"
+IDLE_WAIT = "decode.idle_wait"
+PREFILL_DISPATCH = "decode.prefill_dispatch"
+PHASES = (IDLE_WAIT, TICK, "decode.reap", "decode.kv_jobs", "decode.admit",
+          PREFILL_DISPATCH, "decode.grant_pages", "decode.upload",
+          "decode.draft", "decode.step_dispatch", "decode.d2h",
+          "decode.account", "decode.flush_first", "decode.emit")
+
+#: `snapshot()["slow_ticks"]` holds the longest pass of each of the last
+#: SLOW_TICKS_KEPT intervals of SLOW_TICK_INTERVAL_S seconds: about the
+#: last minute of service, however long start-up's passes were
+SLOW_TICKS_KEPT = 8
+SLOW_TICK_INTERVAL_S = 8
 
 #: per-queued-item service estimate feeding the backlog-derived
 #: Retry-After on a tier shed: interactive items are short user turns,
@@ -245,6 +276,14 @@ class GenerationStream:
         #: `token_index`, which is the router's exactly-once dedupe key
         #: (docs/SERVING.md "Streaming", docs/FLEET.md failover)
         self.token_index_base = 0
+        #: the loop's count of this request, and the four stamps of its
+        #: life on `time.perf_counter` (None until reached): see
+        #: `timeline()`
+        self.request_id: Optional[int] = None
+        self.submitted: Optional[float] = None
+        self.admitted: Optional[float] = None
+        self.first_token: Optional[float] = None
+        self.finished: Optional[float] = None
         self.finish_reason: Optional[str] = None
         self.error: Optional[BaseException] = None
         self._generated: List[int] = []
@@ -255,15 +294,56 @@ class GenerationStream:
 
     # ------------------------------------------------- scheduler side
     def _emit(self, token: int) -> None:
+        if self.first_token is None:
+            self.first_token = time.perf_counter()
         self._generated.append(int(token))
         self._q.put(int(token))
 
     def _finish(self, reason: str,
                 error: Optional[BaseException] = None) -> None:
+        self.finished = time.perf_counter()
         self.finish_reason = reason
         self.error = error
+        tracer = active_tracer()
+        if tracer is not None:
+            self._record_life(tracer)
         self._q.put(_DONE)
         self._done.set()
+
+    def _record_life(self, tracer) -> None:
+        """The stages of this request as spans that share `request`:
+        `request.queued`, `request.prefill` (admitted to first token)
+        and `request.decode`, children of one `request`, on a lane of
+        the trace of their own. A stage the request never reached is
+        left out; the queue's ends where the request did."""
+        loop = self._loop_ref() if self._loop_ref is not None else None
+        args = {"request": self.request_id, "finish": self.finish_reason,
+                "loop": None if loop is None else loop.label}
+        lane = self.request_id or 0
+
+        def ns(t: float) -> int:
+            return int(t * 1e9)
+
+        start, end = ns(self.submitted or self.finished), ns(self.finished)
+        root = tracer.add("request", start, end, thread_id=lane, **args)
+        stages = (("request.queued", self.submitted, self.admitted),
+                  ("request.prefill", self.admitted, self.first_token),
+                  ("request.decode", self.first_token, self.finished))
+        for name, t0, t1 in stages:
+            if t0 is None:
+                break
+            tracer.add(name, ns(t0), end if t1 is None else ns(t1),
+                       parent_id=root, thread_id=lane, **args)
+
+    def timeline(self) -> dict:
+        """The request's life on `time.perf_counter`: when it was
+        enqueued (`submitted`), when a pass of the scheduler claimed its
+        slot (`admitted`), when its first token was emitted
+        (`first_token`) and when it finished; None for what has not
+        happened, or never did."""
+        return {"request_id": self.request_id,
+                "submitted": self.submitted, "admitted": self.admitted,
+                "first_token": self.first_token, "finished": self.finished}
 
     # --------------------------------------------------- client side
     def tokens(self, timeout: Optional[float] = None) -> Iterator[int]:
@@ -710,6 +790,26 @@ class DecodeLoop:
             "wall time of one compiled decode dispatch (covers "
             "`horizon` token steps), dispatch through the token D2H "
             "sync").labels(**lab)
+        self._phases = PhaseTotals(reg.histogram(
+            "dl4j_decode_phase_seconds",
+            "wall time of the scheduler's spans by phase: decode.tick "
+            "is one pass, the other decode.* are what a pass is made "
+            "of, decode.idle_wait is the scheduler asleep between "
+            "passes"), PHASES, **lab)
+        self._m_queue_wait = reg.histogram(
+            "dl4j_decode_queue_wait_seconds",
+            "time a generate request waited in the admission queue, "
+            "submit to the scheduler pass that claimed its slot"
+        ).labels(**lab)
+        self._m_prefill_passes = reg.counter(
+            "dl4j_decode_prefill_passes",
+            "scheduler passes that ran a decode dispatch and at least "
+            "one prefill: the token gaps a prefill lengthened"
+        ).labels(**lab)
+        self._request_ids = itertools.count()
+        #: ring of the longest pass per interval (snapshot()
+        #: ["slow_ticks"]); slot k holds interval number k mod its size
+        self._slow_ticks: List[Optional[dict]] = [None] * SLOW_TICKS_KEPT
         reg.gauge(
             "dl4j_kv_pages_total",
             "usable KV pages in the block pool").labels(**lab).set(
@@ -911,7 +1011,10 @@ class DecodeLoop:
                             tier_q + len(prompts),
                             _TIER_ITEM_MS[tier]),
                         tier=tier)
+            now = time.perf_counter()
             for stream in streams:
+                stream.request_id = next(self._request_ids)
+                stream.submitted = now
                 self._m_requests.inc()
                 self._m_tier_requests[tier].inc()
                 self._waiting.append(stream)
@@ -1395,7 +1498,8 @@ class DecodeLoop:
                 if not self._kv_jobs:
                     return
                 job = self._kv_jobs.popleft()
-            self._run_kv_job(job)
+            with span("decode.kv_jobs", self._phases):
+                self._run_kv_job(job)
 
     def _run_kv_job(self, job: dict) -> None:
         try:
@@ -1499,6 +1603,13 @@ class DecodeLoop:
                 "cancelled": int(self._m_cancelled.value),
                 "admission_waits": int(self._m_waits.value),
                 "dispatches": int(self._m_steps.value),
+                "prefill_passes": int(self._m_prefill_passes.value),
+                "phases": self._phases.totals(),
+                "queue_wait": {"seconds": self._m_queue_wait.sum,
+                               "count": self._m_queue_wait.count},
+                "slow_ticks": sorted(
+                    (t for t in self._slow_ticks if t is not None),
+                    key=lambda t: t["start_s"]),
                 "decode_kernel": {
                     "requested": self.kernel_requested,
                     "selected": self.decode_kernel,
@@ -1562,13 +1673,19 @@ class DecodeLoop:
         self.close()
 
     # ------------------------------------------------------ scheduler
+    def _idle(self) -> bool:
+        """Nothing queued, installed or in flight. Caller holds the
+        lock."""
+        return (not self._closed and not self._waiting
+                and not self._kv_jobs and self.occupied_slots == 0)
+
     def _run(self) -> None:
         while True:
             with self._cond:
-                while (not self._closed and not self._waiting
-                       and not self._kv_jobs
-                       and self.occupied_slots == 0):
-                    self._cond.wait(timeout=0.1)
+                if self._idle():
+                    with span(IDLE_WAIT, self._phases):
+                        while self._idle():
+                            self._cond.wait(timeout=0.1)
                 if (self._closed and not self._waiting
                         and self.occupied_slots == 0):
                     self._drain_kv_jobs(
@@ -1603,7 +1720,39 @@ class DecodeLoop:
         retire finished slots. Returns True if a dispatch ran. Public so
         tests (and `start=False` callers) can drive the loop
         deterministically."""
-        self._reap()
+        phases = self._phases
+        phases.begin_pass()
+        with span(TICK, phases) as tick:
+            ran = tick.args["dispatched"] = self._pass()
+        if ran and phases.pass_ns[PREFILL_DISPATCH]:
+            self._m_prefill_passes.inc()
+        self._keep_if_slowest(tick)
+        return ran
+
+    def _keep_if_slowest(self, tick) -> None:
+        """Keep this pass if it is the longest of its interval so far:
+        its start on `time.perf_counter`, its length, and the
+        milliseconds of each phase that ran in it (what they leave of
+        the length is the tick's self time). A pass that is not
+        allocates nothing."""
+        start_s, dur_ms = tick.start_ns / 1e9, tick.dur_ns / 1e6
+        interval = int(start_s // SLOW_TICK_INTERVAL_S)
+        at = interval % SLOW_TICKS_KEPT
+        kept = self._slow_ticks[at]
+        if (kept is not None and kept["dur_ms"] >= dur_ms
+                and int(kept["start_s"] // SLOW_TICK_INTERVAL_S)
+                == interval):
+            return
+        self._slow_ticks[at] = {
+            "start_s": start_s, "dur_ms": dur_ms,
+            "phases": {n: ns / 1e6
+                       for n, ns in self._phases.pass_ns.items()
+                       if ns and n != TICK}}
+
+    def _pass(self) -> bool:
+        """The body of `tick()`."""
+        with span("decode.reap", self._phases):
+            self._reap()
         # shipped-page installs land before admission so the very next
         # `_admit` match sees them as cached chunks
         self._service_kv_jobs()
@@ -1612,7 +1761,8 @@ class DecodeLoop:
         # preemption to observably fire); an "error" drills the
         # fail-loudly path in _run
         chaos.hit("decode.step")
-        self._admit()
+        with span("decode.admit", self._phases) as admit:
+            admit.args["admitted"] = self._admit()
         ran = self._dispatch()
         if not ran:
             # no chunk ran (e.g. every admitted request has
@@ -1701,12 +1851,15 @@ class DecodeLoop:
         used.discard(victim)
         return True
 
-    def _admit(self) -> None:
+    def _admit(self) -> int:
+        """Claim slots and pages for what fits, then prefill it by
+        groups. Returns the number of requests admitted."""
         import jax.numpy as jnp
 
         ps = self.page_size
         # claim everything that fits in one lock pass
         admitted = []  # (slot_idx, stream, pages, plen, covered)
+        now = None  # one clock read for the requests this pass admits
         with self._cond:
             used = {i for i, s in enumerate(self._slot_state)
                     if s is not None}
@@ -1807,12 +1960,16 @@ class DecodeLoop:
                     pages.append(page)
                 if use_cache:
                     (self._m_hits if matched else self._m_misses).inc()
+                if now is None:
+                    now = time.perf_counter()
+                stream.admitted = now
+                self._m_queue_wait.observe(now - stream.submitted)
                 admitted.append((idx, stream, pages, plen, covered))
             if admitted:
                 self._peak_pages = max(self._peak_pages,
                                        self.pages_in_use)
         if not admitted:
-            return
+            return 0
         cold = [a for a in admitted if a[4] == 0]
         warm = [a for a in admitted if 0 < a[4] < a[3]]
         full = [a for a in admitted if a[4] >= a[3]]
@@ -1845,19 +2002,21 @@ class DecodeLoop:
             bb = 1
             while bb < len(group):
                 bb *= 2
-            n_pids = tb // ps
-            padded = np.zeros((bb, tb), np.int32)
-            lens = np.ones((bb,), np.int32)  # pad rows: true_len 1
-            pids = np.full((bb, n_pids), self._trash, np.int32)
-            for row, (idx, stream, pages, plen, _cov) in enumerate(group):
-                padded[row, :plen] = stream.prompt
-                lens[row] = plen
-                pids[row, :len(pages)] = pages
-                self._prefill_token_count += plen
-            self._plan_prefill.add((bb, tb))
-            first, self._pool = self._prefill(
-                self.params, jnp.asarray(padded), jnp.asarray(lens),
-                self._pool, jnp.asarray(pids))
+            with self._prefill_span(group, bb, tb, ctx=0):
+                n_pids = tb // ps
+                padded = np.zeros((bb, tb), np.int32)
+                lens = np.ones((bb,), np.int32)  # pad rows: true_len 1
+                pids = np.full((bb, n_pids), self._trash, np.int32)
+                for row, (idx, stream, pages, plen, _cov) in enumerate(
+                        group):
+                    padded[row, :plen] = stream.prompt
+                    lens[row] = plen
+                    pids[row, :len(pages)] = pages
+                    self._prefill_token_count += plen
+                self._plan_prefill.add((bb, tb))
+                first, self._pool = self._prefill(
+                    self.params, jnp.asarray(padded), jnp.asarray(lens),
+                    self._pool, jnp.asarray(pids))
             self._install_prefilled(group, first)
         # warm tails ride the ctx-aware prefill, bucketed by (cached
         # pages, tail length) — tails start on a page boundary by
@@ -1875,27 +2034,40 @@ class DecodeLoop:
             bb = 1
             while bb < len(group):
                 bb *= 2
-            n_pids = tb // ps
-            padded = np.zeros((bb, tb), np.int32)
-            lens = np.ones((bb,), np.int32)
-            pids = np.full((bb, n_pids), self._trash, np.int32)
-            ctab = np.full((bb, cb), self._trash, np.int32)
-            clen = np.zeros((bb,), np.int32)
-            for row, (idx, stream, pages, plen, cov) in enumerate(group):
-                cp = cov // ps
-                tl = plen - cov
-                padded[row, :tl] = stream.prompt[cov:]
-                lens[row] = tl
-                pids[row, :len(pages) - cp] = pages[cp:]
-                ctab[row, :cp] = pages[:cp]
-                clen[row] = cov
-                self._prefill_token_count += tl
-            self._plan_prefill_ctx.add((bb, cb, tb))
-            first, self._pool = self._prefill_ctx(
-                self.params, jnp.asarray(padded), jnp.asarray(lens),
-                self._pool, jnp.asarray(pids), jnp.asarray(ctab),
-                jnp.asarray(clen))
+            with self._prefill_span(group, bb, tb, ctx=cb):
+                n_pids = tb // ps
+                padded = np.zeros((bb, tb), np.int32)
+                lens = np.ones((bb,), np.int32)
+                pids = np.full((bb, n_pids), self._trash, np.int32)
+                ctab = np.full((bb, cb), self._trash, np.int32)
+                clen = np.zeros((bb,), np.int32)
+                for row, (idx, stream, pages, plen, cov) in enumerate(
+                        group):
+                    cp = cov // ps
+                    tl = plen - cov
+                    padded[row, :tl] = stream.prompt[cov:]
+                    lens[row] = tl
+                    pids[row, :len(pages) - cp] = pages[cp:]
+                    ctab[row, :cp] = pages[:cp]
+                    clen[row] = cov
+                    self._prefill_token_count += tl
+                self._plan_prefill_ctx.add((bb, cb, tb))
+                first, self._pool = self._prefill_ctx(
+                    self.params, jnp.asarray(padded), jnp.asarray(lens),
+                    self._pool, jnp.asarray(pids), jnp.asarray(ctab),
+                    jnp.asarray(clen))
             self._install_prefilled(group, first)
+        return len(admitted)
+
+    def _prefill_span(self, group, bb: int, tb: int, ctx: int) -> span:
+        """The span of one prefill group, host packing and enqueue: the
+        program's batch and token buckets, its real rows and tokens, the
+        context pages it reads (0 = a cold prefill) and the requests in
+        it."""
+        return span(PREFILL_DISPATCH, self._phases, bb=bb, tb=tb,
+                    rows=len(group), ctx=ctx,
+                    tokens=sum(plen - cov for *_, plen, cov in group),
+                    requests=[a[1].request_id for a in group])
 
     def _install_prefilled(self, group, first) -> None:
         """Install slots for one prefill group; first tokens stay on
@@ -1926,7 +2098,7 @@ class DecodeLoop:
         write — including draft tokens that get rejected — lands in
         private pages: rollback is just the host cursor not moving."""
         adv = (self.spec_k + 1) if self.spec_k else self.horizon
-        with self._cond:
+        with span("decode.grant_pages", self._phases), self._cond:
             for i, slot in enumerate(self._slot_state):
                 if slot is None:
                     continue
@@ -2010,6 +2182,7 @@ class DecodeLoop:
     def _dispatch_plain(self) -> bool:
         import jax.numpy as jnp
 
+        phases = self._phases
         self._grant_pages()
         with self._cond:
             runnable = [i for i, s in enumerate(self._slot_state)
@@ -2018,27 +2191,31 @@ class DecodeLoop:
             if not runnable:
                 return False
             before = self._lengths.copy()
-            if self._dirty or self._d_tokens is None:
-                self._d_tokens = jnp.asarray(self._pending)
-                self._d_table = jnp.asarray(self._table)
-                self._d_lengths = jnp.asarray(self._lengths)
-                self._d_stop = jnp.asarray(self._stop)
-                self._dirty = False
-            # overlay deferred prefill tokens (still device-resident)
-            # into the feedback array — ONE scatter per prefill group,
-            # no sync
-            for arr, members in self._deferred:
-                rows = jnp.asarray([r for r, _ in members])
-                idxs = jnp.asarray([i for _, i in members])
-                self._d_tokens = self._d_tokens.at[idxs].set(arr[rows])
+            with span("decode.upload", phases):
+                if self._dirty or self._d_tokens is None:
+                    self._d_tokens = jnp.asarray(self._pending)
+                    self._d_table = jnp.asarray(self._table)
+                    self._d_lengths = jnp.asarray(self._lengths)
+                    self._d_stop = jnp.asarray(self._stop)
+                    self._dirty = False
+                # overlay deferred prefill tokens (still
+                # device-resident) into the feedback array — ONE scatter
+                # per prefill group, no sync
+                for arr, members in self._deferred:
+                    rows = jnp.asarray([r for r, _ in members])
+                    idxs = jnp.asarray([i for _, i in members])
+                    self._d_tokens = self._d_tokens.at[idxs].set(
+                        arr[rows])
         t0 = time.perf_counter()
         self._plan_step = True
-        toks, t_out, l_out, self._pool = self._step(
-            self.params, self._d_tokens, self._pool, self._d_table,
-            self._d_lengths, self._d_stop)
+        with span("decode.step_dispatch", phases, runnable=len(runnable)):
+            toks, t_out, l_out, self._pool = self._step(
+                self.params, self._d_tokens, self._pool, self._d_table,
+                self._d_lengths, self._d_stop)
         self._m_steps.inc()
         # the (K, S) token D2H is the sync the streams need anyway
-        toks = np.asarray(toks)
+        with span("decode.d2h", phases):
+            toks = np.asarray(toks)
         self._m_step_s.observe(time.perf_counter() - t0)
         self._d_tokens, self._d_lengths = t_out, l_out
         # per-token-step KV read accounting, host math mirroring the
@@ -2046,30 +2223,36 @@ class DecodeLoop:
         # at each slot's stop bound (stalled/idle slots hold still).
         # Both figures are recorded each dispatch — the selected lane
         # is in snapshot()["decode_kernel"]
-        advance = np.maximum(self._stop - before, 0)
-        ideal = dense = 0
-        for k in range(self.horizon):
-            cur = before + np.minimum(k, advance)
-            ideal += decode_read_bytes(self._pool, cur, self._pps)
-            dense += decode_read_bytes(self._pool, cur, self._pps,
-                                       dense=True)
-        self._m_kv_read["kernel"].inc(ideal)
-        self._m_kv_read["gather"].inc(dense)
+        with span("decode.account", phases):
+            advance = np.maximum(self._stop - before, 0)
+            ideal = dense = 0
+            for k in range(self.horizon):
+                cur = before + np.minimum(k, advance)
+                ideal += decode_read_bytes(self._pool, cur, self._pps)
+                dense += decode_read_bytes(self._pool, cur, self._pps,
+                                           dense=True)
+            self._m_kv_read["kernel"].inc(ideal)
+            self._m_kv_read["gather"].inc(dense)
         self._flush_first_tokens()  # emit firsts BEFORE chunk tokens
-        for i in runnable:
-            slot = self._slot_state[i]
-            if slot is None:  # retired at flush (eos on first token)
-                continue
-            consumed = min(self.horizon, int(self._stop[i] - before[i]))
-            with self._cond:
-                self._lengths[i] = before[i] + consumed
-            for j in range(consumed):
-                tok = int(toks[j, i])
-                self._pending[i] = tok
-                slot.emitted += 1
-                self._emit_and_maybe_finish(i, slot, tok)
-                if self._slot_state[i] is None:
-                    break  # retired: discard speculative overshoot
+        with span("decode.emit", phases) as emit:
+            emitted = 0
+            for i in runnable:
+                slot = self._slot_state[i]
+                if slot is None:  # retired at flush (eos on first token)
+                    continue
+                consumed = min(self.horizon,
+                               int(self._stop[i] - before[i]))
+                with self._cond:
+                    self._lengths[i] = before[i] + consumed
+                for j in range(consumed):
+                    tok = int(toks[j, i])
+                    self._pending[i] = tok
+                    slot.emitted += 1
+                    emitted += 1
+                    self._emit_and_maybe_finish(i, slot, tok)
+                    if self._slot_state[i] is None:
+                        break  # retired: discard speculative overshoot
+            emit.args["tokens"] = emitted
         return True
 
     # ---- speculative dispatch (draft k on the host, verify k+1 wide)
@@ -2090,6 +2273,7 @@ class DecodeLoop:
         can see it."""
         import jax.numpy as jnp
 
+        phases = self._phases
         # drafting extends each slot's last token on the HOST, so any
         # deferred prefill firsts flush (one D2H per group) and emit
         # now — same firsts-before-chunk order as the plain lane
@@ -2109,40 +2293,42 @@ class DecodeLoop:
         widths = np.zeros((self.slots,), np.int32)
         proposals = {}
         model_rows = []
-        for i in runnable:
-            slot = self._slot_state[i]
-            tokens[i, 0] = self._pending[i]
-            widths[i] = 1
-            # room for length-advance this round; >= 2 means at least
-            # one draft position fits under the granted/budget frontier
-            room = int(self._stop[i] - before[i])
-            if room < 2 or not slot.stream.speculation:
-                continue
-            if self._drafter.kind == "model":
-                model_rows.append(i)
-            else:
-                history = slot.stream.prompt + slot.stream._generated
-                prop = self._drafter.propose(
-                    history, min(self.spec_k, room - 1))
-                if prop:
-                    proposals[i] = [int(t) for t in prop]
-        if model_rows:
-            # one fixed-shape (S, window) batch through the draft
-            # program — idle rows ride along and are ignored
-            win = self._drafter.window
-            windows = np.zeros((self.slots, win), np.int32)
-            for i in model_rows:
+        with span("decode.draft", phases):
+            for i in runnable:
                 slot = self._slot_state[i]
-                hist = (slot.stream.prompt
-                        + slot.stream._generated)[-win:]
-                windows[i, win - len(hist):] = hist
-            drafted = self._drafter.propose_all(windows, self.spec_k)
-            for i in model_rows:
+                tokens[i, 0] = self._pending[i]
+                widths[i] = 1
+                # room for length-advance this round; >= 2 means at
+                # least one draft position fits under the
+                # granted/budget frontier
                 room = int(self._stop[i] - before[i])
-                prop = [int(t) for t in
-                        drafted[i, :min(self.spec_k, room - 1)]]
-                if prop:
-                    proposals[i] = prop
+                if room < 2 or not slot.stream.speculation:
+                    continue
+                if self._drafter.kind == "model":
+                    model_rows.append(i)
+                else:
+                    history = slot.stream.prompt + slot.stream._generated
+                    prop = self._drafter.propose(
+                        history, min(self.spec_k, room - 1))
+                    if prop:
+                        proposals[i] = [int(t) for t in prop]
+            if model_rows:
+                # one fixed-shape (S, window) batch through the draft
+                # program — idle rows ride along and are ignored
+                win = self._drafter.window
+                windows = np.zeros((self.slots, win), np.int32)
+                for i in model_rows:
+                    slot = self._slot_state[i]
+                    hist = (slot.stream.prompt
+                            + slot.stream._generated)[-win:]
+                    windows[i, win - len(hist):] = hist
+                drafted = self._drafter.propose_all(windows, self.spec_k)
+                for i in model_rows:
+                    room = int(self._stop[i] - before[i])
+                    prop = [int(t) for t in
+                            drafted[i, :min(self.spec_k, room - 1)]]
+                    if prop:
+                        proposals[i] = prop
         if not proposals:
             # nothing drafted — run the plain width-1 chain instead so
             # an idle/unluckly round costs exactly what it always did
@@ -2155,42 +2341,51 @@ class DecodeLoop:
             self._m_spec_proposed.inc(n)
         t0 = time.perf_counter()
         self._plan_verify = True
-        out, self._pool = self._verify(
-            self.params, jnp.asarray(tokens), self._pool,
-            jnp.asarray(self._table), jnp.asarray(before),
-            jnp.asarray(widths))
+        with span("decode.upload", phases):
+            d_tokens, d_table = jnp.asarray(tokens), jnp.asarray(self._table)
+            d_before, d_widths = jnp.asarray(before), jnp.asarray(widths)
+        with span("decode.step_dispatch", phases, runnable=len(runnable)):
+            out, self._pool = self._verify(
+                self.params, d_tokens, self._pool, d_table, d_before,
+                d_widths)
         self._m_steps.inc()
         self._m_spec_rounds.inc()
-        out = np.asarray(out)  # (S, W) argmax — the sync streams need
+        with span("decode.d2h", phases):
+            out = np.asarray(out)  # (S, W) argmax — the sync streams need
         self._m_step_s.observe(time.perf_counter() - t0)
         # KV read accounting mirrors the widened step: column j of slot
         # i attends at cursor before+j (clamped to its real width)
-        for j in range(int(widths.max())):
-            cur = before + np.minimum(j, np.maximum(widths - 1, 0))
-            self._m_kv_read["kernel"].inc(
-                decode_read_bytes(self._pool, cur, self._pps))
-            self._m_kv_read["gather"].inc(
-                decode_read_bytes(self._pool, cur, self._pps,
-                                  dense=True))
-        for i in runnable:
-            slot = self._slot_state[i]
-            if slot is None:
-                continue
-            prop = proposals.get(i, [])
-            m = 0
-            while m < len(prop) and prop[m] == int(out[i, m]):
-                m += 1
-            with self._cond:
-                self._lengths[i] = before[i] + m + 1
-            if prop:
-                self._m_spec_accepted.inc(m)
-            for j in range(m + 1):
-                tok = int(out[i, j])
-                self._pending[i] = tok
-                slot.emitted += 1
-                self._emit_and_maybe_finish(i, slot, tok)
-                if self._slot_state[i] is None:
-                    break  # retired (eos/budget): overshoot discarded
+        with span("decode.account", phases):
+            for j in range(int(widths.max())):
+                cur = before + np.minimum(j, np.maximum(widths - 1, 0))
+                self._m_kv_read["kernel"].inc(
+                    decode_read_bytes(self._pool, cur, self._pps))
+                self._m_kv_read["gather"].inc(
+                    decode_read_bytes(self._pool, cur, self._pps,
+                                      dense=True))
+        with span("decode.emit", phases) as emit:
+            emitted = 0
+            for i in runnable:
+                slot = self._slot_state[i]
+                if slot is None:
+                    continue
+                prop = proposals.get(i, [])
+                m = 0
+                while m < len(prop) and prop[m] == int(out[i, m]):
+                    m += 1
+                with self._cond:
+                    self._lengths[i] = before[i] + m + 1
+                if prop:
+                    self._m_spec_accepted.inc(m)
+                for j in range(m + 1):
+                    tok = int(out[i, j])
+                    self._pending[i] = tok
+                    slot.emitted += 1
+                    emitted += 1
+                    self._emit_and_maybe_finish(i, slot, tok)
+                    if self._slot_state[i] is None:
+                        break  # retired (eos/budget): overshoot discarded
+            emit.args["tokens"] = emitted
         # host cursors moved without touching the plain device carry —
         # any later plain-lane dispatch must re-upload
         self._dirty = True
@@ -2200,17 +2395,19 @@ class DecodeLoop:
         """Read deferred prefill tokens (one D2H per prefill group —
         the compute is long finished) and emit them."""
         deferred, self._deferred = self._deferred, []
-        for arr, members in deferred:
-            host = np.asarray(arr)
-            for row, i in members:
-                slot = self._slot_state[i]
-                if slot is None or not slot.awaiting_first:
-                    continue  # failed/cleared meanwhile
-                tok = int(host[row])
-                slot.awaiting_first = False
-                self._pending[i] = tok
-                slot.emitted += 1
-                self._emit_and_maybe_finish(i, slot, tok)
+        with span("decode.flush_first", self._phases,
+                  groups=len(deferred)):
+            for arr, members in deferred:
+                host = np.asarray(arr)
+                for row, i in members:
+                    slot = self._slot_state[i]
+                    if slot is None or not slot.awaiting_first:
+                        continue  # failed/cleared meanwhile
+                    tok = int(host[row])
+                    slot.awaiting_first = False
+                    self._pending[i] = tok
+                    slot.emitted += 1
+                    self._emit_and_maybe_finish(i, slot, tok)
 
     # ---- emission / retirement
     def _emit_and_maybe_finish(self, idx: int, slot: _Slot,

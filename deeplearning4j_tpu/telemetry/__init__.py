@@ -30,8 +30,9 @@ process-global `MetricsRegistry`:
 Export: `GET /metrics` (Prometheus text) and `GET /snapshot` (JSON) on
 the serving server, the scaleout StatusServer, or a standalone
 `exposition.start_metrics_server()`. Tracing: `span("train_step")`
-regions with Chrome-trace export and an opt-in
-`jax.profiler.TraceAnnotation` bridge (trace.py). Catalogue, scrape
+regions with Chrome-trace export, always-on phase totals for the
+decode scheduler, and a `jax.profiler.TraceAnnotation` of the same name
+around every span that runs (trace.py). Catalogue, scrape
 quickstart and overhead envelope: docs/OBSERVABILITY.md.
 """
 
@@ -46,6 +47,7 @@ from deeplearning4j_tpu.telemetry.registry import (  # noqa: F401
     set_enabled,
 )
 from deeplearning4j_tpu.telemetry.trace import (  # noqa: F401
+    PhaseTotals,
     SpanRecord,
     Tracer,
     active_tracer,
@@ -54,15 +56,15 @@ from deeplearning4j_tpu.telemetry.trace import (  # noqa: F401
     span,
     start_tracing,
     stop_tracing,
-    tracing,
 )
 
 __all__ = [
     "MetricsRegistry", "Counter", "Gauge", "Histogram", "DEFAULT_BUCKETS",
     "get_registry", "set_enabled", "enabled",
     "counter", "gauge", "histogram",
-    "span", "start_tracing", "stop_tracing", "tracing", "active_tracer",
+    "span", "start_tracing", "stop_tracing", "active_tracer",
     "chrome_trace", "save_chrome_trace", "Tracer", "SpanRecord",
+    "PhaseTotals",
 ]
 
 

@@ -1,99 +1,115 @@
-"""Span-based step tracing with Chrome-trace export and an xprof bridge.
+"""Span-based tracing: one primitive, three places a span can land.
 
-`span("train_step")` wraps a host-side region; spans nest per thread
-(parent/child from a thread-local stack), clock on
-`time.perf_counter_ns` (monotonic), and land in a bounded in-memory
-buffer. Export is Chrome trace format (`chrome://tracing` /
-Perfetto-compatible `{"traceEvents": [...]}` with "X" complete events),
-so a training run's host timeline opens in the same tooling as a device
-profile.
+`span("train_step")` wraps a host-side region and clocks it on
+`time.perf_counter_ns` (monotonic, the clock `time.perf_counter` reads).
+What a closed span leaves behind depends on what is listening:
 
-Off by default: until `start_tracing()` (or the CLI's `--trace`), a
-span is a no-op context manager — a couple of attribute loads per use,
-cheap enough to leave in the hot fit/serve loops permanently.
+- **A `SpanRecord`**, while `start_tracing()` is active (the CLI's
+  `--trace PATH`): name, start, duration, thread, an identifier and the
+  identifier of the span that caused it (the enclosing span on the
+  thread, or `parent_id=` for work caused from another thread), plus the
+  keyword arguments, `request=` among them, so that the spans of one
+  request can be joined. Records sit in a bounded in-memory buffer and
+  are written out at the end as Chrome trace JSON (`chrome://tracing`,
+  Perfetto), nesting rebuilt by the viewer from containment per `tid`.
+- **The profiler's own trace.** A span that runs also enters a
+  `jax.profiler.TraceAnnotation` of the same name. A TraceMe does nothing
+  while no profiler session is live; inside a `jax.profiler` window,
+  whoever opened it (`ProfilerListener`, the benchmark's traced run), the
+  program's spans sit on the device trace's own clock beside PjRt's, and
+  an idle gap of the device can be put down to the phase that covers it.
+- **Phase totals**, for a span given `phases=`: seconds and count by
+  span name, always on. These are counters like any other of the
+  registry (`PhaseTotals` keeps them in one histogram family), so
+  `/metrics` has them with no tracer and no profiler.
 
-Opt-in xprof bridge: `start_tracing(jax_annotations=True)` additionally
-enters `jax.profiler.TraceAnnotation(name)` for every span, so when a
-`jax.profiler.trace` window is open (optimize/listeners.ProfilerListener)
-the host spans line up against the device timeline in xprof — the
-methodology of the array-redistribution profiling work (arXiv:2112.01075):
-step phases as first-class trace data, not log lines.
+A span with no `phases=` runs only while tracing is on: until then it
+is an object and two attribute checks, cheap enough for the fit and
+checkpoint loops. A span with `phases=` always runs: two clock reads, one
+histogram observation and one TraceMe.
 """
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
 import time
-from contextlib import contextmanager
-from typing import List, NamedTuple, Optional
+from collections import deque
+from typing import Dict, Iterable, List, NamedTuple, Optional
 
 __all__ = [
-    "SpanRecord", "Tracer", "span", "start_tracing", "stop_tracing",
-    "tracing", "active_tracer", "chrome_trace", "save_chrome_trace",
+    "SpanRecord", "Tracer", "PhaseTotals", "span", "start_tracing",
+    "stop_tracing", "active_tracer", "chrome_trace", "save_chrome_trace",
 ]
 
 
 class SpanRecord(NamedTuple):
-    """One closed span. Times are perf_counter nanoseconds; `depth` is
-    the nesting level on its thread (0 = root)."""
+    """One closed span. Times are perf_counter nanoseconds; `parent_id`
+    is None for a root."""
 
     name: str
     start_ns: int
     dur_ns: int
     thread_id: int
-    depth: int
     args: dict
+    span_id: int
+    parent_id: Optional[int]
 
 
 class Tracer:
     """Bounded span buffer + per-thread nesting state."""
 
-    def __init__(self, max_spans: int = 100_000,
-                 jax_annotations: bool = False):
-        from collections import deque
+    def __init__(self, max_spans: int = 100_000):
         self.max_spans = int(max_spans)
-        self.jax_annotations = bool(jax_annotations)
         self._spans = deque(maxlen=self.max_spans)
         self._local = threading.local()
         self._lock = threading.Lock()
+        self._ids = itertools.count(1)
 
     # ------------------------------------------------------------ record
-    def _depth(self) -> int:
-        return getattr(self._local, "depth", 0)
-
-    def _push(self) -> int:
-        d = self._depth()
-        self._local.depth = d + 1
-        return d
-
-    def _pop(self) -> None:
-        self._local.depth = max(0, self._depth() - 1)
+    def _stack(self) -> List[int]:
+        """Identifiers of the spans open on this thread, outermost
+        first."""
+        try:
+            return self._local.stack
+        except AttributeError:
+            stack = self._local.stack = []
+            return stack
 
     def record(self, rec: SpanRecord) -> None:
         with self._lock:
             self._spans.append(rec)
 
+    def add(self, name: str, start_ns: int, end_ns: int,
+            parent_id: Optional[int] = None,
+            thread_id: Optional[int] = None, **args) -> int:
+        """Record a span whose two ends were stamped elsewhere (the
+        stages of a request's life, known only when it finishes).
+        Returns its identifier, for its children's `parent_id`."""
+        span_id = next(self._ids)
+        self.record(SpanRecord(
+            name, int(start_ns), max(0, int(end_ns) - int(start_ns)),
+            threading.get_ident() if thread_id is None else thread_id,
+            args, span_id, parent_id))
+        return span_id
+
     def spans(self) -> List[SpanRecord]:
         with self._lock:
             return list(self._spans)
-
-    def clear(self) -> None:
-        with self._lock:
-            self._spans.clear()
 
     # ------------------------------------------------------------ export
     def chrome_trace(self) -> dict:
         """Chrome trace format dict: "X" (complete) events, microsecond
         timestamps. Nesting is reconstructed by the viewer from
-        timestamp containment per tid; `depth` rides in args for
-        programmatic consumers."""
+        timestamp containment per tid; `span_id` and `parent_id` ride in
+        args for programmatic consumers."""
         pid = os.getpid()
         events = []
         for s in self.spans():
             args = dict(s.args)
-            args["depth"] = s.depth
+            args.update(span_id=s.span_id, parent_id=s.parent_id)
             events.append({
                 "name": s.name,
                 "ph": "X",
@@ -111,15 +127,53 @@ class Tracer:
         return path
 
 
+class PhaseTotals:
+    """Seconds and count of the spans one owner closes, by span name.
+
+    The totals live in one histogram family of the registry (label
+    `phase`, plus the owner's labels), so a scrape has them; `pass_ns`
+    holds the nanoseconds since the owner last called `begin_pass()`,
+    which is how a scheduler says what one of its passes was made of.
+    One thread closes an owner's spans; any thread may read."""
+
+    def __init__(self, family, names: Iterable[str], **labels):
+        self._cells = {n: family.labels(phase=n, **labels) for n in names}
+        self.pass_ns: Dict[str, int] = dict.fromkeys(self._cells, 0)
+
+    def begin_pass(self) -> None:
+        ns = self.pass_ns
+        for name in ns:
+            ns[name] = 0
+
+    def add(self, name: str, dur_ns: int) -> None:
+        self._cells[name].observe(dur_ns * 1e-9)
+        self.pass_ns[name] += dur_ns
+
+    def totals(self) -> Dict[str, dict]:
+        return {n: {"seconds": c.sum, "count": c.count}
+                for n, c in self._cells.items()}
+
+
 _active: Optional[Tracer] = None
+_annotation = None
 
 
-def start_tracing(max_spans: int = 100_000,
-                  jax_annotations: bool = False) -> Tracer:
+def _trace_annotation():
+    """`jax.profiler.TraceAnnotation`, imported at a span's first run
+    and not with this module: telemetry is imported by processes that
+    never start a back end."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation
+
+
+def start_tracing(max_spans: int = 100_000) -> Tracer:
     """Install (and return) the process tracer. Idempotent-ish: a second
     call replaces the tracer (fresh buffer)."""
     global _active
-    _active = Tracer(max_spans=max_spans, jax_annotations=jax_annotations)
+    _active = Tracer(max_spans=max_spans)
     return _active
 
 
@@ -130,45 +184,63 @@ def stop_tracing() -> Optional[Tracer]:
     return t
 
 
-def tracing() -> bool:
-    return _active is not None
-
-
 def active_tracer() -> Optional[Tracer]:
     return _active
 
 
-@contextmanager
-def span(name: str, **args):
-    """Time a host-side region. No-op (and allocation-light) while
-    tracing is off; with `jax_annotations` the region is also annotated
-    onto the device timeline for xprof correlation."""
-    tracer = _active
-    if tracer is None:
-        yield
-        return
-    ann = None
-    if tracer.jax_annotations:
-        try:
-            import jax
-            ann = jax.profiler.TraceAnnotation(name)
-            ann.__enter__()
-        except Exception:
-            ann = None
-    depth = tracer._push()
-    start = time.perf_counter_ns()
-    try:
-        yield
-    finally:
-        dur = time.perf_counter_ns() - start
-        tracer._pop()
-        if ann is not None:
-            try:
-                ann.__exit__(None, None, None)
-            except Exception:
-                pass
-        tracer.record(SpanRecord(name, start, dur,
-                                 threading.get_ident(), depth, args))
+class span:  # noqa: N801 - a context manager called like a function
+    """Time a host-side region: `with span("stage", epoch=i): ...`.
+
+    `phases=` names the `PhaseTotals` that count this span whether or
+    not anybody traces; `parent_id=` names the span that caused this one
+    where that is not the enclosing span of the thread. `args` may be
+    added to until the span closes (`with span(...) as s: s.args["n"] =
+    n`); `start_ns` and `dur_ns` can be read once it has."""
+
+    __slots__ = ("name", "args", "phases", "parent_id", "span_id",
+                 "start_ns", "dur_ns", "_tracer", "_stack", "_ann")
+
+    def __init__(self, name: str, phases: Optional[PhaseTotals] = None,
+                 parent_id: Optional[int] = None, **args):
+        self.name = name
+        self.args = args
+        self.phases = phases
+        self.parent_id = parent_id
+        self.span_id = None
+        self.start_ns = self.dur_ns = 0
+        self._tracer = None
+        self._ann = None
+
+    def __enter__(self) -> "span":
+        tracer = self._tracer = _active
+        if tracer is None and self.phases is None:
+            return self
+        if tracer is not None:
+            stack = self._stack = tracer._stack()
+            if self.parent_id is None and stack:
+                self.parent_id = stack[-1]
+            self.span_id = next(tracer._ids)
+            stack.append(self.span_id)
+        ann = self._ann = _trace_annotation()(self.name)
+        ann.__enter__()
+        self.start_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        ann = self._ann
+        if ann is None:
+            return
+        self.dur_ns = time.perf_counter_ns() - self.start_ns
+        ann.__exit__(*exc)
+        if self.phases is not None:
+            self.phases.add(self.name, self.dur_ns)
+        tracer = self._tracer
+        if tracer is not None:
+            self._stack.pop()
+            tracer.record(SpanRecord(
+                self.name, self.start_ns, self.dur_ns,
+                threading.get_ident(), self.args, self.span_id,
+                self.parent_id))
 
 
 def chrome_trace() -> dict:

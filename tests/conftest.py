@@ -124,3 +124,15 @@ def pytest_collection_modifyitems(config, items):
 @pytest.fixture(scope="session")
 def rng():
     return np.random.RandomState(0)
+
+
+@pytest.fixture(autouse=True)
+def no_tracer_left_behind():
+    """Tracing is process-wide state: whichever test starts it (a
+    `start_tracing()`, a `cli ... --trace` run in this process), the
+    next one begins without it."""
+    from deeplearning4j_tpu import telemetry
+
+    telemetry.stop_tracing()
+    yield
+    telemetry.stop_tracing()

@@ -258,14 +258,14 @@ class TestExposition:
 
 # ===================================================================== trace
 class TestTrace:
-    def teardown_method(self):
-        telemetry.stop_tracing()
+    """Every test starts and ends with no tracer: conftest's
+    `no_tracer_left_behind`."""
 
     def test_disabled_span_records_nothing(self):
-        telemetry.stop_tracing()
-        with telemetry.span("ghost"):
+        with telemetry.span("ghost") as s:
             pass
         assert telemetry.chrome_trace() == {"traceEvents": []}
+        assert s.span_id is None and s.dur_ns == 0  # it never ran
 
     def test_nesting_and_chrome_round_trip(self, tmp_path):
         tracer = telemetry.start_tracing()
@@ -276,9 +276,11 @@ class TestTrace:
                 pass
         spans = tracer.spans()
         assert [s.name for s in spans] == ["inner", "inner", "outer"]
-        assert [s.depth for s in spans] == [1, 1, 0]
         outer = spans[-1]
+        assert outer.parent_id is None
+        assert len({s.span_id for s in spans}) == 3
         for inner in spans[:2]:  # children nest inside the parent window
+            assert inner.parent_id == outer.span_id
             assert outer.start_ns <= inner.start_ns
             assert (inner.start_ns + inner.dur_ns
                     <= outer.start_ns + outer.dur_ns)
@@ -295,9 +297,9 @@ class TestTrace:
             by_name.setdefault(e["name"], []).append(e)
         out = by_name["outer"][0]
         assert out["args"]["phase"] == "epoch"
-        assert out["args"]["depth"] == 0
+        assert out["args"]["parent_id"] is None
         for inner in by_name["inner"]:
-            assert inner["args"]["depth"] == 1
+            assert inner["args"]["parent_id"] == out["args"]["span_id"]
             assert out["ts"] <= inner["ts"]
             assert inner["ts"] + inner["dur"] <= out["ts"] + out["dur"] + 1e-3
 
@@ -308,12 +310,67 @@ class TestTrace:
                 pass
         assert [s.name for s in tracer.spans()] == ["s6", "s7", "s8", "s9"]
 
-    def test_jax_annotation_bridge_smoke(self):
-        telemetry.start_tracing(jax_annotations=True)
-        with telemetry.span("annotated"):
+    def test_explicit_parent_and_spans_stamped_elsewhere(self):
+        """Work caused from another thread names its cause; a span whose
+        ends were stamped elsewhere (a request's stages) is added whole."""
+        tracer = telemetry.start_tracing()
+        with telemetry.span("cause") as cause:
             pass
+        done = []
+
+        def worker():
+            with telemetry.span("effect", parent_id=cause.span_id,
+                                request=7) as s:
+                done.append(s)
+
+        t = threading.Thread(target=worker)
+        t.start()
+        t.join(timeout=10)
+        assert not t.is_alive() and done
+        root = tracer.add("request", 1_000, 5_000, thread_id=7, request=7)
+        child = tracer.add("request.queued", 1_000, 2_000, parent_id=root,
+                           thread_id=7, request=7)
+        by_name = {s.name: s for s in tracer.spans()}
+        assert by_name["effect"].parent_id == cause.span_id
+        assert by_name["effect"].thread_id != by_name["cause"].thread_id
+        assert by_name["request.queued"].span_id == child
+        assert by_name["request.queued"].parent_id == root
+        assert (by_name["request"].start_ns,
+                by_name["request"].dur_ns) == (1_000, 4_000)
+        events = telemetry.chrome_trace()["traceEvents"]
+        assert {e["args"].get("request") for e in events} == {None, 7}
+
+    def test_jax_annotation_bridge_smoke(self, tmp_path):
+        """A span that runs is a TraceMe of the same name, with no flag
+        to set: inside a `jax.profiler` window the trace holds it, and a
+        span that does not run (no tracer, no totals) leaves nothing."""
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        with telemetry.span("not_recorded"):
+            pass
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            with telemetry.span("unseen"):
+                pass
+            telemetry.start_tracing()
+            with telemetry.span("annotated", step=3):
+                pass
+        finally:
+            jax.profiler.stop_trace()
         assert [s.name for s in telemetry.active_tracer().spans()] \
             == ["annotated"]
+        found = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                              / "*.xplane.pb"))
+        assert found
+        names = {ev.name for plane in ProfileData.from_file(found[-1]).planes
+                 for line in plane.lines for ev in line.events}
+        assert "annotated" in names
+        assert not names & {"unseen", "not_recorded"}
 
 
 # ==================================================================== device
