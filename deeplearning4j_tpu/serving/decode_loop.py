@@ -30,8 +30,13 @@ The device carry — last tokens, pool, page table, lengths, stop bounds
 (S,)/(S,P) control arrays only after a visible event (admission,
 completion, page grant). Steady-state per-token cost is one dispatch
 slice plus the token D2H the streams need anyway. On accelerators the
-pool is donated to the step, so KV updates alias in place; CPU ignores
-donation (gated off to avoid the warning, same as InferenceEngine).
+pool is donated to the step and KV updates alias in place: that holds
+because the step's write (`paged_kv._write_rows`) keeps the pool in the
+layout the paged kernel reads, so the compiled step holds no copy of
+the pool (tests/test_paged_step_layout.py pins it; the earlier
+two-index scatter was donated too and still copied each layer's pool
+four times a step). CPU ignores donation (gated off to avoid the
+warning, same as InferenceEngine).
 
 **Decode horizon**: `horizon=K` runs K decode steps inside one compiled
 dispatch (a `lax.scan` feeding each slot's argmax back on device). The

@@ -12,6 +12,13 @@ portable O(1)-cache decode, PAPERS.md arXiv:2603.09555):
   (inactive / paused) direct their writes there so the scatter in the
   compiled step never needs a data-dependent shape. The host allocator
   never hands the trash page out.
+- the pool never changes layout inside a compiled program: it arrives
+  donated, the step writes its new rows into it in place
+  (`_write_rows`: one scatter that indexes page, head and offset), the
+  paged kernel reads it as it stands and the output aliases the input.
+  A write that leaves the head dimension as a window between the page
+  and the offset index costs two copies of the whole pool per layer
+  for K and for V each on the TPU (tests/test_paged_step_layout.py).
 - a per-slot **page table** `(S, pages_per_slot)` of pool indices maps a
   slot's logical positions `[0, max_len)` onto physical pages.
   Unallocated entries hold the trash index so gathers are always valid
@@ -341,6 +348,31 @@ def decode_read_bytes(pool: PagedKVPool, lengths, table_width: int, *,
     return 2 * len(pool.layers) * page_bytes * int(pages)
 
 
+def _write_rows(arr, dest, offset, rows):
+    """Write one `head_dim` row per (..., head) into pool array `arr`
+    (n_pages + 1, H, page_size, hd): `dest` and `offset` (any shape
+    `idx`, the physical page and the offset inside it) name where
+    `rows` (`idx` + (H, hd)) go. The decode and the verify step's only
+    write.
+
+    The scatter indexes EVERY major dimension (page, head, offset) and
+    leaves the `head_dim` row as its only window. Written as
+    `arr.at[dest, :, offset, :]` the head dimension is a window between
+    two indexed dimensions, and the TPU compiler then gives the
+    scatter's operand the layout {3,1,2,0} where the donated pool and
+    the paged kernel hold {3,2,1,0}: two layout changes of the WHOLE
+    pool per layer for K and for V each, 96 copies of 168 MB a step at
+    the served widths (PERF.md section 6, PR 27). In this form the
+    pool keeps its layout and is updated in place.
+    tests/test_paged_step_layout.py compiles both steps for a v5e and
+    fails on any pool-shaped copy. Duplicate destinations (inactive
+    slots colliding on the trash page) stay legal: no `unique_indices`
+    promise is made."""
+    heads = jnp.arange(arr.shape[1])
+    return arr.at[dest[..., None], heads, offset[..., None], :].set(
+        rows.astype(arr.dtype))
+
+
 def paged_verify_step(params, tokens, pool: PagedKVPool, page_table,
                       lengths, widths, cfg: TransformerConfig,
                       kernel: str = "gather"):
@@ -354,7 +386,9 @@ def paged_verify_step(params, tokens, pool: PagedKVPool, page_table,
 
     All real positions write K/V through the page table in one
     dispatch (columns past a row's width write to the trash page, same
-    contract as `paged_decode_step`'s inactive slots) and every query
+    contract as `paged_decode_step`'s inactive slots; the write is
+    `_write_rows`, indexed by page, head and offset so that the donated
+    pool keeps its layout and is updated in place) and every query
     attends causally — column j sees positions <= lengths[s] + j, so
     draft K/V written "in the future" of a query is masked exactly like
     unwritten page-tail garbage. logits[s, j] is therefore the target
@@ -405,11 +439,11 @@ def paged_verify_step(params, tokens, pool: PagedKVPool, page_table,
         q = _heads(h, p["Wq"], cfg)                    # (S, H, W, hd)
         k_new = _heads(h, p["Wk"], cfg)
         v_new = _heads(h, p["Wv"], cfg)
-        # advanced indices (S, W) land in front: value is (S, W, H, hd)
-        ks = layer["k"].at[dest, :, offset, :].set(
-            k_new.transpose(0, 2, 1, 3).astype(layer["k"].dtype))
-        vs = layer["v"].at[dest, :, offset, :].set(
-            v_new.transpose(0, 2, 1, 3).astype(layer["v"].dtype))
+        # rows are (S, W, H, hd), one per (slot, column, head)
+        ks = _write_rows(layer["k"], dest, offset,
+                         k_new.transpose(0, 2, 1, 3))
+        vs = _write_rows(layer["v"], dest, offset,
+                         v_new.transpose(0, 2, 1, 3))
         if kernel == "pallas":
             # one streamed single-query pass per column, each at its
             # own cursor — garbage lanes (invalid columns) stay finite
@@ -457,7 +491,9 @@ def paged_decode_step(params, tokens, pool: PagedKVPool, page_table,
     join and leave at token boundaries under ONE compiled program for
     the life of the server. Inactive slots write to the trash page and
     their logits are garbage the host ignores; lengths advance on the
-    host side only for slots that ran.
+    host side only for slots that ran. The write is `_write_rows`: it
+    indexes page, head and offset, which keeps the donated pool in the
+    layout the paged kernel reads, updated in place.
 
     `kernel` picks the attention read: "gather" materializes each
     slot's dense `(S, H, window, hd)` K/V window (O(S × max_len) HBM
@@ -500,10 +536,8 @@ def paged_decode_step(params, tokens, pool: PagedKVPool, page_table,
         q = _heads(h, p["Wq"], cfg)                        # (S, H, 1, hd)
         k_new = _heads(h, p["Wk"], cfg)[:, :, 0, :]        # (S, H, hd)
         v_new = _heads(h, p["Wv"], cfg)[:, :, 0, :]
-        ks = layer["k"].at[dest, :, offset, :].set(
-            k_new.astype(layer["k"].dtype))
-        vs = layer["v"].at[dest, :, offset, :].set(
-            v_new.astype(layer["v"].dtype))
+        ks = _write_rows(layer["k"], dest, offset, k_new)
+        vs = _write_rows(layer["v"], dest, offset, v_new)
         if kernel == "pallas":
             # stream the written pages straight from the pool — no
             # dense window; masking/trash/window-edge handled in-kernel

@@ -32,7 +32,8 @@ from deeplearning4j_tpu.serving.kv_cache import (decode_step,
                                                  generate_cached,
                                                  init_cache, kv_cache_bytes,
                                                  prefill)
-from deeplearning4j_tpu.serving.paged_kv import (init_paged_pool,
+from deeplearning4j_tpu.serving.paged_kv import (_write_rows,
+                                                 init_paged_pool,
                                                  paged_decode_step,
                                                  paged_kv_bytes,
                                                  paged_prefill,
@@ -213,6 +214,82 @@ class TestPagedParity:
         after = [np.asarray(layer["k"])[:2] for layer in pool.layers]
         for b, a in zip(before, after):
             np.testing.assert_array_equal(b, a)
+
+
+# ------------------------------------------------------- the K/V write
+def _destinations(table, pos, live, ps, trash):
+    """Physical (page, offset) of each cursor in `pos`, as both step
+    functions compute them: not live, or at or past the window's edge,
+    goes to the trash page."""
+    n_p = table.shape[1]
+    rows = np.arange(table.shape[0]).reshape((-1,) + (1,) * (pos.ndim - 1))
+    page = table[rows, np.minimum(pos // ps, n_p - 1)]
+    return (np.where(live & (pos // ps < n_p), page, trash).astype(np.int32),
+            (pos % ps).astype(np.int32))
+
+
+#: name -> (cursors (S,), widths (S,)); page 8, a window of 4 pages.
+#: A width of 0 is an idle slot; in the step a slot is active iff its
+#: width is nonzero
+WRITE_CASES = {
+    "all_live": ([0, 7, 8, 21], [1, 2, 4, 3]),
+    # slots 1 and 3 idle at the SAME offset: they collide on the trash
+    "idle_collide_on_trash": ([5, 13, 9, 21], [4, 0, 2, 0]),
+    # cursor 32 is the window's edge (page 4 of 4); 30 crosses it at
+    # its third column
+    "cursor_at_window_edge": ([32, 30, 31, 3], [1, 4, 1, 2]),
+    "ragged_widths": ([2, 15, 16, 23], [4, 1, 3, 2]),
+}
+
+
+class TestWriteRows:
+    """ISSUE 27: `_write_rows` indexes page, head and offset where the
+    old write indexed page and offset with the head dimension as a
+    window between them. Same values in the same places, bit for bit,
+    for the step ((S,) cursors) and the verify step ((S, W))."""
+
+    PS, N_PAGES, H, HD, W = 8, 20, 2, 16, 4
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("case", sorted(WRITE_CASES))
+    @pytest.mark.parametrize("step", ["decode", "verify"])
+    def test_same_bits_as_the_two_index_scatter(self, step, case, dtype):
+        rng = np.random.RandomState(27)
+        s, trash = 4, self.N_PAGES
+        shape = (self.N_PAGES + 1, self.H, self.PS, self.HD)
+        arr = jnp.asarray(rng.randn(*shape), dtype)
+        table = rng.permutation(self.N_PAGES)[:s * 4].reshape(s, 4)
+        cursors, widths = (np.asarray(a) for a in WRITE_CASES[case])
+        if step == "decode":
+            pos, live = cursors, widths > 0
+        else:
+            pos = cursors[:, None] + np.arange(self.W)[None, :]
+            live = np.arange(self.W)[None, :] < widths[:, None]
+        dest, offset = _destinations(table, pos, live, self.PS, trash)
+        # float32 rows into a bfloat16 pool: the write casts
+        rows = jnp.asarray(rng.randn(*pos.shape, self.H, self.HD),
+                           jnp.float32)
+        new = np.asarray(_write_rows(arr, jnp.asarray(dest),
+                                     jnp.asarray(offset), rows)
+                         .astype(jnp.float32))
+        old = np.asarray(arr.at[dest, :, offset, :].set(
+            rows.astype(arr.dtype)).astype(jnp.float32))
+        assert new.dtype == old.dtype and new.shape == shape
+        np.testing.assert_array_equal(new[:trash], old[:trash])
+        before = np.asarray(arr.astype(jnp.float32))
+        assert (new[:trash] != before[:trash]).any()  # it did write
+        # the trash page: a row that several writes hit holds one of
+        # them (their order is not promised), any other is untouched
+        want = np.asarray(rows.astype(arr.dtype).astype(jnp.float32))
+        hits = list(zip(*np.nonzero(dest == trash)))
+        for off in range(self.PS):
+            got = new[trash, :, off, :]
+            cands = [want[i] for i in hits if offset[i] == off]
+            if not cands:
+                np.testing.assert_array_equal(got, before[trash, :, off])
+            else:
+                for h in range(self.H):
+                    assert any((got[h] == c[h]).all() for c in cands)
 
 
 # --------------------------------------------------------- decode loop

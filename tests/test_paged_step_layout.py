@@ -1,0 +1,174 @@
+"""Compile-only, for a v5e that is described and not attached: the
+decode step and the verify step at the served widths (d 2048, 16 heads
+of 128, a bfloat16 pool of 2560 pages and the trash page, donated)
+must update the pool IN PLACE. The optimized HLO may hold no `copy`
+whose result has the pool's shape, and `input_output_alias` must name
+every leaf of the pool.
+
+What this guards (PERF.md section 6, PR 27): written as
+`arr.at[dest, :, offset, :].set(rows)` the step's K/V write is a
+scatter whose operand the TPU compiler lays out as {3,1,2,0}, against
+the {3,2,1,0} of the donated pool and of `paged_decode_attention`: two
+layout changes of a whole 168 MB pool per layer for K and for V each,
+65% of the device time of a served decode step. About 8 s a compile, no
+chip time.
+
+The topology is described inside a module fixture, never at import, and
+the tests skip where it cannot be described (the same set-up as
+tests/benchmark_suite/test_compile_v5e.py). All of them live in this
+one file: the worker that is given it loads libtpu and keeps it."""
+
+import os
+import re
+
+import pytest
+
+pytestmark = pytest.mark.pallas
+
+SLOTS, PAGES, PAGE, HEADS, HD = 16, 2560, 16, 16, 128
+POOL_SHAPE = (PAGES + 1, HEADS, PAGE, HD)
+N_LAYERS = 2
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+    os.environ.setdefault("TPU_WORKER_HOSTNAMES", "localhost")
+    os.environ.setdefault("TPU_SKIP_MDS_QUERY", "1")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means: skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip is written to JAX's persistent
+    cache but cannot be read back without one; keep these out of it."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def _cfg():
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.models.transformer import TransformerConfig
+
+    return TransformerConfig(vocab_size=50257, d_model=HEADS * HD,
+                             n_heads=HEADS, n_layers=N_LAYERS, d_ff=8192,
+                             max_len=2048, dtype=jnp.bfloat16)
+
+
+def _described(one_chip, cfg):
+    """(params, pool, table) as shapes on the described chip, and
+    `vec(*shape)` for an int32 argument of that shape."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.models.transformer import \
+        init_transformer_params
+    from deeplearning4j_tpu.serving.paged_kv import (init_paged_pool,
+                                                     pages_per_slot)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_transformer_params(jax.random.PRNGKey(0), cfg)))
+    pool = on_chip(jax.eval_shape(
+        lambda: init_paged_pool(cfg, PAGES, PAGE)))
+    assert pool.layers[0]["k"].shape == POOL_SHAPE
+
+    def vec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    return params, pool, vec(SLOTS, pages_per_slot(cfg, PAGE)), vec
+
+
+def _assert_pool_updated_in_place(text):
+    dims = ",".join(str(n) for n in POOL_SHAPE)
+    copies = re.findall(
+        rf"^.*= bf16\[{dims}\]\{{[^}}]*\}} copy\(.*$", text, re.M)
+    assert not copies, (
+        f"{len(copies)} pool-shaped copies in the step's program, "
+        f"first: {copies[0][:200]}")
+    alias = re.search(r"input_output_alias=\{(.*?)\}, entry", text, re.S)
+    assert alias, "the compiled module aliases no input to an output"
+    # one alias per pool leaf: K and V of every layer
+    leaves = re.findall(r"(?:may|must)-alias", alias.group(1))
+    assert len(leaves) == 2 * N_LAYERS, alias.group(1)
+
+
+def test_decode_step_holds_no_pool_shaped_copy(one_chip,
+                                               no_compile_cache):
+    """The step as `DecodeLoop` jits it: `paged_decode_step` on the
+    paged lane with the argmax fed back, under a `lax.scan` of length
+    1 (horizon 1), pool donated."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.serving.paged_kv import paged_decode_step
+
+    cfg = _cfg()
+    params, pool, table, vec = _described(one_chip, cfg)
+
+    def step_fn(params, tokens, pool, table, lengths, stop):
+        def inner(carry, _):
+            tokens, lengths, pool = carry
+            act = lengths < stop
+            logits, pool = paged_decode_step(
+                params, tokens, pool, table, lengths, act, cfg,
+                kernel="pallas")
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            tokens = jnp.where(act, nxt, tokens)
+            lengths = lengths + act.astype(lengths.dtype)
+            return (tokens, lengths, pool), nxt
+
+        (tokens, lengths, pool), toks = jax.lax.scan(
+            inner, (tokens, lengths, pool), None, length=1)
+        return toks, tokens, lengths, pool
+
+    text = jax.jit(step_fn, donate_argnums=(2,)).lower(
+        params, vec(SLOTS), pool, table, vec(SLOTS),
+        vec(SLOTS)).compile().as_text()
+    assert "paged_decode_attention" in text
+    _assert_pool_updated_in_place(text)
+
+
+def test_verify_step_holds_no_pool_shaped_copy(one_chip,
+                                               no_compile_cache):
+    """`paged_verify_step` at W 4 on the paged lane, pool donated, as
+    `DecodeLoop`'s `verify_fn` jits it."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.serving.paged_kv import paged_verify_step
+
+    cfg = _cfg()
+    params, pool, table, vec = _described(one_chip, cfg)
+
+    def verify_fn(params, tokens, pool, table, lengths, widths):
+        logits, pool = paged_verify_step(
+            params, tokens, pool, table, lengths, widths, cfg,
+            kernel="pallas")
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), pool
+
+    text = jax.jit(verify_fn, donate_argnums=(2,)).lower(
+        params, vec(SLOTS, 4), pool, table, vec(SLOTS),
+        vec(SLOTS)).compile().as_text()
+    assert "paged_decode_attention" in text
+    _assert_pool_updated_in_place(text)
