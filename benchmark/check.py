@@ -35,7 +35,7 @@ from typing import Dict, List
 import numpy as np
 
 from benchmark import weights
-from benchmark.manifest import Cell, load_reference, shape_of
+from benchmark.manifest import Cell
 
 PAD_TO = 512
 SMALL_GRADIENT = 1e-3
@@ -91,10 +91,9 @@ def serve_numbers(cell: Cell, seed: int, sample: List[dict],
     import jax
     import jax.numpy as jnp
 
-    ref = load_reference(cell.config)
-    shape = shape_of(cell.config)
-    params = weights.make_params(seed, shape,
-                                 jnp.dtype(cell.config["dtype"]))
+    ref = cell.family.reference()
+    max_len = cell.family.sizes(cell.config)["max_len"]
+    params = weights.make_params(seed, cell.family, cell.config)
     position_gaps = jax.jit(_position_gaps)
     argmax = jax.jit(lambda lg: jnp.argmax(lg, axis=-1).astype(jnp.int32))
     gaps, tokens = [], 0
@@ -105,19 +104,19 @@ def serve_numbers(cell: Cell, seed: int, sample: List[dict],
                               served[:-1]])
         first = rec["prompt_len"] - 1
         last = first + len(served)
-        width = min(-(-len(seq) // PAD_TO) * PAD_TO, shape["max_len"])
+        width = min(-(-len(seq) // PAD_TO) * PAD_TO, max_len)
         padded = np.zeros((1, width), np.int32)
         padded[0, :len(seq)] = seq
         picks = np.zeros((width,), np.int32)
         picks[first:last] = served
-        lg = ref.logits(params, jnp.asarray(padded), shape["n_heads"],
-                        0, width)[0]
+        lg = ref.logits(cell.config, params, jnp.asarray(padded), 0,
+                        width)[0]
         gaps.append(np.asarray(
             position_gaps(lg, jnp.asarray(picks)))[first:last])
         tokens += len(served)
         for mode in control_modes:
-            low = ref.logits(params, jnp.asarray(padded),
-                             shape["n_heads"], 0, width, mode=mode)[0]
+            low = ref.logits(cell.config, params, jnp.asarray(padded),
+                             0, width, mode=mode)[0]
             control[mode].append(np.asarray(
                 position_gaps(lg, argmax(low)))[first:last])
     if not gaps:
@@ -182,29 +181,24 @@ def reference_steps(cell: Cell, seed: int, steps: int, mode: str = "f32",
 
     from benchmark.train import batch_ids, leaf_norms
 
-    ref = load_reference(cell.config)
-    shape = shape_of(cell.config)
-    tr = cell.config["training"]
+    ref = cell.family.reference()
+    vocab = cell.family.sizes(cell.config)["vocab_size"]
     n_rows, seq_len = int(cell.traffic["batch"]), int(cell.traffic["seq_len"])
     per_block = max(1, 1024 // seq_len)
-    store = jnp.dtype(cell.config["dtype"])
-    f32 = lambda t: jax.tree_util.tree_map(  # noqa: E731
-        lambda a: a.astype(jnp.float32), t)
-    base = f32(weights.make_params(seed, shape, store))
+    base = jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.float32),
+        weights.make_params(seed, cell.family, cell.config))
     params = jax.tree_util.tree_map(jnp.copy, base)
-    velocity = jax.tree_util.tree_map(jnp.zeros_like, base)
+    state = ref.init_state(base)
     out = {"loss": []}
     for i in range(steps):
-        ids = jnp.asarray(batch_ids(seed, i, n_rows, seq_len,
-                                    shape["vocab_size"]))
-        loss, grads = ref.loss_and_grad(params, ids, shape["n_heads"],
+        ids = jnp.asarray(batch_ids(seed, i, n_rows, seq_len, vocab))
+        loss, grads = ref.loss_and_grad(cell.config, params, ids,
                                         per_block, mode=mode, rows=rows)
         out["loss"].append(float(loss))
         if i == 0:
             out["grad_norms"] = leaf_norms(grads)
-        params, velocity = ref.sgd_momentum(
-            params, velocity, grads, float(tr["lr"]),
-            float(tr["momentum"]), store=cell.config["dtype"])
+        params, state = ref.update(cell.config, params, state, grads)
     out["change_norms"] = leaf_norms(params, base)
     return out
 

@@ -77,11 +77,10 @@ def prefilled_in_trace(ctx: dict) -> Tuple[int, float, float]:
     if not tokens or not mine:
         return 0, 0.0, 0.0
     scale = tokens / sum(mine)
-    shape, peak = ctx["shape"], ctx["peak"]
-    ops = sum(flops.prefill_flops(shape, n) for n in mine) * scale
-    least = sum(flops.least_seconds(
-        flops.flash_fwd_work(shape, 1, n, ctx["itemsize"]), peak)
-        for n in mine) * scale * shape["n_layers"]
+    family = ctx["family"]
+    ops = sum(family.prefill_flops(ctx, n) for n in mine) * scale
+    least = sum(flops.least_seconds(w, ctx["peak"]) for n in mine
+                for w in family.flash_fwd_work(ctx, 1, n)) * scale
     return tokens, ops, least
 
 

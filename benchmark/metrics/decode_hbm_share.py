@@ -1,6 +1,6 @@
 """Bytes a decode step must move (every weight once, the live K/V once)
 over the chip's bandwidth, over the decode program's device time."""
-from benchmark import flops, measure
+from benchmark import measure
 
 
 def read(ctx):
@@ -12,6 +12,6 @@ def read(ctx):
         return None
     # keys read per step: the decoded tokens' contexts, a step's worth
     keys = sum(measure.decoded_in_trace(ctx)) / n
-    byts = flops.decode_step_bytes(ctx["shape"], [keys], ctx["itemsize"])
+    byts = ctx["family"].decode_step_bytes(ctx, [keys])
     least = byts / ctx["peak"]["hbm_bytes_per_s"]
     return measure.share(least, secs / calls)

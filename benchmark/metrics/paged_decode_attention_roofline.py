@@ -1,7 +1,7 @@
 """The paged decode kernel's least time (whole pages of K and V read
 once, bandwidth-bound) over its time in the trace. A call is one layer
-of one dispatch; its work is a dispatch's mean, from the contexts of the
-tokens decoded while the trace ran."""
+of one dispatch; a dispatch's work is the mean over the traced span,
+from the contexts of the tokens decoded while the trace ran."""
 from benchmark import flops, measure, trace_reduce
 
 
@@ -15,8 +15,6 @@ def read(ctx):
     contexts = measure.decoded_in_trace(ctx)
     if not secs or not n or not contexts:
         return None
-    srv = ctx["config"]["serving"]
-    work = flops.paged_decode_attention_work(
-        ctx["shape"], contexts, int(srv["page_size"]), ctx["itemsize"])
-    least = flops.least_seconds(work, ctx["peak"]) / n * calls
+    works = ctx["family"].paged_decode_attention_work(ctx, contexts)
+    least = flops.least_seconds_for(works, calls, ctx["peak"]) / n
     return measure.share(least, secs)

@@ -10,7 +10,6 @@ def read(ctx):
     secs = trace_reduce.matching(tr["op_s"], "flash_bwd")
     calls = trace_reduce.matching(tr["op_n"], "flash_bwd_dq")
     t = ctx["train"]
-    work = flops.flash_bwd_work(ctx["shape"], t["rows"], t["seq_len"],
-                                ctx["itemsize"])
+    works = ctx["family"].flash_bwd_work(ctx, t["rows"], t["seq_len"])
     return measure.share(
-        flops.least_seconds(work, ctx["peak"]) * calls, secs)
+        flops.least_seconds_for(works, calls, ctx["peak"]), secs)

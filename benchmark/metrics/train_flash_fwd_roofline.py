@@ -1,5 +1,5 @@
-"""The flash forward kernel's least time per call (one layer, all rows
-of the step) x its calls in the trace, over its time there."""
+"""The flash forward kernel's least time for its calls in the trace
+(one a layer, all rows of the step), over its time there."""
 from benchmark import flops, measure, trace_reduce
 
 
@@ -10,7 +10,6 @@ def read(ctx):
     secs = trace_reduce.matching(tr["op_s"], "flash_fwd")
     calls = trace_reduce.matching(tr["op_n"], "flash_fwd")
     t = ctx["train"]
-    work = flops.flash_fwd_work(ctx["shape"], t["rows"], t["seq_len"],
-                                ctx["itemsize"])
+    works = ctx["family"].flash_fwd_work(ctx, t["rows"], t["seq_len"])
     return measure.share(
-        flops.least_seconds(work, ctx["peak"]) * calls, secs)
+        flops.least_seconds_for(works, calls, ctx["peak"]), secs)

@@ -1,3 +1,2 @@
-"""Plain references, one module per architecture, found by the
-`reference` key of a configuration file. They import nothing of the
-program under test."""
+"""Plain references, one module per family, handed out by the family's
+`reference()`. They import nothing of the program under test."""

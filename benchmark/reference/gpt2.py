@@ -10,6 +10,8 @@ tanh form of GELU for both models.
 
 It imports nothing from `deeplearning4j_tpu` and is handed nothing the
 program made: the weights come from `benchmark.weights` and the seed.
+Its entries (`logits`, `loss_and_grad`, `init_state`, `update`) take
+the configuration file, as `benchmark/families/__init__.py` says.
 
 `mode` lowers the precision for the control that has to FAIL the
 comparison (`benchmark/check.py`): "f32" is the reference; "bf16"
@@ -121,11 +123,12 @@ def _head(ln_f, embed, x, mode: str = "f32"):
     return _mm(layer_norm(ln_f, x), embed.astype(jnp.float32).T, mode)
 
 
-def logits(params, tokens, n_heads: int, first: int, last: int,
+def logits(config: dict, params, tokens, first: int, last: int,
            mode: str = "f32"):
     """Logits (B, last - first, V) of positions first..last-1 of
     `tokens` (B, T), layer by layer so that one block's float32 copy
-    lives at a time."""
+    lives at a time. `config` is the configuration file."""
+    n_heads = int(config["n_head"])
     x = _embed(params["embed"], params["pos"], tokens)
     for p in params["blocks"]:
         x = _block_jit(p, x, n_heads=n_heads, mode=mode)
@@ -161,11 +164,12 @@ _loss_grad = jax.jit(jax.value_and_grad(loss_sum),
                      static_argnames=("n_heads", "mode"))
 
 
-def loss_and_grad(params, tokens, n_heads: int, rows_per_block: int,
+def loss_and_grad(config: dict, params, tokens, rows_per_block: int,
                   mode: str = "f32", rows=None):
     """Mean loss over the step's rows and its gradient, in blocks of
     rows. `rows` picks a subset (the half-batch fault of the tests and
     the calibration); the mean is over the rows taken."""
+    n_heads = int(config["n_head"])
     if rows is not None:
         tokens = tokens[rows]
     n_rows, width = tokens.shape
@@ -182,9 +186,22 @@ def loss_and_grad(params, tokens, n_heads: int, rows_per_block: int,
         lambda a: a * scale, grads)
 
 
+def init_state(params):
+    """The optimizer's state before the first step: a velocity of 0."""
+    return jax.tree_util.tree_map(jnp.zeros_like, params)
+
+
+def update(config: dict, params, state, grads):
+    """One step of the optimizer the configuration's `training` section
+    states; returns (params, state)."""
+    tr = config["training"]
+    return _sgd_momentum(params, state, grads, float(tr["lr"]),
+                         float(tr["momentum"]), store=config["dtype"])
+
+
 @partial(jax.jit, static_argnames=("store",), donate_argnums=(0, 1))
-def sgd_momentum(params, velocity, grads, lr: float, momentum: float,
-                 store: str):
+def _sgd_momentum(params, velocity, grads, lr: float, momentum: float,
+                  store: str):
     """v <- m v + g in float32; p <- p - lr v, rounded to the type the
     configuration stores parameters in (`store`: "bfloat16" or
     "float32"), held here as float32 values of that type."""

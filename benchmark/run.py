@@ -127,7 +127,7 @@ def drive_serve(cell, seed, seconds, ctx, programs, tracer,
     from benchmark import check, serve
 
     engine, params = serve.build_engine(cell, seed)
-    plan = serve.warm(engine, cell, seed, seconds)
+    warm_groups = serve.warm(engine, cell, seed, seconds)
 
     def stamp_setup():
         ctx["setup_s"] = time.perf_counter() - ctx["process_start"]
@@ -136,7 +136,7 @@ def drive_serve(cell, seed, seconds, ctx, programs, tracer,
                                 tracer, on_start=stamp_setup))
     ctx["memory_peak_bytes"] = memory_peak()
     attempted, failed = serve.attempted_failed(ctx)
-    say(f"[{cell.name}] warm_groups={plan['executed']} "
+    say(f"[{cell.name}] warm_groups={warm_groups} "
         + json.dumps(ctx["diagnosis"]))
     sample = check.pick_requests(ctx["requests"], ctx["window"],
                                  int(cell.traffic["check_requests"]), seed)
@@ -170,7 +170,7 @@ def execute(cell: manifest.Cell, seed: int, seconds: float, trace: bool,
     programs = serve.ProgramCounter()
     tracer = tracing.Tracer(keep_trace_in) if trace else None
     ctx = {"cell": cell.name, "config": cell.config,
-           "traffic": cell.traffic, "shape": manifest.shape_of(cell.config),
+           "traffic": cell.traffic, "family": cell.family,
            "itemsize": manifest.itemsize_of(cell.config), "peak": peak,
            "seconds": float(seconds), "device": device,
            "process_start": time.perf_counter() if process_start is None

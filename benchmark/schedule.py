@@ -111,19 +111,6 @@ def pow2_at_least(n: int) -> int:
     return b
 
 
-def prompt_buckets(max_len: int, page_size: int) -> Tuple[int, ...]:
-    """The program's prefill buckets (page-multiple powers of two up to
-    the window), the benchmark's own copy of the rule in
-    `serving/paged_kv.py`."""
-    top = -(-max_len // page_size) * page_size
-    out, b = [], page_size
-    while b < top:
-        out.append(b)
-        b *= 2
-    out.append(top)
-    return tuple(out)
-
-
 def bucket_of(plen: int, buckets: Sequence[int]) -> int:
     return next(b for b in buckets if b >= plen)
 
@@ -147,17 +134,17 @@ def max_arrivals(offsets: Sequence[float], span_s: float) -> int:
 
 
 def warm_groups(traffic: dict, seconds: float, slots: int,
-                max_len: int, page_size: int) -> Dict[str, list]:
+                buckets: Sequence[int]) -> Dict[str, list]:
     """Every prefill program the cell's schedule CAN reach, not those a
-    replay happened to reach: `groups` is each (bb, tb) with tb a bucket
-    the file's prompt range touches and bb a power of two up to the most
-    requests one scheduler pass can admit; `sizes` is every count of
-    requests 1..that, because the program's hand-over of first tokens
-    compiles per (bb, count). Closed loop: all clients start at once, so
+    replay happened to reach, given the prompt lengths the program has
+    prefill programs for (`buckets`, the family's to say): `groups` is
+    each (bb, tb) with tb a bucket the file's prompt range touches and
+    bb a power of two up to the most requests one scheduler pass can
+    admit; `sizes` is every count of requests 1..that, because the
+    program's hand-over of first tokens compiles per (bb, count). Closed loop: all clients start at once, so
     up to the slots. Open loop: the most arrivals of its schedule in any
     half second, doubled, rounded up to a power of two, never above the
     slots."""
-    buckets = prompt_buckets(max_len, page_size)
     lo, hi = length_range(traffic["prompt_len"])
     touched = [b for b in buckets
                if b >= bucket_of(lo, buckets) and b <= bucket_of(hi, buckets)]

@@ -4,10 +4,10 @@ arrivals, both through `InferenceEngine.generate_stream`.
 One process, no HTTP server and no child: the window drives
 `generate_stream` -> `DecodeLoop.submit` -> paged cache -> flash prefill
 -> paged decode kernel. Set-up makes the weights on the device from the
-seed, builds the engine, and executes once every prefill group the
-cell's schedule can reach (`schedule.warm_groups`), so that nothing is
-compiled or loaded inside the window; `programs_in_window` counts what
-was all the same.
+seed, has the cell's family build the engine, and executes once every
+prefill group the cell's schedule can reach (the family's
+`warm_requests`), so that nothing is compiled or loaded inside the
+window; `programs_in_window` counts what was all the same.
 """
 
 from __future__ import annotations
@@ -20,7 +20,7 @@ from typing import List, Optional
 import numpy as np
 
 from benchmark import schedule, weights
-from benchmark.manifest import Cell, shape_of
+from benchmark.manifest import Cell
 
 #: a client gives up on a token after this long: the request has failed
 TOKEN_TIMEOUT_S = 120.0
@@ -75,59 +75,28 @@ def loop_programs(loop) -> int:
 
 
 # ---------------------------------------------------------------- set-up
-def transformer_config(config: dict):
-    import jax.numpy as jnp
-
-    from deeplearning4j_tpu.models.transformer import TransformerConfig
-
-    s = shape_of(config)
-    return TransformerConfig(
-        vocab_size=s["vocab_size"], d_model=s["d_model"],
-        n_heads=s["n_heads"], n_layers=s["n_layers"], d_ff=s["d_ff"],
-        max_len=s["max_len"], dtype=jnp.dtype(config["dtype"]))
-
-
 def build_engine(cell: Cell, seed: int):
     """Weights on the device from the seed, then the engine the cell's
-    configuration states."""
-    import jax.numpy as jnp
-
-    from deeplearning4j_tpu.serving.engine import InferenceEngine
-
-    srv = cell.config["serving"]
-    params = weights.make_params(seed, shape_of(cell.config),
-                                 jnp.dtype(cell.config["dtype"]))
-    engine = InferenceEngine.for_transformer(
-        params, transformer_config(cell.config),
-        decode_slots=int(srv["slots"]), page_size=int(srv["page_size"]),
-        kv_pages=int(srv["kv_pages"]),
-        decode_kernel=srv["decode_kernel"], horizon=int(srv["horizon"]),
-        speculation=int(srv["speculation"]),
-        prefix_cache=bool(srv["prefix_cache"]))
-    return engine, params
+    configuration states, as its family builds it."""
+    params = weights.make_params(seed, cell.family, cell.config)
+    return cell.family.build_engine(cell.config, params), params
 
 
-def warm(engine, cell: Cell, seed: int, seconds: float) -> dict:
-    """Execute once, on throw-away requests, every prefill group the
-    schedule can reach and every group size, and with them the decode
-    step. The requests stay out of the prefix cache."""
-    shape = shape_of(cell.config)
-    srv = cell.config["serving"]
-    plan = schedule.warm_groups(cell.traffic, seconds, int(srv["slots"]),
-                                shape["max_len"], int(srv["page_size"]))
-    loop = engine.decode_loop
-    todo = [(n, plan["buckets"][0]) for n in plan["sizes"]]
-    done = {(schedule.pow2_at_least(n), tb) for n, tb in todo}
-    todo += [(bb, tb) for bb, tb in plan["groups"] if (bb, tb) not in done]
-    for i, (n, tb) in enumerate(todo):
-        plen = min(tb, shape["max_len"] - 2)
+def warm(engine, cell: Cell, seed: int, seconds: float) -> int:
+    """Execute once, on throw-away requests, every program the schedule
+    can reach: the family's groups of requests, one after the other.
+    The requests stay out of the prefix cache. Returns the count of
+    groups."""
+    vocab = cell.family.sizes(cell.config)["vocab_size"]
+    todo = cell.family.warm_requests(cell.config, cell.traffic, seconds)
+    for i, (n, plen) in enumerate(todo):
         prompts = [weights.token_ids(seed, WARM_STREAM, i * 64 + r, plen,
-                                     shape["vocab_size"])
+                                     vocab)
                    for r in range(n)]
-        for s in loop.submit_many(prompts, 2, prefix_cache=False):
+        for s in engine.decode_loop.submit_many(prompts, 2,
+                                                prefix_cache=False):
             s.result(timeout=TOKEN_TIMEOUT_S * 5)
-    plan["executed"] = len(todo)
-    return plan
+    return len(todo)
 
 
 # ---------------------------------------------------------------- window
@@ -169,7 +138,7 @@ def _send(engine, rec: dict) -> None:
 def _prompt(cell: Cell, seed: int, req: schedule.Request) -> np.ndarray:
     return weights.token_ids(seed, PROMPT_STREAM, req.index,
                              req.prompt_len,
-                             shape_of(cell.config)["vocab_size"])
+                             cell.family.sizes(cell.config)["vocab_size"])
 
 
 class Traffic:

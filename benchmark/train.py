@@ -3,11 +3,12 @@ back, the host waiting on the loss every `sync_every`-th step and at the
 end of the window; the input pipeline (token batches made on the host
 from the seed and put on the device) runs inside the window.
 
-Set-up builds ONE object, the compiled step with its state, drives it
-through its first three steps on the seed's first three batches (their
-losses, the first gradient as the optimizer got it and the parameters'
-change are what `check.py` holds against the reference), warms it up,
-and hands that same object to the window.
+Set-up builds ONE object, the compiled step with its state (the cell's
+family's `make_train_step`), drives it through its first three steps on
+the seed's first three batches (their losses, the first gradient as the
+optimizer got it and the parameters' change are what `check.py` holds
+against the reference), warms it up, and hands that same object to the
+window.
 """
 
 from __future__ import annotations
@@ -20,7 +21,7 @@ from typing import Optional
 import numpy as np
 
 from benchmark import weights
-from benchmark.manifest import Cell, shape_of
+from benchmark.manifest import Cell
 
 TRAIN_STREAM = 1
 CHECK_STEPS = 3
@@ -57,21 +58,13 @@ class TrainLoop:
     """The compiled step with its state and its feed."""
 
     def __init__(self, cell: Cell, seed: int):
-        import jax.numpy as jnp
-
-        from benchmark.serve import transformer_config
-        from deeplearning4j_tpu.models import transformer
-
         self.cell, self.seed = cell, seed
-        self.shape = shape_of(cell.config)
+        self.vocab = cell.family.sizes(cell.config)["vocab_size"]
         self.rows = int(cell.traffic["batch"])
         self.seq_len = int(cell.traffic["seq_len"])
-        self.dtype = jnp.dtype(cell.config["dtype"])
-        self.params = weights.make_params(seed, self.shape, self.dtype)
-        self.velocity = transformer.init_velocity(self.params)
-        self.step = transformer.make_train_step(
-            transformer_config(cell.config),
-            lr=float(cell.config["training"]["lr"]))
+        self.params = weights.make_params(seed, cell.family, cell.config)
+        self.step, self.state = cell.family.make_train_step(
+            cell.config, self.params)
         self.index = 0
 
     @property
@@ -83,7 +76,7 @@ class TrainLoop:
 
         with jax.profiler.TraceAnnotation("bench.feed"):
             ids = batch_ids(self.seed, self.index, self.rows,
-                            self.seq_len, self.shape["vocab_size"])
+                            self.seq_len, self.vocab)
             return jax.device_put(ids)
 
     def advance(self):
@@ -93,8 +86,8 @@ class TrainLoop:
 
         batch = self.feed()
         with jax.profiler.TraceAnnotation("bench.train_step"):
-            self.params, self.velocity, loss = self.step(
-                self.params, self.velocity, batch)
+            self.params, self.state, loss = self.step(
+                self.params, self.state, batch)
         self.index += 1
         return loss
 
@@ -104,10 +97,10 @@ class TrainLoop:
         for i in range(CHECK_STEPS):
             out["loss"].append(float(self.advance()))
             if i == 0:
-                # momentum starts at 0, so the velocity after one step
-                # IS the first gradient as the optimizer got it
-                out["grad_norms"] = leaf_norms(self.velocity)
-        base = weights.make_params(self.seed, self.shape, self.dtype)
+                out["grad_norms"] = leaf_norms(
+                    self.cell.family.first_gradient(self.state))
+        base = weights.make_params(self.seed, self.cell.family,
+                                   self.cell.config)
         out["change_norms"] = leaf_norms(self.params, base)
         return out
 
@@ -118,7 +111,7 @@ class TrainLoop:
         float(loss)
 
     def free(self) -> None:
-        self.params = self.velocity = None
+        self.params = self.state = None
 
 
 def run_window(loop: TrainLoop, seconds: float, tracer=None) -> dict:

@@ -1,18 +1,18 @@
 """Weights and token ids from `--seed`, and nothing else from it.
 
 The weights are made on the device in one jitted call, in the type they
-are served or trained in. The layout is the one `models/transformer.py`
-takes (embed, pos, ln_f, blocks of ln1/Wq/Wk/Wv/Wo/ln2/W1/b1/W2/b2);
-the plain reference builds the same tree from the same seed by calling
-this module again, so it never takes an array the program has held.
+are served or trained in. The layout is the cell's family's
+(`param_shapes`, `is_gain`); the plain reference builds the same tree
+from the same seed by calling this module again, so it never takes an
+array the program has held.
 """
 
 from __future__ import annotations
 
 import numpy as np
 
-#: every matrix, the embedding and the positions are N(0, STD); biases
-#: too, so that a path that drops one is seen; LayerNorm gains are 1
+#: every leaf is N(0, STD), biases too, so that a path that drops one
+#: is seen; the leaves the family calls gains are 1
 STD = 0.02
 
 
@@ -28,27 +28,14 @@ def key_for(seed: int):
     return jax.random.wrap_key_data(data, impl="threefry2x32")
 
 
-def leaf_shapes(shape_cfg: dict) -> dict:
-    """The parameter tree as shapes: {"embed": (V, d), ..., "blocks":
-    [{...}] * n_layers}. `shape_cfg` has vocab_size, d_model, n_layers,
-    d_ff, max_len."""
-    v, d = shape_cfg["vocab_size"], shape_cfg["d_model"]
-    f, t = shape_cfg["d_ff"], shape_cfg["max_len"]
-    block = {"ln1": {"g": (d,), "b": (d,)},
-             "Wq": (d, d), "Wk": (d, d), "Wv": (d, d), "Wo": (d, d),
-             "ln2": {"g": (d,), "b": (d,)},
-             "W1": (d, f), "b1": (f,), "W2": (f, d), "b2": (d,)}
-    return {"embed": (v, d), "pos": (t, d),
-            "ln_f": {"g": (d,), "b": (d,)},
-            "blocks": [block for _ in range(shape_cfg["n_layers"])]}
-
-
-def make_params(seed: int, shape_cfg: dict, dtype):
-    """The whole tree on the device, one jitted call."""
+def make_params(seed: int, family, config: dict):
+    """The family's whole tree for this configuration on the device,
+    one jitted call, in the type the configuration states."""
     import jax
     import jax.numpy as jnp
 
-    shapes = leaf_shapes(shape_cfg)
+    shapes = family.param_shapes(config)
+    dtype = jnp.dtype(config["dtype"])
     is_shape = lambda x: isinstance(x, tuple)  # noqa: E731
     leaves, treedef = jax.tree_util.tree_flatten_with_path(
         shapes, is_leaf=is_shape)
@@ -57,7 +44,7 @@ def make_params(seed: int, shape_cfg: dict, dtype):
         out = []
         for i, (path, shape) in enumerate(leaves):
             k = jax.random.fold_in(key, i)
-            if getattr(path[-1], "key", None) == "g":
+            if family.is_gain(jax.tree_util.keystr(path)):
                 out.append(jnp.ones(shape, dtype))
             else:
                 out.append((STD * jax.random.normal(

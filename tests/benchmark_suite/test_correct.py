@@ -64,25 +64,10 @@ def test_serving_control_in_fp8_is_not_correct(root):
     positions the fp8 reading of the same weights puts other tokens
     first, by more than the limit; the bfloat16 reading, the precision
     the configuration states, stays within it."""
-    import jax.numpy as jnp
-    import numpy as np
-
-    from benchmark import check, weights
-    from benchmark.reference import gpt2
+    from benchmark import check
 
     cell = manifest.load_cell("tiny.tiny-closed", root)
-    shape = manifest.shape_of(cell.config)
-    params = weights.make_params(SEED, shape, jnp.bfloat16)
-    sample = []
-    for i in range(40):
-        prompt = weights.token_ids(SEED, 0, i, 24, shape["vocab_size"])
-        seq = list(prompt)
-        for _ in range(16):
-            lg = gpt2.logits(params, jnp.asarray([seq], jnp.int32),
-                             shape["n_heads"], len(seq) - 1, len(seq))
-            seq.append(int(jnp.argmax(lg[0, 0])))
-        sample.append({"prompt": np.asarray(prompt), "prompt_len": 24,
-                       "tokens": seq[24:]})
+    sample = tiny.greedy_sample(cell, SEED, 40, 24, 16)
     numbers = check.serve_numbers(cell, SEED, sample, ("fp8", "bf16"))
     limit = cell.limits["token_gap_max"]
     assert numbers["tokens_compared"] == 40 * 16

@@ -118,7 +118,8 @@ def test_every_configuration_has_a_cell_and_its_files():
             raw = json.load(f)
         assert raw["source"] == c["source"]
         assert c["reduced"] == []
-        manifest.load_reference(raw)
+        family = manifest.load_family(raw)
+        assert callable(family.reference().logits)
     files = [c["file"] for c in M["configs"]]
     assert len(files) == len(set(files))
     four = [w for w in M["workloads"] if w["chips"] == 4]
@@ -149,3 +150,12 @@ def test_an_unknown_device_is_an_error():
         manifest.load_peak("TPU v9 imaginary")
     with pytest.raises(KeyError):
         manifest.load_cell("no-such-cell")
+
+
+@pytest.mark.parametrize("config", [{"family": "no_such_family"}, {}],
+                         ids=["unknown", "unnamed"])
+def test_an_unknown_family_is_an_error_that_names_it(config):
+    with pytest.raises(KeyError) as e:
+        manifest.load_family(config)
+    assert repr(config.get("family")) in str(e.value)
+    assert "gpt2" in str(e.value)      # what the benchmark does have

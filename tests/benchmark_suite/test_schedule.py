@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from benchmark import manifest, schedule, weights
+from benchmark.families import gpt2
 
 TRAFFIC_DIR = os.path.join(manifest.ROOT, "benchmark", "traffic")
 FILES = sorted(glob.glob(os.path.join(TRAFFIC_DIR, "*.json")))
@@ -119,7 +120,7 @@ def brute_force_groups(traffic, slots, max_len, page_size):
     form: closed loop, any number of clients up to all of them; open
     loop, every run of consecutive arrivals inside one second (twice the
     half second the rule reckons with), as many as the slots take."""
-    buckets = schedule.prompt_buckets(max_len, page_size)
+    buckets = gpt2.prompt_buckets(max_len, page_size)
     out = set()
 
     def add(reqs):
@@ -149,7 +150,8 @@ def brute_force_groups(traffic, slots, max_len, page_size):
                                   != "train"], ids=os.path.basename)
 def test_warm_set_covers_every_group_the_schedule_can_form(path):
     traffic = load(path)
-    warm = schedule.warm_groups(traffic, SECONDS, 16, 2048, 16)
+    warm = schedule.warm_groups(traffic, SECONDS, 16,
+                                gpt2.prompt_buckets(2048, 16))
     reachable = brute_force_groups(traffic, 16, 2048, 16)
     assert reachable and reachable <= set(warm["groups"])
     most = max(bb for bb, _ in reachable)
@@ -163,5 +165,5 @@ def test_buckets_are_the_program_s():
 
     for max_len, page in ((2048, 16), (1024, 16), (100, 16), (64, 8)):
         cfg = TransformerConfig(vocab_size=8, max_len=max_len)
-        assert schedule.prompt_buckets(max_len, page) == \
+        assert gpt2.prompt_buckets(max_len, page) == \
             prompt_buckets(cfg, page)

@@ -85,10 +85,10 @@ def test_failed_or_silent_requests_count_as_the_worst():
 
 
 def test_train_rate_and_mfu_use_all_the_time():
+    cell = manifest.load_cell("gpt2m-train-t1024")
     ctx = {"train": {"steps": 100, "tokens_per_step": 8192,
                      "elapsed": 20.0, "seq_len": 1024, "rows": 8},
-           "shape": manifest.shape_of(manifest.load_cell(
-               "gpt2m-train-t1024").config),
+           "config": cell.config, "family": cell.family, "itemsize": 2,
            "peak": manifest.load_peak("TPU v5 lite")}
     assert reader("train_tok_s")(ctx) == pytest.approx(40960.0)
     mfu = reader("train_mfu")(ctx)

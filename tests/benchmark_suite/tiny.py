@@ -1,8 +1,8 @@
 """A whole benchmark at a size a test run can hold: a temporary root
 with its own `BENCHMARK.json`, one tiny configuration, one traffic mix
-of each driver, their cells and limits, and the real metric readers and
-peaks copied beside them. The harness's loader and drivers run over it
-exactly as over the real files."""
+of each driver, their cells and limits, and the real metric readers,
+families and peaks copied beside them. The harness's loader and drivers
+run over it exactly as over the real files."""
 
 from __future__ import annotations
 
@@ -13,7 +13,7 @@ import shutil
 from benchmark import manifest
 
 CONFIG = {
-    "source": "none: a test size", "reference": "gpt2",
+    "source": "none: a test size", "family": "gpt2",
     "vocab_size": 97, "n_embd": 32, "n_head": 4, "n_layer": 2,
     "n_inner": 64, "n_positions": 64, "dtype": "bfloat16",
     "serving": {"slots": 4, "page_size": 16, "kv_pages": 16,
@@ -57,8 +57,10 @@ def build(root: str) -> str:
     bench = os.path.join(root, "benchmark")
     for sub in ("configs", "traffic", "cells"):
         os.makedirs(os.path.join(bench, sub), exist_ok=True)
-    shutil.copytree(os.path.join(manifest.ROOT, "benchmark", "metrics"),
-                    os.path.join(bench, "metrics"), dirs_exist_ok=True)
+    for sub in ("metrics", "families"):
+        shutil.copytree(os.path.join(manifest.ROOT, "benchmark", sub),
+                        os.path.join(bench, sub), dirs_exist_ok=True,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     shutil.copy(os.path.join(manifest.ROOT, "benchmark", "peaks.json"),
                 bench)
     with open(os.path.join(bench, "configs", "tiny.json"), "w") as f:
@@ -106,3 +108,31 @@ def build(root: str) -> str:
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(bm, f)
     return root
+
+
+def greedy_sample(cell, seed: int, n: int, prompt_len: int,
+                  tokens: int) -> list:
+    """What a sound server would have served: `n` prompts from the
+    seed, each decoded greedily by the cell's reference in float32, as
+    the records `check.serve_numbers` takes."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark import weights
+
+    ref = cell.family.reference()
+    vocab = cell.family.sizes(cell.config)["vocab_size"]
+    params = weights.make_params(seed, cell.family, cell.config)
+    sample = []
+    for i in range(n):
+        prompt = weights.token_ids(seed, 0, i, prompt_len, vocab)
+        seq = list(prompt)
+        for _ in range(tokens):
+            lg = ref.logits(cell.config, params,
+                            jnp.asarray([seq], jnp.int32),
+                            len(seq) - 1, len(seq))
+            seq.append(int(jnp.argmax(lg[0, 0])))
+        sample.append({"prompt": np.asarray(prompt),
+                       "prompt_len": prompt_len,
+                       "tokens": seq[prompt_len:]})
+    return sample
