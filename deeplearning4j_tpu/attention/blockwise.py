@@ -33,11 +33,12 @@ def naive_attention(q, k, v, causal: bool = False, scale: Optional[float] = None
 
 
 @partial(jax.jit, static_argnames=("causal", "block_size", "q_offset",
-                                   "k_offset", "return_lse"))
+                                   "k_offset", "return_lse", "window"))
 def blockwise_attention(q, k, v, causal: bool = False,
                         block_size: int = 512,
                         q_offset: Optional[int] = None, k_offset: int = 0,
-                        return_lse: bool = False):
+                        return_lse: bool = False,
+                        window: Optional[int] = None):
     """Online-softmax attention over KV blocks.
 
     q: (..., Tq, d); k, v: (..., Tk, d). `q_offset`/`k_offset` are the
@@ -45,7 +46,8 @@ def blockwise_attention(q, k, v, causal: bool = False,
     sequence shards. Default alignment is BOTTOM-RIGHT (query i attends
     keys up to i + Tk - Tq — the KV-cache decode convention, matching
     `naive_attention`); pass q_offset explicitly for other geometries.
-    Fully-masked query rows output zeros.
+    Fully-masked query rows output zeros. `window=W` (with `causal`)
+    narrows what query i sees to keys j with i - W < j <= i.
 
     `return_lse=True` additionally returns the per-row log-sum-exp of
     the scaled scores (natural log) — fully-masked rows get the +1e30
@@ -86,6 +88,8 @@ def blockwise_attention(q, k, v, causal: bool = False,
         valid = (k_pos < k_offset + tk)
         if causal:
             valid = valid[None, :] & (k_pos[None, :] <= q_pos[:, None])
+            if window is not None:
+                valid = valid & (k_pos[None, :] > q_pos[:, None] - window)
         else:
             valid = jnp.broadcast_to(valid[None, :],
                                      scores.shape[-2:])

@@ -84,8 +84,27 @@ def _causal_branches(causal: bool, qi, ki, q_tile: int, block_k: int,
     return visible, diagonal
 
 
+def _window_branches(qi, ki, q_tile: int, block_k: int,
+                     causal_offset: int, window: int):
+    """`_causal_branches` for a causal WINDOW: query i sees key j iff
+    i - window < j <= i (its own position counts). A KV block wholly
+    above the diagonal or wholly left of the tile's first query's
+    window fires neither branch; one that every query of the tile sees
+    whole takes the mask-free branch; the rest are masked."""
+    q_lo = qi * q_tile + causal_offset
+    q_hi = q_lo + q_tile - 1
+    k_lo = ki * block_k
+    k_hi = k_lo + block_k - 1
+    skip = jnp.logical_or(k_lo > q_hi, k_hi < q_lo - window + 1)
+    visible = jnp.logical_and(k_hi <= q_lo, k_lo >= q_hi - window + 1)
+    masked = jnp.logical_and(jnp.logical_not(skip),
+                             jnp.logical_not(visible))
+    return visible, masked
+
+
 def _kernel(q_ref, k_ref, v_ref, o_ref, *rest, causal: bool, q_tile: int,
-            block_k: int, causal_offset: int, group: int, want_lse: bool):
+            block_k: int, causal_offset: int, group: int, want_lse: bool,
+            window=None):
     from jax.experimental import pallas as pl
 
     if want_lse:
@@ -109,8 +128,12 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *rest, causal: bool, q_tile: int,
     # mean-of-V). Blocks entirely BELOW the diagonal take the mask-free
     # branch: the per-block iota/compare/where VPU work only runs on
     # diagonal-crossing blocks.
-    visible, diagonal = _causal_branches(
-        causal, qi, ki, q_tile, block_k, causal_offset)
+    if window is None:
+        visible, diagonal = _causal_branches(
+            causal, qi, ki, q_tile, block_k, causal_offset)
+    else:
+        visible, diagonal = _window_branches(
+            qi, ki, q_tile, block_k, causal_offset, window)
 
     def _tile_update(masked: bool):
         # operands stay in their storage dtype (bf16): the v5e MXU runs
@@ -135,6 +158,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *rest, causal: bool, q_tile: int,
             k_pos = ki * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (group, q_tile, block_k), 2)
             mask = k_pos <= q_pos + causal_offset
+            if window is not None:
+                mask = jnp.logical_and(
+                    mask, k_pos > q_pos + causal_offset - window)
             scores = jnp.where(mask, scores, NEG_INF)
         m_prev, s_prev = m_ref[...], s_ref[...]
         m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
@@ -210,13 +236,21 @@ def _pick_group(b: int, d: int, itemsize: int, q_tile: int,
 
 
 def _flash_forward(q, k, v, causal: bool, q_tile: int, block_k: int,
-                   interpret: bool, want_lse: bool = True):
+                   interpret: bool, want_lse: bool = True, window=None):
+    """q (b, Tq, d); k, v (b // kv_rows, Tk, d): `kv_rows` successive
+    rows of q (the query heads of one K/V head, heads being the minor
+    part of the flattened batch) read one row of k and v. With as many
+    rows as q and no window this is the program it always was."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, t_q, d = q.shape
     t_k = k.shape[1]
-    group = _pick_group(b, d, q.dtype.itemsize, q_tile, block_k, want_lse)
+    kv_rows = b // k.shape[0]
+    group = 1 if kv_rows > 1 else _pick_group(
+        b, d, q.dtype.itemsize, q_tile, block_k, want_lse)
+    kv_map = ((lambda bi, qi, ki: (bi, ki, 0)) if kv_rows == 1
+              else (lambda bi, qi, ki: (bi // kv_rows, ki, 0)))
     grid = (b // group, t_q // q_tile, t_k // block_k)
     out_shape = [jax.ShapeDtypeStruct(q.shape, q.dtype)]
     out_specs = [pl.BlockSpec((group, q_tile, d),
@@ -230,18 +264,17 @@ def _flash_forward(q, k, v, causal: bool, q_tile: int, block_k: int,
                                       memory_space=pltpu.VMEM))
     res = pl.pallas_call(
         partial(_kernel, causal=causal, q_tile=q_tile, block_k=block_k,
-                causal_offset=t_k - t_q, group=group, want_lse=want_lse),
+                causal_offset=t_k - t_q, group=group, want_lse=want_lse,
+                window=window),
         out_shape=tuple(out_shape) if want_lse else out_shape[0],
         grid=grid,
         in_specs=[
             pl.BlockSpec((group, q_tile, d),
                          lambda bi, qi, ki: (bi, qi, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((group, block_k, d),
-                         lambda bi, qi, ki: (bi, ki, 0),
+            pl.BlockSpec((group, block_k, d), kv_map,
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((group, block_k, d),
-                         lambda bi, qi, ki: (bi, ki, 0),
+            pl.BlockSpec((group, block_k, d), kv_map,
                          memory_space=pltpu.VMEM),
         ],
         out_specs=tuple(out_specs) if want_lse else out_specs[0],
@@ -261,9 +294,33 @@ def _flash_forward(q, k, v, causal: bool, q_tile: int, block_k: int,
     return res if want_lse else (res, None)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _kv_expanded(q, k, v):
+    """k and v with as many heads as q, for the blockwise fallback of a
+    grouped call: query head n reads K/V head n // (Hq / Hkv)."""
+    if q.shape[:-2] == k.shape[:-2]:
+        return k, v
+    rep = q.shape[-3] // k.shape[-3]
+    return jnp.repeat(k, rep, axis=-3), jnp.repeat(v, rep, axis=-3)
+
+
+def _check_grouped(q, k, causal: bool, window) -> bool:
+    """True for a call with grouped K/V heads or a window (forward
+    only); raises on shapes that are neither that nor today's."""
+    grouped = q.shape[:-2] != k.shape[:-2]
+    if grouped and (q.ndim < 3 or q.shape[:-3] != k.shape[:-3]
+                    or q.shape[-3] % k.shape[-3]):
+        raise ValueError(
+            f"grouped K/V heads need q (..., Hq, T, d) and k/v (..., Hkv, "
+            f"T, d) with Hkv dividing Hq, got {q.shape} and {k.shape}")
+    if window is not None and (not causal or window < 1):
+        raise ValueError("a window is causal and at least 1 key wide")
+    return grouped or window is not None
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal: bool = False, q_tile: int = 1024,
-                    block_k: int = 1024, interpret: bool = False):
+                    block_k: int = 1024, interpret: bool = False,
+                    window=None):
     """Pallas flash attention. q/k/v: (batch[*heads], T, d). Tile sizes
     fit to T (largest 128-aligned divisor <= the requested tile), so
     short or oddly-sized-but-aligned sequences stay on the kernel; T
@@ -279,7 +336,17 @@ def flash_attention(q, k, v, causal: bool = False, q_tile: int = 1024,
 
     NOTE: sequence length is axis -2 (NOT axis 1 — a 4-D (B, H, T, d)
     input's axis 1 is heads; reading it as T silently routed every 4-D
-    call to the blockwise fallback)."""
+    call to the blockwise fallback).
+
+    Grouped K/V heads: k/v may hold fewer heads than q, (..., Hkv, T, d)
+    against (..., Hq, T, d); query head n reads K/V head n // (Hq /
+    Hkv), and the kernel's K/V index map does that without a copy.
+    `window=W` (causal only): query i sees key j iff i - W < j <= i;
+    KV blocks wholly outside a tile's window are skipped like those
+    above the diagonal. Both are forward only (no trainer uses them
+    yet: differentiating raises). With neither, the call is what it
+    always was."""
+    _check_grouped(q, k, causal, window)
     t_q, t_k = q.shape[-2], k.shape[-2]
     # fit tiles: largest 128-aligned divisor <= the requested tile, so
     # e.g. T=768 runs the kernel at tile 384 instead of falling back;
@@ -287,7 +354,8 @@ def flash_attention(q, k, v, causal: bool = False, q_tile: int = 1024,
     q_tile = _fit_tile(t_q, q_tile)
     block_k = _fit_tile(t_k, block_k)
     if q_tile is None or block_k is None:
-        return blockwise_attention(q, k, v, causal=causal)
+        return blockwise_attention(q, *_kv_expanded(q, k, v),
+                                   causal=causal, window=window)
     # primal/inference path: no lse output — skips the extra output
     # block + finalize log, which is what lets batch-pair grouping fit
     # VMEM at the 1024x1024 tiles (the vjp fwd below pays for the lse)
@@ -295,7 +363,7 @@ def flash_attention(q, k, v, causal: bool = False, q_tile: int = 1024,
                             k.reshape(-1, t_k, k.shape[-1]),
                             v.reshape(-1, t_k, v.shape[-1]),
                             causal, q_tile, block_k, interpret,
-                            want_lse=False)
+                            want_lse=False, window=window)
     return out.reshape(q.shape)
 
 
@@ -580,7 +648,11 @@ def _bwd_with_lse(causal, q_tile, block_k, interpret, res, g):
 flash_attention_with_lse.defvjp(_fwd_with_lse, _bwd_with_lse)
 
 
-def _fwd(q, k, v, causal, q_tile, block_k, interpret):
+def _fwd(q, k, v, causal, q_tile, block_k, interpret, window=None):
+    if _check_grouped(q, k, causal, window):
+        raise NotImplementedError(
+            "flash_attention with grouped K/V heads or a window has no "
+            "backward: no trainer for such a block is written yet")
     t_q, t_k = q.shape[-2], k.shape[-2]
     qt = _fit_tile(t_q, q_tile)
     bk = _fit_tile(t_k, block_k)
@@ -595,7 +667,7 @@ def _fwd(q, k, v, causal, q_tile, block_k, interpret):
     return out3.reshape(q.shape), (q, k, v, out3, lse)
 
 
-def _bwd(causal, q_tile, block_k, interpret, res, g):
+def _bwd(causal, q_tile, block_k, interpret, window, res, g):
     q, k, v, out3, lse = res
     if out3 is None:
         # blockwise-fallback forward: differentiate the blockwise form

@@ -47,20 +47,33 @@ __all__ = ["paged_attention", "resolve_decode_kernel", "DECODE_KERNELS"]
 DECODE_KERNELS = ("auto", "pallas", "gather")
 
 
-def _decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                   acc_ref, m_ref, s_ref, *, page_size: int):
-    """One (slot, page) grid step. `pt_ref`/`len_ref` are the
-    scalar-prefetch operands (the same arrays the BlockSpec index maps
-    read); K/V refs already hold the PHYSICAL page the index map
-    selected for this step. The query block carries `rows` identical
-    copies of the slot's one query row (see `paged_attention`), so the
-    softmax state below is (H, rows, ·) with every row equal."""
+def _decode_kernel(pt_ref, len_ref, *refs, page_size: int,
+                   windowed: bool = False):
+    """One (slot, page) grid step. `pt_ref`/`len_ref` (and `first_ref`
+    where `windowed`) are the scalar-prefetch operands (the same arrays
+    the BlockSpec index maps read); K/V refs already hold the PHYSICAL
+    page the index map selected for this step. The query block carries
+    `rows` rows a K/V head: identical copies of the slot's one query
+    row where there are as many K/V heads as query heads, the query
+    heads that share the K/V head where there are fewer (see
+    `paged_attention`); the softmax state below is (H, rows, ·).
+
+    `windowed`: the slot sees positions [first, pos] only, and grid
+    step j stands for logical page first // page_size + j, so the sweep
+    starts at the first page that still holds a visible key."""
     from jax.experimental import pallas as pl
 
+    if windowed:
+        first_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, s_ref = refs
+    else:
+        q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, s_ref = refs
     si = pl.program_id(0)
     j = pl.program_id(1)
     n_j = pl.num_programs(1)
     pos = len_ref[si]   # this slot's cursor: positions [0, pos] visible
+    if windowed:
+        first = first_ref[si]
+        page = first // page_size + j
 
     @pl.when(j == 0)
     def _init():
@@ -71,7 +84,7 @@ def _decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     # pages wholly past the written frontier contribute exactly 0 in the
     # gather path (every lane masked): skip them here — page 0 always
     # computes (pos >= 0), so the softmax sum is never empty
-    @pl.when(j * page_size <= pos)
+    @pl.when((page if windowed else j) * page_size <= pos)
     def _tile():
         q = q_ref[0]          # (H, rows, hd)
         k = k_ref[0]          # (H, ps, hd)
@@ -84,9 +97,11 @@ def _decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
             q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
             precision=_dot_precision(q.dtype)) * scale2   # (H, rows, ps)
-        k_pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, scores.shape, 2)
+        k_pos = (page if windowed else j) * page_size \
+            + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 2)
         mask = k_pos <= pos   # current token at `pos` IS visible
+        if windowed:
+            mask = jnp.logical_and(mask, k_pos >= first)
         scores = jnp.where(mask, scores, NEG_INF)
         m_prev, s_prev = m_ref[...], s_ref[...]
         m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
@@ -117,6 +132,7 @@ def _dot_precision(dtype):
 
 
 def paged_attention(q, k_pool, v_pool, page_table, lengths, *,
+                    first=None, window_pages=None,
                     interpret: bool = False):
     """Single-token paged attention over the block pool.
 
@@ -139,23 +155,59 @@ def paged_attention(q, k_pool, v_pool, page_table, lengths, *,
     has none on the left (it does not lower — the form this kernel had
     before it ever met the compiler). The replicas cost `rows` x the
     query/output bytes, which are ~1/page_count of the K/V bytes the
-    step streams; row 0 of the result is the answer."""
+    step streams; row 0 of the result is the answer.
+
+    Grouped K/V heads: the pools may hold fewer heads than q, Hkv
+    dividing Hq. The rows of a K/V head's block are then REAL ones, the
+    Hq / Hkv query heads that read it (query head n reads K/V head
+    n // (Hq / Hkv)), padded with zero rows up to whole sublane tiles.
+
+    `first` (S,) int32: a first visible position per slot beside the
+    last, for layers that see a window: positions [first[s],
+    lengths[s]] are attended, and the sweep covers `window_pages`
+    table columns from column first[s] // page_size on (pages before it
+    are never fetched, as pages past the cursor are skipped), so
+    `window_pages` is at least the most columns a window can straddle.
+    Without `first` and with as many K/V heads as query heads the call
+    compiles to the program it always was."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    s, h, hd = q.shape
-    ps = k_pool.shape[2]
-    n_j = page_table.shape[1]
-    rows = 32 // jnp.dtype(q.dtype).itemsize   # one sublane tile
-    q_rows = jnp.broadcast_to(q[:, :, None, :], (s, h, rows, hd))
-    q_spec = pl.BlockSpec((1, h, rows, hd),
-                          lambda si, j, pt, ln: (si, 0, 0, 0),
+    s, hq, hd = q.shape
+    h, ps = k_pool.shape[1], k_pool.shape[2]
+    n_p = page_table.shape[1]
+    tile = 32 // jnp.dtype(q.dtype).itemsize   # one sublane tile
+    if hq == h:
+        rows = tile
+        q_rows = jnp.broadcast_to(q[:, :, None, :], (s, h, rows, hd))
+    else:
+        if hq % h:
+            raise ValueError(f"{h} K/V heads do not divide {hq} query "
+                             f"heads")
+        real = hq // h
+        rows = -(-real // tile) * tile
+        q_rows = jnp.pad(q.reshape(s, h, real, hd),
+                         ((0, 0), (0, 0), (0, rows - real), (0, 0)))
+    windowed = first is not None
+    if windowed:
+        n_j = min(n_p, int(window_pages) if window_pages else n_p)
+        scalars = (page_table.astype(jnp.int32),
+                   lengths.astype(jnp.int32), first.astype(jnp.int32))
+        q_map = lambda si, j, pt, ln, fs: (si, 0, 0, 0)  # noqa: E731
+        kv_map = lambda si, j, pt, ln, fs: (  # noqa: E731
+            pt[si, jnp.minimum(fs[si] // ps + j, n_p - 1)], 0, 0, 0)
+    else:
+        n_j = n_p
+        scalars = (page_table.astype(jnp.int32),
+                   lengths.astype(jnp.int32))
+        q_map = lambda si, j, pt, ln: (si, 0, 0, 0)  # noqa: E731
+        kv_map = lambda si, j, pt, ln: (pt[si, j], 0, 0, 0)  # noqa: E731
+    q_spec = pl.BlockSpec((1, h, rows, hd), q_map,
                           memory_space=pltpu.VMEM)
-    kv_spec = pl.BlockSpec((1, h, ps, hd),
-                           lambda si, j, pt, ln: (pt[si, j], 0, 0, 0),
+    kv_spec = pl.BlockSpec((1, h, ps, hd), kv_map,
                            memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(scalars),
         grid=(s, n_j),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=q_spec,
@@ -165,7 +217,7 @@ def paged_attention(q, k_pool, v_pool, page_table, lengths, *,
             pltpu.VMEM((h, rows, 1), jnp.float32),    # running sum
         ])
     out = pl.pallas_call(
-        partial(_decode_kernel, page_size=ps),
+        partial(_decode_kernel, page_size=ps, windowed=windowed),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, h, rows, hd), q.dtype),
         # slots are independent (scratch init/finalize is per-row);
@@ -174,9 +226,10 @@ def paged_attention(q, k_pool, v_pool, page_table, lengths, *,
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="paged_decode_attention",
-    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      q_rows, k_pool, v_pool)
-    return out[:, :, 0, :]
+    )(*scalars, q_rows, k_pool, v_pool)
+    if hq == h:
+        return out[:, :, 0, :]
+    return out[:, :, :hq // h, :].reshape(s, hq, hd)
 
 
 def resolve_decode_kernel(kernel: str, cfg, page_size: int) -> str:
@@ -218,7 +271,9 @@ def resolve_decode_kernel(kernel: str, cfg, page_size: int) -> str:
     # auto
     if not on_tpu:
         return "gather"
-    hd = cfg.d_model // cfg.n_heads
+    # a model description that states its head size says so itself
+    # (grouped heads: d_model / n_heads is not it)
+    hd = getattr(cfg, "head_dim", None) or cfg.d_model // cfg.n_heads
     itemsize = jnp.dtype(cfg.dtype).itemsize
     if hd > 128 or itemsize > 4 or page_size < 8:
         return "gather"

@@ -14,3 +14,7 @@ from deeplearning4j_tpu.models.transformer import (  # noqa: F401
     init_transformer_params,
     transformer_logits,
 )
+from deeplearning4j_tpu.models.moe_transformer import (  # noqa: F401
+    MoEConfig,
+    init_moe_params,
+)

@@ -141,6 +141,35 @@ dl4j_kv_prefix_{hits,misses,forks,evictions} /
 dl4j_decode_kv_read_bytes{path} counters, dl4j_decode_step_seconds
 histogram (docs/OBSERVABILITY.md).
 
+**Two kinds of layer in one manager** (`models/moe_transformer.py`): a
+model whose description has `layer_kinds` keeps "full" layers, which
+hold every key of a sequence, and "window" layers, which only ever read
+the last `cfg.window` keys. Each kind has its own pool size, page table
+and free list here (`serving/paged_kinds.py` is the device side): the
+full kind rides the lists every model uses, the window kind
+`_WindowPages`. Admission checks both kinds; a prompt longer than the
+window claims, for the window kind, only the pages that still hold a
+key its first decoded token can see; and every pass returns to the
+window kind's free list the pages whose last key has left the window
+(`decode.release_window`), the table taking the trash page in their
+place. A stall for pages of either kind is the same stall.
+`snapshot()["pages_by_kind"]` has each kind's pages; `pages_total` and
+`peak_pages_in_use` are then sums over kinds weighted by the kind's
+layers (one page of a kind spans all layers of that kind). The step and
+the prefill hand back, with the tokens, how many (token, expert) pairs
+fell on each expert this chip holds (`snapshot()["moe"]`). What two
+kinds of page cannot do yet is an error at construction that names it:
+prefix sharing, speculation, a horizon above 1 (and with prefix sharing
+off there is no trie for `/kv/export` to read).
+
+**A bound on the tokens a pass prefills** (`prefill_tokens_per_pass`,
+None = no bound): a pass stops claiming queued requests once the rows it
+would prefill, each padded to its bucket, pass the bound (it always
+claims one); the rest wait for the next pass, behind one decode step.
+A burst of long prompts then neither needs the memory of one program
+over all of them nor holds every running stream for the sum of their
+prefills.
+
 **Spans** (`telemetry.span`, names in `PHASES`): every scheduler pass
 is one `decode.tick` whose children are the phases of the pass, so each
 nanosecond of a pass lies in exactly one child or in the tick's self
@@ -175,7 +204,7 @@ from deeplearning4j_tpu.serving.errors import (TIER_BATCH,
                                                DeadlineExceededError,
                                                OverloadedError,
                                                backlog_retry_ms)
-from deeplearning4j_tpu.serving import fleetkv
+from deeplearning4j_tpu.serving import fleetkv, paged_kinds
 from deeplearning4j_tpu.serving.paged_kv import (copy_page,
                                                  decode_read_bytes,
                                                  extract_page,
@@ -220,8 +249,10 @@ ROLES = (ROLE_UNIFIED, ROLE_PREFILL, ROLE_DECODE)
 TICK = "decode.tick"
 IDLE_WAIT = "decode.idle_wait"
 PREFILL_DISPATCH = "decode.prefill_dispatch"
+RELEASE_WINDOW = "decode.release_window"
 PHASES = (IDLE_WAIT, TICK, "decode.reap", "decode.kv_jobs", "decode.admit",
-          PREFILL_DISPATCH, "decode.grant_pages", "decode.upload",
+          PREFILL_DISPATCH, RELEASE_WINDOW, "decode.grant_pages",
+          "decode.upload",
           "decode.draft", "decode.step_dispatch", "decode.d2h",
           "decode.account", "decode.flush_first", "decode.emit")
 
@@ -419,6 +450,83 @@ class GenerationStream:
         return self.prompt + self.result(timeout)
 
 
+class _WindowPages:
+    """The window kind's pages on the host: its own pool size, page
+    table and free list. A slot holds the logical pages [lo, hi): pages
+    are granted at the back as the cursor moves on and returned at the
+    front as their last key leaves the window, so a slot never holds
+    more than `paged_kinds.window_table_pages` of them. No page of this
+    kind is ever shared. The scheduler thread owns it, under the
+    loop's lock."""
+
+    def __init__(self, n_pages: int, slots: int, columns: int,
+                 page_size: int, window: int):
+        self.n_pages = int(n_pages)
+        self.trash = self.n_pages
+        self.page_size, self.window = int(page_size), int(window)
+        self.free: deque = deque(range(self.n_pages))
+        self.table = np.full((slots, columns), self.trash, np.int32)
+        self.lo = np.zeros((slots,), np.int64)
+        self.hi = np.zeros((slots,), np.int64)
+        self.released = 0          # pages returned because they fell out
+        self.peak_in_use = 0
+        self.peak_per_slot = 0
+
+    @property
+    def in_use(self) -> int:
+        return self.n_pages - len(self.free)
+
+    def first_page(self, cursor: int) -> int:
+        """The first logical page that still holds a key the query at
+        `cursor` sees."""
+        return max(0, cursor - self.window + 1) // self.page_size
+
+    def needed(self, plen: int) -> int:
+        """Pages a prompt of `plen` needs at admission: what its first
+        decoded token can see, and room for that token's own write."""
+        return (pages_for_tokens(plen + 1, self.page_size)
+                - self.first_page(plen))
+
+    def claim(self, slot: int, plen: int) -> None:
+        """The pages of a prompt's last window (the caller checked
+        `needed` against `free`)."""
+        self.lo[slot] = self.hi[slot] = self.first_page(plen)
+        self.extend(slot, pages_for_tokens(plen, self.page_size))
+
+    def extend(self, slot: int, want_hi: int) -> int:
+        """Grant pages up to logical page `want_hi`; returns the end
+        actually reached (short when the kind's free list is empty)."""
+        while self.hi[slot] < want_hi and self.free:
+            self.table[slot, self.hi[slot]] = self.free.popleft()
+            self.hi[slot] += 1
+        held = int(self.hi[slot] - self.lo[slot])
+        self.peak_per_slot = max(self.peak_per_slot, held)
+        self.peak_in_use = max(self.peak_in_use, self.in_use)
+        return int(self.hi[slot])
+
+    def release_before(self, slot: int, cursor: int) -> int:
+        """Return the pages whose every key the query at `cursor` no
+        longer sees; the table takes the trash page in their place."""
+        upto = min(self.first_page(cursor), int(self.hi[slot]))
+        n = 0
+        while self.lo[slot] < upto:
+            j = self.lo[slot]
+            self.free.append(int(self.table[slot, j]))
+            self.table[slot, j] = self.trash
+            self.lo[slot] += 1
+            n += 1
+        self.released += n
+        return n
+
+    def drop(self, slot: int) -> None:
+        """A retiring slot's pages, all of them (not counted as
+        released: nothing fell out of a window)."""
+        for j in range(int(self.lo[slot]), int(self.hi[slot])):
+            self.free.append(int(self.table[slot, j]))
+        self.table[slot, :] = self.trash
+        self.lo[slot] = self.hi[slot] = 0
+
+
 class _Slot:
     __slots__ = ("stream", "pages", "awaiting_first", "emitted",
                  "stop_len", "no_cache")
@@ -454,6 +562,8 @@ class DecodeLoop:
                  batch_share: float = 0.5,
                  batch_max_waiting: Optional[int] = None,
                  role: str = ROLE_UNIFIED,
+                 window_pages: Optional[int] = None,
+                 prefill_tokens_per_pass: Optional[int] = None,
                  start: bool = True, name: Optional[str] = None):
         import jax
         import jax.numpy as jnp
@@ -483,6 +593,18 @@ class DecodeLoop:
             raise ValueError(
                 f"batch_max_waiting must be >= 0, "
                 f"got {batch_max_waiting}")
+        if (prefill_tokens_per_pass is not None
+                and prefill_tokens_per_pass < 1):
+            raise ValueError(f"prefill_tokens_per_pass must be >= 1, "
+                             f"got {prefill_tokens_per_pass}")
+        #: a model with kinds of layer (models/moe_transformer.py)
+        self._kinds = getattr(cfg, "layer_kinds", None) is not None
+        if self._kinds:
+            self._check_kinds(cfg, prefix_cache, speculation, horizon,
+                              role)
+        self.prefill_tokens_per_pass = (
+            None if prefill_tokens_per_pass is None
+            else int(prefill_tokens_per_pass))
         self.cfg = cfg
         self.params = params
         self.role = role
@@ -527,8 +649,38 @@ class DecodeLoop:
         self._buckets = prompt_buckets(cfg, self.page_size)
 
         # device state ------------------------------------------------
-        self._pool = init_paged_pool(cfg, self.n_pages, self.page_size)
-        self._trash = self._pool.trash_page
+        #: the window kind's pages (None: every layer keeps all keys)
+        self._win: Optional[_WindowPages] = None
+        if self._kinds:
+            pages = {paged_kinds.KIND_FULL: self.n_pages}
+            if paged_kinds.KIND_WINDOW in cfg.layer_kinds:
+                columns = paged_kinds.window_table_pages(cfg,
+                                                         self.page_size)
+                if window_pages is None:
+                    window_pages = self.slots * min(columns, self._pps)
+                if window_pages < 1:
+                    raise ValueError(f"window_pages must be >= 1, got "
+                                     f"{window_pages}")
+                self._win = _WindowPages(window_pages, self.slots,
+                                         self._pps, self.page_size,
+                                         cfg.window)
+                pages[paged_kinds.KIND_WINDOW] = self._win.n_pages
+            self._kind_pages = pages
+            self._kind_layers = paged_kinds.layers_of(cfg)
+            self._pool = paged_kinds.init_pool(cfg, pages, self.page_size)
+            self._trash = self.n_pages
+        else:
+            self._pool = init_paged_pool(cfg, self.n_pages,
+                                         self.page_size)
+            self._trash = self._pool.trash_page
+        #: pairs by (layer, held expert) and what they are of, for a
+        #: model with an expert layer (snapshot()["moe"])
+        self._moe = None
+        if self._kinds:
+            self._moe = {
+                "pairs": np.zeros((cfg.n_layers, cfg.n_held), np.int64),
+                "tokens": 0, "decode_tokens": 0, "decode_pairs": 0,
+                "decode_steps": 0, "experts_touched": 0}
         self._d_tokens = None       # (S,) int32
         self._d_table = None        # (S, P) int32
         self._d_lengths = None      # (S,) int32
@@ -633,6 +785,26 @@ class DecodeLoop:
             logits, pool = paged_prefill(params, tokens, true_len, pool,
                                          page_ids, cfg)
             return jnp.argmax(logits, axis=-1).astype(jnp.int32), pool
+
+        if self._kinds:
+            # the same two programs under the same names, over the
+            # model's own block; `table` and `page_ids` are dicts by
+            # kind, and the pairs by held expert ride beside the tokens
+            def step_fn(params, tokens, pool, table, lengths, stop):  # noqa: F811
+                act = lengths < stop
+                logits, pool, pairs = paged_kinds.decode_step(
+                    params, tokens, pool, table, lengths, act, cfg,
+                    kernel=self.decode_kernel)
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                tokens = jnp.where(act, nxt, tokens)
+                lengths = lengths + act.astype(lengths.dtype)
+                return (nxt[None], pairs), tokens, lengths, pool
+
+            def prefill_fn(params, tokens, true_len, pool, page_ids):  # noqa: F811
+                logits, pool, pairs = paged_kinds.prefill(
+                    params, tokens, true_len, pool, page_ids, cfg)
+                first = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                return (first, pairs), pool
 
         def prefill_ctx_fn(params, tokens, true_len, pool, page_ids,
                            ctx_table, ctx_len):
@@ -859,10 +1031,127 @@ class DecodeLoop:
             lambda: (lambda o: o.spec_acceptance_rate if o else 0.0)(
                 ref()))
 
+        if self._kinds:
+            self._register_kind_metrics(reg, lab, ref)
+
         if start:
             self._thread = threading.Thread(target=self._run, daemon=True,
                                             name=f"decode-loop-{self.label}")
             self._thread.start()
+
+    # ------------------------------------------- a model with kinds
+    @staticmethod
+    def _check_kinds(cfg, prefix_cache, speculation, horizon,
+                     role) -> None:
+        """What a cache of two kinds of page cannot do yet is an error
+        here, by name, never a silent wrong answer."""
+        if paged_kinds.KIND_FULL not in cfg.layer_kinds:
+            raise ValueError(
+                "layer_kinds needs a full layer: a request's token "
+                "budget rides the full kind's page table")
+        if prefix_cache:
+            raise ValueError(
+                "prefix sharing is not written for a model with window "
+                "layers (a shared page would have to be shared in every "
+                "kind, and a window layer gives its pages back): pass "
+                "prefix_cache=False")
+        if speculation:
+            raise ValueError(
+                "speculation is not written for a model with window "
+                "layers (a rejected draft's rows would need a window "
+                "page back): pass speculation=0")
+        if horizon > 1:
+            raise ValueError(
+                "horizon > 1 is not written for a model with window "
+                "layers (pages are returned once a pass, between "
+                "steps): pass horizon=1")
+        if role == ROLE_PREFILL:
+            raise ValueError(
+                "a prefill-role loop ships pages over /kv/export, which "
+                "is not written for a model with window layers")
+
+    def _register_kind_metrics(self, reg, lab: dict, ref) -> None:
+        """Pages by kind and the expert layer's pairs (the families with
+        only a `loop` label keep their shape for every model)."""
+        total = reg.gauge(
+            "dl4j_kv_pages_total_by_kind",
+            "usable KV pages by kind of layer (a page of a kind spans "
+            "every layer of that kind)")
+        in_use = reg.gauge(
+            "dl4j_kv_pages_in_use_by_kind",
+            "KV pages held by in-flight requests, by kind of layer")
+        for kind, n in self._kind_pages.items():
+            total.labels(kind=kind, **lab).set(n)
+            in_use.labels(kind=kind, **lab).set_function(
+                (lambda k: lambda: (lambda o: o._kind_in_use(k)
+                                    if o else 0)(ref()))(kind))
+        self._m_win_released = reg.counter(
+            "dl4j_kv_window_pages_released",
+            "window-layer KV pages returned to their free list because "
+            "their last key left the window").labels(**lab)
+        self._m_moe_tokens = reg.counter(
+            "dl4j_moe_tokens",
+            "tokens routed by the expert layer (prefill and decode, "
+            "padding and idle slots left out)").labels(**lab)
+        self._m_moe_touched = reg.counter(
+            "dl4j_moe_experts_touched",
+            "held experts with at least one pair, summed over decode "
+            "steps and layers: the expert weights a step had to read"
+        ).labels(**lab)
+        pairs = reg.counter(
+            "dl4j_moe_pairs",
+            "(token, expert) pairs that fell on an expert this chip "
+            "holds, by layer and held expert")
+        self._m_moe_pairs = [
+            [pairs.labels(layer=str(i), expert=str(e), **lab)
+             for e in range(self.cfg.n_held)]
+            for i in range(self.cfg.n_layers)]
+
+    def _kind_in_use(self, kind: str) -> int:
+        if kind == paged_kinds.KIND_WINDOW:
+            return self._win.in_use
+        return self.pages_in_use
+
+    def _weighted_in_use(self) -> int:
+        """Pages in use, every layer's counted: the one-kind cache's
+        `pages_in_use` where there is one kind."""
+        if not self._kinds:
+            return self.pages_in_use
+        return sum(self._kind_layers[k] * self._kind_in_use(k)
+                   for k in self._kind_pages)
+
+    def _note_peak(self) -> None:
+        self._peak_pages = max(self._peak_pages, self._weighted_in_use())
+
+    def _count_pairs(self, pairs: np.ndarray, tokens: int,
+                     decode: bool) -> None:
+        """Fold one program's pairs (layers, n_held) into the counters;
+        `tokens` real tokens went through each layer's router."""
+        moe = self._moe
+        moe["pairs"] += pairs
+        moe["tokens"] += tokens
+        self._m_moe_tokens.inc(tokens)
+        for i, e in zip(*np.nonzero(pairs)):
+            self._m_moe_pairs[i][e].inc(int(pairs[i, e]))
+        if decode:
+            touched = int(np.count_nonzero(pairs))
+            moe["decode_tokens"] += tokens
+            moe["decode_pairs"] += int(pairs.sum())
+            moe["decode_steps"] += 1
+            moe["experts_touched"] += touched
+            self._m_moe_touched.inc(touched)
+
+    def _device_tables(self):
+        """The page tables as the step takes them: one array, or a dict
+        by kind."""
+        import jax.numpy as jnp
+
+        if not self._kinds:
+            return jnp.asarray(self._table)
+        tables = {paged_kinds.KIND_FULL: jnp.asarray(self._table)}
+        if self._win is not None:
+            tables[paged_kinds.KIND_WINDOW] = jnp.asarray(self._win.table)
+        return tables
 
     # ----------------------------------------------------- public API
     @staticmethod
@@ -897,6 +1186,11 @@ class DecodeLoop:
             raise ValueError(
                 f"prompt needs {need} pages but the pool only has "
                 f"{self.n_pages}")
+        if (self._win is not None
+                and self._win.needed(int(prompt.size)) > self._win.n_pages):
+            raise ValueError(
+                f"prompt needs {self._win.needed(int(prompt.size))} window "
+                f"pages but that pool only has {self._win.n_pages}")
         return prompt
 
     def submit(self, prompt, max_tokens: int,
@@ -1129,6 +1423,9 @@ class DecodeLoop:
         return int(self._m_spec_accepted.value) / proposed
 
     def kv_pool_bytes(self) -> int:
+        if self._kinds:
+            return paged_kinds.pool_bytes(self.cfg, self._kind_pages,
+                                          self.page_size)
         return paged_kv_bytes(self.cfg, self.n_pages, self.page_size)
 
     def decode_step_programs(self) -> int:
@@ -1200,6 +1497,17 @@ class DecodeLoop:
         pool_spec = jax.tree_util.tree_map(sds, self._pool)
         S, P, ps = self.slots, self._pps, self.page_size
         n = 0
+        if self._kinds:
+            kinds = list(self._kind_pages)
+            if frag.get("step"):
+                n += self._step.warm(
+                    params_spec, ints(S), pool_spec,
+                    {k: ints(S, P) for k in kinds}, ints(S), ints(S))
+            for bb, tb in frag.get("prefill", ()):
+                n += self._prefill.warm(
+                    params_spec, ints(bb, tb), ints(bb), pool_spec,
+                    {k: ints(bb, tb // ps) for k in kinds})
+            return n
         if frag.get("step"):
             n += self._step.warm(params_spec, ints(S), pool_spec,
                                  ints(S, P), ints(S), ints(S))
@@ -1579,9 +1887,7 @@ class DecodeLoop:
                 "queued": len(self._waiting),
                 "page_size": self.page_size,
                 "horizon": self.horizon,
-                "pages_total": self.n_pages,
-                "pages_in_use": self.pages_in_use,
-                "peak_pages_in_use": self._peak_pages,
+                **self._snapshot_pages(),
                 "pool_bytes": self.kv_pool_bytes(),
                 "max_waiting": self.max_waiting,
                 "tiers": {
@@ -1660,6 +1966,44 @@ class DecodeLoop:
                 },
             }
 
+    def _snapshot_pages(self) -> dict:
+        """`pages_total`, `pages_in_use` and `peak_pages_in_use`: pages
+        of the one kind, or, for a model with kinds of layer, sums over
+        kinds weighted by the kind's layers, with each kind's own pages
+        under `pages_by_kind` and the expert layer's pairs under `moe`.
+        Caller holds the lock."""
+        if not self._kinds:
+            return {"pages_total": self.n_pages,
+                    "pages_in_use": self.pages_in_use,
+                    "peak_pages_in_use": self._peak_pages}
+        by_kind = {}
+        for kind, n in self._kind_pages.items():
+            by_kind[kind] = {"layers": self._kind_layers[kind],
+                             "pages_total": n,
+                             "pages_in_use": self._kind_in_use(kind)}
+        win = self._win
+        if win is not None:
+            by_kind[paged_kinds.KIND_WINDOW].update(
+                peak_pages_in_use=win.peak_in_use, released=win.released,
+                pages_per_slot_peak=win.peak_per_slot,
+                table_pages=paged_kinds.window_table_pages(
+                    self.cfg, self.page_size))
+        moe = self._moe
+        return {
+            "pages_total": sum(self._kind_layers[k] * n
+                               for k, n in self._kind_pages.items()),
+            "pages_in_use": self._weighted_in_use(),
+            "peak_pages_in_use": self._peak_pages,
+            "pages_by_kind": by_kind,
+            "moe": {"held_first": self.cfg.held_first,
+                    "n_held": self.cfg.n_held,
+                    "n_experts": self.cfg.n_experts,
+                    "experts_per_token": self.cfg.experts_per_token,
+                    "pairs_by_layer_expert": moe["pairs"].tolist(),
+                    "pairs": int(moe["pairs"].sum()),
+                    **{k: v for k, v in moe.items() if k != "pairs"}},
+        }
+
     def close(self, timeout: float = 30.0) -> None:
         """Stop accepting new requests, drain everything queued and in
         flight, stop the scheduler thread."""
@@ -1712,6 +2056,8 @@ class DecodeLoop:
                 if slot is not None:
                     for page in slot.pages:
                         self._release_page(page)
+                    if self._win is not None:
+                        self._win.drop(i)
                     slot.stream._finish("error", exc)
                     self._slot_state[i] = None
             while self._waiting:
@@ -1780,7 +2126,9 @@ class DecodeLoop:
             # than spin forever
             with self._cond:
                 stuck = (self.occupied_slots > 0
-                         and self._avail_pages() == 0
+                         and (self._avail_pages() == 0
+                              or (self._win is not None
+                                  and not self._win.free))
                          and all(s is None
                                  or self._stop[i] <= self._lengths[i]
                                  for i, s in enumerate(self._slot_state)))
@@ -1865,6 +2213,8 @@ class DecodeLoop:
         # claim everything that fits in one lock pass
         admitted = []  # (slot_idx, stream, pages, plen, covered)
         now = None  # one clock read for the requests this pass admits
+        budget = self.prefill_tokens_per_pass  # bucket tokens left
+        win = self._win
         with self._cond:
             used = {i for i, s in enumerate(self._slot_state)
                     if s is not None}
@@ -1911,6 +2261,13 @@ class DecodeLoop:
                     self._m_waits.inc()
                     break
                 plen = len(stream.prompt)
+                if budget is not None:
+                    # the bound on what one pass prefills: rows count at
+                    # their bucket's width, and a pass always takes one
+                    tb = next(b for b in self._buckets if b >= plen)
+                    if admitted and tb > budget:
+                        break
+                    budget -= tb
                 idx = next((i for i in range(self.slots)
                             if i not in used), None)
                 while (idx is None and interactive
@@ -1942,7 +2299,9 @@ class DecodeLoop:
                 while (self._avail_pages() < need and interactive
                        and self._preempt_one(used)):
                     batch_held -= 1
-                if self._avail_pages() < need:
+                if (self._avail_pages() < need
+                        or (win is not None
+                            and len(win.free) < win.needed(plen))):
                     for page in matched:
                         self._release_page(page)
                     self._m_waits.inc()
@@ -1963,6 +2322,8 @@ class DecodeLoop:
                             "page allocation failed after availability "
                             "check")
                     pages.append(page)
+                if win is not None:
+                    win.claim(idx, plen)
                 if use_cache:
                     (self._m_hits if matched else self._m_misses).inc()
                 if now is None:
@@ -1971,8 +2332,7 @@ class DecodeLoop:
                 self._m_queue_wait.observe(now - stream.submitted)
                 admitted.append((idx, stream, pages, plen, covered))
             if admitted:
-                self._peak_pages = max(self._peak_pages,
-                                       self.pages_in_use)
+                self._note_peak()
         if not admitted:
             return 0
         cold = [a for a in admitted if a[4] == 0]
@@ -2019,9 +2379,22 @@ class DecodeLoop:
                     pids[row, :len(pages)] = pages
                     self._prefill_token_count += plen
                 self._plan_prefill.add((bb, tb))
+                d_pids = jnp.asarray(pids)
+                if self._kinds:
+                    d_pids = {paged_kinds.KIND_FULL: d_pids}
+                    if win is not None:
+                        # only the pages the first decoded token can
+                        # still see; the rest of the prompt's K/V in the
+                        # window layers goes to that kind's trash page
+                        wids = np.full((bb, n_pids), win.trash, np.int32)
+                        for row, (idx, *_rest) in enumerate(group):
+                            lo, hi = int(win.lo[idx]), int(win.hi[idx])
+                            wids[row, lo:hi] = win.table[idx, lo:hi]
+                        d_pids[paged_kinds.KIND_WINDOW] = jnp.asarray(
+                            wids)
                 first, self._pool = self._prefill(
                     self.params, jnp.asarray(padded), jnp.asarray(lens),
-                    self._pool, jnp.asarray(pids))
+                    self._pool, d_pids)
             self._install_prefilled(group, first)
         # warm tails ride the ctx-aware prefill, bucketed by (cached
         # pages, tail length) — tails start on a page boundary by
@@ -2078,6 +2451,12 @@ class DecodeLoop:
         """Install slots for one prefill group; first tokens stay on
         device until the next flush (`self._deferred`)."""
         members = []
+        pairs = None
+        if self._kinds:
+            # the pairs by held expert stay on the device with the
+            # first tokens, and come back in the same read
+            first, pairs = first
+            pairs = (pairs, sum(item[3] for item in group))
         for row, (idx, stream, pages, plen, _cov) in enumerate(group):
             slot = _Slot(stream, pages,
                          stop_len=plen + stream.max_tokens - 1)
@@ -2089,7 +2468,7 @@ class DecodeLoop:
                 self._pending[idx] = 0  # real value still on device
                 self._stop[idx] = 0  # set by _grant_pages
                 self._dirty = True
-        self._deferred.append((first, members))
+        self._deferred.append((first, members, pairs))
 
     # ---- page granting
     def _grant_pages(self) -> None:
@@ -2118,10 +2497,16 @@ class DecodeLoop:
                     self._table[i, len(slot.pages)] = page
                     slot.pages.append(page)
                     granted = True
-                if granted:
-                    self._peak_pages = max(self._peak_pages,
-                                           self.pages_in_use)
                 alloc_end = len(slot.pages) * self.page_size
+                if self._win is not None:
+                    # the window kind grants from its own free list; the
+                    # slot advances as far as BOTH kinds have pages
+                    held = self._win.hi[i]
+                    w_end = self._win.extend(i, want)
+                    granted = granted or w_end > held
+                    alloc_end = min(alloc_end, w_end * self.page_size)
+                if granted:
+                    self._note_peak()
                 stop = min(slot.stop_len, alloc_end)
                 if stop > length:
                     stop = self._cow_guard(i, slot, length, stop)
@@ -2185,9 +2570,12 @@ class DecodeLoop:
 
     # ---- plain dispatch (horizon token steps)
     def _dispatch_plain(self) -> bool:
+        import jax
         import jax.numpy as jnp
 
         phases = self._phases
+        if self._win is not None:
+            self._release_window()
         self._grant_pages()
         with self._cond:
             runnable = [i for i, s in enumerate(self._slot_state)
@@ -2199,14 +2587,14 @@ class DecodeLoop:
             with span("decode.upload", phases):
                 if self._dirty or self._d_tokens is None:
                     self._d_tokens = jnp.asarray(self._pending)
-                    self._d_table = jnp.asarray(self._table)
+                    self._d_table = self._device_tables()
                     self._d_lengths = jnp.asarray(self._lengths)
                     self._d_stop = jnp.asarray(self._stop)
                     self._dirty = False
                 # overlay deferred prefill tokens (still
                 # device-resident) into the feedback array — ONE scatter
                 # per prefill group, no sync
-                for arr, members in self._deferred:
+                for arr, members, _pairs in self._deferred:
                     rows = jnp.asarray([r for r, _ in members])
                     idxs = jnp.asarray([i for _, i in members])
                     self._d_tokens = self._d_tokens.at[idxs].set(
@@ -2220,7 +2608,12 @@ class DecodeLoop:
         self._m_steps.inc()
         # the (K, S) token D2H is the sync the streams need anyway
         with span("decode.d2h", phases):
-            toks = np.asarray(toks)
+            if self._kinds:
+                # the pairs by held expert, in the read-back the tokens
+                # make anyway
+                toks, pairs = jax.device_get(toks)
+            else:
+                toks = np.asarray(toks)
         self._m_step_s.observe(time.perf_counter() - t0)
         self._d_tokens, self._d_lengths = t_out, l_out
         # per-token-step KV read accounting, host math mirroring the
@@ -2229,13 +2622,17 @@ class DecodeLoop:
         # Both figures are recorded each dispatch — the selected lane
         # is in snapshot()["decode_kernel"]
         with span("decode.account", phases):
-            advance = np.maximum(self._stop - before, 0)
-            ideal = dense = 0
-            for k in range(self.horizon):
-                cur = before + np.minimum(k, advance)
-                ideal += decode_read_bytes(self._pool, cur, self._pps)
-                dense += decode_read_bytes(self._pool, cur, self._pps,
-                                           dense=True)
+            if self._kinds:
+                self._count_pairs(pairs, len(runnable), decode=True)
+                ideal, dense = self._kinds_read_bytes(before)
+            else:
+                advance = np.maximum(self._stop - before, 0)
+                ideal = dense = 0
+                for k in range(self.horizon):
+                    cur = before + np.minimum(k, advance)
+                    ideal += decode_read_bytes(self._pool, cur, self._pps)
+                    dense += decode_read_bytes(self._pool, cur,
+                                               self._pps, dense=True)
             self._m_kv_read["kernel"].inc(ideal)
             self._m_kv_read["gather"].inc(dense)
         self._flush_first_tokens()  # emit firsts BEFORE chunk tokens
@@ -2259,6 +2656,38 @@ class DecodeLoop:
                         break  # retired: discard speculative overshoot
             emit.args["tokens"] = emitted
         return True
+
+    def _release_window(self) -> None:
+        """Before the grants of a pass: return to the window kind's
+        free list every page whose last key the slot's next query no
+        longer sees; the table takes the trash page in its place. The
+        pages a pass returns are the pass's to grant."""
+        with span(RELEASE_WINDOW, self._phases) as rel, self._cond:
+            n = 0
+            for i, slot in enumerate(self._slot_state):
+                if slot is not None:
+                    n += self._win.release_before(i,
+                                                  int(self._lengths[i]))
+            rel.args["pages"] = n
+            if n:
+                self._m_win_released.inc(n)
+                self._dirty = True
+
+    def _kinds_read_bytes(self, cursors) -> tuple:
+        """(streamed, dense) K/V bytes one decode step reads, as
+        `decode_read_bytes` counts them, for a cache of two kinds: whole
+        pages from the first visible to the cursor's, by layer."""
+        ps = self.page_size
+        page = paged_kinds.page_bytes(self.cfg, ps)
+        ideal = 0
+        for kind, layers in self._kind_layers.items():
+            for pos in cursors:
+                last = min(int(pos) // ps + 1, self._pps)
+                first = (self._win.first_page(int(pos))
+                         if kind == paged_kinds.KIND_WINDOW else 0)
+                ideal += layers * page * (last - first)
+        dense = self.cfg.n_layers * page * len(cursors) * self._pps
+        return ideal, dense
 
     # ---- speculative dispatch (draft k on the host, verify k+1 wide)
     def _dispatch_spec(self) -> bool:
@@ -2399,11 +2828,17 @@ class DecodeLoop:
     def _flush_first_tokens(self) -> None:
         """Read deferred prefill tokens (one D2H per prefill group —
         the compute is long finished) and emit them."""
+        import jax
+
         deferred, self._deferred = self._deferred, []
         with span("decode.flush_first", self._phases,
                   groups=len(deferred)):
-            for arr, members in deferred:
-                host = np.asarray(arr)
+            for arr, members, pairs in deferred:
+                if pairs is None:
+                    host = np.asarray(arr)
+                else:
+                    host, counted = jax.device_get((arr, pairs[0]))
+                    self._count_pairs(counted, pairs[1], decode=False)
                 for row, i in members:
                     slot = self._slot_state[i]
                     if slot is None or not slot.awaiting_first:
@@ -2449,6 +2884,8 @@ class DecodeLoop:
                                     skip=slot.no_cache)
             for page in slot.pages:
                 self._release_page(page)
+            if self._win is not None:
+                self._win.drop(idx)
             self._dirty = True
             self._cond.notify_all()  # admissions may proceed
         slot.stream._finish(reason, error)

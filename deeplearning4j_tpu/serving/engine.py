@@ -271,6 +271,41 @@ class InferenceEngine:
         return eng
 
     @classmethod
+    def for_moe_transformer(cls, params, cfg, *, decode_slots: int = 0,
+                            page_size: int = 16,
+                            kv_pages: Optional[int] = None,
+                            window_pages: Optional[int] = None,
+                            prefill_tokens_per_pass: Optional[int] = None,
+                            max_waiting: Optional[int] = None,
+                            decode_kernel: str = "auto",
+                            **kw) -> "InferenceEngine":
+        """Wrap a model of `models/moe_transformer.py` (grouped K/V
+        heads, window and full layers, an expert layer of which this
+        chip holds a part): apply = full logits (B, T, vocab) with
+        nothing cached; `decode_slots > 0` starts the `DecodeLoop` over
+        a cache of two kinds of page (`kv_pages` for the full kind,
+        `window_pages` for the window kind) and `generate_stream()`.
+        Prefix sharing, speculation and a horizon above 1 are not
+        written for this cache and stay off; there is no per-request
+        `generate()` (no contiguous cache for this block)."""
+        from deeplearning4j_tpu.compilecache import config_digest
+        from deeplearning4j_tpu.models import moe_transformer
+
+        cfg.check()
+        kw.setdefault("cache_key", "serve.moe:" + config_digest(cfg))
+        eng = cls(lambda p, tok: moe_transformer.logits(p, tok, cfg),
+                  params, **kw)
+        eng._tf_cfg = cfg
+        if decode_slots:
+            eng.start_decode_loop(
+                slots=decode_slots, page_size=page_size, n_pages=kv_pages,
+                window_pages=window_pages,
+                prefill_tokens_per_pass=prefill_tokens_per_pass,
+                max_waiting=max_waiting, prefix_cache=False,
+                kernel=decode_kernel)
+        return eng
+
+    @classmethod
     def for_lstm(cls, layer, params, **kw) -> "InferenceEngine":
         """Wrap an LSTM layer: apply = per-timestep decoded outputs over
         (B, T, n_in) input."""
@@ -354,7 +389,9 @@ class InferenceEngine:
                           draft_window: int = 32,
                           batch_share: float = 0.5,
                           batch_max_waiting: Optional[int] = None,
-                          role: str = "unified"):
+                          role: str = "unified",
+                          window_pages: Optional[int] = None,
+                          prefill_tokens_per_pass: Optional[int] = None):
         """Start the continuous-batching slot scheduler
         (serving/decode_loop.py) for this transformer engine: S slots
         over a paged KV pool riding ONE compiled decode step. `/generate`
@@ -366,7 +403,10 @@ class InferenceEngine:
         `drafter` ("ngram"|"model"). `batch_share`/`batch_max_waiting`
         tune the batch SLO tier's weighted-fair slot share and its
         (lower) admission-queue bound (docs/SERVING.md "Priority
-        tiers")."""
+        tiers"). `prefill_tokens_per_pass` bounds what one scheduler
+        pass prefills; `window_pages` sizes the window kind's pool of a
+        model with kinds of layer (docs/SERVING.md "Two kinds of
+        layer")."""
         from deeplearning4j_tpu.serving.decode_loop import DecodeLoop
 
         if self._tf_cfg is None:
@@ -390,7 +430,10 @@ class InferenceEngine:
                                       draft_window=draft_window,
                                       batch_share=batch_share,
                                       batch_max_waiting=batch_max_waiting,
-                                      role=role)
+                                      role=role,
+                                      window_pages=window_pages,
+                                      prefill_tokens_per_pass=(
+                                          prefill_tokens_per_pass))
         return self.decode_loop
 
     def generate_stream(self, prompt, max_tokens: int,

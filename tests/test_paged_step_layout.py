@@ -172,3 +172,69 @@ def test_verify_step_holds_no_pool_shaped_copy(one_chip,
         vec(SLOTS)).compile().as_text()
     assert "paged_decode_attention" in text
     _assert_pool_updated_in_place(text)
+
+
+def test_two_kind_decode_step_holds_no_pool_shaped_copy(one_chip,
+                                                        no_compile_cache):
+    """The decode step of the block with grouped K/V heads, window and
+    full layers and a held-expert layer (`paged_kinds.decode_step`, as
+    `DecodeLoop` jits it for a model with kinds of layer) at the served
+    widths: 128 query heads over 8 K/V heads of 128, bfloat16 pools of
+    2048 full and 1056 window pages of 128 tokens, donated. One window
+    and one full layer: what the compiler does to a pool it does to
+    each. The grouped expert products are the megablox kernel, as on
+    the chip (the backend here is the CPU, so the test says "tpu")."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.models import moe_transformer as moe
+    from deeplearning4j_tpu.serving import paged_kinds
+
+    cfg = moe.MoEConfig(
+        vocab_size=32768, d_model=4096, n_heads=128, n_kv_heads=8,
+        head_dim=128, d_ff=4096, layer_kinds=("window", "full"),
+        window=4096, n_experts=128, experts_per_token=8, n_shared=4,
+        n_held=16, rope_theta=50000.0, max_len=8192,
+        dtype=jnp.bfloat16).check()
+    pages = {"full": 2048, "window": 1056}
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    def vec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = on_chip(jax.eval_shape(
+        lambda: moe.init_moe_params(jax.random.PRNGKey(0), cfg)))
+    pool = on_chip(jax.eval_shape(
+        lambda: paged_kinds.init_pool(cfg, pages, 128)))
+    tables = {kind: vec(32, 64) for kind in pages}
+
+    def step_fn(params, tokens, pool, table, lengths, stop):
+        act = lengths < stop
+        logits, pool, pairs = paged_kinds.decode_step(
+            params, tokens, pool, table, lengths, act, cfg,
+            kernel="pallas")
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return (nxt[None], pairs), jnp.where(act, nxt, tokens), \
+            lengths + act.astype(lengths.dtype), pool
+
+    backend = jax.default_backend
+    jax.default_backend = lambda: "tpu"
+    try:
+        text = jax.jit(step_fn, donate_argnums=(2,)).lower(
+            params, vec(32), pool, tables, vec(32),
+            vec(32)).compile().as_text()
+    finally:
+        jax.default_backend = backend
+    assert "paged_decode_attention" in text and "%gmm" in text
+    for n in pages.values():
+        copies = re.findall(
+            rf"^.*= bf16\[{n + 1},8,128,128\]\{{[^}}]*\}} copy\(.*$",
+            text, re.M)
+        assert not copies, copies[0][:200]
+    alias = re.search(r"input_output_alias=\{(.*?)\}, entry", text, re.S)
+    assert alias, "the compiled module aliases no input to an output"
+    assert len(re.findall(r"(?:may|must)-alias", alias.group(1))) == 4
