@@ -191,12 +191,13 @@ import threading
 import time
 import weakref
 from collections import deque
-from typing import Iterator, List, Optional, Sequence
+from typing import Dict, Iterator, List, Optional, Sequence
 
 import numpy as np
 
 from deeplearning4j_tpu import telemetry
-from deeplearning4j_tpu.attention.paged_pallas import resolve_decode_kernel
+from deeplearning4j_tpu.attention.paged_pallas import (
+    block_pages, resolve_decode_kernel)
 from deeplearning4j_tpu.models.transformer import TransformerConfig
 from deeplearning4j_tpu.serving.errors import (TIER_BATCH,
                                                TIER_INTERACTIVE, TIERS,
@@ -1033,6 +1034,16 @@ class DecodeLoop:
 
         if self._kinds:
             self._register_kind_metrics(reg, lab, ref)
+        #: pages a block of the paged kernel's sweep holds, by kind of
+        #: layer: a constant of the step's shapes (0: the gather lane)
+        self._block_pages = self._paged_block_pages()
+        block = reg.gauge(
+            "dl4j_paged_kernel_block_pages",
+            "page-table columns the paged decode kernel sweeps as one "
+            "block, derived from the pool's shapes (1: a page a grid "
+            "step; 0: the step runs the gather lane)")
+        for kind, n in self._block_pages.items():
+            block.labels(kind=kind, **lab).set(n)
 
         if start:
             self._thread = threading.Thread(target=self._run, daemon=True,
@@ -1069,6 +1080,24 @@ class DecodeLoop:
             raise ValueError(
                 "a prefill-role loop ships pages over /kv/export, which "
                 "is not written for a model with window layers")
+
+    def _paged_block_pages(self) -> Dict[str, int]:
+        """`attention/paged_pallas.block_pages` of each kind's call in
+        the decode step, from the pool as it was built."""
+        columns = {paged_kinds.KIND_FULL: self._pps}
+        if self._win is not None:
+            columns[paged_kinds.KIND_WINDOW] = min(
+                self._pps, paged_kinds.window_table_pages(
+                    self.cfg, self.page_size))
+        out = {}
+        for kind, n in columns.items():
+            layer = self._pool.layers[
+                self.cfg.layer_kinds.index(kind) if self._kinds else 0]
+            _, heads, page_size, head_dim = layer["k"].shape
+            out[kind] = (block_pages(page_size, heads, head_dim,
+                                     layer["k"].dtype, n)
+                         if self.decode_kernel == "pallas" else 0)
+        return out
 
     def _register_kind_metrics(self, reg, lab: dict, ref) -> None:
         """Pages by kind and the expert layer's pairs (the families with
@@ -1929,6 +1958,9 @@ class DecodeLoop:
                         "gather": int(self._m_kv_read["gather"].value),
                     },
                 },
+                "paged_block_pages": (
+                    dict(self._block_pages) if self._kinds
+                    else self._block_pages[paged_kinds.KIND_FULL]),
                 "decode_step_programs": self.decode_step_programs(),
                 "prefill_programs": self.prefill_programs(),
                 "prefill_ctx_programs": jit_cache_size(self._prefill_ctx),

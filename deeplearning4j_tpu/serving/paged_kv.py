@@ -329,8 +329,9 @@ def decode_read_bytes(pool: PagedKVPool, lengths, table_width: int, *,
     for attention, summed over slots. Default (`dense=False`) is the
     streamed-kernel figure — K+V for each slot's written pages only,
     `min(floor(pos / page_size) + 1, table_width)` pages at cursor
-    `pos` (exactly the pages `paged_attention`'s grid computes, the
-    trash-page read of an idle slot included). `dense=True` is the
+    `pos` (exactly the pages `paged_attention`'s sweep fetches, a page
+    or a block of pages a step, the trash-page read of an idle slot
+    included). `dense=True` is the
     dense-gather figure: every slot touches its FULL page-table
     reservation (`S × table_width` pages) regardless of how little was
     written. The ratio of the two is the kernel's traffic win, exported

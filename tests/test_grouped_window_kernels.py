@@ -4,7 +4,8 @@ per slot against a dense float64 reference and against the gather path
 of the decode step; the flash forward with groups and a window against
 `attention/blockwise.py`. And what they were is what they are: with as
 many K/V heads as query heads and nothing windowed the new arguments
-change no bit."""
+change no bit. Since PR 31 pages that are whole tiles are swept a block
+of several a step: the same cases at a head size that engages it."""
 
 import jax
 import jax.numpy as jnp
@@ -13,7 +14,8 @@ import pytest
 
 from deeplearning4j_tpu.attention.blockwise import blockwise_attention
 from deeplearning4j_tpu.attention.flash_pallas import flash_attention
-from deeplearning4j_tpu.attention.paged_pallas import paged_attention
+from deeplearning4j_tpu.attention.paged_pallas import (block_pages,
+                                                       paged_attention)
 
 pytestmark = pytest.mark.pallas
 
@@ -31,21 +33,20 @@ def _paged_case(hq, hkv, dtype=jnp.float32, seed=0):
 
 
 def _dense(q, k, v, table, lengths, first, ps):
-    """float64, key by key."""
+    """float64, key by key; a cursor past the table sees the table."""
     q, k, v = (np.asarray(a, np.float64) for a in (q, k, v))
     s, hq, hd = q.shape
     group = hq // k.shape[1]
     out = np.zeros((s, hq, hd))
     for i in range(s):
-        pos = np.arange(first[i], lengths[i] + 1)
+        pos = np.arange(first[i],
+                        min(lengths[i], table.shape[1] * ps - 1) + 1)
+        kk = k[table[i, pos // ps], :, pos % ps]       # (keys, Hkv, hd)
+        vv = v[table[i, pos // ps], :, pos % ps]
         for n in range(hq):
-            kk = np.stack([k[table[i, p // ps], n // group, p % ps]
-                           for p in pos])
-            vv = np.stack([v[table[i, p // ps], n // group, p % ps]
-                           for p in pos])
-            sc = kk @ q[i, n] / np.sqrt(hd)
+            sc = kk[:, n // group] @ q[i, n] / np.sqrt(hd)
             w = np.exp(sc - sc.max())
-            out[i, n] = (w / w.sum()) @ vv
+            out[i, n] = (w / w.sum()) @ vv[:, n // group]
     return out
 
 
@@ -67,6 +68,44 @@ def test_paged_kernel_grouped_heads_and_a_first_position(hq, hkv):
     got = paged_attention(q, k, v, jnp.asarray(table), jnp.asarray(lengths),
                           interpret=True)
     want = _dense(q, k, v, table, lengths, np.zeros(3, np.int32), ps)
+    assert np.abs(np.asarray(got) - want).max() < 1e-5
+
+
+@pytest.mark.parametrize("window", [150, 40, None])
+@pytest.mark.parametrize("hq,hkv", [(8, 2), (16, 1), (4, 4)])
+def test_paged_kernel_blocks_of_pages_grouped_and_windowed(hq, hkv, window):
+    """Pages of 16 at head size 128: 8 pages a block. A window of 150
+    keys straddles 11 columns (no multiple of the block) from a first
+    position in the middle of a page and of the table's blocks; one of
+    40 keys straddles 4, fewer than a block holds."""
+    s, ps, hd, n_p, pages = 4, 16, 128, 20, 80
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(ks[0], (s, hq, hd))
+    k = jax.random.normal(ks[1], (pages + 1, hkv, ps, hd))
+    v = jax.random.normal(ks[2], (pages + 1, hkv, ps, hd))
+    table = np.random.RandomState(3).permutation(pages).reshape(s, n_p) \
+        .astype(np.int32)
+    lengths = np.asarray([5, 160, 299, 319], np.int32)
+    if window is None:
+        assert block_pages(ps, hkv, hd, jnp.float32, n_p) == 8
+        got = paged_attention(q, k, v, jnp.asarray(table),
+                              jnp.asarray(lengths), interpret=True)
+        want = _dense(q, k, v, table, lengths, np.zeros(s, np.int32), ps)
+        assert np.abs(np.asarray(got) - want).max() < 1e-5
+        return
+    columns = -(-(window - 1) // ps) + 1
+    assert block_pages(ps, hkv, hd, jnp.float32, columns) == min(8, columns)
+    first = np.maximum(lengths - window + 1, 0).astype(np.int32)
+    # pages before the first visible one were given back, those past
+    # the cursor's never granted: trash
+    held = np.full_like(table, pages)
+    for i in range(s):
+        lo, hi = first[i] // ps, lengths[i] // ps
+        held[i, lo:hi + 1] = table[i, lo:hi + 1]
+    got = paged_attention(q, k, v, jnp.asarray(held), jnp.asarray(lengths),
+                          first=jnp.asarray(first), window_pages=columns,
+                          interpret=True)
+    want = _dense(q, k, v, table, lengths, first, ps)
     assert np.abs(np.asarray(got) - want).max() < 1e-5
 
 

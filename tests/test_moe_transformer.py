@@ -302,6 +302,33 @@ def test_counters_of_pages_by_kind_and_of_pairs_and_the_release_span():
     assert f'dl4j_moe_pairs_total{{expert="0",layer="0",{lab}}}' in text
     # the one-kind families keep their shape
     assert f"dl4j_kv_pages_total{{{lab}}} 48" in text
+    # the paged kernel's block by kind: this loop gathers, so none
+    assert snap["paged_block_pages"] == {"full": 0, "window": 0}
+    assert f'dl4j_paged_kernel_block_pages{{kind="window",{lab}}} 0' \
+        in text
+
+
+def test_paged_kernel_block_by_kind_follows_each_kind_s_columns():
+    """At a head size that engages the block (pages of 16, 2 K/V heads
+    of 128): the full kind sweeps 64 columns 8 a block, the window kind
+    `window_table_pages` = 5 columns in one block of 5."""
+    from deeplearning4j_tpu.attention.paged_pallas import block_pages
+
+    cfg = _model(_config(), head_dim=128, window=50, max_len=1024,
+                 interpret=True)
+    params = moe.init_moe_params(jax.random.PRNGKey(0), cfg)
+    with dl.DecodeLoop(params, cfg, slots=2, page_size=16,
+                       prefix_cache=False, kernel="pallas", start=False,
+                       name="moe-blocks") as loop:
+        assert loop.snapshot()["paged_block_pages"] == {
+            "full": block_pages(16, cfg.n_kv_heads, 128, cfg.dtype, 64),
+            "window": 5}
+        assert loop.snapshot()["paged_block_pages"]["full"] == 8
+    text = exposition.render_prometheus()
+    assert ('dl4j_paged_kernel_block_pages{kind="full",'
+            'loop="moe-blocks"} 8') in text
+    assert ('dl4j_paged_kernel_block_pages{kind="window",'
+            'loop="moe-blocks"} 5') in text
 
 
 def test_rope_turns_pairs_and_keeps_norms():

@@ -22,6 +22,16 @@ docs/SERVING.md "Decode kernel"):
    figures every dispatch.
 5. **flash q_len=1** (satellite): `_fit_tile` admits the decode-shaped
    single-row query tile instead of demoting it to the dense fallback.
+6. **Blocks of pages** (ISSUE 31): pages that are whole (sublane, lane)
+   tiles and hold fewer than 128 keys are swept several a step
+   (`block_pages`: 8 for sat's pages of 16). Against a dense float64
+   reference and the gather lane with the block engaged: cursors in a
+   block's first, middle and last page and on its edges, widths the
+   block does not divide, a table narrower than a block, shared and
+   trash columns inside a block, the cursor at max_len; pages of 128
+   tokens stay one a grid step, bit for bit. The count the loop
+   reports (`snapshot()["paged_block_pages"]`,
+   `dl4j_paged_kernel_block_pages`) is that rule's.
 """
 
 from __future__ import annotations
@@ -35,7 +45,7 @@ from deeplearning4j_tpu.attention.blockwise import blockwise_attention
 from deeplearning4j_tpu.attention.flash_pallas import (_fit_tile,
                                                        flash_attention)
 from deeplearning4j_tpu.attention.paged_pallas import (
-    paged_attention, resolve_decode_kernel)
+    block_pages, paged_attention, resolve_decode_kernel)
 from deeplearning4j_tpu.models.transformer import (TransformerConfig,
                                                    init_transformer_params)
 from deeplearning4j_tpu.serving.decode_loop import DecodeLoop
@@ -46,6 +56,8 @@ from deeplearning4j_tpu.serving.paged_kv import (decode_read_bytes,
                                                  paged_prefill,
                                                  pages_for_tokens,
                                                  pages_per_slot)
+
+from tests.test_grouped_window_kernels import _dense
 
 pytestmark = pytest.mark.pallas
 
@@ -116,6 +128,153 @@ class TestPagedAttentionUnit:
         f(jnp.zeros((2, 3), jnp.int32), jnp.asarray([0, 5], jnp.int32))
         f(jnp.full((2, 3), 4, jnp.int32), jnp.asarray([11, 2], jnp.int32))
         assert jit_cache_size(f) in (1, -1)
+
+
+# ------------------------------------------------- blocks of pages
+def _block_case(ps, n_p, lengths, hq=2, hkv=2, hd=128, dtype=jnp.float32,
+                seed=0):
+    """Slots at `lengths` over distinct random pages, trash past each
+    cursor's page. The last slot's first pages are slot 1's (a prefix
+    two requests hold, one of them further on), and the one before it
+    holds nothing but trash."""
+    lengths = np.asarray(lengths, np.int32)
+    s = len(lengths)
+    n_pages = s * n_p
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(ks[0], (s, hq, hd), dtype)
+    k = jax.random.normal(ks[1], (n_pages + 1, hkv, ps, hd), dtype)
+    v = jax.random.normal(ks[2], (n_pages + 1, hkv, ps, hd), dtype)
+    free = list(np.random.RandomState(seed).permutation(n_pages))
+    table = np.full((s, n_p), n_pages, np.int32)
+    for i in range(s):
+        if i == s - 2:
+            continue
+        for c in range(min(int(lengths[i]) // ps, n_p - 1) + 1):
+            table[i, c] = free.pop()
+    shared = min(int(lengths[1]), int(lengths[s - 1])) // ps
+    table[s - 1, :shared] = table[1, :shared]
+    return q, k, v, table, lengths
+
+
+class TestBlocksOfPages:
+    # (page size, dtype, table width, pages a block): widths the block
+    # does not divide and one narrower than a block
+    SHAPES = [(16, "float32", 20, 8), (8, "float32", 37, 16),
+              (16, "bfloat16", 19, 8), (32, "float32", 9, 4),
+              (16, "float32", 5, 5)]
+
+    def test_the_rule(self):
+        """From the call's shapes alone: pages that are whole tiles,
+        as many as hold 128 keys, no more than the columns swept nor
+        than a MiB of K; sat's shapes take 8, ep8's 1."""
+        assert block_pages(16, 16, 128, jnp.bfloat16, 128) == 8
+        assert block_pages(16, 16, 128, jnp.float32, 128) == 8
+        assert block_pages(128, 8, 128, jnp.bfloat16, 64) == 1
+        assert block_pages(128, 8, 128, jnp.bfloat16, 33) == 1
+        assert block_pages(256, 8, 128, jnp.bfloat16, 33) == 1
+        assert block_pages(16, 16, 128, jnp.bfloat16, 3) == 3
+        assert block_pages(16, 32, 128, jnp.float32, 128) == 4
+        # no aligned place inside a block: half a bf16 tile, half a lane
+        # tile, a page of 4
+        assert block_pages(8, 8, 128, jnp.bfloat16, 32) == 1
+        assert block_pages(16, 16, 64, jnp.bfloat16, 32) == 1
+        assert block_pages(4, 2, 16, jnp.float32, 6) == 1
+
+    @pytest.mark.parametrize("ps,dtype,n_p,pages", SHAPES)
+    def test_kernel_against_dense_float64(self, ps, dtype, n_p, pages):
+        """Cursors at 0, in the first, a middle and the last page of a
+        block, on a block's last lane and the next block's first, at
+        the table's end and AT max_len; a slot of nothing but trash
+        (cursor 0) and one that shares another's pages."""
+        assert block_pages(ps, 2, 128, dtype, n_p) == pages
+        span, end = pages * ps, n_p * ps
+        lengths = [3, min(span + ps + 3, end - 1), span - 1,
+                   min(span, end - 1), end - 1, end,
+                   min(span + span // 2, end - 2), 0,
+                   min(span + 2 * ps + 1, end - 1)]
+        q, k, v, table, lengths = _block_case(ps, n_p, lengths,
+                                              dtype=jnp.dtype(dtype))
+        got = paged_attention(q, k, v, jnp.asarray(table),
+                              jnp.asarray(lengths), interpret=True)
+        want = _dense(q, k, v, table, lengths,
+                        np.zeros(len(lengths), np.int32), ps)
+        tol = 1e-5 if dtype == "float32" else 2e-2
+        assert np.abs(np.asarray(got, np.float64) - want).max() < tol
+
+    def test_step_kernel_lane_equals_the_gather_lane_with_blocks(self):
+        """`paged_decode_step` at a head size that engages the block
+        (hd 128, pages of 8: 16 a block over a 20-column table),
+        teacher-forced from cursors on both sides of a block's edge."""
+        cfg = TransformerConfig(vocab_size=17, d_model=256, n_heads=2,
+                                n_layers=1, d_ff=32, max_len=160,
+                                interpret=True)
+        ps = 8
+        n_p = pages_per_slot(cfg, ps)
+        assert block_pages(ps, 2, 128, cfg.dtype, n_p) == 16
+        p = init_transformer_params(jax.random.PRNGKey(0), cfg)
+        rng = np.random.RandomState(0)
+        pool = init_paged_pool(cfg, 3 * n_p, ps)
+        # the pool's rows as some prefill left them
+        pool = pool._replace(layers=tuple(
+            {kk: jnp.asarray(rng.normal(size=a.shape).astype(np.float32))
+             for kk, a in layer.items()} for layer in pool.layers))
+        table = rng.permutation(3 * n_p).reshape(3, n_p).astype(np.int32)
+        lengths = np.asarray([126, 5, 150], np.int32)
+        pool_k = pool
+        for _ in range(4):
+            args = (jnp.asarray(rng.randint(0, 17, (3,)).astype(np.int32)),
+                    jnp.asarray(table), jnp.asarray(lengths),
+                    jnp.asarray([True, True, True]))
+            lg_g, pool = paged_decode_step(p, args[0], pool, *args[1:],
+                                           cfg, kernel="gather")
+            lg_p, pool_k = paged_decode_step(p, args[0], pool_k, *args[1:],
+                                             cfg, kernel="pallas")
+            np.testing.assert_allclose(np.asarray(lg_p), np.asarray(lg_g),
+                                       atol=1e-5)
+            lengths += 1
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_pages_of_128_are_one_a_grid_step_bit_for_bit(self, dtype):
+        """ep8's page: a block of one, fetched by Pallas's own pipeline
+        over grid (slot, column) as ever: no copy by hand in the call,
+        and the sums of `test_paged_kernel_at_today_s_shapes_is_bit_
+        for_bit` (tests/test_grouped_window_kernels.py) bit for bit."""
+        q, k, v, table, lengths = _block_case(
+            128, 3, [0, 200, 127, 128, 383, 384, 5], hq=4, hkv=2,
+            dtype=dtype)
+        args = (q, k, v, jnp.asarray(table), jnp.asarray(lengths))
+        text = str(jax.make_jaxpr(
+            lambda *a: paged_attention(*a, interpret=True))(*args))
+        assert "dma_start" not in text
+        sat = jax.ShapeDtypeStruct((9, 2, 16, 128), dtype)
+        assert "dma_start" in str(jax.make_jaxpr(paged_attention)(
+            q, sat, sat, *args[3:]))
+        today = paged_attention(*args, interpret=True)
+        swept = paged_attention(*args, first=jnp.zeros((7,), jnp.int32),
+                                window_pages=3, interpret=True)
+        assert np.array_equal(np.asarray(today), np.asarray(swept))
+        want = _dense(q, k, v, table, lengths, np.zeros(7, np.int32), 128)
+        tol = 1e-5 if dtype == jnp.float32 else 2e-2
+        assert np.abs(np.asarray(today, np.float64) - want).max() < tol
+
+    def test_loop_reports_the_block_it_was_built_with(self):
+        """`snapshot()["paged_block_pages"]` and the gauge: the rule's
+        answer for the pool the loop built, 0 on the gather lane."""
+        from deeplearning4j_tpu.telemetry import exposition
+
+        cfg = TransformerConfig(vocab_size=17, d_model=256, n_heads=2,
+                                n_layers=1, d_ff=32, max_len=512,
+                                interpret=True)
+        p = init_transformer_params(jax.random.PRNGKey(0), cfg)
+        for ps, kernel, want in ((16, "pallas", 8), (128, "pallas", 1),
+                                 (16, "gather", 0)):
+            name = f"blk-{kernel}-{ps}"
+            with DecodeLoop(p, cfg, slots=2, page_size=ps, kernel=kernel,
+                            start=False, name=name) as loop:
+                assert loop.snapshot()["paged_block_pages"] == want
+            assert (f'dl4j_paged_kernel_block_pages{{kind="full",'
+                    f'loop="{name}"}} {want}'
+                    ) in exposition.render_prometheus()
 
 
 class TestStepParity:

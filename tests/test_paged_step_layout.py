@@ -11,7 +11,10 @@ scatter whose operand the TPU compiler lays out as {3,1,2,0}, against
 the {3,2,1,0} of the donated pool and of `paged_decode_attention`: two
 layout changes of a whole 168 MB pool per layer for K and for V each,
 65% of the device time of a served decode step. About 8 s a compile, no
-chip time.
+chip time. Since PR 31 the kernel at these shapes sweeps blocks of 8
+pages that it copies out of the pool itself (the pool is an operand
+left in HBM): each call must still be handed the pool as it lies,
+row-major, whatever the block.
 
 The topology is described inside a module fixture, never at import, and
 the tests skip where it cannot be described (the same set-up as
@@ -113,6 +116,22 @@ def _assert_pool_updated_in_place(text):
     assert len(leaves) == 2 * N_LAYERS, alias.group(1)
 
 
+def _assert_kernel_reads_the_pool_as_it_lies(text, shapes, calls):
+    """Every `paged_decode_attention` call constrains its K and V
+    operands to the pool's shape, row-major: the layout of the donated
+    pool and of the step's write."""
+    found = re.findall(
+        r"^.*paged_decode_attention.*custom-call\(.*"
+        r"operand_layout_constraints=\{(.*?\})\}, ", text, re.M)
+    assert len(found) == calls, len(found)
+    wanted = {"bf16[" + ",".join(str(n) for n in shape) + "]{3,2,1,0}"
+              for shape in shapes}
+    for constraints in found:
+        pools = re.findall(r"bf16\[\d+,\d+,\d+,\d+\]\{[\d,]*\}",
+                           constraints)[-2:]
+        assert len(set(pools)) == 1 and pools[0] in wanted, constraints
+
+
 def test_decode_step_holds_no_pool_shaped_copy(one_chip,
                                                no_compile_cache):
     """The step as `DecodeLoop` jits it: `paged_decode_step` on the
@@ -145,7 +164,7 @@ def test_decode_step_holds_no_pool_shaped_copy(one_chip,
     text = jax.jit(step_fn, donate_argnums=(2,)).lower(
         params, vec(SLOTS), pool, table, vec(SLOTS),
         vec(SLOTS)).compile().as_text()
-    assert "paged_decode_attention" in text
+    _assert_kernel_reads_the_pool_as_it_lies(text, [POOL_SHAPE], N_LAYERS)
     _assert_pool_updated_in_place(text)
 
 
@@ -170,7 +189,9 @@ def test_verify_step_holds_no_pool_shaped_copy(one_chip,
     text = jax.jit(verify_fn, donate_argnums=(2,)).lower(
         params, vec(SLOTS, 4), pool, table, vec(SLOTS),
         vec(SLOTS)).compile().as_text()
-    assert "paged_decode_attention" in text
+    # one single-query pass a draft column a layer
+    _assert_kernel_reads_the_pool_as_it_lies(text, [POOL_SHAPE],
+                                             4 * N_LAYERS)
     _assert_pool_updated_in_place(text)
 
 
@@ -229,7 +250,9 @@ def test_two_kind_decode_step_holds_no_pool_shaped_copy(one_chip,
             vec(32)).compile().as_text()
     finally:
         jax.default_backend = backend
-    assert "paged_decode_attention" in text and "%gmm" in text
+    assert "%gmm" in text
+    _assert_kernel_reads_the_pool_as_it_lies(
+        text, [(n + 1, 8, 128, 128) for n in pages.values()], 2)
     for n in pages.values():
         copies = re.findall(
             rf"^.*= bf16\[{n + 1},8,128,128\]\{{[^}}]*\}} copy\(.*$",

@@ -46,6 +46,28 @@ def test_paged_attention_lowers(h, hd, dtype):
     assert 'kernel_name = "paged_decode_attention"' in text
 
 
+def test_paged_attention_lowers_at_the_served_shape_with_blocks():
+    """`cgpt13b-decode-sat`'s call (S16, H16, hd128, pages of 16, 128
+    columns, bf16): 8 pages a block, the pools left in HBM and each
+    page copied by the kernel itself, which the one-page kernel of the
+    cases above does not do."""
+    from deeplearning4j_tpu.attention.paged_pallas import block_pages
+
+    s, h, hd, ps, n_p = 16, 16, 128, 16, 128
+    assert block_pages(ps, h, hd, "bfloat16", n_p) == 8
+    pool = sds((2561, h, ps, hd), "bfloat16")
+    args = (sds((s, h, hd), "bfloat16"), pool, pool,
+            sds((s, n_p), "int32"), sds((s,), "int32"))
+    text = tpu_module(paged_attention, *args)
+    assert text.count('kernel_name = "paged_decode_attention"') == 1
+    jaxpr = str(jax.make_jaxpr(paged_attention)(*args))
+    assert "dma_start" in jaxpr and "dma_wait" in jaxpr
+    small = (sds((8, 8, 128), "bfloat16"), sds((65, 8, 8, 128), "bfloat16"),
+             sds((65, 8, 8, 128), "bfloat16"), sds((8, 8), "int32"),
+             sds((8,), "int32"))
+    assert "dma_start" not in str(jax.make_jaxpr(paged_attention)(*small))
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_decode_and_verify_steps_lower_with_the_kernel(dtype):
     """The two programs `DecodeLoop` jits around the kernel, at the
@@ -68,8 +90,10 @@ def test_decode_and_verify_steps_lower_with_the_kernel(dtype):
         lambda p, t, pool, tb, ln, wd: paged_verify_step(
             p, t, pool, tb, ln, wd, cfg, kernel="pallas"),
         params, sds((s, w), "int32"), pool, table, lengths, lengths)
-    # one single-query pass per draft column
-    assert text.count('kernel_name = "paged_decode_attention"') == w
+    # one single-query pass per draft column, the kernel lowered once
+    # a shape (PR 31: `paged_attention`'s body is jitted)
+    assert text.count('kernel_name = "paged_decode_attention"') == 1
+    assert text.count("call @_paged_attention(") == w
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])

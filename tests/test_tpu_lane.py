@@ -274,6 +274,28 @@ class TestPagedKernelOnChip:
             np.asarray(out.astype(jnp.float32), np.float64), ref,
             atol=atol)
 
+    @pytest.mark.parametrize("dtype,atol", [("float32", 1e-5),
+                                            ("bfloat16", 2e-2)])
+    def test_kernel_at_the_served_shape_sweeps_blocks(self, dtype, atol):
+        """`cgpt13b-decode-sat`'s call: 16 slots, 16 heads of 128, pages
+        of 16 over a 128-column table. Since PR 31 a block of 8 pages a
+        step, each page copied from where it lies in the pool."""
+        import jax
+        import jax.numpy as jnp
+
+        from deeplearning4j_tpu.attention.paged_pallas import (
+            block_pages, paged_attention)
+
+        assert jax.devices()[0].platform == "tpu"
+        assert block_pages(16, 16, 128, jnp.dtype(dtype), 128) == 8
+        case = _paged_case(np.random.default_rng(1), 16, 16, 128, 16,
+                           128, jnp.dtype(dtype))
+        out = jax.jit(paged_attention)(*case)
+        ref = _dense_paged_reference(*case)
+        np.testing.assert_allclose(
+            np.asarray(out.astype(jnp.float32), np.float64), ref,
+            atol=atol)
+
     @pytest.mark.parametrize("dtype,rtol", [("float32", 1e-4),
                                             ("bfloat16", 5e-2)])
     def test_decode_and_verify_steps_match_gather(self, dtype, rtol):
