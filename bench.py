@@ -3559,10 +3559,9 @@ def bench_paged_kernel():
         resolve_decode_kernel)
     from deeplearning4j_tpu.models.transformer import (
         TransformerConfig, init_transformer_params)
+    from deeplearning4j_tpu.serving import paged_kinds
     from deeplearning4j_tpu.serving.decode_loop import DecodeLoop
     from deeplearning4j_tpu.serving.paged_kv import (init_paged_pool,
-                                                     paged_decode_step,
-                                                     paged_prefill,
                                                      pages_for_tokens,
                                                      pages_per_slot)
 
@@ -3597,9 +3596,9 @@ def bench_paged_kernel():
         table[i, :need] = pages
     # the window-edge slot owns its FULL reservation (all pages real)
     table[3] = [free.pop(0) for _ in range(P)]
-    _, pool_g = paged_prefill(params, jnp.asarray(padded),
-                              jnp.asarray(np.minimum(lengths, tb)),
-                              pool_g, jnp.asarray(pids), cfg)
+    _, pool_g, _ = paged_kinds.prefill(
+        params, jnp.asarray(padded), jnp.asarray(np.minimum(lengths, tb)),
+        pool_g, {"full": jnp.asarray(pids)}, cfg)
     pool_p = pool_g
     active = np.asarray([True, True, True, False])
     max_err, steps = 0.0, 4
@@ -3612,12 +3611,12 @@ def bench_paged_kernel():
                     table[i, pidx] = free.pop(0)
         args = (jnp.asarray(toks), jnp.asarray(table),
                 jnp.asarray(lengths), jnp.asarray(active))
-        lg_g, pool_g = paged_decode_step(params, args[0], pool_g,
-                                         args[1], args[2], args[3],
-                                         cfg, kernel="gather")
-        lg_p, pool_p = paged_decode_step(params, args[0], pool_p,
-                                         args[1], args[2], args[3],
-                                         cfg, kernel="pallas")
+        lg_g, pool_g, _ = paged_kinds.decode_step(
+            params, args[0], pool_g, {"full": args[1]}, args[2], args[3],
+            cfg, kernel="gather")
+        lg_p, pool_p, _ = paged_kinds.decode_step(
+            params, args[0], pool_p, {"full": args[1]}, args[2], args[3],
+            cfg, kernel="pallas")
         max_err = max(max_err, float(jnp.max(jnp.abs(lg_p - lg_g))))
         lengths = lengths + np.where(active, 1, 0).astype(np.int32)
     if max_err > 1e-5:

@@ -323,7 +323,7 @@ def serve_leg(lm: dict = LM, mlp=MLP, prompt_lens=PROMPT_LENS,
                     raise r
                 check(r is not None, "a burst request never returned")
             # the same long prompt again: its full pages are cached, so
-            # only the tail is prefilled (paged_prefill_ctx), and greedy
+            # only the tail is prefilled (paged_kinds.prefill_ctx), and greedy
             # decoding must give the same tokens
             t0 = time.monotonic()
             again = _generate(url, long_, new_tokens + 16, timeout)

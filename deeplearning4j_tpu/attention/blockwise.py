@@ -32,6 +32,25 @@ def naive_attention(q, k, v, causal: bool = False, scale: Optional[float] = None
     return jnp.einsum("...qk,...kd->...qd", p, v)
 
 
+def masked_attention(q, k, v, mask):
+    """The caches' dense read: an exact masked softmax in f32 of q (B,
+    Hq, Tq, hd) over every key of k, v (B, Hkv, Tk, hd) that `mask`
+    (broadcastable to (B, Tq, Tk)) lets the query see; query head n
+    reads K/V head n // (Hq / Hkv). Returns (B, Hq, Tq, hd) f32. A
+    masked score underflows to exactly 0 in the softmax, so whatever
+    lies in rows never written counts for exactly nothing; a query must
+    see at least one key."""
+    b, hq, tq, hd = q.shape
+    hkv = k.shape[1]
+    qg = q.reshape(b, hkv, hq // hkv, tq, hd)
+    s = jnp.einsum("bhgqd,bhkd->bhgqk", qg.astype(jnp.float32),
+                   k.astype(jnp.float32)) * (1.0 / jnp.sqrt(jnp.float32(hd)))
+    s = jnp.where(jnp.asarray(mask)[..., None, None, :, :], s, NEG_INF)
+    att = jnp.einsum("bhgqk,bhkd->bhgqd", jax.nn.softmax(s, axis=-1),
+                     v.astype(jnp.float32))
+    return att.reshape(b, hq, tq, hd)
+
+
 @partial(jax.jit, static_argnames=("causal", "block_size", "q_offset",
                                    "k_offset", "return_lse", "window"))
 def blockwise_attention(q, k, v, causal: bool = False,

@@ -1,12 +1,13 @@
 """Paged-attention decode as a Pallas TPU kernel.
 
-`paged_decode_step` (serving/paged_kv.py) historically gathered every
-slot's page list into a dense `(S, H, window, hd)` K/V window each
-step — per-step HBM traffic scaling with the page-table RESERVATION
-(`S × max_len`), not the tokens actually written. This kernel streams
-pages straight from the pool instead (the PagedAttention design,
-PAPERS.md arXiv:2603.09555, on the repo's kernel-with-interpret
-portability pattern from `attention/flash_pallas.py`):
+The decode step (`serving/paged_kinds.decode_step`) historically
+gathered every slot's page list into a dense `(S, H, window, hd)` K/V
+window each step — per-step HBM traffic scaling with the page-table
+RESERVATION (`S × max_len`), not the tokens actually written. This
+kernel streams pages straight from the pool instead (the
+PagedAttention design, PAPERS.md arXiv:2603.09555, on the repo's
+kernel-with-interpret portability pattern from
+`attention/flash_pallas.py`):
 
 - the sweep of a slot goes over BLOCKS of `block_pages(...)`
   consecutive page-table columns. The page table and per-slot lengths
@@ -268,7 +269,7 @@ def paged_attention(q, k_pool, v_pool, page_table, lengths, *,
     indices (trash-filled past each slot's allocation). lengths: (S,)
     int32 cursors — positions [0, lengths[s]] are attended (the
     incoming token's K/V must already be scattered at its cursor,
-    exactly as `paged_decode_step` orders writes before attention).
+    exactly as `paged_kinds.decode_step` orders writes before attention).
 
     Returns (S, H, hd) in q.dtype. page_table/lengths are traced
     values: membership changes never recompile (the
@@ -431,7 +432,7 @@ def block_pages(page_size: int, kv_heads: int, head_dim: int, dtype,
 
 def resolve_decode_kernel(kernel: str, cfg, page_size: int) -> str:
     """Resolve the `kernel="pallas"|"gather"|"auto"` knob to the lane
-    `paged_decode_step` actually runs — ONCE, at loop construction, so
+    the decode step actually runs — ONCE, at loop construction, so
     the decode step stays one compiled program.
 
     - "gather": always the dense-gather path.
@@ -474,10 +475,7 @@ def resolve_decode_kernel(kernel: str, cfg, page_size: int) -> str:
     # auto
     if not on_tpu:
         return "gather"
-    # a model description that states its head size says so itself
-    # (grouped heads: d_model / n_heads is not it)
-    hd = getattr(cfg, "head_dim", None) or cfg.d_model // cfg.n_heads
     itemsize = jnp.dtype(cfg.dtype).itemsize
-    if hd > 128 or itemsize > 4 or page_size < 8:
+    if cfg.head_dim > 128 or itemsize > 4 or page_size < 8:
         return "gather"
     return "pallas"

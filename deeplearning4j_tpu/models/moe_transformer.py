@@ -24,10 +24,11 @@ written: ROADMAP Reach). `expert_layer` returns, beside its result, how
 many (token, expert) pairs fell on each held expert.
 
 **The block stands once.** `block` takes an `attend` callback for
-"write these K/V rows, read what is visible". The uncached forward
-(`logits`), the paged prefill and the paged decode step
-(`serving/paged_kinds.py`) are that one definition under three
-callbacks; the router and the rotation are written nowhere else.
+"write these K/V rows, read what is visible" (`models/transformer.py`
+has the contract, one for every model). The uncached forward (`logits`)
+and every lane of the paged cache (`serving/paged_kinds.py`) are that
+one definition under their callbacks; the router and the rotation are
+written nowhere else.
 
 The routed products are grouped: the pairs that fall on held experts
 are sorted by expert and multiplied group by group (`grouped_matmul`:
@@ -40,16 +41,15 @@ taken `chunk` rows at a time until none is left.
 
 from __future__ import annotations
 
-from typing import Any, Callable, NamedTuple, Tuple
+from typing import Any, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from deeplearning4j_tpu.attention.flash_pallas import flash_attention
-
-KIND_FULL = "full"
-KIND_WINDOW = "window"
-KINDS = (KIND_FULL, KIND_WINDOW)
+from deeplearning4j_tpu.models.transformer import (KIND_FULL,  # noqa: F401
+                                                   KIND_WINDOW, KINDS,
+                                                   Attend,
+                                                   causal_attention)
 
 __all__ = ["MoEConfig", "KINDS", "KIND_FULL", "KIND_WINDOW",
            "init_moe_params", "rope", "expert_layer", "grouped_matmul",
@@ -280,17 +280,12 @@ def expert_layer(p, h, cfg: MoEConfig, valid=None):
 
 
 # ------------------------------------------------------------- the block
-Attend = Callable[[int, str, Any, Any, Any], Tuple[Any, Any]]
-
-
 def block(p, x, positions, layer: int, cfg: MoEConfig, attend: Attend,
           valid=None):
-    """One parallel block on x (B, T, d). `attend(layer, kind, q, k, v)`
-    with q (B, T, Hq, hd) and k, v (B, T, Hkv, hd), rotated where the
-    layer's kind has positions, writes the K/V rows where its cache
-    wants them and returns (what each query reads of what is visible,
-    (B, T, Hq, hd); the cache's new state for this layer). Returns
-    (x', that state, pairs by held expert)."""
+    """One parallel block on x (B, T, d). `attend` (the contract is
+    `models/transformer.Attend`'s) gets q, k and v with heads before
+    positions, rotated where the layer's kind has positions. Returns
+    (x', the cache's new state for this layer, pairs by held expert)."""
     b, t, d = x.shape
     kind = cfg.layer_kinds[layer]
     h = _gain_norm(p["ln"], x, cfg.ln_eps)
@@ -300,8 +295,10 @@ def block(p, x, positions, layer: int, cfg: MoEConfig, attend: Attend,
     if kind == KIND_WINDOW:
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
-    att, state = attend(layer, kind, q, k, v)
-    attn = att.astype(x.dtype).reshape(b, t, -1) @ p["Wo"]
+    att, state = attend(layer, kind, q.transpose(0, 2, 1, 3),
+                        k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
+    attn = att.astype(x.dtype).transpose(0, 2, 1, 3).reshape(
+        b, t, -1) @ p["Wo"]
     moe, pairs = expert_layer(
         p, h.reshape(b * t, d), cfg,
         None if valid is None else valid.reshape(b * t))
@@ -312,9 +309,10 @@ def block(p, x, positions, layer: int, cfg: MoEConfig, attend: Attend,
 
 def forward(params, tokens, positions, cfg: MoEConfig, attend: Attend,
             valid=None):
-    """Every block over tokens (B, T) at `positions` (B, T). Returns
-    (hidden (B, T, d) before the final norm, the cache states a layer,
-    pairs (layers, n_held) int32)."""
+    """Every block over tokens (B, T) at `positions` (B, T), or (T,)
+    where every row stands at the same ones. Returns (hidden (B, T, d)
+    before the final norm, the cache states a layer, pairs (layers,
+    n_held) int32)."""
     x = params["embed"][tokens]
     states, pairs = [], []
     for i, p in enumerate(params["blocks"]):
@@ -331,23 +329,10 @@ def head(params, x, cfg: MoEConfig):
         x, params["embed"].T, preferred_element_type=jnp.float32)
 
 
-def causal_attention(cfg: MoEConfig, kind: str, q, k, v):
-    """Whole rows at once, nothing cached: q (B, T, Hq, hd) over k, v
-    (B, T, Hkv, hd), causal, windowed in a window layer. The flash
-    kernel (grouped heads, window) where T is tile-aligned."""
-    att = flash_attention(
-        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-        v.transpose(0, 2, 1, 3), True, interpret=cfg.interpret,
-        window=cfg.window if kind == KIND_WINDOW else None)
-    return att.transpose(0, 2, 1, 3)
-
-
 def logits(params, tokens, cfg: MoEConfig):
     """tokens (B, T) -> (B, T, vocab_size) f32, nothing cached."""
-    b, t = tokens.shape
-    positions = jnp.broadcast_to(jnp.arange(t), (b, t))
     x, _, _ = forward(
-        params, tokens, positions, cfg,
+        params, tokens, jnp.arange(tokens.shape[1]), cfg,
         lambda _l, kind, q, k, v: (causal_attention(cfg, kind, q, k, v),
                                    None))
     return head(params, x, cfg)

@@ -18,12 +18,31 @@ LSTM module made (models/lstm.py).
 from __future__ import annotations
 
 from functools import partial
-from typing import Any, Dict, NamedTuple
+from typing import Any, Callable, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.attention.flash_pallas import flash_attention
+
+#: the kinds of layer a cache can be asked to hold: a "full" layer keeps
+#: every key of a sequence, a "window" layer only ever reads the last
+#: `cfg.window` (models/moe_transformer.py has both; this model is all
+#: full)
+KIND_FULL = "full"
+KIND_WINDOW = "window"
+KINDS = (KIND_FULL, KIND_WINDOW)
+
+#: `attend(layer, kind, q, k, v) -> (att, the cache's new state for this
+#: layer)`: the one thing a block leaves to its caller. q is (B, Hq, T,
+#: hd) and k, v are (B, Hkv, T, hd), heads before positions, the order
+#: the kernels and the caches hold, for every model of this package. The
+#: callback writes the K/V rows where its cache wants them and returns
+#: what each query reads of what is visible to it, (B, Hq, T, hd). The
+#: uncached forward, the contiguous cache (serving/kv_cache.py) and the
+#: paged cache (serving/paged_kinds.py) are a model's one block under
+#: their callbacks.
+Attend = Callable[[int, str, Any, Any, Any], Tuple[Any, Any]]
 
 
 class TransformerConfig(NamedTuple):
@@ -36,6 +55,28 @@ class TransformerConfig(NamedTuple):
     dtype: Any = jnp.float32
     #: interpret-mode pallas for CPU tests; ignored by the fallback
     interpret: bool = False
+
+    # what a cache asks of any model's description, derived here: every
+    # layer keeps all keys, a K/V head a query head, no expert layer
+    @property
+    def head_dim(self) -> int:
+        return self.d_model // self.n_heads
+
+    @property
+    def n_kv_heads(self) -> int:
+        return self.n_heads
+
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        return (KIND_FULL,) * self.n_layers
+
+    @property
+    def window(self) -> None:
+        return None
+
+    @property
+    def n_held(self) -> int:
+        return 0
 
 
 def init_transformer_params(key, cfg: TransformerConfig) -> Dict[str, Any]:
@@ -85,35 +126,84 @@ def _layer_norm(p, x, eps=1e-5):
             * p["g"] + p["b"])
 
 
-def _block(p, x, cfg: TransformerConfig):
+def causal_attention(cfg, kind: str, q, k, v):
+    """Whole rows at once, nothing cached: q (B, Hq, T, hd) over k, v
+    (B, Hkv, T, hd), causal, windowed in a window layer. The flash
+    kernel (grouped heads, window; its custom vjp is the backward)
+    where T is tile-aligned."""
+    return flash_attention(
+        q, k, v, True, interpret=cfg.interpret,
+        window=cfg.window if kind == KIND_WINDOW else None)
+
+
+def visible(cfg, kind: str, q_pos, k_pos):
+    """The same rule as a mask (..., Tq, Tk) for the caches' dense
+    reads: the query at q_pos (..., Tq) sees the key at k_pos (..., Tk)
+    iff k_pos <= q_pos and, in a window layer, q_pos - window < k_pos
+    (the query's own position counts among the `window`)."""
+    q_pos, k_pos = q_pos[..., :, None], k_pos[..., None, :]
+    seen = k_pos <= q_pos
+    if kind == KIND_WINDOW:
+        seen = seen & (k_pos > q_pos - cfg.window)
+    return seen
+
+
+def block(p, x, cfg: TransformerConfig, attend: Attend, layer: int):
+    """One pre-LN block on x (B, T, d): the only place its mathematics
+    is written. Returns (x', the cache's new state for this layer)."""
     b, t, d = x.shape
-    hd = d // cfg.n_heads
     h = _layer_norm(p["ln1"], x)
 
     def heads(w):
-        return (h @ w).reshape(b, t, cfg.n_heads, hd).transpose(0, 2, 1, 3)
+        return (h @ w).reshape(b, t, cfg.n_heads,
+                               cfg.head_dim).transpose(0, 2, 1, 3)
 
-    # flash kernel over (B, H, T, hd); custom vjp supplies the backward
-    att = flash_attention(heads(p["Wq"]), heads(p["Wk"]), heads(p["Wv"]),
-                          True, interpret=cfg.interpret)
-    att = att.transpose(0, 2, 1, 3).reshape(b, t, d)
+    att, state = attend(layer, KIND_FULL, heads(p["Wq"]), heads(p["Wk"]),
+                        heads(p["Wv"]))
+    att = att.astype(x.dtype).transpose(0, 2, 1, 3).reshape(b, t, d)
     x = x + att @ p["Wo"]
     h = _layer_norm(p["ln2"], x)
     x = x + jax.nn.gelu(h @ p["W1"] + p["b1"]) @ p["W2"] + p["b2"]
-    return x
+    return x, state
+
+
+def forward(params, tokens, positions, cfg: TransformerConfig,
+            attend: Attend, valid=None):
+    """Every block over tokens (B, T). `positions` (B, T), or (T,) where
+    every row stands at the same ones, index the learned table, clamped
+    to it (a bucket of padding may overshoot `max_len`); None is 0..T-1
+    as a slice of the table, the training step's form. `valid` is taken
+    for the models that count their tokens and is not read. Returns
+    (hidden (B, T, d) before the final norm, the cache states a layer,
+    () for what an expert layer would count)."""
+    x = params["embed"][tokens]
+    if positions is None:
+        x = x + params["pos"][:tokens.shape[1]]
+    else:
+        x = x + params["pos"][jnp.minimum(positions, cfg.max_len - 1)]
+    states = []
+    for i, p in enumerate(params["blocks"]):
+        x, state = block(p, x, cfg, attend, i)
+        states.append(state)
+    return x, tuple(states), ()
+
+
+def head(params, x, cfg: TransformerConfig):
+    """x (..., d) -> logits over the vocabulary; the head is tied to
+    the embedding."""
+    return _layer_norm(params["ln_f"], x) @ params["embed"].T
 
 
 def transformer_logits(params, tokens, cfg: TransformerConfig):
-    """tokens: (B, T) int32 -> (B, T, vocab) logits. Output head tied
-    to the embedding (standard weight tying)."""
-    b, t = tokens.shape
+    """tokens: (B, T) int32 -> (B, T, vocab) logits, nothing cached."""
+    t = tokens.shape[1]
     if t > cfg.max_len:
         raise ValueError(f"sequence {t} exceeds max_len {cfg.max_len}")
-    x = params["embed"][tokens] + params["pos"][:t]
-    for p in params["blocks"]:
-        x = _block(p, x, cfg)
-    x = _layer_norm(params["ln_f"], x)
-    return x @ params["embed"].T
+    x, _, _ = forward(
+        params, tokens, None, cfg,
+        lambda _l, kind, q, k, v: (causal_attention(cfg, kind, q, k, v),
+                                   None))
+    return head(params, x, cfg)
 
 
 def lm_loss(params, tokens, cfg: TransformerConfig):
@@ -214,6 +304,7 @@ def generate(params, prompt, cfg: TransformerConfig, n_tokens: int,
     return buf
 
 
-__all__ = ["TransformerConfig", "init_transformer_params",
-           "transformer_logits", "lm_loss", "make_train_step",
-           "init_velocity", "fit_scan", "generate"]
+__all__ = ["TransformerConfig", "KINDS", "KIND_FULL", "KIND_WINDOW",
+           "init_transformer_params", "causal_attention", "visible",
+           "block", "forward", "head", "transformer_logits", "lm_loss",
+           "make_train_step", "init_velocity", "fit_scan", "generate"]

@@ -57,9 +57,7 @@ from deeplearning4j_tpu.serving.kv_cache import (  # noqa: F401
 from deeplearning4j_tpu.serving.paged_kv import (  # noqa: F401
     PagedKVPool,
     init_paged_pool,
-    paged_decode_step,
     paged_kv_bytes,
-    paged_prefill,
 )
 from deeplearning4j_tpu.serving.replicas import ReplicaSet  # noqa: F401
 from deeplearning4j_tpu.serving.server import serve_network  # noqa: F401

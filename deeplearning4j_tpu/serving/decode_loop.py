@@ -8,7 +8,7 @@ modern serving shape (the PagedAttention / continuous-batching lineage;
 ROADMAP "Continuous batching + paged KV cache"):
 
 - a fixed pool of **S slots** rides ONE jitted decode step
-  (`paged_kv.paged_decode_step` + on-device argmax feedback). Slot
+  (`paged_kinds.decode_step` + on-device argmax feedback). Slot
   membership is a traced per-slot `stop` bound, never a shape — the
   step compiles exactly once and requests join/leave without
   recompiling for the life of the server (`decode_step_programs()`
@@ -31,7 +31,7 @@ The device carry — last tokens, pool, page table, lengths, stop bounds
 completion, page grant). Steady-state per-token cost is one dispatch
 slice plus the token D2H the streams need anyway. On accelerators the
 pool is donated to the step and KV updates alias in place: that holds
-because the step's write (`paged_kv._write_rows`) keeps the pool in the
+because the step's write (`paged_kinds._write_rows`) keeps the pool in the
 layout the paged kernel reads, so the compiled step holds no copy of
 the pool (tests/test_paged_step_layout.py pins it; the earlier
 two-index scatter was donated too and still copied each layer's pool
@@ -61,7 +61,7 @@ content-addressed index (`prefix_cache.PrefixIndex`, a radix trie over
 page-aligned token-id chunks) sits in front of admission. Pages become
 REFCOUNTED: a request whose prompt starts with cached chunks maps those
 pool pages into its page table by reference and prefills only the
-uncovered tail (`paged_prefill_ctx` — the tail attends to the shared
+uncovered tail (`paged_kinds.prefill_ctx` — the tail attends to the shared
 prefix through the pool); a fully-covered prompt skips prefill
 entirely and replays its last prompt token through the decode step.
 Shared pages are read-only: the first divergent write — the decode
@@ -94,7 +94,7 @@ is visible whichever lane runs.
 round, a drafter (serving/speculation.py — "ngram" prompt-lookup fed by
 the slot's own history and the prefix-cache trie, or "model" with a
 small draft transformer) proposes up to k continuation tokens per slot,
-and ONE widened verify dispatch (`paged_kv.paged_verify_step` — the
+and ONE widened verify dispatch (`paged_kinds.verify_step` — the
 horizon idea turned sideways: k+1 positions of one step instead of k+1
 chained steps) scores every position against the target model. The
 longest prefix where the draft matches the target's own argmax is
@@ -141,26 +141,32 @@ dl4j_kv_prefix_{hits,misses,forks,evictions} /
 dl4j_decode_kv_read_bytes{path} counters, dl4j_decode_step_seconds
 histogram (docs/OBSERVABILITY.md).
 
-**Two kinds of layer in one manager** (`models/moe_transformer.py`): a
-model whose description has `layer_kinds` keeps "full" layers, which
-hold every key of a sequence, and "window" layers, which only ever read
-the last `cfg.window` keys. Each kind has its own pool size, page table
-and free list here (`serving/paged_kinds.py` is the device side): the
-full kind rides the lists every model uses, the window kind
-`_WindowPages`. Admission checks both kinds; a prompt longer than the
-window claims, for the window kind, only the pages that still hold a
-key its first decoded token can see; and every pass returns to the
-window kind's free list the pages whose last key has left the window
+**Kinds of layer** (`cfg.layer_kinds`): a "full" layer holds every key
+of a sequence, a "window" layer only ever reads the last `cfg.window`.
+The loop does not know which model it serves. It keeps pages BY KIND:
+each kind has its own pool size, page table and free list, the tables
+and page ids it hands the programs are dicts by kind, and
+`serving/paged_kinds.py` is the device side for every model
+(`models.model_of(cfg)` finds the model's one forward; a model whose
+layers are all full has one kind). The full kind rides the lists of this
+class, a window kind `_WindowPages` (`self._win`, None where no layer has
+a window). Admission checks every kind; a prompt longer than the window
+claims, for the window kind, only the pages that still hold a key its
+first decoded token can see; and every pass returns to the window kind's
+free list the pages whose last key has left the window
 (`decode.release_window`), the table taking the trash page in their
-place. A stall for pages of either kind is the same stall.
+place. A stall for pages of any kind is the same stall.
 `snapshot()["pages_by_kind"]` has each kind's pages; `pages_total` and
-`peak_pages_in_use` are then sums over kinds weighted by the kind's
-layers (one page of a kind spans all layers of that kind). The step and
-the prefill hand back, with the tokens, how many (token, expert) pairs
-fell on each expert this chip holds (`snapshot()["moe"]`). What two
-kinds of page cannot do yet is an error at construction that names it:
-prefix sharing, speculation, a horizon above 1 (and with prefix sharing
-off there is no trie for `/kv/export` to read).
+`peak_pages_in_use` are the one kind's pages or, where there are several
+kinds, sums weighted by the kind's layers (one page of a kind spans all
+layers of that kind). A step and a prefill hand back `(tokens, aux)`:
+`aux` is what the model's layers count, the (token, expert) pairs by
+held expert where the configuration has an expert layer
+(`snapshot()["moe"]`), nothing otherwise, and it comes back in the read
+the tokens make anyway. What the host's side cannot do yet for what a
+model has is an error at construction that names it (`_check_refusals`):
+prefix sharing, speculation, a horizon above 1 and the prefill role, for
+a window kind (it gives pages back) or an expert layer's counts.
 
 **A bound on the tokens a pass prefills** (`prefill_tokens_per_pass`,
 None = no bound): a pass stops claiming queued requests once the rows it
@@ -198,7 +204,6 @@ import numpy as np
 from deeplearning4j_tpu import telemetry
 from deeplearning4j_tpu.attention.paged_pallas import (
     block_pages, resolve_decode_kernel)
-from deeplearning4j_tpu.models.transformer import TransformerConfig
 from deeplearning4j_tpu.serving.errors import (TIER_BATCH,
                                                TIER_INTERACTIVE, TIERS,
                                                Deadline,
@@ -206,16 +211,8 @@ from deeplearning4j_tpu.serving.errors import (TIER_BATCH,
                                                OverloadedError,
                                                backlog_retry_ms)
 from deeplearning4j_tpu.serving import fleetkv, paged_kinds
-from deeplearning4j_tpu.serving.paged_kv import (copy_page,
-                                                 decode_read_bytes,
-                                                 extract_page,
-                                                 init_paged_pool,
+from deeplearning4j_tpu.serving.paged_kv import (copy_page, extract_page,
                                                  install_page,
-                                                 paged_decode_step,
-                                                 paged_kv_bytes,
-                                                 paged_prefill,
-                                                 paged_prefill_ctx,
-                                                 paged_verify_step,
                                                  pages_for_tokens,
                                                  pages_per_slot,
                                                  prompt_buckets)
@@ -231,6 +228,15 @@ __all__ = ["GenerationStream", "DecodeLoop", "ROLES", "ROLE_UNIFIED",
 
 _DONE = object()
 _loop_seq = itertools.count()
+
+
+def _pow2(n: int) -> int:
+    """The least power of two >= n: the ladder that batches of rows and
+    tables of cached pages are padded to, so programs stay few."""
+    p = 1
+    while p < n:
+        p *= 2
+    return p
 
 #: replica roles (docs/FLEET.md "Disaggregated roles"): a `unified`
 #: loop serves prefill AND decode (the default — existing deployments
@@ -551,7 +557,7 @@ class DecodeLoop:
     step, and the scheduler thread. `submit()` is thread-safe and
     returns a `GenerationStream`."""
 
-    def __init__(self, params, cfg: TransformerConfig, *, slots: int = 8,
+    def __init__(self, params, cfg, *, slots: int = 8,
                  page_size: int = 16, n_pages: Optional[int] = None,
                  horizon: int = 1, max_waiting: Optional[int] = None,
                  prefix_cache: bool = True, fleet_kv: str = "on",
@@ -598,11 +604,7 @@ class DecodeLoop:
                 and prefill_tokens_per_pass < 1):
             raise ValueError(f"prefill_tokens_per_pass must be >= 1, "
                              f"got {prefill_tokens_per_pass}")
-        #: a model with kinds of layer (models/moe_transformer.py)
-        self._kinds = getattr(cfg, "layer_kinds", None) is not None
-        if self._kinds:
-            self._check_kinds(cfg, prefix_cache, speculation, horizon,
-                              role)
+        self._check_refusals(cfg, prefix_cache, speculation, horizon, role)
         self.prefill_tokens_per_pass = (
             None if prefill_tokens_per_pass is None
             else int(prefill_tokens_per_pass))
@@ -652,32 +654,31 @@ class DecodeLoop:
         # device state ------------------------------------------------
         #: the window kind's pages (None: every layer keeps all keys)
         self._win: Optional[_WindowPages] = None
-        if self._kinds:
-            pages = {paged_kinds.KIND_FULL: self.n_pages}
-            if paged_kinds.KIND_WINDOW in cfg.layer_kinds:
-                columns = paged_kinds.window_table_pages(cfg,
-                                                         self.page_size)
-                if window_pages is None:
-                    window_pages = self.slots * min(columns, self._pps)
-                if window_pages < 1:
-                    raise ValueError(f"window_pages must be >= 1, got "
-                                     f"{window_pages}")
-                self._win = _WindowPages(window_pages, self.slots,
-                                         self._pps, self.page_size,
-                                         cfg.window)
-                pages[paged_kinds.KIND_WINDOW] = self._win.n_pages
-            self._kind_pages = pages
-            self._kind_layers = paged_kinds.layers_of(cfg)
-            self._pool = paged_kinds.init_pool(cfg, pages, self.page_size)
-            self._trash = self.n_pages
-        else:
-            self._pool = init_paged_pool(cfg, self.n_pages,
-                                         self.page_size)
-            self._trash = self._pool.trash_page
+        pages = {paged_kinds.KIND_FULL: self.n_pages}
+        if paged_kinds.KIND_WINDOW in cfg.layer_kinds:
+            columns = paged_kinds.window_table_pages(cfg, self.page_size)
+            if window_pages is None:
+                window_pages = self.slots * min(columns, self._pps)
+            if window_pages < 1:
+                raise ValueError(f"window_pages must be >= 1, got "
+                                 f"{window_pages}")
+            self._win = _WindowPages(window_pages, self.slots, self._pps,
+                                     self.page_size, cfg.window)
+            pages[paged_kinds.KIND_WINDOW] = self._win.n_pages
+        self._kind_pages = pages
+        self._kind_layers = paged_kinds.layers_of(cfg)
+        #: what a page of a kind counts for in `pages_total` and its
+        #: like: the kind's layers where kinds have to be summed (a page
+        #: of a kind spans every layer of that kind), 1 where there is
+        #: one kind and the counts are plainly its pages
+        self._kind_weight = (self._kind_layers if len(pages) > 1
+                             else dict.fromkeys(pages, 1))
+        self._pool = paged_kinds.init_pool(cfg, pages, self.page_size)
+        self._trash = self.n_pages
         #: pairs by (layer, held expert) and what they are of, for a
         #: model with an expert layer (snapshot()["moe"])
         self._moe = None
-        if self._kinds:
+        if cfg.n_held:
             self._moe = {
                 "pairs": np.zeros((cfg.n_layers, cfg.n_held), np.int64),
                 "tokens": 0, "decode_tokens": 0, "decode_pairs": 0,
@@ -766,50 +767,35 @@ class DecodeLoop:
             """K chained decode steps in one dispatch. Per-slot
             activity is `lengths < stop` — a slot out of budget or out
             of allocated pages stops advancing mid-chunk exactly where
-            it should, so horizon never corrupts state."""
+            it should, so horizon never corrupts state. `table` is a
+            dict by kind; what the model's layers count (`aux`: pairs
+            by held expert, or nothing) rides beside the tokens."""
             def inner(carry, _):
                 tokens, lengths, pool = carry
                 act = lengths < stop
-                logits, pool = paged_decode_step(
+                logits, pool, aux = paged_kinds.decode_step(
                     params, tokens, pool, table, lengths, act, cfg,
                     kernel=self.decode_kernel)
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 tokens = jnp.where(act, nxt, tokens)
                 lengths = lengths + act.astype(lengths.dtype)
-                return (tokens, lengths, pool), nxt
+                return (tokens, lengths, pool), (nxt, aux)
 
-            (tokens, lengths, pool), toks = jax.lax.scan(
+            (tokens, lengths, pool), out = jax.lax.scan(
                 inner, (tokens, lengths, pool), None, length=k_steps)
-            return toks, tokens, lengths, pool
+            return out, tokens, lengths, pool
 
         def prefill_fn(params, tokens, true_len, pool, page_ids):
-            logits, pool = paged_prefill(params, tokens, true_len, pool,
-                                         page_ids, cfg)
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), pool
+            logits, pool, aux = paged_kinds.prefill(
+                params, tokens, true_len, pool, page_ids, cfg)
+            return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
+                    aux), pool
 
-        if self._kinds:
-            # the same two programs under the same names, over the
-            # model's own block; `table` and `page_ids` are dicts by
-            # kind, and the pairs by held expert ride beside the tokens
-            def step_fn(params, tokens, pool, table, lengths, stop):  # noqa: F811
-                act = lengths < stop
-                logits, pool, pairs = paged_kinds.decode_step(
-                    params, tokens, pool, table, lengths, act, cfg,
-                    kernel=self.decode_kernel)
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                tokens = jnp.where(act, nxt, tokens)
-                lengths = lengths + act.astype(lengths.dtype)
-                return (nxt[None], pairs), tokens, lengths, pool
-
-            def prefill_fn(params, tokens, true_len, pool, page_ids):  # noqa: F811
-                logits, pool, pairs = paged_kinds.prefill(
-                    params, tokens, true_len, pool, page_ids, cfg)
-                first = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                return (first, pairs), pool
-
+        # the two below run only for a model whose layers count nothing
+        # (`_check_refusals`): their `aux` is empty
         def prefill_ctx_fn(params, tokens, true_len, pool, page_ids,
                            ctx_table, ctx_len):
-            logits, pool = paged_prefill_ctx(
+            logits, pool, _ = paged_kinds.prefill_ctx(
                 params, tokens, true_len, pool, page_ids, ctx_table,
                 ctx_len, cfg)
             return jnp.argmax(logits, axis=-1).astype(jnp.int32), pool
@@ -819,7 +805,7 @@ class DecodeLoop:
             writes K/V at `lengths + j` and the returned argmax row is
             the target model's own next-token choice after each draft
             prefix — the exact-accept rule's ground truth."""
-            logits, pool = paged_verify_step(
+            logits, pool, _ = paged_kinds.verify_step(
                 params, tokens, pool, table, lengths, widths, cfg,
                 kernel=self.decode_kernel)
             return jnp.argmax(logits, axis=-1).astype(jnp.int32), pool
@@ -1032,8 +1018,7 @@ class DecodeLoop:
             lambda: (lambda o: o.spec_acceptance_rate if o else 0.0)(
                 ref()))
 
-        if self._kinds:
-            self._register_kind_metrics(reg, lab, ref)
+        self._register_kind_metrics(reg, lab, ref)
         #: pages a block of the paged kernel's sweep holds, by kind of
         #: layer: a constant of the step's shapes (0: the gather lane)
         self._block_pages = self._paged_block_pages()
@@ -1050,16 +1035,21 @@ class DecodeLoop:
                                             name=f"decode-loop-{self.label}")
             self._thread.start()
 
-    # ------------------------------------------- a model with kinds
+    # ---------------------------------------- kinds of layer, counts
     @staticmethod
-    def _check_kinds(cfg, prefix_cache, speculation, horizon,
-                     role) -> None:
-        """What a cache of two kinds of page cannot do yet is an error
-        here, by name, never a silent wrong answer."""
+    def _check_refusals(cfg, prefix_cache, speculation, horizon,
+                        role) -> None:
+        """What the host's side cannot do yet for what a model has is an
+        error here, by name, never a silent wrong answer: a window kind
+        gives its pages back as the cursor moves, and what an expert
+        layer counts is read back from the plain step and the cold
+        prefill only."""
         if paged_kinds.KIND_FULL not in cfg.layer_kinds:
             raise ValueError(
                 "layer_kinds needs a full layer: a request's token "
                 "budget rides the full kind's page table")
+        if not (paged_kinds.KIND_WINDOW in cfg.layer_kinds or cfg.n_held):
+            return
         if prefix_cache:
             raise ValueError(
                 "prefix sharing is not written for a model with window "
@@ -1091,8 +1081,7 @@ class DecodeLoop:
                     self.cfg, self.page_size))
         out = {}
         for kind, n in columns.items():
-            layer = self._pool.layers[
-                self.cfg.layer_kinds.index(kind) if self._kinds else 0]
+            layer = self._pool.layers[self.cfg.layer_kinds.index(kind)]
             _, heads, page_size, head_dim = layer["k"].shape
             out[kind] = (block_pages(page_size, heads, head_dim,
                                      layer["k"].dtype, n)
@@ -1100,7 +1089,8 @@ class DecodeLoop:
         return out
 
     def _register_kind_metrics(self, reg, lab: dict, ref) -> None:
-        """Pages by kind and the expert layer's pairs (the families with
+        """Pages by kind, and for a model that has them the window
+        kind's releases and the expert layer's pairs (the families with
         only a `loop` label keep their shape for every model)."""
         total = reg.gauge(
             "dl4j_kv_pages_total_by_kind",
@@ -1114,10 +1104,13 @@ class DecodeLoop:
             in_use.labels(kind=kind, **lab).set_function(
                 (lambda k: lambda: (lambda o: o._kind_in_use(k)
                                     if o else 0)(ref()))(kind))
-        self._m_win_released = reg.counter(
-            "dl4j_kv_window_pages_released",
-            "window-layer KV pages returned to their free list because "
-            "their last key left the window").labels(**lab)
+        if self._win is not None:
+            self._m_win_released = reg.counter(
+                "dl4j_kv_window_pages_released",
+                "window-layer KV pages returned to their free list "
+                "because their last key left the window").labels(**lab)
+        if self._moe is None:
+            return
         self._m_moe_tokens = reg.counter(
             "dl4j_moe_tokens",
             "tokens routed by the expert layer (prefill and decode, "
@@ -1142,12 +1135,10 @@ class DecodeLoop:
         return self.pages_in_use
 
     def _weighted_in_use(self) -> int:
-        """Pages in use, every layer's counted: the one-kind cache's
-        `pages_in_use` where there is one kind."""
-        if not self._kinds:
-            return self.pages_in_use
-        return sum(self._kind_layers[k] * self._kind_in_use(k)
-                   for k in self._kind_pages)
+        """Pages in use over the kinds (`_kind_weight`): `pages_in_use`
+        where there is one kind."""
+        return sum(w * self._kind_in_use(k)
+                   for k, w in self._kind_weight.items())
 
     def _note_peak(self) -> None:
         self._peak_pages = max(self._peak_pages, self._weighted_in_use())
@@ -1171,12 +1162,9 @@ class DecodeLoop:
             self._m_moe_touched.inc(touched)
 
     def _device_tables(self):
-        """The page tables as the step takes them: one array, or a dict
-        by kind."""
+        """The page tables as the steps take them: a dict by kind."""
         import jax.numpy as jnp
 
-        if not self._kinds:
-            return jnp.asarray(self._table)
         tables = {paged_kinds.KIND_FULL: jnp.asarray(self._table)}
         if self._win is not None:
             tables[paged_kinds.KIND_WINDOW] = jnp.asarray(self._win.table)
@@ -1452,10 +1440,8 @@ class DecodeLoop:
         return int(self._m_spec_accepted.value) / proposed
 
     def kv_pool_bytes(self) -> int:
-        if self._kinds:
-            return paged_kinds.pool_bytes(self.cfg, self._kind_pages,
-                                          self.page_size)
-        return paged_kv_bytes(self.cfg, self.n_pages, self.page_size)
+        return paged_kinds.pool_bytes(self.cfg, self._kind_pages,
+                                      self.page_size)
 
     def decode_step_programs(self) -> int:
         """Compiled-program count for the decode lane — the
@@ -1525,34 +1511,26 @@ class DecodeLoop:
         params_spec = jax.tree_util.tree_map(sds, self.params)
         pool_spec = jax.tree_util.tree_map(sds, self._pool)
         S, P, ps = self.slots, self._pps, self.page_size
+        def by_kind(*shape):
+            return {k: ints(*shape) for k in self._kind_pages}
+
         n = 0
-        if self._kinds:
-            kinds = list(self._kind_pages)
-            if frag.get("step"):
-                n += self._step.warm(
-                    params_spec, ints(S), pool_spec,
-                    {k: ints(S, P) for k in kinds}, ints(S), ints(S))
-            for bb, tb in frag.get("prefill", ()):
-                n += self._prefill.warm(
-                    params_spec, ints(bb, tb), ints(bb), pool_spec,
-                    {k: ints(bb, tb // ps) for k in kinds})
-            return n
         if frag.get("step"):
             n += self._step.warm(params_spec, ints(S), pool_spec,
-                                 ints(S, P), ints(S), ints(S))
+                                 by_kind(S, P), ints(S), ints(S))
         if frag.get("verify") and self.spec_k:
             n += self._verify.warm(params_spec,
                                    ints(S, self.spec_k + 1), pool_spec,
-                                   ints(S, P), ints(S), ints(S))
+                                   by_kind(S, P), ints(S), ints(S))
         if frag.get("copy"):
             n += self._copy.warm(pool_spec, ints(), ints())
         for bb, tb in frag.get("prefill", ()):
             n += self._prefill.warm(params_spec, ints(bb, tb), ints(bb),
-                                    pool_spec, ints(bb, tb // ps))
+                                    pool_spec, by_kind(bb, tb // ps))
         for bb, cb, tb in frag.get("prefill_ctx", ()):
             n += self._prefill_ctx.warm(
                 params_spec, ints(bb, tb), ints(bb), pool_spec,
-                ints(bb, tb // ps), ints(bb, cb), ints(bb))
+                by_kind(bb, tb // ps), by_kind(bb, cb), ints(bb))
         draft = frag.get("draft")
         if (draft and self._drafter is not None
                 and hasattr(self._drafter, "warm")):
@@ -1628,8 +1606,8 @@ class DecodeLoop:
             "page_size": self.page_size,
             "chunks": len(matched),
             "layers": self.cfg.n_layers,
-            "shape": [self.cfg.n_heads, self.page_size,
-                      self.cfg.d_model // self.cfg.n_heads],
+            "shape": [self.cfg.n_kv_heads, self.page_size,
+                      self.cfg.head_dim],
         }
         return fleetkv.pack_pages(meta, chunks)
 
@@ -1759,8 +1737,6 @@ class DecodeLoop:
         pages into the trie, release every pin. Mirrors
         `_kv_apply_install`'s pin/alloc/adopt/release discipline so
         the three-way page invariant holds at every exit."""
-        import jax.numpy as jnp
-
         ps = self.page_size
         head = [int(t) for t in tokens[:(len(tokens) // ps) * ps]]
         n_full = len(head) // ps
@@ -1772,7 +1748,8 @@ class DecodeLoop:
             matched = self._prefix.match(head)
             covered = len(matched)
             need = n_full - covered
-            page_bytes = paged_kv_bytes(self.cfg, 1, self.page_size)
+            page_bytes = paged_kinds.pool_bytes(
+                self.cfg, dict.fromkeys(self._kind_pages, 1), ps)
             if need <= 0:
                 return {"chunks": n_full, "covered": covered,
                         "cached": 0, "kv_bytes": n_full * page_bytes}
@@ -1793,32 +1770,10 @@ class DecodeLoop:
                     f"({len(self._free)}/{self.n_pages} free)",
                     retry_after_ms=1000)
             cov_tok = covered * ps
-            tl = len(head) - cov_tok
-            tb = next(b for b in self._buckets if b >= tl)
-            padded = np.zeros((1, tb), np.int32)
-            padded[0, :tl] = head[cov_tok:]
-            lens = np.full((1,), tl, np.int32)
-            pids = np.full((1, tb // ps), self._trash, np.int32)
-            pids[0, :len(fresh)] = fresh
-            if covered == 0:
-                self._plan_prefill.add((1, tb))
-                _first, self._pool = self._prefill(
-                    self.params, jnp.asarray(padded), jnp.asarray(lens),
-                    self._pool, jnp.asarray(pids))
-            else:
-                cb = 1
-                while cb < covered:
-                    cb *= 2
-                cb = min(cb, self._pps)
-                ctab = np.full((1, cb), self._trash, np.int32)
-                ctab[0, :covered] = matched
-                clen = np.full((1,), cov_tok, np.int32)
-                self._plan_prefill_ctx.add((1, cb, tb))
-                _first, self._pool = self._prefill_ctx(
-                    self.params, jnp.asarray(padded), jnp.asarray(lens),
-                    self._pool, jnp.asarray(pids), jnp.asarray(ctab),
-                    jnp.asarray(clen))
-            self._prefill_token_count += tl
+            tb = next(b for b in self._buckets if b >= len(head) - cov_tok)
+            self._prefill_rows(
+                [(head, matched + fresh, cov_tok)],
+                min(_pow2(covered), self._pps) if covered else 0, tb)
             with self._cond:
                 adopted = self._prefix.insert(head, matched + fresh)
                 self._ship_stats["prefill_handoffs"] = (
@@ -1958,8 +1913,9 @@ class DecodeLoop:
                         "gather": int(self._m_kv_read["gather"].value),
                     },
                 },
+                # by kind where there are kinds to tell apart
                 "paged_block_pages": (
-                    dict(self._block_pages) if self._kinds
+                    dict(self._block_pages) if len(self._block_pages) > 1
                     else self._block_pages[paged_kinds.KIND_FULL]),
                 "decode_step_programs": self.decode_step_programs(),
                 "prefill_programs": self.prefill_programs(),
@@ -2000,14 +1956,11 @@ class DecodeLoop:
 
     def _snapshot_pages(self) -> dict:
         """`pages_total`, `pages_in_use` and `peak_pages_in_use`: pages
-        of the one kind, or, for a model with kinds of layer, sums over
-        kinds weighted by the kind's layers, with each kind's own pages
-        under `pages_by_kind` and the expert layer's pairs under `moe`.
-        Caller holds the lock."""
-        if not self._kinds:
-            return {"pages_total": self.n_pages,
-                    "pages_in_use": self.pages_in_use,
-                    "peak_pages_in_use": self._peak_pages}
+        of the one kind, or, where the model's layers are of several,
+        sums over kinds weighted by the kind's layers (`_kind_weight`);
+        each kind's own pages under `pages_by_kind`, and the pairs of a
+        model with an expert layer under `moe`. Caller holds the
+        lock."""
         by_kind = {}
         for kind, n in self._kind_pages.items():
             by_kind[kind] = {"layers": self._kind_layers[kind],
@@ -2020,21 +1973,23 @@ class DecodeLoop:
                 pages_per_slot_peak=win.peak_per_slot,
                 table_pages=paged_kinds.window_table_pages(
                     self.cfg, self.page_size))
-        moe = self._moe
-        return {
-            "pages_total": sum(self._kind_layers[k] * n
+        pages = {
+            "pages_total": sum(self._kind_weight[k] * n
                                for k, n in self._kind_pages.items()),
             "pages_in_use": self._weighted_in_use(),
             "peak_pages_in_use": self._peak_pages,
-            "pages_by_kind": by_kind,
-            "moe": {"held_first": self.cfg.held_first,
-                    "n_held": self.cfg.n_held,
-                    "n_experts": self.cfg.n_experts,
-                    "experts_per_token": self.cfg.experts_per_token,
-                    "pairs_by_layer_expert": moe["pairs"].tolist(),
-                    "pairs": int(moe["pairs"].sum()),
-                    **{k: v for k, v in moe.items() if k != "pairs"}},
-        }
+            "pages_by_kind": by_kind}
+        moe = self._moe
+        if moe is not None:
+            pages["moe"] = {
+                "held_first": self.cfg.held_first,
+                "n_held": self.cfg.n_held,
+                "n_experts": self.cfg.n_experts,
+                "experts_per_token": self.cfg.experts_per_token,
+                "pairs_by_layer_expert": moe["pairs"].tolist(),
+                "pairs": int(moe["pairs"].sum()),
+                **{k: v for k, v in moe.items() if k != "pairs"}}
+        return pages
 
     def close(self, timeout: float = 30.0) -> None:
         """Stop accepting new requests, drain everything queued and in
@@ -2239,8 +2194,6 @@ class DecodeLoop:
     def _admit(self) -> int:
         """Claim slots and pages for what fits, then prefill it by
         groups. Returns the number of requests admitted."""
-        import jax.numpy as jnp
-
         ps = self.page_size
         # claim everything that fits in one lock pass
         admitted = []  # (slot_idx, stream, pages, plen, covered)
@@ -2386,88 +2339,76 @@ class DecodeLoop:
                 self._pending[idx] = stream.prompt[-1]
                 self._stop[idx] = 0  # set by _grant_pages
                 self._dirty = True
-        # one compiled prefill per (prompt-bucket, batch-bucket) group:
-        # an admission burst costs O(groups) dispatches, not O(streams).
-        # The prefill is dispatched but NOT synced — first tokens stay
-        # on device until the next flush, so back-to-back groups queue
-        # without a host round trip between them.
-        by_bucket: dict = {}
-        for item in cold:
-            tb = next(b for b in self._buckets if b >= item[3])
-            by_bucket.setdefault(tb, []).append(item)
-        for tb, group in by_bucket.items():
-            bb = 1
-            while bb < len(group):
-                bb *= 2
-            with self._prefill_span(group, bb, tb, ctx=0):
-                n_pids = tb // ps
-                padded = np.zeros((bb, tb), np.int32)
-                lens = np.ones((bb,), np.int32)  # pad rows: true_len 1
-                pids = np.full((bb, n_pids), self._trash, np.int32)
-                for row, (idx, stream, pages, plen, _cov) in enumerate(
-                        group):
-                    padded[row, :plen] = stream.prompt
-                    lens[row] = plen
-                    pids[row, :len(pages)] = pages
-                    self._prefill_token_count += plen
-                self._plan_prefill.add((bb, tb))
-                d_pids = jnp.asarray(pids)
-                if self._kinds:
-                    d_pids = {paged_kinds.KIND_FULL: d_pids}
-                    if win is not None:
-                        # only the pages the first decoded token can
-                        # still see; the rest of the prompt's K/V in the
-                        # window layers goes to that kind's trash page
-                        wids = np.full((bb, n_pids), win.trash, np.int32)
-                        for row, (idx, *_rest) in enumerate(group):
-                            lo, hi = int(win.lo[idx]), int(win.hi[idx])
-                            wids[row, lo:hi] = win.table[idx, lo:hi]
-                        d_pids[paged_kinds.KIND_WINDOW] = jnp.asarray(
-                            wids)
-                first, self._pool = self._prefill(
-                    self.params, jnp.asarray(padded), jnp.asarray(lens),
-                    self._pool, d_pids)
-            self._install_prefilled(group, first)
-        # warm tails ride the ctx-aware prefill, bucketed by (cached
-        # pages, tail length) — tails start on a page boundary by
-        # construction (only FULL chunks match)
-        by_ctx: dict = {}
-        for item in warm:
-            idx, stream, pages, plen, covered = item
-            cb = 1
-            while cb < covered // ps:
-                cb *= 2
-            cb = min(cb, self._pps)
+        # one compiled prefill per (cached pages, prompt-bucket,
+        # batch-bucket) group: an admission burst costs O(groups)
+        # dispatches, not O(streams). Cold prompts first, then the warm
+        # tails, which start on a page boundary by construction (only
+        # FULL chunks match) and ride the ctx-aware prefill.
+        groups: dict = {}
+        for item in cold + warm:
+            _idx, _stream, _pages, plen, covered = item
+            cb = min(_pow2(covered // ps), self._pps) if covered else 0
             tb = next(b for b in self._buckets if b >= plen - covered)
-            by_ctx.setdefault((cb, tb), []).append(item)
-        for (cb, tb), group in by_ctx.items():
-            bb = 1
-            while bb < len(group):
-                bb *= 2
-            with self._prefill_span(group, bb, tb, ctx=cb):
-                n_pids = tb // ps
-                padded = np.zeros((bb, tb), np.int32)
-                lens = np.ones((bb,), np.int32)
-                pids = np.full((bb, n_pids), self._trash, np.int32)
-                ctab = np.full((bb, cb), self._trash, np.int32)
-                clen = np.zeros((bb,), np.int32)
-                for row, (idx, stream, pages, plen, cov) in enumerate(
-                        group):
-                    cp = cov // ps
-                    tl = plen - cov
-                    padded[row, :tl] = stream.prompt[cov:]
-                    lens[row] = tl
-                    pids[row, :len(pages) - cp] = pages[cp:]
-                    ctab[row, :cp] = pages[:cp]
-                    clen[row] = cov
-                    self._prefill_token_count += tl
-                self._plan_prefill_ctx.add((bb, cb, tb))
-                first, self._pool = self._prefill_ctx(
-                    self.params, jnp.asarray(padded), jnp.asarray(lens),
-                    self._pool, jnp.asarray(pids), jnp.asarray(ctab),
-                    jnp.asarray(clen))
+            groups.setdefault((cb, tb), []).append(item)
+        for (cb, tb), group in groups.items():
+            with self._prefill_span(group, _pow2(len(group)), tb, ctx=cb):
+                first = self._prefill_rows(
+                    [(stream.prompt, pages, cov)
+                     for _, stream, pages, _, cov in group], cb, tb,
+                    slots=[item[0] for item in group])
             self._install_prefilled(group, first)
         return len(admitted)
+
+    def _prefill_rows(self, rows, cb: int, tb: int, slots=None):
+        """Pack one prefill group and enqueue its program: `rows` of
+        (a prompt's tokens, its pages in logical order, how many of the
+        tokens cached pages cover), padded to a power of two of rows of
+        `tb` tokens. `cb` is the width of the table of cached pages a
+        warm group reads (`prefill_ctx`); 0 is a cold group (`prefill`),
+        whose `slots` say where a window kind claimed pages for the
+        rows. The program is dispatched but NOT synced — back-to-back
+        groups queue without a host round trip between them. Returns
+        its (first tokens, aux), still on the device."""
+        import jax.numpy as jnp
+
+        ps, win = self.page_size, self._win
+        bb = _pow2(len(rows))
+        padded = np.zeros((bb, tb), np.int32)
+        lens = np.ones((bb,), np.int32)  # pad rows: true_len 1
+        pids = np.full((bb, tb // ps), self._trash, np.int32)
+        ctab = np.full((bb, cb), self._trash, np.int32)
+        clen = np.zeros((bb,), np.int32)
+        for row, (tokens, pages, cov) in enumerate(rows):
+            cp, tl = cov // ps, len(tokens) - cov
+            padded[row, :tl] = tokens[cov:]
+            lens[row] = tl
+            pids[row, :len(pages) - cp] = pages[cp:]
+            ctab[row, :cp] = pages[:cp]
+            clen[row] = cov
+            self._prefill_token_count += tl
+        d_pids = {paged_kinds.KIND_FULL: jnp.asarray(pids)}
+        if cb:
+            self._plan_prefill_ctx.add((bb, cb, tb))
+            first, self._pool = self._prefill_ctx(
+                self.params, jnp.asarray(padded), jnp.asarray(lens),
+                self._pool, d_pids,
+                {paged_kinds.KIND_FULL: jnp.asarray(ctab)},
+                jnp.asarray(clen))
+            return first, ()
+        if win is not None and slots is not None:
+            # only the pages the first decoded token can still see; the
+            # rest of the prompt's K/V in the window layers goes to that
+            # kind's trash page
+            wids = np.full(pids.shape, win.trash, np.int32)
+            for row, idx in enumerate(slots):
+                lo, hi = int(win.lo[idx]), int(win.hi[idx])
+                wids[row, lo:hi] = win.table[idx, lo:hi]
+            d_pids[paged_kinds.KIND_WINDOW] = jnp.asarray(wids)
+        self._plan_prefill.add((bb, tb))
+        first, self._pool = self._prefill(
+            self.params, jnp.asarray(padded), jnp.asarray(lens),
+            self._pool, d_pids)
+        return first
 
     def _prefill_span(self, group, bb: int, tb: int, ctx: int) -> span:
         """The span of one prefill group, host packing and enqueue: the
@@ -2480,15 +2421,10 @@ class DecodeLoop:
                     requests=[a[1].request_id for a in group])
 
     def _install_prefilled(self, group, first) -> None:
-        """Install slots for one prefill group; first tokens stay on
-        device until the next flush (`self._deferred`)."""
+        """Install slots for one prefill group. `first` is the program's
+        (first tokens, aux): both stay on the device until the next
+        flush (`self._deferred`) and come back in one read."""
         members = []
-        pairs = None
-        if self._kinds:
-            # the pairs by held expert stay on the device with the
-            # first tokens, and come back in the same read
-            first, pairs = first
-            pairs = (pairs, sum(item[3] for item in group))
         for row, (idx, stream, pages, plen, _cov) in enumerate(group):
             slot = _Slot(stream, pages,
                          stop_len=plen + stream.max_tokens - 1)
@@ -2500,7 +2436,8 @@ class DecodeLoop:
                 self._pending[idx] = 0  # real value still on device
                 self._stop[idx] = 0  # set by _grant_pages
                 self._dirty = True
-        self._deferred.append((first, members, pairs))
+        self._deferred.append(
+            (first, members, sum(plen - cov for *_, plen, cov in group)))
 
     # ---- page granting
     def _grant_pages(self) -> None:
@@ -2626,7 +2563,7 @@ class DecodeLoop:
                 # overlay deferred prefill tokens (still
                 # device-resident) into the feedback array — ONE scatter
                 # per prefill group, no sync
-                for arr, members, _pairs in self._deferred:
+                for (arr, _aux), members, _n in self._deferred:
                     rows = jnp.asarray([r for r, _ in members])
                     idxs = jnp.asarray([i for _, i in members])
                     self._d_tokens = self._d_tokens.at[idxs].set(
@@ -2634,18 +2571,18 @@ class DecodeLoop:
         t0 = time.perf_counter()
         self._plan_step = True
         with span("decode.step_dispatch", phases, runnable=len(runnable)):
-            toks, t_out, l_out, self._pool = self._step(
+            (toks, aux), t_out, l_out, self._pool = self._step(
                 self.params, self._d_tokens, self._pool, self._d_table,
                 self._d_lengths, self._d_stop)
         self._m_steps.inc()
-        # the (K, S) token D2H is the sync the streams need anyway
+        # the (K, S) token D2H is the sync the streams need anyway; what
+        # the layers count travels beside it, and nothing more is read
+        # where they count nothing. The names are REBOUND, and no other
+        # name may keep a device array of the step: held to the end of
+        # the pass, its release cost 0.6 ms (sat) to 1.3 ms (ep8) a pass
+        # outside every span (PERF.md section 6, PR 32).
         with span("decode.d2h", phases):
-            if self._kinds:
-                # the pairs by held expert, in the read-back the tokens
-                # make anyway
-                toks, pairs = jax.device_get(toks)
-            else:
-                toks = np.asarray(toks)
+            toks, aux = jax.device_get((toks, aux))
         self._m_step_s.observe(time.perf_counter() - t0)
         self._d_tokens, self._d_lengths = t_out, l_out
         # per-token-step KV read accounting, host math mirroring the
@@ -2654,19 +2591,11 @@ class DecodeLoop:
         # Both figures are recorded each dispatch — the selected lane
         # is in snapshot()["decode_kernel"]
         with span("decode.account", phases):
-            if self._kinds:
-                self._count_pairs(pairs, len(runnable), decode=True)
-                ideal, dense = self._kinds_read_bytes(before)
-            else:
-                advance = np.maximum(self._stop - before, 0)
-                ideal = dense = 0
-                for k in range(self.horizon):
-                    cur = before + np.minimum(k, advance)
-                    ideal += decode_read_bytes(self._pool, cur, self._pps)
-                    dense += decode_read_bytes(self._pool, cur,
-                                               self._pps, dense=True)
-            self._m_kv_read["kernel"].inc(ideal)
-            self._m_kv_read["gather"].inc(dense)
+            advance = np.maximum(self._stop - before, 0)
+            for k in range(self.horizon):
+                if self._moe is not None:
+                    self._count_pairs(aux[k], len(runnable), decode=True)
+                self._count_read_bytes(before + np.minimum(k, advance))
         self._flush_first_tokens()  # emit firsts BEFORE chunk tokens
         with span("decode.emit", phases) as emit:
             emitted = 0
@@ -2705,21 +2634,31 @@ class DecodeLoop:
                 self._m_win_released.inc(n)
                 self._dirty = True
 
-    def _kinds_read_bytes(self, cursors) -> tuple:
-        """(streamed, dense) K/V bytes one decode step reads, as
-        `decode_read_bytes` counts them, for a cache of two kinds: whole
-        pages from the first visible to the cursor's, by layer."""
+    def _read_bytes(self, cursors) -> tuple:
+        """The K/V bytes ONE token step at `cursors` (a slot each, idle
+        ones included: they read the trash page) must read for
+        attention, both ways: (streamed, dense). Streamed is whole pages
+        from the first a layer's kind lets the cursor see to the
+        cursor's own, `min(pos // page_size + 1, table width)`: exactly
+        what `paged_attention`'s sweep fetches. Dense is every slot's
+        whole table in every layer, however little was written. Their
+        ratio is the kernel's traffic win, counted every dispatch as
+        dl4j_decode_kv_read_bytes{path="kernel"|"gather"}."""
         ps = self.page_size
         page = paged_kinds.page_bytes(self.cfg, ps)
-        ideal = 0
+        streamed = 0
         for kind, layers in self._kind_layers.items():
             for pos in cursors:
                 last = min(int(pos) // ps + 1, self._pps)
                 first = (self._win.first_page(int(pos))
                          if kind == paged_kinds.KIND_WINDOW else 0)
-                ideal += layers * page * (last - first)
-        dense = self.cfg.n_layers * page * len(cursors) * self._pps
-        return ideal, dense
+                streamed += layers * page * (last - first)
+        return streamed, self.cfg.n_layers * page * len(cursors) * self._pps
+
+    def _count_read_bytes(self, cursors) -> None:
+        streamed, dense = self._read_bytes(cursors)
+        self._m_kv_read["kernel"].inc(streamed)
+        self._m_kv_read["gather"].inc(dense)
 
     # ---- speculative dispatch (draft k on the host, verify k+1 wide)
     def _dispatch_spec(self) -> bool:
@@ -2808,7 +2747,7 @@ class DecodeLoop:
         t0 = time.perf_counter()
         self._plan_verify = True
         with span("decode.upload", phases):
-            d_tokens, d_table = jnp.asarray(tokens), jnp.asarray(self._table)
+            d_tokens, d_table = jnp.asarray(tokens), self._device_tables()
             d_before, d_widths = jnp.asarray(before), jnp.asarray(widths)
         with span("decode.step_dispatch", phases, runnable=len(runnable)):
             out, self._pool = self._verify(
@@ -2823,12 +2762,8 @@ class DecodeLoop:
         # i attends at cursor before+j (clamped to its real width)
         with span("decode.account", phases):
             for j in range(int(widths.max())):
-                cur = before + np.minimum(j, np.maximum(widths - 1, 0))
-                self._m_kv_read["kernel"].inc(
-                    decode_read_bytes(self._pool, cur, self._pps))
-                self._m_kv_read["gather"].inc(
-                    decode_read_bytes(self._pool, cur, self._pps,
-                                      dense=True))
+                self._count_read_bytes(
+                    before + np.minimum(j, np.maximum(widths - 1, 0)))
         with span("decode.emit", phases) as emit:
             emitted = 0
             for i in runnable:
@@ -2865,12 +2800,10 @@ class DecodeLoop:
         deferred, self._deferred = self._deferred, []
         with span("decode.flush_first", self._phases,
                   groups=len(deferred)):
-            for arr, members, pairs in deferred:
-                if pairs is None:
-                    host = np.asarray(arr)
-                else:
-                    host, counted = jax.device_get((arr, pairs[0]))
-                    self._count_pairs(counted, pairs[1], decode=False)
+            for (first, aux), members, n_tokens in deferred:
+                host, aux = np.asarray(first), jax.device_get(aux)
+                if self._moe is not None:
+                    self._count_pairs(aux, n_tokens, decode=False)
                 for row, i in members:
                     slot = self._slot_state[i]
                     if slot is None or not slot.awaiting_first:

@@ -282,12 +282,12 @@ class InferenceEngine:
         """Wrap a model of `models/moe_transformer.py` (grouped K/V
         heads, window and full layers, an expert layer of which this
         chip holds a part): apply = full logits (B, T, vocab) with
-        nothing cached; `decode_slots > 0` starts the `DecodeLoop` over
-        a cache of two kinds of page (`kv_pages` for the full kind,
-        `window_pages` for the window kind) and `generate_stream()`.
-        Prefix sharing, speculation and a horizon above 1 are not
-        written for this cache and stay off; there is no per-request
-        `generate()` (no contiguous cache for this block)."""
+        nothing cached; `decode_slots > 0` starts the `DecodeLoop`, which
+        keeps pages by kind of layer (`kv_pages` for the full kind,
+        `window_pages` for the window kind), and `generate_stream()`.
+        Prefix sharing, speculation and a horizon above 1 are refused
+        by name for what this model has and stay off; this engine has
+        no per-request `generate()`."""
         from deeplearning4j_tpu.compilecache import config_digest
         from deeplearning4j_tpu.models import moe_transformer
 
@@ -405,8 +405,7 @@ class InferenceEngine:
         (lower) admission-queue bound (docs/SERVING.md "Priority
         tiers"). `prefill_tokens_per_pass` bounds what one scheduler
         pass prefills; `window_pages` sizes the window kind's pool of a
-        model with kinds of layer (docs/SERVING.md "Two kinds of
-        layer")."""
+        model with window layers (docs/SERVING.md "Kinds of layer")."""
         from deeplearning4j_tpu.serving.decode_loop import DecodeLoop
 
         if self._tf_cfg is None:

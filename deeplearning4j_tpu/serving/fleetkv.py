@@ -29,7 +29,7 @@ makes the fleet behave like ONE cache, in two independent halves:
    checkpoint format's dtype-name/byte-view idiom (crc-framed raw
    array bytes, no pickle) — and installs them into its own pool +
    trie through the existing refcount machinery, so the subsequent
-   admission sees a warm `paged_prefill_ctx` hit. Shipping is an
+   admission sees a warm `paged_kinds.prefill_ctx` hit. Shipping is an
    optimization, never a correctness dependency: ANY failure (donor
    dead, timeout, crc mismatch, model identity mismatch, pool full)
    falls back to plain prefill of the same tokens.

@@ -1,28 +1,32 @@
-"""Preallocated KV cache: O(1)-per-token transformer decode.
+"""Preallocated KV cache: O(1)-per-token decode.
 
 The demo `transformer.generate` recomputes the full prefix every token —
 O(T) attention AND O(T) ffn/embedding work per emitted token. Serving
 needs the standard two-phase shape (the "portable O(1) autoregressive
-caching" design in PAPERS.md):
+caching" design in PAPERS.md), here as the model's one forward
+(`models.model_of(cfg)`) under two `attend` callbacks:
 
-- **prefill**: one pass over the prompt (flash attention, same math as
-  `transformer_logits`) that also writes every block's K/V into a
-  preallocated `(B, H, max_len, hd)` buffer;
-- **decode**: one token per step — project q/k/v for the single new
-  position, write k/v at the cursor, attend over the cache with a
-  `position <= cursor` mask. Per-token work no longer grows with the
-  number of generated tokens' recompute (the masked-score sweep over the
-  fixed buffer is one fused (B,H,1,L) einsum).
+- **prefill**: one pass over the prompt (flash attention, the uncached
+  forward's own read) whose callback also writes every block's K/V into
+  a preallocated `(B, H, max_len, hd)` buffer;
+- **decode**: one token per step — the callback writes the new
+  position's k/v at the cursor (`dynamic_update_slice`) and attends over
+  the buffer with a `position <= cursor` mask (`masked_attention`, the
+  dense read every cache shares). Per-token work no longer grows with
+  the number of generated tokens' recompute.
 
 Shapes are fixed by `cfg.max_len`, so the whole generate loop (prefill +
 `lax.scan` of decode steps) is ONE compiled program per
 (batch, prompt_len, n_tokens) signature — the cursor is a traced scalar,
 never a shape. Parity: `generate(cache=True)` matches the naive path to
-1e-5 (tests/test_serving.py) because both run the same block math; the
-only difference is exact masked softmax here vs online softmax there.
+1e-5 (tests/test_serving.py) because both run the same block; the only
+difference is exact masked softmax here vs online softmax there.
 
-Memory envelope: 2 (K and V) * n_layers * B * max_len * d_model elements
-per cache — `kv_cache_bytes` computes it; docs/SERVING.md budgets it.
+Memory envelope: 2 (K and V) * n_layers * B * max_len * n_kv_heads *
+head_dim elements per cache — `kv_cache_bytes` computes it;
+docs/SERVING.md budgets it. The paged cache (`paged_kv.py` the pool,
+`paged_kinds.py` its device side) holds pages for the tokens actually
+written instead.
 """
 
 from __future__ import annotations
@@ -33,10 +37,10 @@ from typing import Any, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
-from deeplearning4j_tpu.attention.blockwise import NEG_INF
-from deeplearning4j_tpu.attention.flash_pallas import flash_attention
-from deeplearning4j_tpu.models.transformer import (TransformerConfig,
-                                                   _layer_norm)
+from deeplearning4j_tpu.attention.blockwise import masked_attention
+from deeplearning4j_tpu.models import model_of
+from deeplearning4j_tpu.models.transformer import (causal_attention,
+                                                   visible)
 
 __all__ = ["KVCache", "init_cache", "kv_cache_bytes", "prefill",
            "decode_step", "generate_cached"]
@@ -46,7 +50,7 @@ class KVCache(NamedTuple):
     """Per-block K/V buffers plus the write cursor.
 
     `layers`: tuple (one per transformer block) of {"k", "v"} arrays of
-    shape (B, n_heads, max_len, head_dim); positions >= `cursor` are
+    shape (B, n_kv_heads, max_len, head_dim); positions >= `cursor` are
     unwritten zeros, masked out of every attention sweep.
     """
 
@@ -70,109 +74,77 @@ def _check_cache_args(batch_size: int, length, max_len: int) -> int:
     return length
 
 
-def init_cache(cfg: TransformerConfig, batch_size: int,
-               length: int = None) -> KVCache:
+def init_cache(cfg, batch_size: int, length: int = None) -> KVCache:
     """Empty cache for `batch_size` streams. `length` defaults to
     cfg.max_len — always allocating the full window keeps decode-step
     shapes identical across requests (one program, any prompt)."""
     length = _check_cache_args(batch_size, length, cfg.max_len)
-    hd = cfg.d_model // cfg.n_heads
-    shape = (batch_size, cfg.n_heads, length, hd)
+    shape = (batch_size, cfg.n_kv_heads, length, cfg.head_dim)
     layers = tuple({"k": jnp.zeros(shape, cfg.dtype),
                     "v": jnp.zeros(shape, cfg.dtype)}
                    for _ in range(cfg.n_layers))
     return KVCache(layers, jnp.int32(0))
 
 
-def kv_cache_bytes(cfg: TransformerConfig, batch_size: int,
-                   length: int = None) -> int:
+def kv_cache_bytes(cfg, batch_size: int, length: int = None) -> int:
     """HBM the cache pins per batch — the serving memory envelope for
     the contiguous path (the paged pool's twin is
     `paged_kv.paged_kv_bytes`, which budgets pages, not requests)."""
     length = _check_cache_args(batch_size, length, cfg.max_len)
     itemsize = jnp.dtype(cfg.dtype).itemsize
-    return 2 * cfg.n_layers * batch_size * length * cfg.d_model * itemsize
+    return (2 * cfg.n_layers * batch_size * length
+            * cfg.n_kv_heads * cfg.head_dim * itemsize)
 
 
-def _heads(h, w, cfg: TransformerConfig):
-    b, t, d = h.shape
-    hd = d // cfg.n_heads
-    return (h @ w).reshape(b, t, cfg.n_heads, hd).transpose(0, 2, 1, 3)
+def _written(held, k, v, at):
+    """A layer's buffers with rows k, v (B, H, T, hd) written from
+    position `at` on."""
+    return {name: jax.lax.dynamic_update_slice(
+        held[name], rows.astype(held[name].dtype), (0, 0, at, 0))
+        for name, rows in (("k", k), ("v", v))}
 
 
-def _ffn(p, x):
-    h = _layer_norm(p["ln2"], x)
-    return x + jax.nn.gelu(h @ p["W1"] + p["b1"]) @ p["W2"] + p["b2"]
-
-
-def prefill(params, tokens, cache: KVCache, cfg: TransformerConfig):
+def prefill(params, tokens, cache: KVCache, cfg):
     """Run the prompt (B, T0) through every block, writing K/V into the
     cache at positions [0, T0). Returns (last-position logits (B, vocab),
     cache with cursor=T0). Starts a fresh stream: any prior cache content
     is overwritten from position 0."""
-    b, t0 = tokens.shape
-    x = params["embed"][tokens] + params["pos"][:t0]
-    new_layers = []
-    for p, layer in zip(params["blocks"], cache.layers):
-        h = _layer_norm(p["ln1"], x)
-        q = _heads(h, p["Wq"], cfg)
-        k = _heads(h, p["Wk"], cfg)
-        v = _heads(h, p["Wv"], cfg)
-        att = flash_attention(q, k, v, True, interpret=cfg.interpret)
-        att = att.transpose(0, 2, 1, 3).reshape(b, t0, cfg.d_model)
-        x = x + att @ p["Wo"]
-        x = _ffn(p, x)
-        new_layers.append({
-            "k": jax.lax.dynamic_update_slice(
-                layer["k"], k.astype(layer["k"].dtype), (0, 0, 0, 0)),
-            "v": jax.lax.dynamic_update_slice(
-                layer["v"], v.astype(layer["v"].dtype), (0, 0, 0, 0)),
-        })
-    x = _layer_norm(params["ln_f"], x)
-    logits = x[:, -1, :] @ params["embed"].T
-    return logits, KVCache(tuple(new_layers), jnp.int32(t0))
+    model = model_of(cfg)
+    t0 = tokens.shape[1]
+
+    def attend(layer, kind, q, k, v):
+        return (causal_attention(cfg, kind, q, k, v),
+                _written(cache.layers[layer], k, v, 0))
+
+    x, layers, _ = model.forward(params, tokens, jnp.arange(t0), cfg,
+                                 attend)
+    return model.head(params, x[:, -1, :], cfg), KVCache(layers,
+                                                         jnp.int32(t0))
 
 
-def decode_step(params, token, cache: KVCache, cfg: TransformerConfig):
+def decode_step(params, token, cache: KVCache, cfg):
     """One decode step: embed `token` (B,) at position `cache.cursor`,
     attend over the cache, return (logits (B, vocab), advanced cache).
     Fixed shapes throughout — the cursor is traced, so every step of
     every request shares one compiled program."""
-    b = token.shape[0]
-    d = cfg.d_model
-    hd = d // cfg.n_heads
+    model = model_of(cfg)
     cur = cache.cursor
-    pos = jax.lax.dynamic_slice_in_dim(params["pos"], cur, 1, axis=0)
-    x = params["embed"][token][:, None, :] + pos  # (B, 1, d)
-    length = cache.layers[0]["k"].shape[2]
-    mask = jnp.arange(length) <= cur  # (L,): positions filled after write
-    scale = 1.0 / jnp.sqrt(jnp.float32(hd))
-    new_layers = []
-    for p, layer in zip(params["blocks"], cache.layers):
-        h = _layer_norm(p["ln1"], x)
-        q = _heads(h, p["Wq"], cfg)                        # (B, H, 1, hd)
-        k_new = _heads(h, p["Wk"], cfg).astype(layer["k"].dtype)
-        v_new = _heads(h, p["Wv"], cfg).astype(layer["v"].dtype)
-        ks = jax.lax.dynamic_update_slice(layer["k"], k_new, (0, 0, cur, 0))
-        vs = jax.lax.dynamic_update_slice(layer["v"], v_new, (0, 0, cur, 0))
-        # exact masked softmax in f32 over the fixed-length buffer
-        s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                       ks.astype(jnp.float32)) * scale
-        s = jnp.where(mask[None, None, None, :], s, NEG_INF)
-        w = jax.nn.softmax(s, axis=-1)
-        att = jnp.einsum("bhqk,bhkd->bhqd", w, vs.astype(jnp.float32))
-        att = att.astype(x.dtype).transpose(0, 2, 1, 3).reshape(b, 1, d)
-        x = x + att @ p["Wo"]
-        x = _ffn(p, x)
-        new_layers.append({"k": ks, "v": vs})
-    x = _layer_norm(params["ln_f"], x)
-    logits = x[:, 0, :] @ params["embed"].T
-    return logits, KVCache(tuple(new_layers), cur + 1)
+    k_pos = jnp.arange(cache.layers[0]["k"].shape[2])
+
+    def attend(layer, kind, q, k, v):
+        # the write comes first: the cursor's own key is among the seen
+        new = _written(cache.layers[layer], k, v, cur)
+        att = masked_attention(q, new["k"], new["v"],
+                               visible(cfg, kind, cur[None], k_pos))
+        return att, new
+
+    x, layers, _ = model.forward(params, token[:, None], cur[None], cfg,
+                                 attend)
+    return model.head(params, x[:, 0, :], cfg), KVCache(layers, cur + 1)
 
 
 @partial(jax.jit, static_argnums=(2, 3))
-def generate_cached(params, prompt, cfg: TransformerConfig,
-                    n_tokens: int):
+def generate_cached(params, prompt, cfg, n_tokens: int):
     """Greedy decode with the KV cache: prompt (B, T0) ->
     (B, T0 + n_tokens), same contract (and same tokens, to decode-order
     tie-breaks) as the naive `transformer.generate`. One compiled

@@ -1,68 +1,85 @@
-"""Paged K/V for a model whose layers are of two kinds: the device side.
+"""The device side of the paged cache: every model's forward under the
+cache's `attend` callbacks.
 
-`models/moe_transformer.py` has "full" layers, which keep every key of a
-sequence, and "window" layers, which only ever read the last `window`
-keys. One pool of pages for both would make the window layers hold what
-they never read again. Here each KIND of layer has its own pool size
-and its own page table; all layers of one kind share page ids (page p
-of a kind is row p of every pool of that kind), as all layers of the
-one-kind cache (`paged_kv.py`) do:
+A model's layers are of one or two KINDS (`cfg.layer_kinds`): "full"
+layers keep every key of a sequence, "window" layers only ever read the
+last `cfg.window`. One pool of pages for both would make the window
+layers hold what they never read again, so each kind has its own pool
+size and its own page table; all layers of one kind share page ids
+(page p of a kind is row p of every pool of that kind). A model whose
+layers are all full (`models/transformer.py`) is the case of one kind:
 
-- `init_pool(cfg, pages, page_size)`: one `{"k", "v"}` of shape
-  `(pages[kind] + 1, n_kv_heads, page_size, head_dim)` a layer; the
-  last page of each is that kind's trash page.
-- tables are a dict by kind of `(S, pages_per_slot)` int32, logical
-  page -> page of that kind. A window layer's table holds the trash
-  page for logical pages whose last key has left the window
-  (`DecodeLoop` returns those pages to the kind's free list in the pass
-  in which they fall out), and the step is told nothing more: the first
-  visible position follows from the cursor, `max(0, pos - window + 1)`.
-- `prefill` and `decode_step` are the model's one block under two
-  `attend` callbacks: whole-page scatter then flash attention (grouped
-  heads, window), and `_write_rows` then the paged kernel (grouped
-  heads, a first position) or the dense gather. A prompt longer than
-  the window writes, in the window layers, only the pages that still
-  hold a key the first decoded token can see: the others' ids are the
-  trash page's.
+- `paged_kv.init_pool(cfg, pages, page_size)`: one `{"k", "v"}` of
+  shape `(pages[kind] + 1, n_kv_heads, page_size, head_dim)` a layer;
+  the last page of each is that kind's trash page, where masked writes
+  go.
+- tables and page ids are a dict by kind: a table is `(S,
+  pages_per_slot)` int32, logical page -> page of that kind. A window
+  layer's table holds the trash page for logical pages whose last key
+  has left the window (`DecodeLoop` returns those pages to the kind's
+  free list in the pass in which they fall out), and the step is told
+  nothing more: the first visible position follows from the cursor,
+  `max(0, pos - window + 1)`.
+- `prefill`, `prefill_ctx`, `decode_step` and `verify_step` are the
+  model's one forward (`models.model_of(cfg)`) under four `attend`
+  callbacks, which differ in what attention reads and where K/V is
+  written and in nothing else: whole-page scatter then the flash kernel;
+  whole-page scatter then a dense read of [gathered prefix pages ‖
+  tail]; `_write_rows` at the cursor then the paged kernel or the dense
+  gather; `_write_rows` at W columns then the same two reads a column.
+  Each returns, after the logits and the pool, what the model's layers
+  count (`aux`: the pairs by held expert, or `()`).
 
-What this cache cannot do is an error by name where it is asked for
-(`DecodeLoop`): prefix sharing, speculation, a horizon above 1 and page
-export all assume one kind of page.
+Shapes are fixed for the life of a server: a step is ONE program over S
+slots (tables, lengths and the active mask are traced arrays: requests
+join and leave without recompiling), a prefill one program a bucket of
+prompt lengths. The pool never changes layout inside a program: it
+arrives donated, the step writes its rows into it in place
+(`_write_rows`), the paged kernel reads it as it stands and the output
+aliases the input. Positions a query may not see are masked to NEG_INF
+before the softmax, so whatever lies in page tails and on the trash
+page counts for exactly nothing: every lane is the uncached forward to
+float tolerance (tests/test_lanes.py).
 """
 
 from __future__ import annotations
 
-from typing import Dict
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
 
-from deeplearning4j_tpu.attention.blockwise import NEG_INF
+from deeplearning4j_tpu.attention.blockwise import masked_attention
 from deeplearning4j_tpu.attention.paged_pallas import paged_attention
-from deeplearning4j_tpu.models import moe_transformer as moe
-from deeplearning4j_tpu.models.moe_transformer import (KIND_FULL,
-                                                       KIND_WINDOW,
-                                                       MoEConfig)
-from deeplearning4j_tpu.serving.paged_kv import PagedKVPool, _write_rows
+from deeplearning4j_tpu.models import model_of
+from deeplearning4j_tpu.models.transformer import (KIND_FULL, KIND_WINDOW,
+                                                   causal_attention,
+                                                   visible)
+from deeplearning4j_tpu.serving.paged_kv import (PagedKVPool,  # noqa: F401
+                                                 init_pool, page_bytes,
+                                                 pool_bytes)
 
-__all__ = ["kinds_of", "layers_of", "window_table_pages", "first_visible",
-           "init_pool", "pool_bytes", "page_bytes", "prefill",
-           "decode_step"]
+__all__ = ["KIND_FULL", "KIND_WINDOW", "kinds_of", "layers_of",
+           "window_table_pages", "first_visible", "init_pool",
+           "pool_bytes", "page_bytes", "prefill", "prefill_ctx",
+           "decode_step", "verify_step"]
 
 
-def kinds_of(cfg: MoEConfig):
+def kinds_of(cfg):
     """The kinds this model has, full first."""
     return tuple(k for k in (KIND_FULL, KIND_WINDOW)
                  if k in cfg.layer_kinds)
 
 
-def layers_of(cfg: MoEConfig) -> Dict[str, int]:
+def layers_of(cfg) -> Dict[str, int]:
     return {k: cfg.layer_kinds.count(k) for k in kinds_of(cfg)}
 
 
-def window_table_pages(cfg: MoEConfig, page_size: int) -> int:
+def window_table_pages(cfg, page_size: int) -> Optional[int]:
     """The most table columns a window can straddle: `window` keys that
-    end anywhere in a page."""
+    end anywhere in a page. None where no layer has a window."""
+    if cfg.window is None:
+        return None
     return -(-(cfg.window - 1) // page_size) + 1
 
 
@@ -72,128 +89,272 @@ def first_visible(pos, window: int):
     return jnp.maximum(pos - window + 1, 0)
 
 
-def init_pool(cfg: MoEConfig, pages: Dict[str, int],
-              page_size: int) -> PagedKVPool:
-    layers = []
-    for kind in cfg.layer_kinds:
-        shape = (int(pages[kind]) + 1, cfg.n_kv_heads, page_size,
-                 cfg.head_dim)
-        layers.append({"k": jnp.zeros(shape, cfg.dtype),
-                       "v": jnp.zeros(shape, cfg.dtype)})
-    return PagedKVPool(tuple(layers))
-
-
-def page_bytes(cfg: MoEConfig, page_size: int) -> int:
-    """K and V of one page of one layer."""
-    return (2 * cfg.n_kv_heads * page_size * cfg.head_dim
-            * jnp.dtype(cfg.dtype).itemsize)
-
-
-def pool_bytes(cfg: MoEConfig, pages: Dict[str, int],
-               page_size: int) -> int:
-    """HBM the pools pin, trash pages included."""
-    return sum((int(pages[k]) + 1) * page_bytes(cfg, page_size)
-               for k in cfg.layer_kinds)
-
-
-def prefill(params, tokens, true_len, pool: PagedKVPool,
-            page_ids: Dict[str, jax.Array], cfg: MoEConfig):
-    """A batch of padded prompts (B, Tb) through every block in one
-    dispatch. `page_ids[kind]` (B, Tb / page_size) names, by kind, the
-    page each page-sized run of a row's K/V goes to: the trash page for
-    runs past the row's real pages, for padding rows, and in a window
-    layer for runs no later query can see. Returns (logits (B, vocab)
-    at each row's last real position, the pool, pairs (layers, n_held)
-    of the real tokens)."""
-    b, tb = tokens.shape
-    ps = pool.page_size
-    positions = jnp.broadcast_to(jnp.arange(tb), (b, tb))
-    valid = positions < true_len[:, None]
-    flat = {kind: ids.reshape(-1) for kind, ids in page_ids.items()}
+# ------------------------------------------------- the cache's writes
+def _write_pages(held, flat_ids, k, v):
+    """A prefill's write: k, v (B, Hkv, Tb, hd) cut into page-sized
+    runs, run r of the batch going whole to page `flat_ids[r]` (B * Tb /
+    page_size of them, the trash page for a run nothing will read)."""
+    b, h, tb, hd = k.shape
+    ps = held["k"].shape[2]
 
     def pages(arr, like):
-        # (B, Tb, Hkv, hd) -> (B * Tb/ps pages, Hkv, ps, hd)
-        a = arr.astype(like.dtype).reshape(b, tb // ps, ps,
-                                           cfg.n_kv_heads, cfg.head_dim)
-        return a.transpose(0, 1, 3, 2, 4).reshape(
-            b * (tb // ps), cfg.n_kv_heads, ps, cfg.head_dim)
+        a = arr.astype(like.dtype).reshape(b, h, tb // ps, ps, hd)
+        return a.transpose(0, 2, 1, 3, 4).reshape(b * (tb // ps), h, ps,
+                                                  hd)
 
-    def attend(layer, kind, q, k, v):
-        held = pool.layers[layer]
-        new = {"k": held["k"].at[flat[kind]].set(pages(k, held["k"])),
-               "v": held["v"].at[flat[kind]].set(pages(v, held["v"]))}
-        return moe.causal_attention(cfg, kind, q, k, v), new
-
-    x, layers, pairs = moe.forward(params, tokens, positions, cfg, attend,
-                                   valid)
-    idx = jnp.broadcast_to((true_len - 1)[:, None, None],
-                           (b, 1, cfg.d_model))
-    last_x = jnp.take_along_axis(x, idx, axis=1)[:, 0, :]
-    return moe.head(params, last_x, cfg), PagedKVPool(layers), pairs
+    return {"k": held["k"].at[flat_ids].set(pages(k, held["k"])),
+            "v": held["v"].at[flat_ids].set(pages(v, held["v"]))}
 
 
-def decode_step(params, tokens, pool: PagedKVPool,
-                tables: Dict[str, jax.Array], lengths, active,
-                cfg: MoEConfig, kernel: str = "gather"):
-    """One decode step over S slots, as `paged_kv.paged_decode_step`
-    is for the one-kind cache: write each active slot's K/V row at its
-    cursor through its kind's table (`_write_rows`, so the donated pools
-    keep their layout and are updated in place), attend over what the
-    layer's kind lets the cursor see, return (logits (S, vocab), the
-    pool, pairs (layers, n_held) of the active slots' tokens)."""
-    if kernel not in ("gather", "pallas"):
-        raise ValueError(f"kernel must be 'gather' or 'pallas' here, "
-                         f"got {kernel!r}")
-    s = tokens.shape[0]
+def _write_rows(arr, dest, offset, rows):
+    """Write one `head_dim` row per (..., head) into pool array `arr`
+    (n_pages + 1, H, page_size, hd): `dest` and `offset` (any shape
+    `idx`, the physical page and the offset inside it) name where
+    `rows` (`idx` + (H, hd)) go. The decode and the verify step's only
+    write.
+
+    The scatter indexes EVERY major dimension (page, head, offset) and
+    leaves the `head_dim` row as its only window. Written as
+    `arr.at[dest, :, offset, :]` the head dimension is a window between
+    two indexed dimensions, and the TPU compiler then gives the
+    scatter's operand the layout {3,1,2,0} where the donated pool and
+    the paged kernel hold {3,2,1,0}: two layout changes of the WHOLE
+    pool per layer for K and for V each, 96 copies of 168 MB a step at
+    the served widths (PERF.md section 6, PR 27). In this form the
+    pool keeps its layout and is updated in place.
+    tests/test_paged_step_layout.py compiles both steps for a v5e and
+    fails on any pool-shaped copy. Duplicate destinations (inactive
+    slots colliding on the trash page) stay legal: no `unique_indices`
+    promise is made."""
+    heads = jnp.arange(arr.shape[1])
+    return arr.at[dest[..., None], heads, offset[..., None], :].set(
+        rows.astype(arr.dtype))
+
+
+def _row_dest(pool: PagedKVPool, cfg, tables, pos, live):
+    """By kind, the physical page the row at cursor `pos` goes to: the
+    table's, or the kind's trash page where the row is not `live` or
+    its cursor is at or past the table's end (never clamped into the
+    slot's last real page). `pos` and `live` are (S,) or (S, W)."""
     ps = pool.page_size
-    pos = lengths
-    rows = jnp.arange(s)
-    group = cfg.n_heads // cfg.n_kv_heads
-    dest, n_cols = {}, {}
+    dest = {}
     for kind, table in tables.items():
         n_p = table.shape[1]
         trash = pool.layers[cfg.layer_kinds.index(kind)]["k"].shape[0] - 1
-        dest[kind] = jnp.where(
-            active & (pos // ps < n_p),
-            table[rows, jnp.minimum(pos // ps, n_p - 1)], trash)
-        n_cols[kind] = n_p
-    offset = pos % ps
-    first = first_visible(pos, cfg.window)
-    scale = 1.0 / jnp.sqrt(jnp.float32(cfg.head_dim))
+        column = jnp.minimum(pos // ps, n_p - 1)
+        page = (table[jnp.arange(table.shape[0]), column] if pos.ndim == 1
+                else jnp.take_along_axis(table, column, axis=1))
+        dest[kind] = jnp.where(live & (pos // ps < n_p), page, trash)
+    return dest
+
+
+# -------------------------------------------------- the cache's reads
+def _gathered(arr, table):
+    """A slot's pages side by side, its logical window: pool array
+    (n_pages + 1, H, page_size, hd) through table (S, P) -> (S, H, P *
+    page_size, hd). The dense read's O(S x table) traffic."""
+    s, n_p = table.shape
+    _, h, ps, hd = arr.shape
+    return arr[table].transpose(0, 2, 1, 3, 4).reshape(s, h, n_p * ps, hd)
+
+
+def _gather_read(cfg, kind: str, q, ks, vs, table, pos):
+    """The dense lane of a step: q (S, Hq, W, hd), column j at cursor
+    `pos[s, j]`, over the slot's whole logical window."""
+    k_pos = jnp.arange(table.shape[1] * ks.shape[2])
+    return masked_attention(q, _gathered(ks, table), _gathered(vs, table),
+                            visible(cfg, kind, pos, k_pos))
+
+
+def _check_kernel(kernel: str) -> None:
+    if kernel not in ("gather", "pallas"):
+        raise ValueError(
+            f"kernel must be 'gather' or 'pallas' here (resolve 'auto' "
+            f"via attention.paged_pallas.resolve_decode_kernel), "
+            f"got {kernel!r}")
+
+
+def _last_logits(model, params, x, true_len, cfg):
+    """Each row's LAST REAL position through the head: (B, d) @ (d,
+    vocab), not a (B, Tb, vocab) product."""
+    b, _, d = x.shape
+    idx = jnp.broadcast_to((true_len - 1)[:, None, None], (b, 1, d))
+    return model.head(params, jnp.take_along_axis(x, idx, axis=1)[:, 0, :],
+                      cfg)
+
+
+# ------------------------------------------------------------ the lanes
+def prefill(params, tokens, true_len, pool: PagedKVPool,
+            page_ids: Dict[str, jax.Array], cfg):
+    """A batch of padded prompts (B, Tb) through every block in one
+    dispatch (an admission burst costs one compiled call, not one a
+    prompt). `page_ids[kind]` (B, Tb / page_size) names, by kind, the
+    page each page-sized run of a row's K/V goes to: the trash page for
+    runs past the row's real pages, for padding rows, and in a window
+    layer for runs no later query can see. Causal flash attention means
+    positions < true_len never see the padding, whose K/V lands in the
+    last real page's tail (masked out of decode by the slot's length)
+    or on the trash page. Returns (logits (B, vocab) at each row's last
+    real position, the pool, aux of the real tokens)."""
+    model = model_of(cfg)
+    tb = tokens.shape[1]
+    positions = jnp.arange(tb)
+    valid = positions < true_len[:, None]
+    flat = {kind: ids.reshape(-1) for kind, ids in page_ids.items()}
+
+    def attend(layer, kind, q, k, v):
+        att = causal_attention(cfg, kind, q, k, v)
+        return att, _write_pages(pool.layers[layer], flat[kind], k, v)
+
+    x, layers, aux = model.forward(params, tokens, positions, cfg, attend,
+                                   valid)
+    return (_last_logits(model, params, x, true_len, cfg),
+            PagedKVPool(layers), aux)
+
+
+def prefill_ctx(params, tokens, true_len, pool: PagedKVPool,
+                page_ids: Dict[str, jax.Array],
+                ctx_tables: Dict[str, jax.Array], ctx_len, cfg):
+    """Prefill a batch of prompt TAILS whose prefix K/V already sits in
+    pool pages (the prefix cache's warm path): row b's tokens are prompt
+    positions `[ctx_len[b], ctx_len[b] + true_len[b])`, its cached
+    prefix occupies the pages in `ctx_tables[kind][b]` (trash-padded,
+    masked by `ctx_len`), and its tail K/V goes to `page_ids` exactly as
+    in `prefill` (tails start on a page boundary: admission only reuses
+    FULL cached chunks). Attention is the dense read over [gathered
+    prefix pages ‖ tail], not the flash kernel; shared prefix pages are
+    only READ. Returns what `prefill` returns."""
+    model = model_of(cfg)
+    b, tb = tokens.shape
+    ps = pool.page_size
+    positions = ctx_len[:, None] + jnp.arange(tb)               # (B, Tb)
+    valid = jnp.arange(tb) < true_len[:, None]
+    flat = {kind: ids.reshape(-1) for kind, ids in page_ids.items()}
 
     def attend(layer, kind, q, k, v):
         held = pool.layers[layer]
-        ks = _write_rows(held["k"], dest[kind], offset, k[:, 0])
-        vs = _write_rows(held["v"], dest[kind], offset, v[:, 0])
+        table = ctx_tables[kind]
+        ctx_pos = jnp.broadcast_to(jnp.arange(table.shape[1] * ps),
+                                   (b, table.shape[1] * ps))
+        # prefix columns are real below ctx_len; the tail is causal
+        k_pos = jnp.concatenate([ctx_pos, positions], axis=1)
+        real = jnp.concatenate([ctx_pos < ctx_len[:, None],
+                                jnp.ones((b, tb), bool)], axis=1)
+        att = masked_attention(
+            q, jnp.concatenate([_gathered(held["k"], table), k], axis=2),
+            jnp.concatenate([_gathered(held["v"], table), v], axis=2),
+            visible(cfg, kind, positions, k_pos) & real[:, None, :])
+        return att, _write_pages(held, flat[kind], k, v)
+
+    x, layers, aux = model.forward(params, tokens, positions, cfg, attend,
+                                   valid)
+    return (_last_logits(model, params, x, true_len, cfg),
+            PagedKVPool(layers), aux)
+
+
+def decode_step(params, tokens, pool: PagedKVPool,
+                tables: Dict[str, jax.Array], lengths, active, cfg,
+                kernel: str = "gather"):
+    """One decode step over S slots: embed `tokens` (S,), write each
+    active slot's K/V row at its cursor (`lengths`) through its kind's
+    table (`_write_rows`, so the donated pools keep their layout and
+    are updated in place), attend over what the layer's kind lets the
+    cursor see, return (logits (S, vocab), the pool, aux of the active
+    slots' tokens). Inactive slots write to the trash page and their
+    logits are garbage the host ignores.
+
+    `kernel` picks the read: "gather" materializes each slot's dense
+    window (O(S x table) traffic a step); "pallas" streams only the
+    written pages from the pool through `paged_attention` (grouped
+    heads and a first visible position are its arguments; `cfg.interpret`
+    runs it on the CPU). Callers resolve "auto" BEFORE jitting
+    (`resolve_decode_kernel`): the lane is a constant of the program."""
+    _check_kernel(kernel)
+    model = model_of(cfg)
+    ps = pool.page_size
+    pos = lengths
+    dest = _row_dest(pool, cfg, tables, pos, active)
+    offset = pos % ps
+    first = (first_visible(pos, cfg.window) if KIND_WINDOW in tables
+             else None)
+
+    def attend(layer, kind, q, k, v):
+        held = pool.layers[layer]
+        ks = _write_rows(held["k"], dest[kind], offset, k[:, :, 0])
+        vs = _write_rows(held["v"], dest[kind], offset, v[:, :, 0])
         table = tables[kind]
-        windowed = kind == KIND_WINDOW
         if kernel == "pallas":
             att = paged_attention(
-                q[:, 0], ks, vs, table, lengths,
-                first=first if windowed else None,
+                q[:, :, 0], ks, vs, table, lengths,
+                first=first if kind == KIND_WINDOW else None,
                 window_pages=window_table_pages(cfg, ps),
-                interpret=cfg.interpret)
+                interpret=cfg.interpret)[:, :, None]
         else:
-            span = n_cols[kind] * ps
-            kg = ks[table].transpose(0, 2, 1, 3, 4).reshape(
-                s, cfg.n_kv_heads, span, cfg.head_dim)
-            vg = vs[table].transpose(0, 2, 1, 3, 4).reshape(
-                s, cfg.n_kv_heads, span, cfg.head_dim)
-            qg = q[:, 0].reshape(s, cfg.n_kv_heads, group, cfg.head_dim)
-            sc = jnp.einsum("shgd,shkd->shgk", qg.astype(jnp.float32),
-                            kg.astype(jnp.float32)) * scale
-            k_pos = jnp.arange(span)[None, :]
-            mask = k_pos <= pos[:, None]
-            if windowed:
-                mask = mask & (k_pos >= first[:, None])
-            sc = jnp.where(mask[:, None, None, :], sc, NEG_INF)
-            att = jnp.einsum("shgk,shkd->shgd",
-                             jax.nn.softmax(sc, axis=-1),
-                             vg.astype(jnp.float32))
-            att = att.reshape(s, cfg.n_heads, cfg.head_dim)
-        return att[:, None], {"k": ks, "v": vs}
+            att = _gather_read(cfg, kind, q, ks, vs, table, pos[:, None])
+        return att, {"k": ks, "v": vs}
 
-    x, layers, pairs = moe.forward(
-        params, tokens[:, None], pos[:, None], cfg, attend,
-        active[:, None])
-    return moe.head(params, x[:, 0], cfg), PagedKVPool(layers), pairs
+    x, layers, aux = model.forward(params, tokens[:, None], pos[:, None],
+                                   cfg, attend, active[:, None])
+    return model.head(params, x[:, 0], cfg), PagedKVPool(layers), aux
+
+
+def verify_step(params, tokens, pool: PagedKVPool,
+                tables: Dict[str, jax.Array], lengths, widths, cfg,
+                kernel: str = "gather"):
+    """The WIDENED decode step speculative verify rides: `tokens` is
+    (S, W), row s's column j the token whose K/V belongs at cursor
+    `lengths[s] + j` (column 0 is the slot's pending token, columns
+    1..W-1 the drafter's proposals). `widths` (S,) int32 is how many
+    columns of each row are real (0 = idle slot; 1 = a plain step
+    riding along). Returns (logits (S, W, vocab), the pool, aux of the
+    real columns).
+
+    All real positions write K/V through the tables in one dispatch
+    (columns past a row's width go to the trash page) and every query
+    attends causally: column j sees positions <= lengths[s] + j, so
+    draft K/V written "in the future" of a query is masked exactly like
+    unwritten page-tail garbage, and logits[s, j] is the model's
+    next-token distribution after the prefix extended by proposals
+    1..j. Rejected columns leave garbage past the rolled-back cursor:
+    always masked (the cursor only moves forward over freshly written
+    positions), then overwritten before ever becoming visible.
+
+    "pallas" reuses the single-query streamed kernel once a column
+    (K/V reads are O(W x written pages) either way: speculation's win
+    is the weight sweep and the dispatch, not the K/V reads)."""
+    _check_kernel(kernel)
+    model = model_of(cfg)
+    w = tokens.shape[1]
+    ps = pool.page_size
+    pos = lengths[:, None] + jnp.arange(w)[None, :]            # (S, W)
+    valid = jnp.arange(w)[None, :] < widths[:, None]
+    dest = _row_dest(pool, cfg, tables, pos, valid)
+    offset = pos % ps
+
+    def attend(layer, kind, q, k, v):
+        held = pool.layers[layer]
+        # rows are (S, W, H, hd), one per (slot, column, head)
+        ks = _write_rows(held["k"], dest[kind], offset,
+                         k.transpose(0, 2, 1, 3))
+        vs = _write_rows(held["v"], dest[kind], offset,
+                         v.transpose(0, 2, 1, 3))
+        table = tables[kind]
+        if kernel == "pallas":
+            # each column at its own cursor; garbage lanes (columns
+            # that are not real) stay finite and are never read
+            cols = []
+            for j in range(w):
+                at = jnp.minimum(lengths + j, table.shape[1] * ps - 1)
+                cols.append(paged_attention(
+                    q[:, :, j], ks, vs, table, at,
+                    first=(first_visible(at, cfg.window)
+                           if kind == KIND_WINDOW else None),
+                    window_pages=window_table_pages(cfg, ps),
+                    interpret=cfg.interpret))
+            att = jnp.stack(cols, axis=2)
+        else:
+            att = _gather_read(cfg, kind, q, ks, vs, table, pos)
+        return att, {"k": ks, "v": vs}
+
+    x, layers, aux = model.forward(params, tokens, pos, cfg, attend, valid)
+    return model.head(params, x, cfg), PagedKVPool(layers), aux

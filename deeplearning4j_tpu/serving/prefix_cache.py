@@ -30,7 +30,7 @@ in `decode_loop.DecodeLoop`, which owns the pool:
   O(nodes) — fine at pool scale (pages are hundreds, not millions).
 
 The trie never touches device memory: sharing pool pages between slots
-is pure page-table bookkeeping (`paged_decode_step` gathers through the
+is pure page-table bookkeeping (the decode step gathers through the
 per-slot table), so this index adds zero compiled programs.
 """
 

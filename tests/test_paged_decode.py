@@ -27,16 +27,15 @@ import pytest
 
 from deeplearning4j_tpu.models.transformer import (TransformerConfig,
                                                    init_transformer_params)
+from deeplearning4j_tpu.serving import paged_kinds
 from deeplearning4j_tpu.serving.decode_loop import DecodeLoop
 from deeplearning4j_tpu.serving.kv_cache import (decode_step,
                                                  generate_cached,
                                                  init_cache, kv_cache_bytes,
                                                  prefill)
-from deeplearning4j_tpu.serving.paged_kv import (_write_rows,
-                                                 init_paged_pool,
-                                                 paged_decode_step,
+from deeplearning4j_tpu.serving.paged_kinds import _write_rows
+from deeplearning4j_tpu.serving.paged_kv import (init_paged_pool,
                                                  paged_kv_bytes,
-                                                 paged_prefill,
                                                  pages_for_tokens,
                                                  pages_per_slot,
                                                  prompt_buckets)
@@ -154,9 +153,9 @@ class TestPagedParity:
             pids[i, :need] = pages
             table[i, :need] = pages
             lengths[i] = len(pr)
-        logits, pool = paged_prefill(p, jnp.asarray(padded),
+        logits, pool, _ = paged_kinds.prefill(p, jnp.asarray(padded),
                                      jnp.asarray(lengths), pool,
-                                     jnp.asarray(pids), CFG)
+                                     {"full": jnp.asarray(pids)}, CFG)
         logits = np.asarray(logits)
         for i in range(2):
             np.testing.assert_allclose(logits[i], ref_first[i][0],
@@ -170,8 +169,8 @@ class TestPagedParity:
                 pidx = lengths[i] // ps
                 if table[i, pidx] == trash:
                     table[i, pidx] = free.pop(0)
-            lg, pool = paged_decode_step(
-                p, jnp.asarray(toks), pool, jnp.asarray(table),
+            lg, pool, _ = paged_kinds.decode_step(
+                p, jnp.asarray(toks), pool, {"full": jnp.asarray(table)},
                 jnp.asarray(lengths), jnp.asarray(active), CFG)
             lg = np.asarray(lg)
             for i in range(2):
@@ -198,17 +197,17 @@ class TestPagedParity:
         pids[0] = [0, 1]
         table[0, :2] = [0, 1]
         lengths = np.asarray([9, 0], np.int32)
-        _, pool = paged_prefill(p, jnp.asarray(padded),
+        _, pool, _ = paged_kinds.prefill(p, jnp.asarray(padded),
                                 jnp.asarray([9, 1], np.int32), pool,
-                                jnp.asarray(pids), CFG)
+                                {"full": jnp.asarray(pids)}, CFG)
         before = [np.asarray(layer["k"])[:2] for layer in pool.layers]
         # run steps with slot 0 INACTIVE, slot 1 active on page 2
         table[1, 0] = 2
         active = np.asarray([False, True])
         for _ in range(3):
             toks = rng.randint(0, CFG.vocab_size, (2,)).astype(np.int32)
-            _, pool = paged_decode_step(
-                p, jnp.asarray(toks), pool, jnp.asarray(table),
+            _, pool, _ = paged_kinds.decode_step(
+                p, jnp.asarray(toks), pool, {"full": jnp.asarray(table)},
                 jnp.asarray(lengths), jnp.asarray(active), CFG)
             lengths = lengths + np.asarray([0, 1], np.int32)
         after = [np.asarray(layer["k"])[:2] for layer in pool.layers]
@@ -489,8 +488,8 @@ class TestWindowEdge:
         p = _params()
         pool = init_paged_pool(CFG, n_pages=8, page_size=8)
         table = jnp.arange(8, dtype=jnp.int32)[None, :]  # all real pages
-        logits, new_pool = paged_decode_step(
-            p, jnp.asarray([3], jnp.int32), pool, table,
+        logits, new_pool, _ = paged_kinds.decode_step(
+            p, jnp.asarray([3], jnp.int32), pool, {"full": table},
             jnp.asarray([CFG.max_len], jnp.int32),
             jnp.asarray([False]), CFG)
         assert bool(jnp.isfinite(logits).all())

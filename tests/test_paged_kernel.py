@@ -16,7 +16,7 @@ docs/SERVING.md "Decode kernel"):
    ALWAYS the gather path (interpret mode is a test lane, never a
    silent production fallback), and an explicit `kernel="pallas"`
    off-TPU raises a clear error unless `cfg.interpret` is set.
-4. **Cost accounting**: `decode_read_bytes` matches the pages the
+4. **Cost accounting**: `DecodeLoop._read_bytes` matches the pages the
    kernel grid actually computes, and the loop's
    dl4j_decode_kv_read_bytes{path} counters record streamed vs dense
    figures every dispatch.
@@ -48,12 +48,10 @@ from deeplearning4j_tpu.attention.paged_pallas import (
     block_pages, paged_attention, resolve_decode_kernel)
 from deeplearning4j_tpu.models.transformer import (TransformerConfig,
                                                    init_transformer_params)
+from deeplearning4j_tpu.serving import paged_kinds
 from deeplearning4j_tpu.serving.decode_loop import DecodeLoop
 from deeplearning4j_tpu.serving.kv_cache import generate_cached
-from deeplearning4j_tpu.serving.paged_kv import (decode_read_bytes,
-                                                 init_paged_pool,
-                                                 paged_decode_step,
-                                                 paged_prefill,
+from deeplearning4j_tpu.serving.paged_kv import (init_paged_pool,
                                                  pages_for_tokens,
                                                  pages_per_slot)
 
@@ -225,10 +223,12 @@ class TestBlocksOfPages:
             args = (jnp.asarray(rng.randint(0, 17, (3,)).astype(np.int32)),
                     jnp.asarray(table), jnp.asarray(lengths),
                     jnp.asarray([True, True, True]))
-            lg_g, pool = paged_decode_step(p, args[0], pool, *args[1:],
-                                           cfg, kernel="gather")
-            lg_p, pool_k = paged_decode_step(p, args[0], pool_k, *args[1:],
-                                             cfg, kernel="pallas")
+            lg_g, pool, _ = paged_kinds.decode_step(
+                p, args[0], pool, {"full": args[1]}, *args[2:], cfg,
+                kernel="gather")
+            lg_p, pool_k, _ = paged_kinds.decode_step(
+                p, args[0], pool_k, {"full": args[1]}, *args[2:], cfg,
+                kernel="pallas")
             np.testing.assert_allclose(np.asarray(lg_p), np.asarray(lg_g),
                                        atol=1e-5)
             lengths += 1
@@ -302,9 +302,9 @@ class TestStepParity:
             pids[i, :need] = pages
             table[i, :need] = pages
             lengths[i] = len(pr)
-        _, pool = paged_prefill(p, jnp.asarray(padded),
+        _, pool, _ = paged_kinds.prefill(p, jnp.asarray(padded),
                                 jnp.asarray(lengths), pool,
-                                jnp.asarray(pids), CFG)
+                                {"full": jnp.asarray(pids)}, CFG)
         pool_k = pool  # kernel-lane copy evolves in lockstep
         active = np.ones((2,), bool)
         for _ in range(12):
@@ -315,11 +315,11 @@ class TestStepParity:
                     table[i, pidx] = free.pop(0)
             args = (jnp.asarray(toks), jnp.asarray(table),
                     jnp.asarray(lengths), jnp.asarray(active))
-            lg_g, pool = paged_decode_step(
-                p, args[0], pool, args[1], args[2], args[3], CFG,
+            lg_g, pool, _ = paged_kinds.decode_step(
+                p, args[0], pool, {"full": args[1]}, args[2], args[3], CFG,
                 kernel="gather")
-            lg_p, pool_k = paged_decode_step(
-                p, args[0], pool_k, args[1], args[2], args[3], CFG,
+            lg_p, pool_k, _ = paged_kinds.decode_step(
+                p, args[0], pool_k, {"full": args[1]}, args[2], args[3], CFG,
                 kernel="pallas")
             np.testing.assert_allclose(np.asarray(lg_p), np.asarray(lg_g),
                                        atol=1e-5)
@@ -338,9 +338,11 @@ class TestStepParity:
         args = (jnp.asarray([3], jnp.int32), table,
                 jnp.asarray([CFG.max_len], jnp.int32),
                 jnp.asarray([False]))
-        lg_g, _ = paged_decode_step(p, args[0], pool, args[1], args[2],
+        lg_g, _, _ = paged_kinds.decode_step(p, args[0], pool,
+                                             {"full": args[1]}, args[2],
                                     args[3], CFG, kernel="gather")
-        lg_p, new_pool = paged_decode_step(p, args[0], pool, args[1],
+        lg_p, new_pool, _ = paged_kinds.decode_step(p, args[0], pool,
+                                                    {"full": args[1]},
                                            args[2], args[3], CFG,
                                            kernel="pallas")
         assert bool(jnp.isfinite(lg_p).all())
@@ -354,9 +356,9 @@ class TestStepParity:
         p = _params()
         pool = init_paged_pool(CFG, n_pages=4, page_size=8)
         with pytest.raises(ValueError, match="resolve"):
-            paged_decode_step(
+            paged_kinds.decode_step(
                 p, jnp.asarray([1], jnp.int32), pool,
-                jnp.zeros((1, 8), jnp.int32),
+                {"full": jnp.zeros((1, 8), jnp.int32)},
                 jnp.zeros((1,), jnp.int32), jnp.asarray([True]), CFG,
                 kernel="auto")
 
@@ -475,20 +477,20 @@ class TestKernelSelection:
 # ------------------------------------------------- cost accounting
 class TestDecodeReadBytes:
     def test_formula(self):
-        pool = init_paged_pool(CFG, n_pages=8, page_size=8)
         hd = CFG.d_model // CFG.n_heads
         page_b = CFG.n_heads * 8 * hd * 4
-        # cursors 0, 7 -> 1 page; 8 -> 2 pages; 64 (window edge, 8-page
-        # table) -> capped at 8
-        assert decode_read_bytes(pool, [0], 8) == 2 * 2 * page_b * 1
-        assert decode_read_bytes(pool, [7], 8) == 2 * 2 * page_b * 1
-        assert decode_read_bytes(pool, [8], 8) == 2 * 2 * page_b * 2
-        assert decode_read_bytes(pool, [64], 8) == 2 * 2 * page_b * 8
-        assert (decode_read_bytes(pool, [0, 8], 8)
-                == 2 * 2 * page_b * 3)
-        # the dense-gather figure: every slot reads its FULL reservation
-        assert (decode_read_bytes(pool, [0, 8], 8, dense=True)
-                == 2 * 2 * page_b * 16)
+        with DecodeLoop(_params(), CFG, slots=2, page_size=8,
+                        start=False) as loop:   # an 8-page table
+            # cursors 0, 7 -> 1 page; 8 -> 2 pages; 64 (window edge)
+            # -> capped at 8
+            assert loop._read_bytes([0])[0] == 2 * 2 * page_b * 1
+            assert loop._read_bytes([7])[0] == 2 * 2 * page_b * 1
+            assert loop._read_bytes([8])[0] == 2 * 2 * page_b * 2
+            assert loop._read_bytes([64])[0] == 2 * 2 * page_b * 8
+            assert loop._read_bytes([0, 8])[0] == 2 * 2 * page_b * 3
+            # the dense-gather figure: every slot reads its FULL
+            # reservation
+            assert loop._read_bytes([0, 8])[1] == 2 * 2 * page_b * 16
 
     def test_loop_records_both_paths_per_dispatch(self):
         """Every dispatch accounts streamed-kernel and dense-gather
@@ -501,10 +503,8 @@ class TestDecodeReadBytes:
             snap = loop.snapshot()
         got = snap["decode_kernel"]["kv_read_bytes"]
         assert got["kernel"] > 0
-        pool = init_paged_pool(CFG, 1, 8)  # page-geometry twin
         token_steps = snap["dispatches"]  # horizon=1
-        dense_per_step = decode_read_bytes(
-            pool, [0] * loop.slots, loop._pps, dense=True)
+        dense_per_step = loop._read_bytes([0] * loop.slots)[1]
         assert got["gather"] == token_steps * dense_per_step
         # one busy short slot + one idle slot vs a 2 x 8-page dense
         # window: the streamed figure must be well under the dense one

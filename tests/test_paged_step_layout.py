@@ -134,13 +134,13 @@ def _assert_kernel_reads_the_pool_as_it_lies(text, shapes, calls):
 
 def test_decode_step_holds_no_pool_shaped_copy(one_chip,
                                                no_compile_cache):
-    """The step as `DecodeLoop` jits it: `paged_decode_step` on the
-    paged lane with the argmax fed back, under a `lax.scan` of length
+    """The step as `DecodeLoop` jits it: `paged_kinds.decode_step` on
+    the paged lane with the argmax fed back, under a `lax.scan` of length
     1 (horizon 1), pool donated."""
     import jax
     import jax.numpy as jnp
 
-    from deeplearning4j_tpu.serving.paged_kv import paged_decode_step
+    from deeplearning4j_tpu.serving import paged_kinds
 
     cfg = _cfg()
     params, pool, table, vec = _described(one_chip, cfg)
@@ -149,8 +149,8 @@ def test_decode_step_holds_no_pool_shaped_copy(one_chip,
         def inner(carry, _):
             tokens, lengths, pool = carry
             act = lengths < stop
-            logits, pool = paged_decode_step(
-                params, tokens, pool, table, lengths, act, cfg,
+            logits, pool, _ = paged_kinds.decode_step(
+                params, tokens, pool, {"full": table}, lengths, act, cfg,
                 kernel="pallas")
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             tokens = jnp.where(act, nxt, tokens)
@@ -170,19 +170,19 @@ def test_decode_step_holds_no_pool_shaped_copy(one_chip,
 
 def test_verify_step_holds_no_pool_shaped_copy(one_chip,
                                                no_compile_cache):
-    """`paged_verify_step` at W 4 on the paged lane, pool donated, as
-    `DecodeLoop`'s `verify_fn` jits it."""
+    """`paged_kinds.verify_step` at W 4 on the paged lane, pool donated,
+    as `DecodeLoop`'s `verify_fn` jits it."""
     import jax
     import jax.numpy as jnp
 
-    from deeplearning4j_tpu.serving.paged_kv import paged_verify_step
+    from deeplearning4j_tpu.serving import paged_kinds
 
     cfg = _cfg()
     params, pool, table, vec = _described(one_chip, cfg)
 
     def verify_fn(params, tokens, pool, table, lengths, widths):
-        logits, pool = paged_verify_step(
-            params, tokens, pool, table, lengths, widths, cfg,
+        logits, pool, _ = paged_kinds.verify_step(
+            params, tokens, pool, {"full": table}, lengths, widths, cfg,
             kernel="pallas")
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), pool
 
@@ -203,8 +203,9 @@ def test_two_kind_decode_step_holds_no_pool_shaped_copy(one_chip,
     widths: 128 query heads over 8 K/V heads of 128, bfloat16 pools of
     2048 full and 1056 window pages of 128 tokens, donated. One window
     and one full layer: what the compiler does to a pool it does to
-    each. The grouped expert products are the megablox kernel, as on
-    the chip (the backend here is the CPU, so the test says "tpu")."""
+    each; the same `lax.scan` of length 1 as every model's step. The
+    grouped expert products are the megablox kernel, as on the chip (the
+    backend here is the CPU, so the test says "tpu")."""
     import jax
     import jax.numpy as jnp
 
@@ -234,13 +235,19 @@ def test_two_kind_decode_step_holds_no_pool_shaped_copy(one_chip,
     tables = {kind: vec(32, 64) for kind in pages}
 
     def step_fn(params, tokens, pool, table, lengths, stop):
-        act = lengths < stop
-        logits, pool, pairs = paged_kinds.decode_step(
-            params, tokens, pool, table, lengths, act, cfg,
-            kernel="pallas")
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return (nxt[None], pairs), jnp.where(act, nxt, tokens), \
-            lengths + act.astype(lengths.dtype), pool
+        def inner(carry, _):
+            tokens, lengths, pool = carry
+            act = lengths < stop
+            logits, pool, pairs = paged_kinds.decode_step(
+                params, tokens, pool, table, lengths, act, cfg,
+                kernel="pallas")
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return (jnp.where(act, nxt, tokens),
+                    lengths + act.astype(lengths.dtype), pool), (nxt, pairs)
+
+        (tokens, lengths, pool), out = jax.lax.scan(
+            inner, (tokens, lengths, pool), None, length=1)
+        return out, tokens, lengths, pool
 
     backend = jax.default_backend
     jax.default_backend = lambda: "tpu"

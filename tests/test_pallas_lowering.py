@@ -16,9 +16,8 @@ from deeplearning4j_tpu.attention.flash_pallas import flash_attention
 from deeplearning4j_tpu.attention.paged_pallas import paged_attention
 from deeplearning4j_tpu.models.transformer import (TransformerConfig,
                                                    init_transformer_params)
+from deeplearning4j_tpu.serving import paged_kinds
 from deeplearning4j_tpu.serving.paged_kv import (init_paged_pool,
-                                                 paged_decode_step,
-                                                 paged_verify_step,
                                                  pages_per_slot)
 
 pytestmark = pytest.mark.pallas
@@ -82,13 +81,13 @@ def test_decode_and_verify_steps_lower_with_the_kernel(dtype):
     pool = jax.eval_shape(lambda: init_paged_pool(cfg, s * n_p, ps))
     table, lengths = sds((s, n_p), "int32"), sds((s,), "int32")
     text = tpu_module(
-        lambda p, t, pool, tb, ln, act: paged_decode_step(
-            p, t, pool, tb, ln, act, cfg, kernel="pallas"),
+        lambda p, t, pool, tb, ln, act: paged_kinds.decode_step(
+            p, t, pool, {"full": tb}, ln, act, cfg, kernel="pallas"),
         params, sds((s,), "int32"), pool, table, lengths, sds((s,), "bool"))
     assert text.count('kernel_name = "paged_decode_attention"') == 1
     text = tpu_module(
-        lambda p, t, pool, tb, ln, wd: paged_verify_step(
-            p, t, pool, tb, ln, wd, cfg, kernel="pallas"),
+        lambda p, t, pool, tb, ln, wd: paged_kinds.verify_step(
+            p, t, pool, {"full": tb}, ln, wd, cfg, kernel="pallas"),
         params, sds((s, w), "int32"), pool, table, lengths, lengths)
     # one single-query pass per draft column, the kernel lowered once
     # a shape (PR 31: `paged_attention`'s body is jitted)

@@ -224,9 +224,13 @@ class TestHTTPRoundTrip:
                 assert series in text, f"{series} missing from /metrics"
             # the KV traffic counters carry both lane figures — the
             # streamed-kernel figure must undercut the dense one
+            # (this loop's: the registry keeps every loop the process
+            # ever built, and one that never ran a step counts 0)
+            label = gen.decode_loop.label
             kv_read = {}
             for ln in text.splitlines():
-                if ln.startswith("dl4j_decode_kv_read_bytes_total{"):
+                if (ln.startswith("dl4j_decode_kv_read_bytes_total{")
+                        and f'loop="{label}"' in ln):
                     for path in ("kernel", "gather"):
                         if f'path="{path}"' in ln:
                             kv_read[path] = float(ln.split()[-1])
@@ -234,7 +238,6 @@ class TestHTTPRoundTrip:
             assert kv_read["gather"] > kv_read["kernel"]
             # the pool gauge reports this loop's configured size and
             # the request actually streamed its tokens
-            label = gen.decode_loop.label
             assert (f'dl4j_kv_pages_total{{loop="{label}"}} '
                     f'{gen.decode_loop.n_pages}') in text
             streamed = [ln for ln in text.splitlines()

@@ -37,12 +37,10 @@ import pytest
 
 from deeplearning4j_tpu.models.transformer import (TransformerConfig,
                                                    init_transformer_params)
+from deeplearning4j_tpu.serving import paged_kinds
 from deeplearning4j_tpu.serving.decode_loop import DecodeLoop
 from deeplearning4j_tpu.serving.kv_cache import generate_cached
 from deeplearning4j_tpu.serving.paged_kv import (init_paged_pool,
-                                                 paged_decode_step,
-                                                 paged_prefill,
-                                                 paged_verify_step,
                                                  pages_for_tokens,
                                                  pages_per_slot)
 from deeplearning4j_tpu.serving.prefix_cache import PrefixIndex
@@ -232,9 +230,9 @@ class TestVerifyStepParity:
                     pages[:pages_for_tokens(len(pr), ps)]
                 table[i, :need] = pages
                 lengths[i] = len(pr)
-            _, pool = paged_prefill(params, jnp.asarray(padded),
+            _, pool, _ = paged_kinds.prefill(params, jnp.asarray(padded),
                                     jnp.asarray(lengths), pool,
-                                    jnp.asarray(pids), CFG)
+                                    {"full": jnp.asarray(pids)}, CFG)
             return pool, table, lengths
 
         tokens = rng.randint(0, CFG.vocab_size, (3, W)).astype(np.int32)
@@ -246,9 +244,9 @@ class TestVerifyStepParity:
         cur = lengths.copy()
         for j in range(W):
             act = widths > j
-            lg, pool_a = paged_decode_step(
+            lg, pool_a, _ = paged_kinds.decode_step(
                 params, jnp.asarray(tokens[:, j]), pool_a,
-                jnp.asarray(table), jnp.asarray(cur),
+                {"full": jnp.asarray(table)}, jnp.asarray(cur),
                 jnp.asarray(act), CFG, kernel=kernel)
             lg = np.asarray(lg)
             for i in range(3):
@@ -258,8 +256,8 @@ class TestVerifyStepParity:
 
         # one widened verify step
         pool_b, table, lengths = seeded_pool()
-        lg, pool_b = paged_verify_step(
-            params, jnp.asarray(tokens), pool_b, jnp.asarray(table),
+        lg, pool_b, _ = paged_kinds.verify_step(
+            params, jnp.asarray(tokens), pool_b, {"full": jnp.asarray(table)},
             jnp.asarray(lengths), jnp.asarray(widths), CFG,
             kernel=kernel)
         lg = np.asarray(lg)
@@ -273,9 +271,9 @@ class TestVerifyStepParity:
     def test_rejects_unresolved_kernel(self, params):
         pool = init_paged_pool(CFG, 4, 8)
         with pytest.raises(ValueError, match="kernel"):
-            paged_verify_step(
+            paged_kinds.verify_step(
                 params, jnp.zeros((1, 2), jnp.int32), pool,
-                jnp.zeros((1, 2), jnp.int32),
+                {"full": jnp.zeros((1, 2), jnp.int32)},
                 jnp.zeros((1,), jnp.int32),
                 jnp.ones((1,), jnp.int32), CFG, kernel="auto")
 

@@ -314,9 +314,9 @@ class TestPagedKernelOnChip:
 
         from deeplearning4j_tpu.models.transformer import (
             TransformerConfig, init_transformer_params)
-        from deeplearning4j_tpu.serving.paged_kv import (
-            init_paged_pool, paged_decode_step, paged_prefill,
-            paged_verify_step, pages_per_slot)
+        from deeplearning4j_tpu.serving import paged_kinds
+        from deeplearning4j_tpu.serving.paged_kv import (init_paged_pool,
+                                                         pages_per_slot)
 
         cfg = TransformerConfig(vocab_size=8192, d_model=1024, n_heads=8,
                                 n_layers=2, d_ff=4096, max_len=512,
@@ -332,9 +332,9 @@ class TestPagedKernelOnChip:
         precise = (jax.default_matmul_precision("highest")
                    if dtype == "float32" else contextlib.nullcontext())
         with precise:
-            _, pool = jax.jit(
-                lambda p, t, tl, pool, ids: paged_prefill(
-                    p, t, tl, pool, ids, cfg))(
+            _, pool, _ = jax.jit(
+                lambda p, t, tl, pool, ids: paged_kinds.prefill(
+                    p, t, tl, pool, {"full": ids}, cfg))(
                 params, jnp.asarray(tokens), jnp.asarray(true_len), pool,
                 jnp.asarray(table[:, :128 // ps]))
             nxt = jnp.asarray(rng.integers(0, cfg.vocab_size, (s_n,)),
@@ -342,23 +342,25 @@ class TestPagedKernelOnChip:
             lengths = jnp.asarray(true_len)
             active = jnp.ones((s_n,), bool)
             step = {k: jax.jit(
-                lambda p, t, pool, tb, ln, act, k=k: paged_decode_step(
-                    p, t, pool, tb, ln, act, cfg, kernel=k))
+                lambda p, t, pool, tb, ln, act, k=k:
+                paged_kinds.decode_step(p, t, pool, {"full": tb}, ln, act,
+                                        cfg, kernel=k))
                 for k in ("pallas", "gather")}
-            lk, _ = step["pallas"](params, nxt, pool, jnp.asarray(table),
+            lk, _, _ = step["pallas"](params, nxt, pool, jnp.asarray(table),
                                    lengths, active)
-            lg, _ = step["gather"](params, nxt, pool, jnp.asarray(table),
+            lg, _, _ = step["gather"](params, nxt, pool, jnp.asarray(table),
                                    lengths, active)
             drafts = jnp.asarray(
                 rng.integers(0, cfg.vocab_size, (s_n, 4)), jnp.int32)
             widths = jnp.asarray([4, 1, 3, 0], jnp.int32)
             ver = {k: jax.jit(
-                lambda p, t, pool, tb, ln, w, k=k: paged_verify_step(
-                    p, t, pool, tb, ln, w, cfg, kernel=k))
+                lambda p, t, pool, tb, ln, w, k=k:
+                paged_kinds.verify_step(p, t, pool, {"full": tb}, ln, w,
+                                        cfg, kernel=k))
                 for k in ("pallas", "gather")}
-            vk, _ = ver["pallas"](params, drafts, pool, jnp.asarray(table),
+            vk, _, _ = ver["pallas"](params, drafts, pool, jnp.asarray(table),
                                   lengths, widths)
-            vg, _ = ver["gather"](params, drafts, pool, jnp.asarray(table),
+            vg, _, _ = ver["gather"](params, drafts, pool, jnp.asarray(table),
                                   lengths, widths)
         lk, lg = (np.asarray(a.astype(jnp.float32)) for a in (lk, lg))
         assert np.isfinite(lk).all()
