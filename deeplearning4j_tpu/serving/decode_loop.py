@@ -26,10 +26,93 @@ ROADMAP "Continuous batching + paged KV cache"):
   its slot and pages immediately; the other slots never notice.
 
 The device carry — last tokens, pool, page table, lengths, stop bounds
-— feeds straight back into the next dispatch; the host re-uploads the
-(S,)/(S,P) control arrays only after a visible event (admission,
-completion, page grant). Steady-state per-token cost is one dispatch
-slice plus the token D2H the streams need anyway. On accelerators the
+— feeds straight back into the next dispatch. The page table and the
+stop bounds are the host's: it uploads them whole after a visible event
+(admission, completion, page grant). The tokens and the lengths are the
+device's: a step hands them to the next one and the host sets a slot's
+row only where it is the authority, at admission and at retirement
+(`_host_rows`, one program of fixed shape). Steady-state per-token cost
+is one dispatch slice plus the token D2H the streams need anyway.
+
+**A second step in flight** (the plain lane, every model, every
+horizon; docs/SERVING.md "The order of a pass"): a pass enqueues step
+N+1 BEFORE it reads step N, so the host's pass and the read-back run
+under the device's next step and not between two steps. The order of a
+pass:
+
+1. reap cancelled and expired slots, run page jobs, admit (a prefill is
+   enqueued behind the step in flight; its slot joins the next step);
+2. with step N enqueued and unread, prepare and enqueue step N+1:
+   window pages released, pages granted, the copy-on-write fence run,
+   stop bounds set, all against the DISPATCHED cursor, table and stop
+   uploaded when they changed, the step called on the device's own
+   tokens, lengths and pool;
+3. only then read step N (`decode.d2h` waits for what is left of N),
+   account, flush first tokens (their read waits on the prefill, under
+   a `decode.d2h` span of its own), emit N's tokens and retire what
+   finished, while the device runs N+1.
+
+The dispatched cursor is `_lengths`: where a slot's length will stand
+on the device once every enqueued step has run, i.e. what was emitted
+plus what the step in flight will consume, `clip(stop - length, 0,
+horizon)`, the device's own rule (`lengths < stop`, `horizon` times).
+Nothing step N+1 needs comes from the host's reading of step N. What
+has to hold (tests/test_decode_overlap.py has a test for each):
+
+- The host's mirrors lag a step; the device is never overwritten from
+  them. Tokens and lengths of a slot are set on the device only where
+  the host is the authority (admission, retirement, after a speculative
+  round: `_host_rows`) by ONE program of fixed shape over all slots
+  (`_set_rows`, compiled by the first admission, so in warm-up). Uploads
+  are copies of the mirrors (`_upload`), never views the host writes on:
+  a transfer reads the host's buffer after the call returns, and with a
+  step in flight nothing waits for it before the mirrors change again.
+- A step's tokens reach a stream only if the slot still holds the
+  request the step was dispatched for (the slot OBJECT is compared, not
+  its index: the slot may have been taken again). An end-of-sequence
+  token therefore costs one wasted step, never a wrong token: the slot
+  has already run in step N+1, the stream ends at the token, and the
+  extra position landed in the slot's own page (the fence made it
+  private). A slot that ends by `max_tokens` overshoots nothing (the
+  device stops it at `stop`); cancellation, deadline and preemption
+  retire the same way.
+- A page has one owner at every point of the device's order. Every
+  program that touches the pool takes the pool the program before it
+  returned (`self._pool`), so the device runs them in the order they
+  were enqueued; and pages are only ever granted while work is being
+  PREPARED, on the scheduler thread. So a page given back while a step
+  that reads or writes it is in flight (a window release in
+  `_release_window`, a retirement in `_retire`, a fork or an eviction in
+  `_cow_guard` / `_alloc_page`) can be granted, in the same pass or a
+  later one, only to work enqueued BEHIND that step: a prefill, a
+  fork's copy, an install, the next step. Its new owner writes each
+  position before it reads it, so what the step in flight left there
+  (the wasted position of an end of sequence) counts for nothing.
+- A step enqueued for a slot that had already reached its `stop` writes
+  nothing a later owner can see: `paged_kinds._row_dest` sends the row
+  of a slot with `lengths >= stop` (not `live`) to the trash page of
+  EVERY kind, whatever its table still maps; at the parent such a
+  slot's table held the trash page anyway, with a step in flight it may
+  still map its last occupant's pages.
+- Nothing is in flight when the loop says it is idle (`_drained`:
+  `_idle`, `run_until_idle`, `close`; `_fail_all` drops the handle; the
+  stuck-pool check runs only in a pass that neither enqueued nor read).
+  No name keeps a device array of a step beyond `decode.d2h` but the one
+  handle of the step in flight (`_inflight.out`; PERF.md section 6,
+  PR 32).
+- One order of a pass, not two: no option turns the overlap off, no
+  model is asked its name. If the host's pass outlasts the device's
+  step the read returns at once and the order degrades to one step at a
+  time by itself. The speculative lane stays in turn because its
+  drafter reads a slot's tokens on the host before it can propose
+  (`spec_k`): a verify round, and the plain step it falls back to, are
+  read in the pass that dispatched them.
+
+`dl4j_decode_dispatches_overlapped` (`snapshot()
+["dispatches_overlapped"]`) counts the steps enqueued while the step
+before them was unread.
+
+On accelerators the
 pool is donated to the step and KV updates alias in place: that holds
 because the step's write (`paged_kinds._write_rows`) keeps the pool in the
 layout the paged kernel reads, so the compiled step holds no copy of
@@ -42,8 +125,9 @@ warning, same as InferenceEngine).
 dispatch (a `lax.scan` feeding each slot's argmax back on device). The
 per-slot `stop` bound makes ragged membership exact — a slot never
 writes past its token budget or its allocated pages, whatever K is —
-and the host trims EOS overshoot (at most K-1 speculative tokens are
-discarded; admission waits at most one chunk). K=1 (the default) is
+and the host trims EOS overshoot (at most K-1 speculative tokens of
+the chunk are discarded, and the chunk already in flight behind it;
+admission waits at most one chunk). K=1 (the default) is
 pure token-boundary scheduling; dispatch-bound hosts raise it to
 amortize the per-step round trip (`bench.py serve` runs the CPU smoke
 at K=8).
@@ -252,7 +336,9 @@ ROLES = (ROLE_UNIFIED, ROLE_PREFILL, ROLE_DECODE)
 
 #: the scheduler's spans. `decode.tick` is one pass; `decode.idle_wait`
 #: is the scheduler thread asleep between passes; `decode.prefill_dispatch`
-#: is a child of `decode.admit`; every other one is a child of the tick.
+#: is a child of `decode.admit`; every other one is a child of the tick
+#: (`decode.d2h` once for a step's tokens and once more in a pass that
+#: has prefill groups' first tokens to read).
 TICK = "decode.tick"
 IDLE_WAIT = "decode.idle_wait"
 PREFILL_DISPATCH = "decode.prefill_dispatch"
@@ -552,6 +638,19 @@ class _Slot:
         self.no_cache: set = set()
 
 
+class _Step:
+    """A decode step that is enqueued and not read yet: the one handle
+    on its device arrays, and what the host knew when it dispatched it."""
+    __slots__ = ("out", "members", "before", "advance", "t0")
+
+    def __init__(self, out, members, before, advance, t0: float):
+        self.out = out            # device ((K, S) tokens, aux); None once read
+        self.members = members    # [(slot index, its _Slot then)]
+        self.before = before      # (S,) lengths the step started from
+        self.advance = advance    # (S,) positions it consumes a slot
+        self.t0 = t0
+
+
 class DecodeLoop:
     """Owns the paged pool, the page tables, the single compiled decode
     step, and the scheduler thread. `submit()` is thread-safe and
@@ -683,17 +782,30 @@ class DecodeLoop:
                 "pairs": np.zeros((cfg.n_layers, cfg.n_held), np.int64),
                 "tokens": 0, "decode_tokens": 0, "decode_pairs": 0,
                 "decode_steps": 0, "experts_touched": 0}
-        self._d_tokens = None       # (S,) int32
-        self._d_table = None        # (S, P) int32
-        self._d_lengths = None      # (S,) int32
-        self._d_stop = None         # (S,) int32
         # host mirrors (scheduler-thread-owned) -----------------------
         self._table = np.full((self.slots, self._pps), self._trash,
                               np.int32)
+        #: the DISPATCHED cursor: a slot's length on the device once
+        #: every enqueued step has run (what was emitted plus what the
+        #: step in flight will consume)
         self._lengths = np.zeros((self.slots,), np.int32)
         self._stop = np.zeros((self.slots,), np.int32)
+        #: a slot's last token as the host knows it: a step behind the
+        #: device while a step is in flight
         self._pending = np.zeros((self.slots,), np.int32)
-        self._dirty = True          # mirrors changed since last upload
+        self._dirty = True          # table or stop changed since upload
+        #: slots whose token and length the HOST sets on the device at
+        #: the next dispatch (admission, retirement, a speculative
+        #: round); every other row of the two is the device's own
+        self._host_rows = np.zeros((self.slots,), bool)
+        #: the decode step enqueued and not read yet (plain lane)
+        self._inflight: Optional[_Step] = None
+        #: when the last step was read, on `time.perf_counter`
+        self._read_at = 0.0
+        self._d_tokens = jnp.zeros((self.slots,), jnp.int32)
+        self._d_lengths = jnp.zeros((self.slots,), jnp.int32)
+        self._d_table = None        # by kind, (S, P) int32
+        self._d_stop = None         # (S,) int32
         self._free: deque = deque(range(self.n_pages))
         self._slot_state: List[Optional[_Slot]] = [None] * self.slots
         #: prefill-group first tokens still on device:
@@ -810,7 +922,16 @@ class DecodeLoop:
                 kernel=self.decode_kernel)
             return jnp.argmax(logits, axis=-1).astype(jnp.int32), pool
 
+        def set_rows(rows, tokens, lengths):
+            """Where the host is the authority over a slot (`rows[0]`),
+            its token (`rows[1]`) and length (`rows[2]`) replace the
+            device's. A mask over all slots: one shape, one program."""
+            host = rows[0] != 0
+            return (jnp.where(host, rows[1], tokens),
+                    jnp.where(host, rows[2], lengths))
+
         donate_copy = () if jax.default_backend() == "cpu" else (0,)
+        self._set_rows = jax.jit(set_rows)
         self._step = jax.jit(step_fn, donate_argnums=donate_step)
         self._verify = jax.jit(verify_fn, donate_argnums=donate_step)
         self._prefill = jax.jit(prefill_fn, donate_argnums=donate_pre)
@@ -876,6 +997,11 @@ class DecodeLoop:
             "dl4j_decode_steps",
             "compiled decode dispatches run (each covers `horizon` "
             "token steps)").labels(**lab)
+        self._m_overlapped = reg.counter(
+            "dl4j_decode_dispatches_overlapped",
+            "decode dispatches enqueued while the step before them was "
+            "still unread: the host's pass ran under the device's step"
+        ).labels(**lab)
         self._m_shed = reg.counter(
             "dl4j_decode_shed",
             "generate requests rejected at submit because the admission "
@@ -952,8 +1078,10 @@ class DecodeLoop:
         self._m_step_s = reg.histogram(
             "dl4j_decode_step_seconds",
             "wall time of one compiled decode dispatch (covers "
-            "`horizon` token steps), dispatch through the token D2H "
-            "sync").labels(**lab)
+            "`horizon` token steps) through its token D2H sync, from "
+            "its dispatch or, where that came later, from the read of "
+            "the step before it: the observations do not overlap, and "
+            "their sum is the time some step was unread").labels(**lab)
         self._phases = PhaseTotals(reg.histogram(
             "dl4j_decode_phase_seconds",
             "wall time of the scheduler's spans by phase: decode.tick "
@@ -967,8 +1095,8 @@ class DecodeLoop:
         ).labels(**lab)
         self._m_prefill_passes = reg.counter(
             "dl4j_decode_prefill_passes",
-            "scheduler passes that ran a decode dispatch and at least "
-            "one prefill: the token gaps a prefill lengthened"
+            "scheduler passes that enqueued a decode dispatch and at "
+            "least one prefill: the token gaps a prefill lengthened"
         ).labels(**lab)
         self._request_ids = itertools.count()
         #: ring of the longest pass per interval (snapshot()
@@ -1161,13 +1289,26 @@ class DecodeLoop:
             moe["experts_touched"] += touched
             self._m_moe_touched.inc(touched)
 
-    def _device_tables(self):
-        """The page tables as the steps take them: a dict by kind."""
+    @staticmethod
+    def _upload(mirror: np.ndarray):
+        """A host mirror onto the device, as a COPY made on the host
+        first. The transfer may read the host's buffer after this call
+        returns (on the CPU the device array may BE that buffer, and
+        `jnp.array` copies on the device, after the same late read), and
+        with a step in flight the scheduler writes on in its mirrors
+        (a grant, a release, a retirement) without waiting for any step
+        that took the upload: a view would hand a step a table or a stop
+        bound from a later pass."""
         import jax.numpy as jnp
 
-        tables = {paged_kinds.KIND_FULL: jnp.asarray(self._table)}
+        return jnp.asarray(mirror.copy())
+
+    def _device_tables(self):
+        """The page tables as the steps take them: a dict by kind, each
+        a copy (`_upload`)."""
+        tables = {paged_kinds.KIND_FULL: self._upload(self._table)}
         if self._win is not None:
-            tables[paged_kinds.KIND_WINDOW] = jnp.asarray(self._win.table)
+            tables[paged_kinds.KIND_WINDOW] = self._upload(self._win.table)
         return tables
 
     # ----------------------------------------------------- public API
@@ -1898,6 +2039,7 @@ class DecodeLoop:
                 "cancelled": int(self._m_cancelled.value),
                 "admission_waits": int(self._m_waits.value),
                 "dispatches": int(self._m_steps.value),
+                "dispatches_overlapped": int(self._m_overlapped.value),
                 "prefill_passes": int(self._m_prefill_passes.value),
                 "phases": self._phases.totals(),
                 "queue_wait": {"seconds": self._m_queue_wait.sum,
@@ -2010,10 +2152,15 @@ class DecodeLoop:
 
     # ------------------------------------------------------ scheduler
     def _idle(self) -> bool:
-        """Nothing queued, installed or in flight. Caller holds the
-        lock."""
+        """Nothing queued, installed or in flight, no step unread.
+        Caller holds the lock."""
         return (not self._closed and not self._waiting
-                and not self._kv_jobs and self.occupied_slots == 0)
+                and not self._kv_jobs and self._drained())
+
+    def _drained(self) -> bool:
+        """No slot occupied and no step enqueued and unread (an
+        end-of-sequence token leaves one behind it)."""
+        return self.occupied_slots == 0 and self._inflight is None
 
     def _run(self) -> None:
         while True:
@@ -2023,7 +2170,7 @@ class DecodeLoop:
                         while self._idle():
                             self._cond.wait(timeout=0.1)
                 if (self._closed and not self._waiting
-                        and self.occupied_slots == 0):
+                        and self._drained()):
                     self._drain_kv_jobs(
                         RuntimeError("decode loop closed"))
                     return
@@ -2039,6 +2186,7 @@ class DecodeLoop:
         self._drain_kv_jobs(exc)
         with self._cond:
             self._deferred = []
+            self._inflight = None  # its tokens have no stream to reach
             for i, slot in enumerate(self._slot_state):
                 if slot is not None:
                     for page in slot.pages:
@@ -2054,16 +2202,16 @@ class DecodeLoop:
 
     def tick(self) -> bool:
         """One scheduler pass: admit what fits, grant boundary pages,
-        run one compiled dispatch if any slot can advance, emit tokens,
-        retire finished slots. Returns True if a dispatch ran. Public so
-        tests (and `start=False` callers) can drive the loop
+        enqueue one compiled dispatch if any slot can advance, then read
+        the dispatch of the pass BEFORE, emit its tokens and retire what
+        finished: a token shows a pass after its step was dispatched.
+        Returns True if a dispatch was enqueued or read. Public so tests
+        (and `start=False` callers) can drive the loop
         deterministically."""
         phases = self._phases
         phases.begin_pass()
         with span(TICK, phases) as tick:
             ran = tick.args["dispatched"] = self._pass()
-        if ran and phases.pass_ns[PREFILL_DISPATCH]:
-            self._m_prefill_passes.inc()
         self._keep_if_slowest(tick)
         return ran
 
@@ -2102,15 +2250,16 @@ class DecodeLoop:
         with span("decode.admit", self._phases) as admit:
             admit.args["admitted"] = self._admit()
         ran = self._dispatch()
-        if not ran:
-            # no chunk ran (e.g. every admitted request has
-            # max_tokens=1): deferred prefill tokens still must reach
-            # their streams
+        if self._deferred:
+            # no step was read in this pass (the first of a busy spell,
+            # or every admitted request has max_tokens=1): the firsts
+            # still go out as soon as their prefill is over, after the
+            # step that overlaid them was enqueued
             self._flush_first_tokens()
         if not ran:
-            # nothing advanced: either idle, or every occupied slot is
-            # starved of pages that can never come — fail those rather
-            # than spin forever
+            # nothing advanced and no step is unread: either idle, or
+            # every occupied slot is starved of pages that can never
+            # come — fail those rather than spin forever
             with self._cond:
                 stuck = (self.occupied_slots > 0
                          and (self._avail_pages() == 0
@@ -2131,7 +2280,7 @@ class DecodeLoop:
         (manual mode / tests)."""
         for _ in range(max_ticks):
             with self._cond:
-                if not self._waiting and self.occupied_slots == 0:
+                if not self._waiting and self._drained():
                     return
             self.tick()
         raise RuntimeError("decode loop did not drain")
@@ -2338,7 +2487,7 @@ class DecodeLoop:
                 self._lengths[idx] = plen - 1
                 self._pending[idx] = stream.prompt[-1]
                 self._stop[idx] = 0  # set by _grant_pages
-                self._dirty = True
+                self._dirty = self._host_rows[idx] = True
         # one compiled prefill per (cached pages, prompt-bucket,
         # batch-bucket) group: an admission burst costs O(groups)
         # dispatches, not O(streams). Cold prompts first, then the warm
@@ -2435,19 +2584,20 @@ class DecodeLoop:
                 self._lengths[idx] = plen
                 self._pending[idx] = 0  # real value still on device
                 self._stop[idx] = 0  # set by _grant_pages
-                self._dirty = True
+                self._dirty = self._host_rows[idx] = True
         self._deferred.append(
             (first, members, sum(plen - cov for *_, plen, cov in group)))
 
     # ---- page granting
     def _grant_pages(self) -> None:
         """Before a dispatch: give every occupied slot pages covering
-        its next advance-window positions (`horizon` plain steps, or
-        the `spec_k`-draft + 1 verify width in speculative mode, capped
-        at its token budget) and set its device `stop` bound to the
-        granted frontier — a slot the pool cannot extend simply stops
-        advancing there. Because the CoW guard fences the WHOLE
-        [length, stop) window, every position a speculative verify may
+        the next advance-window positions past its dispatched cursor
+        (`horizon` plain steps, or the `spec_k`-draft + 1 verify width
+        in speculative mode, capped at its token budget) and set its
+        device `stop` bound to the granted frontier — a slot the pool
+        cannot extend simply stops advancing there. Because the CoW
+        guard fences the WHOLE [length, stop) window, every position a
+        speculative verify may
         write — including draft tokens that get rejected — lands in
         private pages: rollback is just the host cursor not moving."""
         adv = (self.spec_k + 1) if self.spec_k else self.horizon
@@ -2497,7 +2647,17 @@ class DecodeLoop:
         stall-until-a-retirement-frees-pages backpressure as page
         granting. Chaos point `decode.fork` fires inside the fork so
         drills can prove a mid-fork fault leaves page accounting
-        balanced. Caller holds the lock."""
+        balanced. Caller holds the lock.
+
+        With a step in flight `length` is the dispatched cursor. The
+        step in flight wrote this slot at `length - 1`, in a page its own
+        fence made private, so a page found shared here is one no
+        enqueued step writes; the copy is enqueued behind that step, and
+        the page the fork gives up keeps a reader or the cache (it was
+        shared) and never reaches the free list from here. The page the
+        fork TAKES may be one `_alloc_page` evicts from the cache: its
+        only possible reader in flight is a step dispatched for a slot
+        that has retired since, whose tokens reach no stream."""
         import jax.numpy as jnp
 
         ps = self.page_size
@@ -2537,9 +2697,23 @@ class DecodeLoop:
             return self._dispatch_spec()
         return self._dispatch_plain()
 
-    # ---- plain dispatch (horizon token steps)
+    # ---- plain dispatch (horizon token steps), a second step in flight
     def _dispatch_plain(self) -> bool:
-        import jax
+        """Enqueue the next step, THEN read the one before it: the rest
+        of the pass runs under the device's next step. The one order of
+        a pass of the plain lane, for every model and horizon."""
+        step = self._enqueue_step()
+        prev, self._inflight = self._inflight, step
+        if prev is not None:
+            self._read_step(prev)
+        return step is not None or prev is not None
+
+    def _enqueue_step(self) -> Optional[_Step]:
+        """Prepare and enqueue one step against the dispatched cursor
+        (`_lengths`), whether or not the step before it was read: window
+        pages released, pages granted, the fence run, stop bounds set,
+        what changed uploaded, the step called on the device's own
+        tokens, lengths and pool. None where no slot can advance."""
         import jax.numpy as jnp
 
         phases = self._phases
@@ -2547,67 +2721,101 @@ class DecodeLoop:
             self._release_window()
         self._grant_pages()
         with self._cond:
-            runnable = [i for i, s in enumerate(self._slot_state)
-                        if s is not None
-                        and self._stop[i] > self._lengths[i]]
-            if not runnable:
-                return False
+            members = [(i, s) for i, s in enumerate(self._slot_state)
+                       if s is not None
+                       and self._stop[i] > self._lengths[i]]
+            if not members:
+                return None
             before = self._lengths.copy()
+            # what the step consumes a slot, the device's own rule
+            # (`lengths < stop`, `horizon` times): 0 for an idle slot
+            advance = np.clip(self._stop - before, 0, self.horizon)
             with span("decode.upload", phases):
-                if self._dirty or self._d_tokens is None:
-                    self._d_tokens = jnp.asarray(self._pending)
+                if self._host_rows.any():
+                    # the host's mirrors lag a step for every other
+                    # slot: the device is never overwritten from them
+                    self._d_tokens, self._d_lengths = self._set_rows(
+                        np.stack((self._host_rows, self._pending,
+                                  self._lengths)),
+                        self._d_tokens, self._d_lengths)
+                    self._host_rows[:] = False
+                if self._dirty:
                     self._d_table = self._device_tables()
-                    self._d_lengths = jnp.asarray(self._lengths)
-                    self._d_stop = jnp.asarray(self._stop)
+                    self._d_stop = self._upload(self._stop)
                     self._dirty = False
                 # overlay deferred prefill tokens (still
                 # device-resident) into the feedback array — ONE scatter
                 # per prefill group, no sync
-                for (arr, _aux), members, _n in self._deferred:
-                    rows = jnp.asarray([r for r, _ in members])
-                    idxs = jnp.asarray([i for _, i in members])
+                for (arr, _aux), group, _n in self._deferred:
+                    rows = jnp.asarray([r for r, _ in group])
+                    idxs = jnp.asarray([i for _, i in group])
                     self._d_tokens = self._d_tokens.at[idxs].set(
                         arr[rows])
+            self._lengths += advance
         t0 = time.perf_counter()
         self._plan_step = True
-        with span("decode.step_dispatch", phases, runnable=len(runnable)):
-            (toks, aux), t_out, l_out, self._pool = self._step(
+        with span("decode.step_dispatch", phases, runnable=len(members)):
+            out, self._d_tokens, self._d_lengths, self._pool = self._step(
                 self.params, self._d_tokens, self._pool, self._d_table,
                 self._d_lengths, self._d_stop)
+        self._count_dispatch()
+        if self._inflight is not None:
+            self._m_overlapped.inc()
+        return _Step(out, members, before, advance, t0)
+
+    def _count_dispatch(self) -> None:
+        """A dispatch was enqueued in this pass; with a prefill enqueued
+        in the same pass it is one whose token gap the prefill
+        lengthens."""
         self._m_steps.inc()
+        if self._phases.pass_ns[PREFILL_DISPATCH]:
+            self._m_prefill_passes.inc()
+
+    def _read_step(self, step: _Step) -> None:
+        """Read an enqueued step's tokens, account for it, flush first
+        tokens, emit its tokens and retire what finished."""
+        import jax
+
+        phases = self._phases
         # the (K, S) token D2H is the sync the streams need anyway; what
         # the layers count travels beside it, and nothing more is read
-        # where they count nothing. The names are REBOUND, and no other
-        # name may keep a device array of the step: held to the end of
-        # the pass, its release cost 0.6 ms (sat) to 1.3 ms (ep8) a pass
-        # outside every span (PERF.md section 6, PR 32).
+        # where they count nothing. With a later step enqueued this
+        # waits only for what is left of `step`. The handle is given up
+        # INSIDE the span, and no other name may keep a device array of
+        # a step: held to the end of the pass, its release cost 0.6 ms
+        # (sat) to 1.3 ms (ep8) a pass outside every span (PERF.md
+        # section 6, PR 32).
         with span("decode.d2h", phases):
-            toks, aux = jax.device_get((toks, aux))
-        self._m_step_s.observe(time.perf_counter() - t0)
-        self._d_tokens, self._d_lengths = t_out, l_out
+            toks, aux = jax.device_get(step.out)
+            step.out = None
+        # from the read of the step before, where the two overlapped:
+        # the sum stays the time some step was unread
+        now = time.perf_counter()
+        self._m_step_s.observe(now - max(step.t0, self._read_at))
+        self._read_at = now
         # per-token-step KV read accounting, host math mirroring the
         # device chain: inner step k runs at cursor before+k, clamped
         # at each slot's stop bound (stalled/idle slots hold still).
         # Both figures are recorded each dispatch — the selected lane
         # is in snapshot()["decode_kernel"]
         with span("decode.account", phases):
-            advance = np.maximum(self._stop - before, 0)
             for k in range(self.horizon):
                 if self._moe is not None:
-                    self._count_pairs(aux[k], len(runnable), decode=True)
-                self._count_read_bytes(before + np.minimum(k, advance))
+                    self._count_pairs(aux[k], len(step.members),
+                                      decode=True)
+                self._count_read_bytes(
+                    step.before + np.minimum(k, step.advance))
         self._flush_first_tokens()  # emit firsts BEFORE chunk tokens
         with span("decode.emit", phases) as emit:
             emitted = 0
-            for i in runnable:
-                slot = self._slot_state[i]
-                if slot is None:  # retired at flush (eos on first token)
+            for i, slot in step.members:
+                # retired since the step was enqueued (an end of
+                # sequence a step ago or at the flush, a cancel, a
+                # deadline, a preemption), the slot maybe taken again:
+                # the step's tokens for it reach no stream
+                if self._slot_state[i] is not slot:
                     continue
-                consumed = min(self.horizon,
-                               int(self._stop[i] - before[i]))
-                with self._cond:
-                    self._lengths[i] = before[i] + consumed
-                for j in range(consumed):
+                for j in range(int(step.advance[i])):
                     tok = int(toks[j, i])
                     self._pending[i] = tok
                     slot.emitted += 1
@@ -2616,13 +2824,15 @@ class DecodeLoop:
                     if self._slot_state[i] is None:
                         break  # retired: discard speculative overshoot
             emit.args["tokens"] = emitted
-        return True
 
     def _release_window(self) -> None:
         """Before the grants of a pass: return to the window kind's
         free list every page whose last key the slot's next query no
-        longer sees; the table takes the trash page in its place. The
-        pages a pass returns are the pass's to grant."""
+        longer sees (the query at the dispatched cursor); the table
+        takes the trash page in its place. The pages a pass returns are
+        the pass's to grant. The step in flight may still read them:
+        whatever writes them next is enqueued behind it, and the device
+        runs its programs in order."""
         with span(RELEASE_WINDOW, self._phases) as rel, self._cond:
             n = 0
             for i, slot in enumerate(self._slot_state):
@@ -2684,7 +2894,7 @@ class DecodeLoop:
         # now — same firsts-before-chunk order as the plain lane
         if self._deferred:
             self._flush_first_tokens()
-            self._dirty = True  # firsts never reached the device carry
+            self._host_rows[:] = True  # firsts never reached the carry
         self._grant_pages()
         with self._cond:
             runnable = [i for i, s in enumerate(self._slot_state)
@@ -2737,8 +2947,13 @@ class DecodeLoop:
         if not proposals:
             # nothing drafted — run the plain width-1 chain instead so
             # an idle/unluckly round costs exactly what it always did
-            # and the plain program stays warm
-            return self._dispatch_plain()
+            # and the plain program stays warm. This lane stays in turn
+            # (the drafter reads a slot's tokens on the host before it
+            # can propose), so the step is read at once
+            step = self._enqueue_step()
+            if step is not None:
+                self._read_step(step)
+            return step is not None
         for i, prop in proposals.items():
             n = len(prop)
             tokens[i, 1:1 + n] = prop
@@ -2753,7 +2968,7 @@ class DecodeLoop:
             out, self._pool = self._verify(
                 self.params, d_tokens, self._pool, d_table, d_before,
                 d_widths)
-        self._m_steps.inc()
+        self._count_dispatch()
         self._m_spec_rounds.inc()
         with span("decode.d2h", phases):
             out = np.asarray(out)  # (S, W) argmax — the sync streams need
@@ -2788,20 +3003,27 @@ class DecodeLoop:
                         break  # retired (eos/budget): overshoot discarded
             emit.args["tokens"] = emitted
         # host cursors moved without touching the plain device carry —
-        # any later plain-lane dispatch must re-upload
-        self._dirty = True
+        # a later plain-lane dispatch sets every row from the host's
+        self._host_rows[:] = True
         return True
 
     def _flush_first_tokens(self) -> None:
-        """Read deferred prefill tokens (one D2H per prefill group —
-        the compute is long finished) and emit them."""
+        """Read deferred prefill tokens (one D2H per prefill group) and
+        emit them. The prefill was enqueued behind the step this pass
+        read, so the read waits for it: a wait on the device like the
+        step's own, under the same span, so that `decode.tick` less
+        `decode.d2h` stays the host's own time."""
         import jax
 
         deferred, self._deferred = self._deferred, []
+        firsts = []
+        if deferred:
+            with span("decode.d2h", self._phases):
+                firsts = jax.device_get([first for first, *_ in deferred])
         with span("decode.flush_first", self._phases,
                   groups=len(deferred)):
-            for (first, aux), members, n_tokens in deferred:
-                host, aux = np.asarray(first), jax.device_get(aux)
+            for (_, members, n_tokens), (host, aux) in zip(deferred,
+                                                           firsts):
                 if self._moe is not None:
                     self._count_pairs(aux, n_tokens, decode=False)
                 for row, i in members:
@@ -2830,9 +3052,13 @@ class DecodeLoop:
         with self._cond:
             self._slot_state[idx] = None
             self._table[idx, :] = self._trash
+            # length back to 0 on the device too, whatever a step in
+            # flight makes of it: an idle slot reads one trash block and
+            # not its old context
             self._lengths[idx] = 0
             self._stop[idx] = 0
             self._pending[idx] = 0
+            self._host_rows[idx] = True
             if (self._prefix is not None and slot.stream.prefix_cache
                     and reason in ("eos", "max_tokens", "preempted")):
                 # seed the cache with the FULL prompt pages only —
@@ -2847,6 +3073,10 @@ class DecodeLoop:
                 self._prefix.insert(slot.stream.prompt,
                                     slot.pages[:n_full],
                                     skip=slot.no_cache)
+            # a step in flight may still read these pages, or write the
+            # one position an end-of-sequence token came too late to
+            # stop (in the slot's own page: the fence made it private):
+            # whatever writes them next is enqueued behind that step
             for page in slot.pages:
                 self._release_page(page)
             if self._win is not None:

@@ -286,8 +286,11 @@ def test_counters_of_pages_by_kind_and_of_pairs_and_the_release_span():
     assert moe_snap["experts_touched"] <= 16 * moe_snap["decode_steps"]
     # pairs a token a layer: 4 chosen of 16, 4 held -> about 1
     assert 0.5 < moe_snap["pairs"] / (4 * moe_snap["tokens"]) < 1.6
+    # a pass releases before it prepares a step: once a dispatch, and
+    # once more in the last pass, which found no slot to advance and
+    # only read the step in flight
     rel = snap["phases"]["decode.release_window"]
-    assert rel["count"] == snap["dispatches"] > 0
+    assert rel["count"] == snap["dispatches"] + 1 > 1
     released = snap["pages_by_kind"]["window"]["released"]
     assert released > 0
     text = exposition.render_prometheus()
