@@ -1,0 +1,354 @@
+"""The gated delta rule as two Pallas TPU kernels: the chunked scan of a
+prefill (`gdn_scan`) and the one-token state update of a decode step
+(`gdn_update`).
+
+The recurrence, a value head (state `S` (dk, dv) float32, zero at the
+start of a sequence; q and k already normalised, q scaled):
+
+    S' = exp(g_t) S_{t-1}
+    S_t = S' + k_t (beta_t (v_t - S'^T k_t))^T
+    o_t = S_t^T q_t
+
+`gdn_update` is that, once: grid `(slots, heads / block)`, the state
+block read once and written once in place (`input_output_aliases`), the
+arithmetic on the VPU in float32. k and q arrive with positions along
+sublanes (`kq_t`, one (dk, 128) tile a slot and head block: lane h is
+head h's k, lane `block + h` its q), because a column of the state is
+scaled by them and a kernel cannot turn a row into a column for free;
+a lane is spread over all lanes by a product with a one-hot matrix,
+which is exact.
+
+`gdn_scan` is the chunked form (Gated DeltaNet, arXiv:2412.06464, the
+WY / UT transform): inside a chunk of C tokens everything is products,
+between chunks only the state is carried. With `gc` the running sum of g
+inside the chunk, `L[i, j] = exp(gc_i - gc_j)` for i >= j (never above
+1), `A = strictly_lower((k beta) k^T * L)` and `T = (I + A)^-1`:
+
+    u = T (v beta);  w = T (k beta exp(gc))
+    v_new = u - w S
+    o = (q exp(gc)) S + lower((q k^T) * L) v_new
+    S <- S exp(gc_C) + (k exp(gc_C - gc))^T v_new
+
+`T` comes from products alone (`_unit_lower_inverse`): the diagonal
+blocks of 16 by the finite Neumann product (their strictly lower part
+is nilpotent of order 16, so intermediate powers grow by at most
+C(15, 7) = 6,435 even where every key is the same), the blocks below
+them by the exact block identity `(D + E)^-1 = (I + D^-1 E)^-1 D^-1`,
+whose Neumann product has two factors. Grid `(rows x heads / block,
+chunks)`: the chunk axis is sequential and carries the state in a VMEM
+scratch; `block` heads a step are independent chains of small products
+for the four MXUs to interleave. Operands of the products are in the
+type the call came in (bfloat16 when serving, float32 accumulation; in
+float32 every product asks for the full contraction); decays, the
+inverse's sums and the state are float32.
+
+Padding must not move the state: past a row's real length the caller
+passes beta = 0 and g = 0, under which a token leaves S as it is, so
+the state after the last chunk is the state at the last real token.
+
+On a TPU, or with `interpret=True` (the CPU test lane), the kernels;
+elsewhere the same chunk arithmetic (`_chunk`) under `vmap` and
+`lax.scan`, and the update as plain products.
+"""
+
+from __future__ import annotations
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+
+__all__ = ["gdn_scan", "gdn_update", "CHUNK"]
+
+#: tokens of a chunk of the scan
+CHUNK = 64
+#: the diagonal blocks whose inverse is a finite Neumann product
+_INV_BLOCK = 16
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _prec(dtype):
+    """float32 operands ask for the full contraction (tests hold the
+    scan to the token-by-token recurrence); narrower ones take the
+    MXU's single pass with float32 accumulation."""
+    return _HIGHEST if jnp.dtype(dtype).itemsize >= 4 else None
+
+
+def _mm(a, b, prec):
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32,
+                               precision=prec)
+
+
+def _mm_nt(a, b, prec):
+    """a @ b^T."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32,
+                               precision=prec)
+
+
+def _mm_tn(a, b, prec):
+    """a^T @ b."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32,
+                               precision=prec)
+
+
+def _unit_lower_inverse(a, row, col, cd, prec):
+    """(I + a)^-1 for a strictly lower (C, C) float32, by products
+    alone (the module's doc-string has the identities). `cd` is the
+    type the products' operands take."""
+    c = a.shape[0]
+    eye = (row == col).astype(jnp.float32)
+
+    def mm(x, y):
+        return _mm(x.astype(cd), y.astype(cd), prec)
+
+    def neumann(m, order):
+        """sum_k m^k for m nilpotent of `order`: prod (I + m^(2^j))."""
+        x, p, reach = eye + m, m, 2
+        while reach < order:
+            p = mm(p, p)
+            x = x + mm(x, p)
+            reach *= 2
+        return x
+
+    if c <= _INV_BLOCK:
+        return neumann(-a, c)
+    shift = _INV_BLOCK.bit_length() - 1
+    same = jnp.right_shift(row, shift) == jnp.right_shift(col, shift)
+    d_inv = neumann(-jnp.where(same, a, 0.0), _INV_BLOCK)
+    n = mm(d_inv, jnp.where(same, 0.0, a))
+    return mm(neumann(-n, c // _INV_BLOCK), d_inv)
+
+
+def _chunk(q, k, v, gc, beta, s, prec):
+    """One chunk of one head: q, k (C, dk) and v (C, dv) in the call's
+    type, gc and beta (1, C) float32 (gc the running sum of g inside
+    the chunk), s (dk, dv) float32. Returns (o (C, dv) float32, the
+    state after the chunk)."""
+    c, cd, f32 = q.shape[0], q.dtype, jnp.float32
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+
+    def column(r):
+        """(1, C) -> (C, 1): the diagonal of the row spread over rows,
+        summed along lanes."""
+        return jnp.sum(jnp.where(row == col, r, 0.0), axis=1,
+                       keepdims=True)
+
+    g_col, b_col = column(gc), column(beta)
+    decay = jnp.exp(jnp.where(row >= col, g_col - gc, -1e30))  # i >= j
+    kb = k.astype(f32) * b_col
+    a = jnp.where(row > col, _mm_nt(kb.astype(cd), k, prec) * decay, 0.0)
+    t = _unit_lower_inverse(a, row, col, cd, prec).astype(cd)
+    e_gc = jnp.exp(g_col)
+    u = _mm(t, (v.astype(f32) * b_col).astype(cd), prec)
+    w = _mm(t, (kb * e_gc).astype(cd), prec)
+    s_cd = s.astype(cd)
+    v_new = u - _mm(w.astype(cd), s_cd, prec)
+    v_cd = v_new.astype(cd)
+    att = (_mm_nt(q, k, prec) * decay).astype(cd)
+    o = _mm((q.astype(f32) * e_gc).astype(cd), s_cd, prec) \
+        + _mm(att, v_cd, prec)
+    g_last = jnp.min(gc, axis=1, keepdims=True)      # g <= 0: the last
+    kd = (k.astype(f32) * jnp.exp(g_last - g_col)).astype(cd)
+    return o, s * jnp.exp(g_last) + _mm_tn(kd, v_cd, prec)
+
+
+# ------------------------------------------------------------ the scan
+def _scan_kernel(q_ref, k_ref, v_ref, gb_ref, o_ref, s_ref, s_scr, *,
+                 heads: int, prec):
+    from jax.experimental import pallas as pl
+
+    ci = pl.program_id(1)
+
+    @pl.when(ci == 0)
+    def _init():
+        s_scr[...] = jnp.zeros_like(s_scr)
+
+    for h in range(heads):
+        gb = gb_ref[h, 0]                                # (2, C) f32
+        o, s = _chunk(q_ref[h], k_ref[h], v_ref[h], gb[0:1], gb[1:2],
+                      s_scr[h], prec)
+        o_ref[h] = o.astype(o_ref.dtype)
+        s_scr[h] = s
+
+    @pl.when(ci == pl.num_programs(1) - 1)
+    def _last():
+        s_ref[...] = s_scr[...]
+
+
+def _head_block(n: int, most: int) -> int:
+    b = min(n, most)
+    while n % b:
+        b -= 1
+    return b
+
+
+@partial(jax.jit, static_argnames=("chunk", "interpret", "kernel"))
+def _gdn_scan(q, k, v, g, beta, *, chunk, interpret, kernel):
+    b, h, t, dk = q.shape
+    dv = v.shape[-1]
+    n = t // chunk
+    bh = b * h
+    gc = jnp.cumsum(g.astype(jnp.float32).reshape(bh, n, chunk), axis=-1)
+    gb = jnp.stack([gc, beta.astype(jnp.float32).reshape(bh, n, chunk)],
+                   axis=2)                               # (BH, N, 2, C)
+    q, k, v = (a.reshape(bh, t, a.shape[-1]) for a in (q, k, v))
+    prec = _prec(q.dtype)
+    if not kernel:
+        def one_head(q, k, v, gb):
+            def step(s, x):
+                o, s = _chunk(x[0], x[1], x[2], x[3][0:1], x[3][1:2], s,
+                              prec)
+                return s, o
+            s, o = jax.lax.scan(
+                step, jnp.zeros((dk, dv), jnp.float32),
+                (q.reshape(n, chunk, dk), k.reshape(n, chunk, dk),
+                 v.reshape(n, chunk, dv), gb))
+            return o.reshape(t, dv), s
+        o, s = jax.vmap(one_head)(q, k, v, gb)
+        return (o.astype(v.dtype).reshape(b, h, t, dv),
+                s.reshape(b, h, dk, dv))
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    hb = _head_block(bh, 8)
+
+    def rows(d):
+        return pl.BlockSpec((hb, chunk, d), lambda i, c: (i, c, 0),
+                            memory_space=pltpu.VMEM)
+
+    o, s = pl.pallas_call(
+        partial(_scan_kernel, heads=hb, prec=prec),
+        grid=(bh // hb, n),
+        in_specs=[rows(dk), rows(dk), rows(dv),
+                  pl.BlockSpec((hb, 1, 2, chunk),
+                               lambda i, c: (i, c, 0, 0),
+                               memory_space=pltpu.VMEM)],
+        out_specs=(rows(dv),
+                   pl.BlockSpec((hb, dk, dv), lambda i, c: (i, 0, 0),
+                                memory_space=pltpu.VMEM)),
+        out_shape=(jax.ShapeDtypeStruct((bh, t, dv), v.dtype),
+                   jax.ShapeDtypeStruct((bh, dk, dv), jnp.float32)),
+        scratch_shapes=[pltpu.VMEM((hb, dk, dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="gdn_scan",
+    )(q, k, v, gb)
+    return o.reshape(b, h, t, dv), s.reshape(b, h, dk, dv)
+
+
+def gdn_scan(q, k, v, g, beta, *, chunk: int = CHUNK,
+             interpret: bool = False):
+    """The recurrence over whole sequences from a zero state: q, k
+    (B, H, T, dk) and v (B, H, T, dv) in one type, g (log decay, <= 0)
+    and beta (B, H, T) float32, T a multiple of `chunk`. Returns (o
+    (B, H, T, dv) in v's type, the state after the last token (B, H,
+    dk, dv) float32). Tokens with g = 0 and beta = 0 leave the state as
+    it is (padding)."""
+    t = q.shape[2]
+    if t % chunk:
+        raise ValueError(f"{t} tokens are no whole chunks of {chunk}")
+    return _gdn_scan(q, k, v, g, beta, chunk=int(chunk),
+                     interpret=bool(interpret),
+                     kernel=bool(interpret)
+                     or jax.default_backend() == "tpu")
+
+
+# ---------------------------------------------------------- the update
+def _update_kernel(eg_ref, beta_ref, kq_ref, v_ref, s_in, o_ref, s_out, *,
+                   heads: int, total: int):
+    from jax.experimental import pallas as pl
+
+    si, bi = pl.program_id(0), pl.program_id(1)
+    kq = kq_ref[0, 0]                                  # (dk, 128)
+    v = v_ref[0]                                       # (heads, dv)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (kq.shape[1], v.shape[-1]),
+                                    0)
+
+    def column(j):
+        """Lane j of `kq` on every lane, (dk, dv) float32: a product
+        with a one-hot matrix, exact because one operand is 0 and 1 (a
+        lane slice spread by broadcast took the kernel 1.17 ms a layer
+        at 64 slots on a v5e, this 0.41)."""
+        return _mm(kq, (lane == j).astype(kq.dtype), _prec(kq.dtype))
+
+    for h in range(heads):
+        at = si * total + bi * heads + h
+        s = s_in[0, h] * eg_ref[at]                    # (dk, dv)
+        k_col, q_col = column(h), column(heads + h)
+        mem = jnp.sum(s * k_col, axis=0, keepdims=True)       # (1, dv)
+        delta = (v[h:h + 1].astype(jnp.float32) - mem) * beta_ref[at]
+        s = s + k_col * delta
+        s_out[0, h] = s
+        o_ref[0, h:h + 1] = jnp.sum(s * q_col, axis=0, keepdims=True)
+
+
+@partial(jax.jit, static_argnames=("interpret", "kernel"))
+def _gdn_update(state, q, k, v, g, beta, *, interpret, kernel):
+    n, h, dk, dv = state.shape
+    f32 = jnp.float32
+    cd = jnp.result_type(q.dtype, k.dtype)
+    q, k, v32 = q.astype(f32), k.astype(f32), v.astype(f32)
+    eg, beta = jnp.exp(g.astype(f32)), beta.astype(f32)
+    if not kernel:
+        s = state * eg[..., None, None]
+        mem = jnp.einsum("nhkv,nhk->nhv", s, k, precision=_HIGHEST)
+        delta = (v32 - mem) * beta[..., None]
+        s = s + k[..., :, None] * delta[..., None, :]
+        return jnp.einsum("nhkv,nhk->nhv", s, q, precision=_HIGHEST), s
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    hb = _head_block(h, 16)
+    nb = h // hb
+    # positions along sublanes, heads along lanes: lane j < hb is head
+    # j's k, lane hb + j its q, the rest of the 128 lanes zero
+    kq = jnp.concatenate([k.reshape(n, nb, hb, dk),
+                          q.reshape(n, nb, hb, dk)], axis=2)
+    kq_t = jnp.pad(kq.transpose(0, 1, 3, 2),
+                   ((0, 0), (0, 0), (0, 0), (0, 128 - 2 * hb))).astype(cd)
+    o, state = pl.pallas_call(
+        partial(_update_kernel, heads=hb, total=h),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(n, nb),
+            in_specs=[
+                pl.BlockSpec((1, 1, dk, 128),
+                             lambda s, b, *_: (s, b, 0, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((1, hb, dv), lambda s, b, *_: (s, b, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((1, hb, dk, dv),
+                             lambda s, b, *_: (s, b, 0, 0),
+                             memory_space=pltpu.VMEM)],
+            out_specs=(
+                pl.BlockSpec((1, hb, dv), lambda s, b, *_: (s, b, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((1, hb, dk, dv),
+                             lambda s, b, *_: (s, b, 0, 0),
+                             memory_space=pltpu.VMEM))),
+        out_shape=(jax.ShapeDtypeStruct((n, h, dv), f32),
+                   jax.ShapeDtypeStruct(state.shape, f32)),
+        # operand 4 (after the two scalar operands, kq_t and v) is the
+        # state: its buffer is the new state's
+        input_output_aliases={4: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="gdn_update",
+    )(eg.reshape(-1), beta.reshape(-1), kq_t, v32, state)
+    return o, state
+
+
+def gdn_update(state, q, k, v, g, beta, *, interpret: bool = False):
+    """One token a slot: state (N, H, dk, dv) float32, q and k (N, H,
+    dk), v (N, H, dv), g and beta (N, H). Returns (o (N, H, dv)
+    float32, the new state, which on a TPU is the old one's buffer: the
+    caller donates it). g = 0 and beta = 0 leave a slot's state as it
+    is, bit for bit (an idle slot)."""
+    return _gdn_update(state, q, k, v, g, beta, interpret=bool(interpret),
+                       kernel=bool(interpret)
+                       or jax.default_backend() == "tpu")

@@ -439,8 +439,9 @@ def resolve_decode_kernel(kernel: str, cfg, page_size: int) -> str:
     - "pallas": the kernel; off-TPU this raises unless `cfg.interpret`
       is set (tests run the kernel code path through the interpreter —
       production must never fall into that silently).
-    - "auto": the kernel on TPU for hd <= 128, a <= 4-byte KV dtype
-      and page_size >= 8; everything else takes the gather path.
+    - "auto": the kernel on TPU for hd <= 128, and for hd 256 on
+      pages of 128 tokens and more, a <= 4-byte KV dtype and page_size
+      >= 8; everything else takes the gather path.
       That envelope is what has been CHECKED on a v5e (PR 21,
       tests/test_tpu_lane.py): H8 x hd128 and H16 x hd64, f32 (1e-5
       against a float64 dense reference) and bf16 (2e-2), page sizes 8
@@ -450,7 +451,12 @@ def resolve_decode_kernel(kernel: str, cfg, page_size: int) -> str:
       f32, 8 pages a block, and 32 query heads over 4 K/V heads with
       a window over 33 columns (2e-2 / 1e-4 against the float64
       reference, cursors on every edge of a page, a block and the
-      table). The chip refused nothing in it. Outside it the kernel
+      table); PR 35: S64 x 16 query heads over 2 K/V heads of 256 (8
+      rows a K/V head, one 128 KB K page a block), pages of 128 over 64
+      columns, bf16 and f32 (2e-2 / 1e-4 against the float64 reference,
+      cursors on the edges of a page and the table): where the dense
+      gather of 64 slots x 64 columns would move 1.1 GB a full layer a
+      step. The chip refused nothing in it. Outside it the kernel
       also compiles (hd 16..256, page sizes 1..128 were compiled for
       v5e, not run), but sub-tile pages pad every K/V block to a full
       (8|16, 128) tile and nothing there has been compared on a chip,
@@ -476,6 +482,9 @@ def resolve_decode_kernel(kernel: str, cfg, page_size: int) -> str:
     if not on_tpu:
         return "gather"
     itemsize = jnp.dtype(cfg.dtype).itemsize
-    if cfg.head_dim > 128 or itemsize > 4 or page_size < 8:
+    if itemsize > 4 or page_size < 8:
+        return "gather"
+    if cfg.head_dim > 128 and not (cfg.head_dim == 256
+                                   and page_size >= 128):
         return "gather"
     return "pallas"

@@ -11,7 +11,8 @@ from deeplearning4j_tpu.models.pretrain import (  # noqa: F401
 )
 from deeplearning4j_tpu.models.conv import ConvolutionDownSampleLayer  # noqa: F401
 from deeplearning4j_tpu.models.lstm import LSTM  # noqa: F401
-from deeplearning4j_tpu.models import moe_transformer, transformer
+from deeplearning4j_tpu.models import (hybrid_transformer, moe_transformer,
+                                       transformer)
 from deeplearning4j_tpu.models.transformer import (  # noqa: F401
     TransformerConfig,
     init_transformer_params,
@@ -20,6 +21,10 @@ from deeplearning4j_tpu.models.transformer import (  # noqa: F401
 from deeplearning4j_tpu.models.moe_transformer import (  # noqa: F401
     MoEConfig,
     init_moe_params,
+)
+from deeplearning4j_tpu.models.hybrid_transformer import (  # noqa: F401
+    HybridConfig,
+    init_hybrid_params,
 )
 
 
@@ -31,7 +36,8 @@ def model_of(cfg):
     under their `attend` callbacks (`models/transformer.Attend`) and ask
     nothing else of a model."""
     for config_type, module in ((TransformerConfig, transformer),
-                                (MoEConfig, moe_transformer)):
+                                (MoEConfig, moe_transformer),
+                                (HybridConfig, hybrid_transformer)):
         if isinstance(cfg, config_type):
             return module
     raise TypeError(f"no language model runs a {type(cfg).__name__}")

@@ -79,6 +79,12 @@ class MoEConfig(NamedTuple):
     dtype: Any = jnp.float32
     #: interpret-mode pallas for CPU tests (kernels AND grouped matmul)
     interpret: bool = False
+    #: what `expert_layer` reads of any configuration beside the counts:
+    #: how the router scores ("sigmoid", or "softmax" over all experts)
+    #: and how the shared experts join ("average": their mean;
+    #: "sigmoid_gate": each behind `sigmoid(h w_s)`, `p["shared_gate"]`)
+    router_score: str = "sigmoid"
+    shared_combine: str = "average"
 
     @property
     def n_layers(self) -> int:
@@ -197,7 +203,7 @@ def grouped_matmul(lhs, rhs, group_sizes, out_dtype, interpret=False):
                               preferred_element_type=out_dtype)
 
 
-def _pair_chunk(t: int, cfg: MoEConfig) -> Tuple[int, int]:
+def _pair_chunk(t: int, cfg) -> Tuple[int, int]:
     """(most pairs that can fall on held experts, rows of the sorted
     pairs taken at a time) for `t` tokens: a chunk is twice the pairs
     expected on this share, at least 256, never more than the most."""
@@ -207,9 +213,18 @@ def _pair_chunk(t: int, cfg: MoEConfig) -> Tuple[int, int]:
     return most, min(most, max(256, -(-2 * expected // 256) * 256))
 
 
-def expert_layer(p, h, cfg: MoEConfig, valid=None):
+ROUTER_SCORES = {"sigmoid": jax.nn.sigmoid,
+                 "softmax": lambda x: jax.nn.softmax(x, axis=-1)}
+SHARED_COMBINES = ("average", "sigmoid_gate")
+
+
+def expert_layer(p, h, cfg, valid=None):
     """Router, choice, grouped expert products over the pairs on held
-    experts, weighted sum; shared experts as plain products, averaged.
+    experts, weighted sum; shared experts as plain products, joined as
+    the configuration says. The one expert layer of every model that
+    has one: `cfg` is any configuration with the counts (`n_experts`,
+    `experts_per_token`, `n_held`, `held_first`, `n_shared`) and the
+    two choices `router_score` and `shared_combine`.
 
     h: (T, d) normed activations; `valid` (T,) bool marks the tokens
     that are real (padding rows and idle slots route nowhere and count
@@ -220,7 +235,7 @@ def expert_layer(p, h, cfg: MoEConfig, valid=None):
     if valid is None:
         valid = jnp.ones((t,), bool)
     with jax.named_scope("moe_router"):
-        scores = jax.nn.sigmoid(jnp.dot(
+        scores = ROUTER_SCORES[cfg.router_score](jnp.dot(
             h.astype(jnp.float32), p["router"].astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST))          # (T, E) f32
         top, chosen = jax.lax.top_k(scores, k)
@@ -269,13 +284,23 @@ def expert_layer(p, h, cfg: MoEConfig, valid=None):
                 0, -(-n_pairs // chunk),
                 lambda c, out: take(c * chunk, out), routed)
     with jax.named_scope("moe_shared"):
+        if cfg.shared_combine not in SHARED_COMBINES:
+            raise ValueError(f"shared_combine must be one of "
+                             f"{SHARED_COMBINES}, got "
+                             f"{cfg.shared_combine!r}")
         sh = p["shared"]
+        gated = cfg.shared_combine == "sigmoid_gate"
+        if gated:
+            opened = jax.nn.sigmoid(jnp.dot(
+                h, p["shared_gate"], preferred_element_type=jnp.float32))
         shared = jnp.zeros((t, d), jnp.float32)
         for j in range(cfg.n_shared):
             act = jax.nn.silu(h @ sh["gate"][j]) * (h @ sh["up"][j])
-            shared = shared + jnp.dot(
-                act, sh["down"][j], preferred_element_type=jnp.float32)
-        shared = shared / cfg.n_shared
+            out = jnp.dot(act, sh["down"][j],
+                          preferred_element_type=jnp.float32)
+            shared = shared + (out * opened[:, j:j + 1] if gated else out)
+        if not gated:
+            shared = shared / cfg.n_shared
     return routed + shared, counts
 
 

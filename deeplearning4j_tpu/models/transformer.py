@@ -32,6 +32,10 @@ from deeplearning4j_tpu.attention.flash_pallas import flash_attention
 KIND_FULL = "full"
 KIND_WINDOW = "window"
 KINDS = (KIND_FULL, KIND_WINDOW)
+#: a layer that keeps no keys at all: a recurrent state and a few
+#: columns a SEQUENCE, whatever its length (models/hybrid_transformer.py).
+#: Not among `KINDS`, which are the kinds of PAGE a cache hands out
+KIND_LINEAR = "linear"
 
 #: `attend(layer, kind, q, k, v) -> (att, the cache's new state for this
 #: layer)`: the one thing a block leaves to its caller. q is (B, Hq, T,
@@ -41,7 +45,10 @@ KINDS = (KIND_FULL, KIND_WINDOW)
 #: what each query reads of what is visible to it, (B, Hq, T, hd). The
 #: uncached forward, the contiguous cache (serving/kv_cache.py) and the
 #: paged cache (serving/paged_kinds.py) are a model's one block under
-#: their callbacks.
+#: their callbacks. A `KIND_LINEAR` layer has no K/V rows: it hands the
+#: callback its pre-convolution columns (B, T, C), its two gates and the
+#: convolution's weights in the three places, and gets its mixer's rows
+#: (B, T, Hv, dv) and the layer's new cache entry back.
 Attend = Callable[[int, str, Any, Any, Any], Tuple[Any, Any]]
 
 
@@ -305,6 +312,7 @@ def generate(params, prompt, cfg: TransformerConfig, n_tokens: int,
 
 
 __all__ = ["TransformerConfig", "KINDS", "KIND_FULL", "KIND_WINDOW",
+           "KIND_LINEAR",
            "init_transformer_params", "causal_attention", "visible",
            "block", "forward", "head", "transformer_logits", "lm_loss",
            "make_train_step", "init_velocity", "fit_scan", "generate"]

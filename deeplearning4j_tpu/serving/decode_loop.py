@@ -250,7 +250,23 @@ held expert where the configuration has an expert layer
 the tokens make anyway. What the host's side cannot do yet for what a
 model has is an error at construction that names it (`_check_refusals`):
 prefix sharing, speculation, a horizon above 1 and the prefill role, for
-a window kind (it gives pages back) or an expert layer's counts.
+a window kind (it gives pages back), an expert layer's counts, or a
+linear kind.
+
+**A kind that is not pages.** A `linear` layer
+(`models/hybrid_transformer.py`) keeps a recurrent state and a few
+convolution columns a sequence, however long: arrays indexed by SLOT in
+the same donated pool, `(slots, ...)` a layer. It needs no grant, no
+table and no free list: admission is bounded by slots, and by the full
+kind's pages as ever. A cold prefill is told each row's slot beside its
+pages (`page_ids["linear"]`) and overwrites that slot's state whole, so
+a retired slot's state never reaches the next request; an idle slot's
+update is masked in the step. Whatever reuses a page would need the
+state at that page's end, which nothing keeps: prefix sharing (and with
+it copy-on-write and `/kv/export`), speculation, a horizon above 1 and
+the prefill role are refused by name. Preemption retires the slot and
+the router re-admits prompt + delivered: an honest second scan.
+`snapshot()["state"]` says what the kind holds.
 
 **A bound on the tokens a pass prefills** (`prefill_tokens_per_pass`,
 None = no bound): a pass stops claiming queued requests once the rows it
@@ -360,6 +376,53 @@ SLOW_TICK_INTERVAL_S = 8
 #: batch items long bulk rows — a deep batch backlog should tell its
 #: client to come back much later than an interactive blip would
 _TIER_ITEM_MS = {TIER_INTERACTIVE: 50.0, TIER_BATCH: 250.0}
+
+
+#: what `DecodeLoop._check_refusals` says, by the kind of layer that
+#: stands in the way and by what was asked. A window kind gives its
+#: pages back as the cursor moves (and an expert layer's counts are read
+#: back from the plain step and the cold prefill only); a linear kind's
+#: state is no page: whatever reuses a page would need the state at that
+#: page's end, and snapshots of state are not written
+_REFUSALS = {
+    paged_kinds.KIND_WINDOW: {
+        "prefix_cache":
+            "prefix sharing is not written for a model with window "
+            "layers (a shared page would have to be shared in every "
+            "kind, and a window layer gives its pages back): pass "
+            "prefix_cache=False",
+        "speculation":
+            "speculation is not written for a model with window "
+            "layers (a rejected draft's rows would need a window "
+            "page back): pass speculation=0",
+        "horizon":
+            "horizon > 1 is not written for a model with window "
+            "layers (pages are returned once a pass, between "
+            "steps): pass horizon=1",
+        "role":
+            "a prefill-role loop ships pages over /kv/export, which "
+            "is not written for a model with window layers"},
+    paged_kinds.KIND_LINEAR: {
+        "prefix_cache":
+            "prefix sharing (and with it copy-on-write forks and "
+            "/kv/export) is not written for a model with linear "
+            "layers (a shared prefix's pages say nothing of the "
+            "recurrent state at its end): pass prefix_cache=False",
+        "speculation":
+            "speculation is not written for a model with linear "
+            "layers (a rejected draft has already moved the "
+            "slot's state, and there is no snapshot to go back "
+            "to): pass speculation=0",
+        "horizon":
+            "horizon > 1 is not written for a model with linear "
+            "layers (the chained step has not been checked "
+            "against a state that is updated in place): pass "
+            "horizon=1",
+        "role":
+            "a prefill-role loop ships pages over /kv/export, "
+            "which is not written for a model with linear layers "
+            "(a page list says nothing of the state)"},
+}
 
 
 class GenerationStream:
@@ -766,13 +829,18 @@ class DecodeLoop:
             pages[paged_kinds.KIND_WINDOW] = self._win.n_pages
         self._kind_pages = pages
         self._kind_layers = paged_kinds.layers_of(cfg)
+        #: layers of the `linear` kind: a state a SLOT, no pages (0:
+        #: every layer keeps keys)
+        self._linear_layers = cfg.layer_kinds.count(
+            paged_kinds.KIND_LINEAR)
         #: what a page of a kind counts for in `pages_total` and its
         #: like: the kind's layers where kinds have to be summed (a page
         #: of a kind spans every layer of that kind), 1 where there is
         #: one kind and the counts are plainly its pages
         self._kind_weight = (self._kind_layers if len(pages) > 1
                              else dict.fromkeys(pages, 1))
-        self._pool = paged_kinds.init_pool(cfg, pages, self.page_size)
+        self._pool = paged_kinds.init_pool(cfg, pages, self.page_size,
+                                           slots=self.slots)
         self._trash = self.n_pages
         #: pairs by (layer, held expert) and what they are of, for a
         #: model with an expert layer (snapshot()["moe"])
@@ -1169,35 +1237,26 @@ class DecodeLoop:
                         role) -> None:
         """What the host's side cannot do yet for what a model has is an
         error here, by name, never a silent wrong answer: a window kind
-        gives its pages back as the cursor moves, and what an expert
-        layer counts is read back from the plain step and the cold
-        prefill only."""
+        gives its pages back as the cursor moves, what an expert layer
+        counts is read back from the plain step and the cold prefill
+        only, and a linear kind's state is no page (`_REFUSALS` has the
+        words)."""
         if paged_kinds.KIND_FULL not in cfg.layer_kinds:
             raise ValueError(
                 "layer_kinds needs a full layer: a request's token "
                 "budget rides the full kind's page table")
-        if not (paged_kinds.KIND_WINDOW in cfg.layer_kinds or cfg.n_held):
+        if paged_kinds.KIND_LINEAR in cfg.layer_kinds:
+            why = _REFUSALS[paged_kinds.KIND_LINEAR]
+        elif paged_kinds.KIND_WINDOW in cfg.layer_kinds or cfg.n_held:
+            why = _REFUSALS[paged_kinds.KIND_WINDOW]
+        else:
             return
-        if prefix_cache:
-            raise ValueError(
-                "prefix sharing is not written for a model with window "
-                "layers (a shared page would have to be shared in every "
-                "kind, and a window layer gives its pages back): pass "
-                "prefix_cache=False")
-        if speculation:
-            raise ValueError(
-                "speculation is not written for a model with window "
-                "layers (a rejected draft's rows would need a window "
-                "page back): pass speculation=0")
-        if horizon > 1:
-            raise ValueError(
-                "horizon > 1 is not written for a model with window "
-                "layers (pages are returned once a pass, between "
-                "steps): pass horizon=1")
-        if role == ROLE_PREFILL:
-            raise ValueError(
-                "a prefill-role loop ships pages over /kv/export, which "
-                "is not written for a model with window layers")
+        for asked, what in ((prefix_cache, "prefix_cache"),
+                            (speculation, "speculation"),
+                            (horizon > 1, "horizon"),
+                            (role == ROLE_PREFILL, "role")):
+            if asked:
+                raise ValueError(why[what])
 
     def _paged_block_pages(self) -> Dict[str, int]:
         """`attention/paged_pallas.block_pages` of each kind's call in
@@ -1237,6 +1296,19 @@ class DecodeLoop:
                 "dl4j_kv_window_pages_released",
                 "window-layer KV pages returned to their free list "
                 "because their last key left the window").labels(**lab)
+        if self._linear_layers:
+            reg.gauge(
+                "dl4j_state_bytes",
+                "bytes of per-slot state the cache holds for layers "
+                "that keep no pages, by kind of layer (a linear layer's "
+                "recurrent state and kept convolution columns, every "
+                "slot)").labels(kind=paged_kinds.KIND_LINEAR,
+                                **lab).set(self.state_bytes())
+            reg.gauge(
+                "dl4j_state_slots_live",
+                "slots whose per-slot state belongs to an in-flight "
+                "request").labels(**lab).set_function(
+                lambda: (lambda o: o.occupied_slots if o else 0)(ref()))
         if self._moe is None:
             return
         self._m_moe_tokens = reg.counter(
@@ -1584,6 +1656,12 @@ class DecodeLoop:
         return paged_kinds.pool_bytes(self.cfg, self._kind_pages,
                                       self.page_size)
 
+    def state_bytes(self) -> int:
+        """HBM the `linear` kind's per-slot state pins: every slot of
+        every such layer. 0 for a model whose layers all keep pages."""
+        return (self.slots * self._linear_layers
+                * paged_kinds.state_bytes_per_slot(self.cfg))
+
     def decode_step_programs(self) -> int:
         """Compiled-program count for the decode lane — the
         continuous-batching recompile guard. Plain mode: exactly 1
@@ -1655,6 +1733,14 @@ class DecodeLoop:
         def by_kind(*shape):
             return {k: ints(*shape) for k in self._kind_pages}
 
+        def rows_of(bb, columns):
+            """A cold prefill's `page_ids`: pages by kind, and the
+            rows' slots where a kind keeps its state by slot."""
+            ids = by_kind(bb, columns)
+            if self._linear_layers:
+                ids[paged_kinds.KIND_LINEAR] = ints(bb)
+            return ids
+
         n = 0
         if frag.get("step"):
             n += self._step.warm(params_spec, ints(S), pool_spec,
@@ -1667,7 +1753,7 @@ class DecodeLoop:
             n += self._copy.warm(pool_spec, ints(), ints())
         for bb, tb in frag.get("prefill", ()):
             n += self._prefill.warm(params_spec, ints(bb, tb), ints(bb),
-                                    pool_spec, by_kind(bb, tb // ps))
+                                    pool_spec, rows_of(bb, tb // ps))
         for bb, cb, tb in frag.get("prefill_ctx", ()):
             n += self._prefill_ctx.warm(
                 params_spec, ints(bb, tb), ints(bb), pool_spec,
@@ -2100,9 +2186,9 @@ class DecodeLoop:
         """`pages_total`, `pages_in_use` and `peak_pages_in_use`: pages
         of the one kind, or, where the model's layers are of several,
         sums over kinds weighted by the kind's layers (`_kind_weight`);
-        each kind's own pages under `pages_by_kind`, and the pairs of a
-        model with an expert layer under `moe`. Caller holds the
-        lock."""
+        each kind's own pages under `pages_by_kind`, what the `linear`
+        kind holds by slot under `state`, and the pairs of a model with
+        an expert layer under `moe`. Caller holds the lock."""
         by_kind = {}
         for kind, n in self._kind_pages.items():
             by_kind[kind] = {"layers": self._kind_layers[kind],
@@ -2121,6 +2207,13 @@ class DecodeLoop:
             "pages_in_use": self._weighted_in_use(),
             "peak_pages_in_use": self._peak_pages,
             "pages_by_kind": by_kind}
+        if self._linear_layers:
+            per_slot = paged_kinds.state_bytes_per_slot(self.cfg)
+            pages["state"] = {
+                "bytes": self.state_bytes(),
+                "bytes_per_slot": self._linear_layers * per_slot,
+                "layers": self._linear_layers,
+                "slots_live": self.occupied_slots}
         moe = self._moe
         if moe is not None:
             pages["moe"] = {
@@ -2553,6 +2646,12 @@ class DecodeLoop:
                 lo, hi = int(win.lo[idx]), int(win.hi[idx])
                 wids[row, lo:hi] = win.table[idx, lo:hi]
             d_pids[paged_kinds.KIND_WINDOW] = jnp.asarray(wids)
+        if self._linear_layers:
+            # where each row's state goes: its slot; a padding row names
+            # a slot past the last, and its write is dropped
+            at = np.full((bb,), self.slots, np.int32)
+            at[:len(slots)] = slots
+            d_pids[paged_kinds.KIND_LINEAR] = jnp.asarray(at)
         self._plan_prefill.add((bb, tb))
         first, self._pool = self._prefill(
             self.params, jnp.asarray(padded), jnp.asarray(lens),
@@ -2863,7 +2962,8 @@ class DecodeLoop:
                 first = (self._win.first_page(int(pos))
                          if kind == paged_kinds.KIND_WINDOW else 0)
                 streamed += layers * page * (last - first)
-        return streamed, self.cfg.n_layers * page * len(cursors) * self._pps
+        return streamed, (sum(self._kind_layers.values()) * page
+                          * len(cursors) * self._pps)
 
     def _count_read_bytes(self, cursors) -> None:
         streamed, dense = self._read_bytes(cursors)

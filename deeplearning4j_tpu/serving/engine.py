@@ -306,6 +306,38 @@ class InferenceEngine:
         return eng
 
     @classmethod
+    def for_hybrid_transformer(cls, params, cfg, *, decode_slots: int = 0,
+                               page_size: int = 16,
+                               kv_pages: Optional[int] = None,
+                               prefill_tokens_per_pass: Optional[int] = None,
+                               max_waiting: Optional[int] = None,
+                               decode_kernel: str = "auto",
+                               **kw) -> "InferenceEngine":
+        """Wrap a model of `models/hybrid_transformer.py` (linear layers
+        that keep a state a sequence beside full layers that keep K/V,
+        an expert layer of which this chip holds a part): apply = full
+        logits (B, T, vocab) with nothing cached; `decode_slots > 0`
+        starts the `DecodeLoop`, whose cache holds `kv_pages` pages for
+        the full kind and a state a slot for the linear kind. What
+        counts on page reuse is refused by name and stays off; this
+        engine has no per-request `generate()`."""
+        from deeplearning4j_tpu.compilecache import config_digest
+        from deeplearning4j_tpu.models import hybrid_transformer
+
+        cfg.check()
+        kw.setdefault("cache_key", "serve.hybrid:" + config_digest(cfg))
+        eng = cls(lambda p, tok: hybrid_transformer.logits(p, tok, cfg),
+                  params, **kw)
+        eng._tf_cfg = cfg
+        if decode_slots:
+            eng.start_decode_loop(
+                slots=decode_slots, page_size=page_size, n_pages=kv_pages,
+                prefill_tokens_per_pass=prefill_tokens_per_pass,
+                max_waiting=max_waiting, prefix_cache=False,
+                kernel=decode_kernel)
+        return eng
+
+    @classmethod
     def for_lstm(cls, layer, params, **kw) -> "InferenceEngine":
         """Wrap an LSTM layer: apply = per-timestep decoded outputs over
         (B, T, n_in) input."""

@@ -30,6 +30,20 @@ layers are all full (`models/transformer.py`) is the case of one kind:
   Each returns, after the logits and the pool, what the model's layers
   count (`aux`: the pairs by held expert, or `()`).
 
+A third kind keeps no pages at all. A `linear` layer
+(`models/hybrid_transformer.py`) holds a recurrent state and the last
+few pre-convolution columns a SEQUENCE, however long: its arrays are
+indexed by SLOT, `(slots, ...)`, donated and updated in place like the
+pools. The block hands its callback the columns and the gates and gets
+the mixer's rows back; the lanes call the model's one `linear_mix` with
+what the cache holds: `prefill` a row's real length (the state at the
+row's last REAL token goes to `page_ids["linear"][row]`, the row's
+slot; a padding row names a slot past the last and is dropped),
+`decode_step` the slot's kept columns and state (an inactive slot's
+update is masked: g = beta = 0 leave its state bit for bit). It needs no
+table, no grant and no trash row. `prefill_ctx` and `verify_step` are
+not written for it (`DecodeLoop._check_refusals` keeps them away).
+
 Shapes are fixed for the life of a server: a step is ONE program over S
 slots (tables, lengths and the active mask are traced arrays: requests
 join and leave without recompiling), a prefill one program a bucket of
@@ -52,21 +66,24 @@ import jax.numpy as jnp
 from deeplearning4j_tpu.attention.blockwise import masked_attention
 from deeplearning4j_tpu.attention.paged_pallas import paged_attention
 from deeplearning4j_tpu.models import model_of
-from deeplearning4j_tpu.models.transformer import (KIND_FULL, KIND_WINDOW,
+from deeplearning4j_tpu.models.transformer import (KIND_FULL, KIND_LINEAR,
+                                                   KIND_WINDOW,
                                                    causal_attention,
                                                    visible)
 from deeplearning4j_tpu.serving.paged_kv import (PagedKVPool,  # noqa: F401
                                                  init_pool, page_bytes,
-                                                 pool_bytes)
+                                                 pool_bytes,
+                                                 state_bytes_per_slot)
 
-__all__ = ["KIND_FULL", "KIND_WINDOW", "kinds_of", "layers_of",
-           "window_table_pages", "first_visible", "init_pool",
-           "pool_bytes", "page_bytes", "prefill", "prefill_ctx",
-           "decode_step", "verify_step"]
+__all__ = ["KIND_FULL", "KIND_WINDOW", "KIND_LINEAR", "kinds_of",
+           "layers_of", "window_table_pages", "first_visible", "init_pool",
+           "pool_bytes", "page_bytes", "state_bytes_per_slot", "prefill",
+           "prefill_ctx", "decode_step", "verify_step"]
 
 
 def kinds_of(cfg):
-    """The kinds this model has, full first."""
+    """The kinds of PAGE this model has, full first (the `linear` kind
+    keeps no pages and is not among them)."""
     return tuple(k for k in (KIND_FULL, KIND_WINDOW)
                  if k in cfg.layer_kinds)
 
@@ -174,6 +191,14 @@ def _check_kernel(kernel: str) -> None:
             f"got {kernel!r}")
 
 
+def _no_linear(kind: str, what: str) -> None:
+    if kind == KIND_LINEAR:
+        raise NotImplementedError(
+            f"{what} is not written for a layer of the linear kind: it "
+            f"would need the recurrent state at a position the cache "
+            f"does not keep (snapshots of state)")
+
+
 def _last_logits(model, params, x, true_len, cfg):
     """Each row's LAST REAL position through the head: (B, d) @ (d,
     vocab), not a (B, Tb, vocab) product."""
@@ -203,8 +228,14 @@ def prefill(params, tokens, true_len, pool: PagedKVPool,
     flat = {kind: ids.reshape(-1) for kind, ids in page_ids.items()}
 
     def attend(layer, kind, q, k, v):
+        held = pool.layers[layer]
+        if kind == KIND_LINEAR:
+            o, entry = model.linear_mix(cfg, q, k, v, true_len=true_len)
+            return o, {name: held[name].at[flat[kind]].set(
+                rows.astype(held[name].dtype), mode="drop")
+                for name, rows in entry.items()}
         att = causal_attention(cfg, kind, q, k, v)
-        return att, _write_pages(pool.layers[layer], flat[kind], k, v)
+        return att, _write_pages(held, flat[kind], k, v)
 
     x, layers, aux = model.forward(params, tokens, positions, cfg, attend,
                                    valid)
@@ -232,6 +263,7 @@ def prefill_ctx(params, tokens, true_len, pool: PagedKVPool,
     flat = {kind: ids.reshape(-1) for kind, ids in page_ids.items()}
 
     def attend(layer, kind, q, k, v):
+        _no_linear(kind, "a prefill on top of cached prefix pages")
         held = pool.layers[layer]
         table = ctx_tables[kind]
         ctx_pos = jnp.broadcast_to(jnp.arange(table.shape[1] * ps),
@@ -280,6 +312,14 @@ def decode_step(params, tokens, pool: PagedKVPool,
 
     def attend(layer, kind, q, k, v):
         held = pool.layers[layer]
+        if kind == KIND_LINEAR:
+            live = active[:, None, None]
+            o, entry = model.linear_mix(
+                cfg, q, tuple(jnp.where(live, gate, 0.0) for gate in k), v,
+                prev=held["conv"], state=held["state"])
+            entry["conv"] = jnp.where(active[:, None], entry["conv"],
+                                      held["conv"])
+            return o, entry
         ks = _write_rows(held["k"], dest[kind], offset, k[:, :, 0])
         vs = _write_rows(held["v"], dest[kind], offset, v[:, :, 0])
         table = tables[kind]
@@ -332,6 +372,7 @@ def verify_step(params, tokens, pool: PagedKVPool,
     offset = pos % ps
 
     def attend(layer, kind, q, k, v):
+        _no_linear(kind, "the widened verify step")
         held = pool.layers[layer]
         # rows are (S, W, H, hd), one per (slot, column, head)
         ks = _write_rows(held["k"], dest[kind], offset,

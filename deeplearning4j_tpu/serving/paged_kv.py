@@ -30,37 +30,49 @@ per prompt-length bucket (buckets are page multiples, `prompt_buckets`).
 
 from __future__ import annotations
 
+import math
 from typing import Any, Dict, NamedTuple, Tuple
 
 import jax.numpy as jnp
 
+from deeplearning4j_tpu.models.transformer import KIND_LINEAR
+
 __all__ = ["PagedKVPool", "init_pool", "page_bytes", "pool_bytes",
+           "state_bytes_per_slot",
            "init_paged_pool", "paged_kv_bytes",
            "pages_per_slot", "pages_for_tokens", "prompt_buckets",
            "copy_page", "extract_page", "install_page"]
 
 
 class PagedKVPool(NamedTuple):
-    """Per-block K/V page pools. `layers`: tuple (one per transformer
-    block) of {"k", "v"} arrays of shape (n_pages + 1, n_kv_heads,
-    page_size, head_dim), n_pages the layer's kind's; the last page is
-    the trash page for masked writes. `n_pages` and `trash_page` are
-    the first layer's: the pool's, where there is one kind."""
+    """What the cache holds a layer. `layers`: tuple (one per block). A
+    layer that keeps keys holds {"k", "v"} arrays of shape (n_pages + 1,
+    n_kv_heads, page_size, head_dim), n_pages the layer's kind's; the
+    last page is the trash page for masked writes. A layer of the
+    `linear` kind holds no pages: its arrays are indexed by SLOT,
+    `cfg.linear_state` says which (`{"state": (slots, Hv, dk, dv)
+    float32, "conv": (slots, columns kept)}`). `page_size`, `n_pages`
+    and `trash_page` are those of the first layer that has pages: the
+    pool's, where there is one kind of page."""
 
     layers: Tuple[Any, ...]
 
     @property
+    def _paged(self):
+        return next(layer for layer in self.layers if "k" in layer)
+
+    @property
     def page_size(self) -> int:
-        return self.layers[0]["k"].shape[2]
+        return self._paged["k"].shape[2]
 
     @property
     def n_pages(self) -> int:
         """Usable pages (the trash page is excluded)."""
-        return self.layers[0]["k"].shape[0] - 1
+        return self._paged["k"].shape[0] - 1
 
     @property
     def trash_page(self) -> int:
-        return self.layers[0]["k"].shape[0] - 1
+        return self._paged["k"].shape[0] - 1
 
 
 def pages_per_slot(cfg, page_size: int) -> int:
@@ -86,18 +98,36 @@ def prompt_buckets(cfg, page_size: int) -> Tuple[int, ...]:
     return tuple(buckets)
 
 
-def init_pool(cfg, pages: Dict[str, int], page_size: int) -> PagedKVPool:
+def init_pool(cfg, pages: Dict[str, int], page_size: int,
+              slots: int = 0) -> PagedKVPool:
     """Allocate the block pools: `pages[kind]` usable pages and the
-    trash page for every layer of that kind. Pool HBM is fixed at
-    construction — per-request cost is page-table bookkeeping, not
-    allocation."""
+    trash page for every layer of that kind, and for every layer of the
+    `linear` kind (which has no entry in `pages`) its state, a row a
+    slot of `slots`. Pool HBM is fixed at construction — per-request
+    cost is page-table bookkeeping, not allocation."""
     layers = []
     for kind in cfg.layer_kinds:
+        if kind not in pages:
+            layers.append({name: jnp.zeros((int(slots),) + shape, dtype)
+                           for name, (shape, dtype)
+                           in cfg.linear_state.items()})
+            continue
         shape = (int(pages[kind]) + 1, cfg.n_kv_heads, page_size,
                  cfg.head_dim)
         layers.append({"k": jnp.zeros(shape, cfg.dtype),
                        "v": jnp.zeros(shape, cfg.dtype)})
     return PagedKVPool(tuple(layers))
+
+
+def state_bytes_per_slot(cfg) -> int:
+    """What ONE slot holds in ONE layer of the `linear` kind: its
+    recurrent state and the convolution's kept columns. 0 for a model
+    that has no such layer."""
+    entry = getattr(cfg, "linear_state", None)
+    if not entry:
+        return 0
+    return sum(math.prod(shape) * jnp.dtype(dtype).itemsize
+               for shape, dtype in entry.values())
 
 
 def page_bytes(cfg, page_size: int) -> int:
@@ -112,7 +142,7 @@ def pool_bytes(cfg, pages: Dict[str, int], page_size: int) -> int:
     independent of concurrency: occupancy (pages in use / pages) is the
     load signal, exported as dl4j_kv_pages_{total,in_use}."""
     return sum((int(pages[k]) + 1) * page_bytes(cfg, page_size)
-               for k in cfg.layer_kinds)
+               for k in cfg.layer_kinds if k in pages)
 
 
 def _same_for_every_kind(cfg, n_pages: int, page_size: int):
@@ -120,7 +150,8 @@ def _same_for_every_kind(cfg, n_pages: int, page_size: int):
         raise ValueError(f"n_pages must be >= 1, got {n_pages}")
     if page_size < 1:
         raise ValueError(f"page_size must be >= 1, got {page_size}")
-    return dict.fromkeys(cfg.layer_kinds, n_pages)
+    return dict.fromkeys((k for k in cfg.layer_kinds if k != KIND_LINEAR),
+                         n_pages)
 
 
 def init_paged_pool(cfg, n_pages: int, page_size: int) -> PagedKVPool:

@@ -268,3 +268,140 @@ def test_two_kind_decode_step_holds_no_pool_shaped_copy(one_chip,
     alias = re.search(r"input_output_alias=\{(.*?)\}, entry", text, re.S)
     assert alias, "the compiled module aliases no input to an output"
     assert len(re.findall(r"(?:may|must)-alias", alias.group(1))) == 4
+
+
+# ------------------------------------- a kind that is not pages (PR 35)
+HYBRID_SLOTS, HYBRID_PAGES = 64, 4096
+
+
+def _hybrid(one_chip):
+    """One period `linear, linear, linear, full` of the block of
+    `models/hybrid_transformer.py` at the served widths (d 2048; linear
+    layers of 16 key and 32 value heads of 128, a state of (32, 128, 128)
+    float32 a slot; 16 query heads over 2 K/V heads of 256; 64 of 512
+    experts held), 64 slots, a bfloat16 pool of 4096 pages of 128
+    tokens: (cfg, params, pool, vec) as shapes on the described chip."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.models import hybrid_transformer as hybrid
+    from deeplearning4j_tpu.serving import paged_kinds
+
+    cfg = hybrid.HybridConfig(
+        vocab_size=18992, d_model=2048, n_heads=16, n_kv_heads=2,
+        head_dim=256, d_ff=512,
+        layer_kinds=("linear", "linear", "linear", "full"),
+        n_experts=512, experts_per_token=10, n_shared=1, n_held=64,
+        max_len=8192, dtype=jnp.bfloat16).check()
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    def vec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = on_chip(jax.eval_shape(
+        lambda: hybrid.init_hybrid_params(jax.random.PRNGKey(0), cfg)))
+    pool = on_chip(jax.eval_shape(
+        lambda: paged_kinds.init_pool(cfg, {"full": HYBRID_PAGES}, 128,
+                                      slots=HYBRID_SLOTS)))
+    assert pool.layers[0]["state"].shape == (HYBRID_SLOTS, 32, 128, 128)
+    assert pool.layers[0]["conv"].shape == (HYBRID_SLOTS, 3 * 8192)
+    assert pool.page_size == 128 and pool.n_pages == HYBRID_PAGES
+    return cfg, params, pool, vec
+
+
+def _compiled_as_on_the_chip(fn, donate, *args):
+    import jax
+
+    backend = jax.default_backend
+    jax.default_backend = lambda: "tpu"
+    try:
+        return jax.jit(fn, donate_argnums=donate).lower(
+            *args).compile().as_text()
+    finally:
+        jax.default_backend = backend
+
+
+def _assert_state_and_pool_updated_in_place(text):
+    """No copy of a whole cache array of either kind, and one alias a
+    leaf: state and kept columns of three linear layers, K and V of the
+    full one."""
+    for shape in (rf"f32\[{HYBRID_SLOTS},32,128,128\]",
+                  rf"bf16\[{HYBRID_SLOTS},24576\]",
+                  rf"bf16\[{HYBRID_PAGES + 1},2,128,256\]"):
+        copies = re.findall(rf"^.*= {shape}\{{[^}}]*\}} copy\(.*$", text,
+                            re.M)
+        assert not copies, copies[0][:200]
+    alias = re.search(r"input_output_alias=\{(.*?)\}, entry", text, re.S)
+    assert alias, "the compiled module aliases no input to an output"
+    assert len(re.findall(r"(?:may|must)-alias", alias.group(1))) == 8
+
+
+def test_hybrid_decode_step_updates_state_in_place(one_chip,
+                                                   no_compile_cache):
+    """The decode step of a model with linear layers, as `DecodeLoop`
+    jits it: each slot's recurrent state goes through `gdn_update` once
+    a layer and comes back in the donated buffer, the kept columns and
+    the full layer's pool likewise; the paged kernel takes heads of
+    256."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.serving import paged_kinds
+
+    cfg, params, pool, vec = _hybrid(one_chip)
+    s = HYBRID_SLOTS
+
+    def step_fn(params, tokens, pool, table, lengths, stop):
+        def inner(carry, _):
+            tokens, lengths, pool = carry
+            act = lengths < stop
+            logits, pool, pairs = paged_kinds.decode_step(
+                params, tokens, pool, table, lengths, act, cfg,
+                kernel="pallas")
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return (jnp.where(act, nxt, tokens),
+                    lengths + act.astype(lengths.dtype), pool), (nxt, pairs)
+
+        (tokens, lengths, pool), out = jax.lax.scan(
+            inner, (tokens, lengths, pool), None, length=1)
+        return out, tokens, lengths, pool
+
+    text = _compiled_as_on_the_chip(
+        step_fn, (2,), params, vec(s), pool, {"full": vec(s, 64)}, vec(s),
+        vec(s))
+    assert "%gmm" in text
+    assert len(re.findall(r"^.*gdn_update.*custom-call\(", text,
+                          re.M)) == 3
+    assert len(re.findall(r"^.*paged_decode_attention.*custom-call\(",
+                          text, re.M)) == 1
+    _assert_state_and_pool_updated_in_place(text)
+
+
+def test_hybrid_prefill_writes_the_slot_s_state_in_place(one_chip,
+                                                         no_compile_cache):
+    """One row of the 8,192 bucket, as `DecodeLoop`'s `prefill_fn` jits
+    it: the chunked scan a linear layer, grouped-head flash at heads of
+    256 in the full one, the row's final state scattered to its slot of
+    the donated arrays."""
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.serving import paged_kinds
+
+    cfg, params, pool, vec = _hybrid(one_chip)
+
+    def prefill_fn(params, tokens, true_len, pool, page_ids):
+        logits, pool, aux = paged_kinds.prefill(
+            params, tokens, true_len, pool, page_ids, cfg)
+        return (jnp.argmax(logits, axis=-1).astype(jnp.int32), aux), pool
+
+    text = _compiled_as_on_the_chip(
+        prefill_fn, (3,), params, vec(1, 8192), vec(1), pool,
+        {"full": vec(1, 64), "linear": vec(1)})
+    assert len(re.findall(r"^.*gdn_scan.*custom-call\(", text, re.M)) == 3
+    assert len(re.findall(r"^.*flash_fwd.*custom-call\(", text,
+                          re.M)) == 1
+    _assert_state_and_pool_updated_in_place(text)

@@ -433,3 +433,152 @@ class TestFlashHd128OnChip:
             err = float(jnp.max(jnp.abs(a.astype(jnp.float32)
                                         - b.astype(jnp.float32))))
             assert err / scale < tol, f"{name} err {err} (scale {scale})"
+
+
+class TestHeadsOf256OnChip:
+    """PR 35: what lets `resolve_decode_kernel("auto")` take heads of
+    256 (`qwen3-next-80b-a3b-ep8`'s full layers: 16 query heads over 2
+    K/V heads, 8 query rows a K/V head, pages of 128 tokens, one page a
+    block), and the flash forward with the same heads."""
+
+    @pytest.mark.parametrize("dtype,atol", [("float32", 1e-4),
+                                            ("bfloat16", 2e-2)])
+    def test_paged_kernel_matches_dense_reference(self, dtype, atol):
+        import jax
+        import jax.numpy as jnp
+
+        from deeplearning4j_tpu.attention.paged_pallas import (
+            block_pages, paged_attention, resolve_decode_kernel)
+        from deeplearning4j_tpu.models.hybrid_transformer import \
+            HybridConfig
+
+        assert jax.devices()[0].platform == "tpu"
+        assert block_pages(128, 2, 256, jnp.dtype(dtype), 64) == 1
+        cfg = HybridConfig(
+            vocab_size=8, d_model=2048, n_heads=16, n_kv_heads=2,
+            head_dim=256, d_ff=512, layer_kinds=("linear", "full"),
+            n_experts=512, experts_per_token=10, n_shared=1, n_held=64,
+            dtype=jnp.dtype(dtype))
+        assert resolve_decode_kernel("auto", cfg, 128) == "pallas"
+        assert resolve_decode_kernel("auto", cfg, 16) == "gather"
+        # cursors on the edges of a page and of the table, an empty slot
+        q, kp, vp, table, lengths = _paged_case(
+            np.random.default_rng(5), 16, 2, 256, 128, 64,
+            jnp.dtype(dtype))
+        q = jnp.asarray(np.random.default_rng(6).normal(
+            size=(16, 16, 256)).astype(np.float32)).astype(dtype)
+        out = jax.jit(paged_attention)(q, kp, vp, table, lengths)
+        # query head n reads K/V head n // 8
+        ref = _dense_paged_reference(
+            q, jnp.repeat(kp, 8, axis=1), jnp.repeat(vp, 8, axis=1),
+            table, lengths)
+        np.testing.assert_allclose(
+            np.asarray(out.astype(jnp.float32), np.float64), ref,
+            atol=atol)
+
+    @pytest.mark.parametrize("dtype,tol", [("float32", 2e-2),
+                                           ("bfloat16", 3e-2)])
+    def test_flash_forward_grouped_at_256_matches_blockwise(self, dtype,
+                                                            tol):
+        import jax
+        import jax.numpy as jnp
+
+        from deeplearning4j_tpu.attention.blockwise import \
+            blockwise_attention
+        from deeplearning4j_tpu.attention.flash_pallas import \
+            flash_attention
+
+        keys = jax.random.split(jax.random.PRNGKey(11), 3)
+        q = jax.random.normal(keys[0], (1, 16, 2048, 256), jnp.dtype(dtype))
+        k = jax.random.normal(keys[1], (1, 2, 2048, 256), jnp.dtype(dtype))
+        v = jax.random.normal(keys[2], (1, 2, 2048, 256), jnp.dtype(dtype))
+        fn = jax.jit(lambda q, k, v: flash_attention(q, k, v, True))
+        assert "flash_fwd" in fn.lower(q, k, v).as_text()
+        out = fn(q, k, v)
+        f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
+        with jax.default_matmul_precision("highest"):
+            ref = blockwise_attention(
+                f32(q), jnp.repeat(f32(k), 8, axis=1),
+                jnp.repeat(f32(v), 8, axis=1), causal=True)
+        err = float(jnp.max(jnp.abs(f32(out) - ref)))
+        assert err < tol, err
+
+
+class TestGatedDeltaRuleOnChip:
+    """PR 35: the chunked scan and the one-token update compiled by
+    Mosaic against the recurrence token by token in float32, at the
+    served head sizes (dk = dv = 128), a slow decay and a fast one."""
+
+    @staticmethod
+    def _case(decay, dtype, t=1024, heads=4):
+        import jax
+        import jax.numpy as jnp
+
+        ks = jax.random.split(jax.random.PRNGKey(3), 5)
+        shape = (1, heads, t, 128)
+        q = jax.random.normal(ks[0], shape)
+        k = jax.random.normal(ks[1], shape)
+        q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) / 128 ** 0.5
+        k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+        v = jax.random.normal(ks[2], shape)
+        g = jnp.log(decay) * jax.random.uniform(
+            ks[3], shape[:3], minval=0.5, maxval=1.5)
+        beta = jax.nn.sigmoid(jax.random.normal(ks[4], shape[:3]))
+        return tuple(a.astype(dtype) for a in (q, k, v)) + (g, beta)
+
+    @staticmethod
+    def _recurrence(q, k, v, g, beta):
+        import jax
+        import jax.numpy as jnp
+
+        def one_head(q, k, v, g, beta):
+            def step(s, now):
+                q, k, v, g, b = now
+                s = s * jnp.exp(g)
+                mem = jnp.dot(s.T, k, precision="highest")
+                s = s + jnp.outer(k, b * (v - mem))
+                return s, jnp.dot(s.T, q, precision="highest")
+            s, o = jax.lax.scan(step, jnp.zeros((128, 128), jnp.float32),
+                                (q, k, v, g, beta))
+            return o, s
+        f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
+        return jax.jit(jax.vmap(jax.vmap(one_head)))(
+            f32(q), f32(k), f32(v), g, beta)
+
+    @pytest.mark.parametrize("dtype,tol", [("float32", 1e-3),
+                                           ("bfloat16", 2e-2)])
+    @pytest.mark.parametrize("decay", [0.997, 0.5])
+    def test_scan_and_update_match_the_recurrence(self, decay, dtype, tol):
+        import jax
+        import jax.numpy as jnp
+
+        from deeplearning4j_tpu.attention.gdn_pallas import (gdn_scan,
+                                                             gdn_update)
+
+        assert jax.devices()[0].platform == "tpu"
+        q, k, v, g, beta = self._case(decay, jnp.dtype(dtype))
+        want_o, want_s = self._recurrence(q, k, v, g, beta)
+        o, s = gdn_scan(q, k, v, g, beta)
+        scale = float(jnp.max(jnp.abs(want_o)))
+        assert float(jnp.max(jnp.abs(o.astype(jnp.float32) - want_o))) \
+            < tol * scale
+        assert float(jnp.max(jnp.abs(s - want_s))) \
+            < tol * float(jnp.max(jnp.abs(want_s)))
+        # one more token on top of the kept state, by the update kernel
+        # and by one step of the recurrence
+        q1, k1, v1, g1, b1 = self._case(decay, jnp.dtype(dtype), t=64)
+        at = (slice(None), slice(None), 7)
+        o1, s1 = gdn_update(want_s + 0, q1[at], k1[at], v1[at], g1[at],
+                            b1[at])
+        f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
+        sp = want_s * jnp.exp(g1[at])[..., None, None]
+        mem = jnp.einsum("nhkv,nhk->nhv", sp, f32(k1[at]),
+                         precision="highest")
+        sn = sp + f32(k1[at])[..., :, None] \
+            * (b1[at][..., None] * (f32(v1[at]) - mem))[..., None, :]
+        on = jnp.einsum("nhkv,nhk->nhv", sn, f32(q1[at]),
+                        precision="highest")
+        np.testing.assert_allclose(np.asarray(s1), np.asarray(sn),
+                                   atol=1e-5)
+        np.testing.assert_allclose(np.asarray(o1), np.asarray(on),
+                                   atol=1e-5)
