@@ -36,7 +36,11 @@ the megablox Pallas kernel on a TPU, whose grid covers only the row
 tiles that hold pairs and fetches only the weights of experts that have
 any; `lax.ragged_dot` elsewhere), so the work follows the pairs. No
 token is dropped and there is no capacity factor: the sorted pairs are
-taken `chunk` rows at a time until none is left.
+taken `chunk` rows at a time until none is left. Between token order
+and the sorted order the rows travel by `models/moe_rows.py`: a row copy
+a pair in, a row copy a pair out under the pair's weight, summed by
+token in float32; no gather, no scatter, and rows that hold no pair are
+not touched.
 """
 
 from __future__ import annotations
@@ -46,6 +50,7 @@ from typing import Any, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from deeplearning4j_tpu.models.moe_rows import rows_in, rows_out
 from deeplearning4j_tpu.models.transformer import (KIND_FULL,  # noqa: F401
                                                    KIND_WINDOW, KINDS,
                                                    Attend,
@@ -205,12 +210,16 @@ def grouped_matmul(lhs, rhs, group_sizes, out_dtype, interpret=False):
 
 def _pair_chunk(t: int, cfg) -> Tuple[int, int]:
     """(most pairs that can fall on held experts, rows of the sorted
-    pairs taken at a time) for `t` tokens: a chunk is twice the pairs
-    expected on this share, at least 256, never more than the most."""
+    pairs taken at a time) for `t` tokens: a chunk is a quarter more
+    than the pairs expected on this share, at least 256, never more than
+    the most. The chunk bounds the buffers under imbalance; what a pass
+    pays follows the pairs (a second chunk is taken only when they
+    outnumber the first), and the few passes over a whole chunk that
+    are left (the activation, `y` laid out as slabs) follow its size."""
     most = t * min(cfg.experts_per_token, cfg.n_held)
     most = -(-most // 256) * 256
     expected = t * cfg.experts_per_token * cfg.n_held // cfg.n_experts
-    return most, min(most, max(256, -(-2 * expected // 256) * 256))
+    return most, min(most, max(256, -(-5 * expected // 4 // 256) * 256))
 
 
 ROUTER_SCORES = {"sigmoid": jax.nn.sigmoid,
@@ -245,26 +254,27 @@ def expert_layer(p, h, cfg, valid=None):
         # group `held` = "not here": sorts behind every held expert
         group = jnp.where(here, local, held).reshape(-1)       # (T*k,)
         order = jnp.argsort(group, stable=True)
-        counts = jnp.bincount(group, length=held + 1)[:held].astype(
-            jnp.int32)
+        counts = jnp.sum(group[:, None] == jnp.arange(held), axis=0,
+                         dtype=jnp.int32)
         starts = jnp.concatenate([jnp.zeros((1,), jnp.int32),
                                   jnp.cumsum(counts)])
         n_pairs = starts[held]
+        # the inverse of the sort: the sorted row of each (token, choice)
+        sorted_at = jnp.argsort(order).astype(jnp.int32).reshape(t, k)
     with jax.named_scope("moe_experts"):
         most, chunk = _pair_chunk(t, cfg)
         order = jnp.pad(order, (0, max(0, most + chunk - order.shape[0])))
-        flat_w = weight.reshape(-1)
         ex = p["experts"]
 
-        def take(lo, out):
-            """Sorted pairs [lo, lo + chunk): gather, three grouped
-            products, scatter-add under the pairs' weights."""
+        def take(lo):
+            """Sorted pairs [lo, lo + chunk): their tokens' rows in,
+            three grouped products, each token's weighted rows out.
+            Only rows that hold a pair move (`models/moe_rows.py`)."""
+            here_n = jnp.clip(n_pairs - lo, 0, chunk)
             pairs = jax.lax.dynamic_slice(order, (lo,), (chunk,))
-            real = lo + jnp.arange(chunk) < n_pairs
-            tok = jnp.where(real, pairs // k, 0)
             sizes = jnp.clip(starts[1:], lo, lo + chunk) \
                 - jnp.clip(starts[:-1], lo, lo + chunk)
-            x = h[tok]
+            x = rows_in(h, pairs // k, here_n, interpret=cfg.interpret)
             g = grouped_matmul(x, ex["gate"], sizes, h.dtype,
                                cfg.interpret)
             u = grouped_matmul(x, ex["up"], sizes, h.dtype,
@@ -273,16 +283,21 @@ def expert_layer(p, h, cfg, valid=None):
                    * u.astype(jnp.float32)).astype(h.dtype)
             y = grouped_matmul(act, ex["down"], sizes, jnp.float32,
                                cfg.interpret)
-            y = jnp.where(real[:, None], y * flat_w[pairs][:, None], 0.0)
-            return out.at[tok].add(y)
+            row = sorted_at - lo
+            return rows_out(
+                y, jnp.where((row >= 0) & (row < here_n), row, -1),
+                weight, interpret=cfg.interpret)
 
-        routed = jnp.zeros((t, d), jnp.float32)
         if chunk >= most:
-            routed = take(jnp.int32(0), routed)
+            routed = take(jnp.int32(0))
         else:
+            # one body for every chunk: a program holds each kernel once
+            # a layer (twice as many made a program's load from the
+            # compile cache seconds longer; PERF.md section 6, PR 36)
             routed = jax.lax.fori_loop(
                 0, -(-n_pairs // chunk),
-                lambda c, out: take(c * chunk, out), routed)
+                lambda c, out: out + take(c * chunk),
+                jnp.zeros((t, d), jnp.float32))
     with jax.named_scope("moe_shared"):
         if cfg.shared_combine not in SHARED_COMBINES:
             raise ValueError(f"shared_combine must be one of "

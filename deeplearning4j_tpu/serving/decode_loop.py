@@ -304,6 +304,7 @@ import numpy as np
 from deeplearning4j_tpu import telemetry
 from deeplearning4j_tpu.attention.paged_pallas import (
     block_pages, resolve_decode_kernel)
+from deeplearning4j_tpu.models.moe_rows import rows_moved
 from deeplearning4j_tpu.serving.errors import (TIER_BATCH,
                                                TIER_INTERACTIVE, TIERS,
                                                Deadline,
@@ -849,7 +850,7 @@ class DecodeLoop:
             self._moe = {
                 "pairs": np.zeros((cfg.n_layers, cfg.n_held), np.int64),
                 "tokens": 0, "decode_tokens": 0, "decode_pairs": 0,
-                "decode_steps": 0, "experts_touched": 0}
+                "decode_steps": 0, "experts_touched": 0, "rows_moved": 0}
         # host mirrors (scheduler-thread-owned) -----------------------
         self._table = np.full((self.slots, self._pps), self._trash,
                               np.int32)
@@ -1320,6 +1321,12 @@ class DecodeLoop:
             "held experts with at least one pair, summed over decode "
             "steps and layers: the expert weights a step had to read"
         ).labels(**lab)
+        self._m_moe_rows = reg.counter(
+            "dl4j_moe_rows_moved",
+            "rows the expert layer copied between token order and the "
+            "order sorted by expert (a layer's pairs rounded up to whole "
+            "grid steps of its kernels): over dl4j_moe_pairs, how much "
+            "of the movement held no pair").labels(**lab)
         pairs = reg.counter(
             "dl4j_moe_pairs",
             "(token, expert) pairs that fell on an expert this chip "
@@ -1351,6 +1358,9 @@ class DecodeLoop:
         moe["pairs"] += pairs
         moe["tokens"] += tokens
         self._m_moe_tokens.inc(tokens)
+        moved = int(rows_moved(pairs.sum(axis=1)).sum())
+        moe["rows_moved"] += moved
+        self._m_moe_rows.inc(moved)
         for i, e in zip(*np.nonzero(pairs)):
             self._m_moe_pairs[i][e].inc(int(pairs[i, e]))
         if decode:

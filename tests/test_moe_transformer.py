@@ -286,6 +286,12 @@ def test_counters_of_pages_by_kind_and_of_pairs_and_the_release_span():
     assert moe_snap["experts_touched"] <= 16 * moe_snap["decode_steps"]
     # pairs a token a layer: 4 chosen of 16, 4 held -> about 1
     assert 0.5 < moe_snap["pairs"] / (4 * moe_snap["tokens"]) < 1.6
+    # the rows the movement went through: a layer's pairs rounded up to
+    # whole grid steps of 128, so at this size a step a layer a program
+    # that held a pair, and never fewer rows than pairs
+    programs = moe_snap["decode_steps"] + 2
+    assert moe_snap["pairs"] <= moe_snap["rows_moved"] <= 4 * 128 * programs
+    assert moe_snap["rows_moved"] % 128 == 0
     # a pass releases before it prepares a step: once a dispatch, and
     # once more in the last pass, which found no slot to advance and
     # only read the step in flight
@@ -303,6 +309,8 @@ def test_counters_of_pages_by_kind_and_of_pairs_and_the_release_span():
     assert (f"dl4j_moe_experts_touched_total{{{lab}}} "
             f"{moe_snap['experts_touched']}") in text
     assert f'dl4j_moe_pairs_total{{expert="0",layer="0",{lab}}}' in text
+    assert (f"dl4j_moe_rows_moved_total{{{lab}}} "
+            f"{moe_snap['rows_moved']}") in text
     # the one-kind families keep their shape
     assert f"dl4j_kv_pages_total{{{lab}}} 48" in text
     # the paged kernel's block by kind: this loop gathers, so none
@@ -347,3 +355,140 @@ def test_rope_turns_pairs_and_keeps_norms():
     want = [a * np.cos(ang) - b * np.sin(ang),
             b * np.cos(ang) + a * np.sin(ang)]
     assert np.allclose(np.asarray(y[0, 3, 1, 6:8]), want, atol=1e-5)
+
+
+# ------------------------------------ (e) how the pairs' rows travel (PR 36)
+ROWS_CFG = dict(vocab_size=8, d_model=64, n_heads=2, n_kv_heads=1,
+                head_dim=16, d_ff=32, layer_kinds=("full",), window=4,
+                n_experts=16, experts_per_token=4, n_shared=2, n_held=4,
+                held_first=4, interpret=True)
+
+
+def _one_hot_layer(p, h, cfg, valid):
+    """The layer with no sorted order at all: every held expert over
+    every token, a one-hot sum under the router's weights. The same
+    types as the program: products in h's type accumulated in float32,
+    the weights and the sum over a token's pairs in float32."""
+    f32 = jnp.float32
+    scores = moe.ROUTER_SCORES[cfg.router_score](jnp.dot(
+        h.astype(f32), p["router"].astype(f32),
+        precision=jax.lax.Precision.HIGHEST))
+    top, chosen = jax.lax.top_k(scores, cfg.experts_per_token)
+    weight = top / jnp.sum(top, axis=-1, keepdims=True)
+    out, held_pairs = jnp.zeros(h.shape, f32), []
+    for e in range(cfg.n_held):
+        hot = (chosen == cfg.held_first + e) & valid[:, None]
+        held_pairs.append(hot)
+        ex = p["experts"]
+        g = jnp.dot(h, ex["gate"][e], preferred_element_type=f32)
+        u = jnp.dot(h, ex["up"][e], preferred_element_type=f32)
+        act = (jax.nn.silu(g.astype(h.dtype).astype(f32))
+               * u.astype(h.dtype).astype(f32)).astype(h.dtype)
+        y = jnp.dot(act, ex["down"][e], preferred_element_type=f32)
+        out = out + jnp.sum(weight * hot, axis=-1)[:, None] * y
+    shared, _ = moe.expert_layer(p, h, cfg, jnp.zeros_like(valid))
+    return out + shared, np.asarray(jnp.stack(held_pairs))  # (held, t, k)
+
+
+def _routed_case(case, t, dtype):
+    """(h, router, valid) that puts the pairs where the case wants them.
+    Column 0 of h pulls a token's choices onto the held experts (+) or
+    off them (-); column 1 pulls one held expert in alone."""
+    k1, k2 = jax.random.split(jax.random.PRNGKey(11))
+    h = jax.random.normal(k1, (t, 64), jnp.float32)
+    router = 0.02 * jax.random.normal(k2, (64, 16), jnp.float32)
+    held = (jnp.arange(16) >= 4) & (jnp.arange(16) < 8)
+    router = router.at[0].set(jnp.where(held, 1.0, -1.0))
+    router = router.at[1].set(jnp.where(jnp.arange(16) == 5, 3.0, 0.0))
+    valid = jnp.ones((t,), bool)
+    pull = {"none_held": -8.0, "beyond_one_chunk": 8.0}.get(case, 0.0)
+    h = h.at[:, 0].set(pull).at[:, 1].set(0.0)
+    if case == "zero_one_and_k":
+        # thirds: all k on held experts, none, exactly one (expert 5)
+        kind = jnp.arange(t) % 3
+        h = h.at[:, 0].set(jnp.where(kind == 0, 8.0, -8.0))
+        h = h.at[:, 1].set(jnp.where(kind == 2, 8.0, 0.0))
+    if case == "valid_masks_rows":
+        valid = (jnp.arange(t) % 5 != 0) & (jnp.arange(t) < t - 9)
+    return h.astype(dtype), router.astype(dtype), valid
+
+
+@pytest.mark.parametrize("score,combine", [("sigmoid", "average"),
+                                           ("softmax", "sigmoid_gate")])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 2e-3)])
+@pytest.mark.parametrize("case,t", [
+    ("none_held", 128), ("one_chunk", 128), ("beyond_one_chunk", 256),
+    ("zero_one_and_k", 128), ("valid_masks_rows", 128)])
+def test_the_rows_travel_as_the_one_hot_sum_says(case, t, dtype, tol,
+                                                 score, combine):
+    """`expert_layer` through the two kernels of `models/moe_rows.py`
+    (interpret mode) against the one-hot sum: no pair held, the pairs of
+    one chunk, every pair held so that the sorted pairs take more than
+    one chunk, tokens with 0, 1 and k pairs, padding rows; both families'
+    score and shared-expert rules; float32 at 1e-5, and bfloat16 at a
+    few of its roundings of results of ~0.01 (the products are the same
+    ones, in another order)."""
+    cfg = moe.MoEConfig(**ROWS_CFG, dtype=dtype, router_score=score,
+                        shared_combine=combine).check()
+    p = moe.init_moe_params(jax.random.PRNGKey(2), cfg)["blocks"][0]
+    h, router, valid = _routed_case(case, t, dtype)
+    p = dict(p, router=router, shared_gate=(0.02 * jax.random.normal(
+        jax.random.PRNGKey(5), (64, 2), jnp.float32)).astype(dtype))
+    got, pairs = jax.jit(
+        lambda p, h, valid: moe.expert_layer(p, h, cfg, valid))(p, h, valid)
+    want, hot = _one_hot_layer(p, h, cfg, valid)
+    assert np.asarray(pairs).tolist() == hot.sum(axis=(1, 2)).tolist()
+    assert np.abs(np.asarray(got - want)).max() < tol
+    by_token = hot.sum(axis=(0, 2))                    # held pairs a token
+    most, chunk = moe._pair_chunk(t, cfg)
+    if case == "none_held":
+        assert by_token.sum() == 0
+    elif case == "beyond_one_chunk":
+        assert by_token.sum() == t * 4 == most > chunk
+    elif case == "zero_one_and_k":
+        assert set(by_token.tolist()) == {0, 1, 4}
+    elif case == "valid_masks_rows":
+        assert by_token[~np.asarray(valid)].sum() == 0 < by_token.sum()
+    else:
+        assert 0 < by_token.sum() <= chunk < most
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_rows_in_copies_the_pairs_rows_and_no_row_past_their_step(dtype):
+    """`rows_in` alone: the first n rows are the tokens' rows bit for
+    bit, whatever n is against the grid step; `rows_moved` is n rounded
+    up to whole steps."""
+    from deeplearning4j_tpu.models import moe_rows
+
+    rng = np.random.RandomState(0)
+    h = jnp.asarray(rng.randn(40, 64), dtype)
+    tok = jnp.asarray(rng.randint(0, 40, (512,)), jnp.int32)
+    for n in (0, 1, 127, 128, 129, 512):
+        got = moe_rows.rows_in(h, tok, jnp.int32(n), interpret=True)
+        assert got.shape == (512, 64) and got.dtype == h.dtype
+        assert np.array_equal(np.asarray(got[:n], np.float32),
+                              np.asarray(h[tok[:n]], np.float32))
+        assert moe_rows.rows_moved(n) == -(-n // 128) * 128
+    assert moe_rows.rows_moved(np.asarray([700, 0])).tolist() == [768, 0]
+
+
+def test_rows_out_sums_each_token_s_pairs_in_float32():
+    """`rows_out` alone, kernel and plain form, against a float64 loop:
+    tokens with no pair get zeros, more pairs than copies in flight."""
+    from deeplearning4j_tpu.models import moe_rows
+
+    rng = np.random.RandomState(1)
+    y = jnp.asarray(rng.randn(256, 64), jnp.float32)
+    for share in (0.0, 0.2, 1.0):
+        pos = rng.randint(0, 256, (256, 3))
+        pos[rng.rand(256, 3) >= share] = -1
+        w = rng.rand(256, 3).astype(np.float32)
+        want = np.zeros((256, 64))
+        for a, b in zip(*np.nonzero(pos >= 0)):
+            want[a] += np.float64(w[a, b]) * np.asarray(y[pos[a, b]],
+                                                        np.float64)
+        for interpret in (True, False):
+            got = moe_rows.rows_out(y, jnp.asarray(pos, jnp.int32),
+                                    jnp.asarray(w), interpret=interpret)
+            assert np.abs(np.asarray(got) - want).max() < 1e-5

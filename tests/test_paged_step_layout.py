@@ -21,6 +21,7 @@ the tests skip where it cannot be described (the same set-up as
 tests/benchmark_suite/test_compile_v5e.py). All of them live in this
 one file: the worker that is given it loads libtpu and keeps it."""
 
+import math
 import os
 import re
 
@@ -405,3 +406,115 @@ def test_hybrid_prefill_writes_the_slot_s_state_in_place(one_chip,
     assert len(re.findall(r"^.*flash_fwd.*custom-call\(", text,
                           re.M)) == 1
     _assert_state_and_pool_updated_in_place(text)
+
+
+# ------------------------- how the expert layer's rows travel (PR 36)
+def _expert_layer_text(one_chip, cfg, t):
+    """Optimized HLO of `expert_layer` alone over `t` normed rows at the
+    configuration's widths, bfloat16, as a prefill or a step holds it."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.models import moe_transformer as moe
+
+    def on_chip(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    d, f = cfg.d_model, cfg.d_ff
+    experts = {"gate": (d, f), "up": (d, f), "down": (f, d)}
+    p = {"router": on_chip((d, cfg.n_experts)),
+         "experts": {k: on_chip((cfg.n_held,) + v)
+                     for k, v in experts.items()},
+         "shared": {k: on_chip((cfg.n_shared,) + v)
+                    for k, v in experts.items()},
+         "shared_gate": on_chip((d, cfg.n_shared))}
+    return _compiled_as_on_the_chip(
+        lambda p, h, valid: moe.expert_layer(p, h, cfg, valid), (),
+        p, on_chip((t, d)), on_chip((t,), jnp.bool_))
+
+
+def _calls(text: str, kernel: str):
+    return re.findall(rf"^\s*(?:ROOT )?%{kernel}[.\d]* = .*custom-call\(",
+                      text, re.M)
+
+
+def _elements(shape: str) -> int:
+    return math.prod(int(dim) for dim in shape.split(","))
+
+
+def _expert_cfg(widths: str):
+    """The expert layer's widths of the two served configurations."""
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.models import moe_transformer as moe
+
+    if widths == "ep8":
+        return moe.MoEConfig(
+            vocab_size=8, d_model=4096, n_heads=128, n_kv_heads=8,
+            head_dim=128, d_ff=4096, layer_kinds=("full",), window=4096,
+            n_experts=128, experts_per_token=8, n_shared=4, n_held=16,
+            dtype=jnp.bfloat16).check()
+    return moe.MoEConfig(
+        vocab_size=8, d_model=2048, n_heads=16, n_kv_heads=2,
+        head_dim=256, d_ff=512, layer_kinds=("full",), window=0,
+        n_experts=512, experts_per_token=10, n_shared=1, n_held=64,
+        dtype=jnp.bfloat16, router_score="softmax",
+        shared_combine="sigmoid_gate").check()
+
+
+@pytest.mark.parametrize("widths", ["ep8", "q3n"])
+def test_prefill_expert_layer_moves_rows_by_row_copies(one_chip,
+                                                       no_compile_cache,
+                                                       widths):
+    """The expert layer of the 8,192-row prefill at the two served
+    configurations' widths. What the parent's program held a layer
+    (PERF.md section 6, PR 36): a row gather of a chunk and a copy of
+    it before `gmm` read it, a `(chunk, d)` float32 product under the
+    weights, and for the scatter-add a sort of the token indices, a
+    second row gather, and a scatter into `(t, d)` float32. Now: the
+    two kernels, the three grouped products between them, no scatter
+    into `(t, d)`, no sort under `moe_experts`, no copy of the sorted
+    rows `gmm` reads, and of the chunk's float32 result the one pass
+    that lays it out as slabs."""
+    from deeplearning4j_tpu.models import moe_transformer as moe
+
+    cfg = _expert_cfg(widths)
+    t, d = 8192, cfg.d_model
+    most, chunk = moe._pair_chunk(t, cfg)
+    assert chunk < most and chunk % 256 == 0
+    text = _expert_layer_text(one_chip, cfg, t)
+    # one body for every chunk: each kernel once, the three grouped
+    # products once (a program's load from the compile cache grows with
+    # the kernels it holds)
+    for kernel, calls in (("moe_rows_in", 1), ("moe_rows_out", 1),
+                          ("gmm", 3)):
+        assert len(_calls(text, kernel)) == calls, kernel
+    scatters = re.findall(r"^.*= (\w+)\[([\d,]+)\]\S* scatter\(.*$", text,
+                          re.M)
+    assert all(_elements(shape) < t for _, shape in scatters), scatters
+    assert not re.findall(r"^.* sort\(.*moe_experts.*$", text, re.M)
+    copies = [(dtype, shape) for dtype, shape in re.findall(
+        r"^.*= (\w+)\[([\d,]+)\]\S* copy\(", text, re.M)
+        if _elements(shape) == chunk * d]
+    assert [dtype for dtype, _ in copies] == ["f32"], copies
+    # and no gather builds the sorted rows (ep8's activation has their
+    # shape: an expert is as wide as the model there)
+    assert not re.findall(rf"^.*= bf16\[{chunk},{d}\]\S* "
+                          r"(?:gather|copy)\(", text, re.M)
+    assert not re.findall(r"^.*= \w+\[[\d,]+\]\S* gather\(.*moe_experts"
+                          r"(?!.*jit\(gmm\)).*$", text, re.M)
+
+
+def test_decode_step_expert_layer_takes_the_kernels_too(one_chip,
+                                                        no_compile_cache):
+    """The decode step's expert layer (64 rows at q3n's widths: a chunk
+    of 256 of at most 768 pairs) is the same movement: one fork, on
+    whether a chunk holds every pair, and no second form."""
+    from deeplearning4j_tpu.models import moe_transformer as moe
+
+    cfg = _expert_cfg("q3n")
+    assert moe._pair_chunk(64, cfg) == (768, 256)
+    text = _expert_layer_text(one_chip, cfg, 64)
+    assert len(_calls(text, "moe_rows_in")) == 1
+    assert len(_calls(text, "moe_rows_out")) == 1
+    assert not re.findall(r"^.* sort\(.*moe_experts.*$", text, re.M)

@@ -582,3 +582,61 @@ class TestGatedDeltaRuleOnChip:
                                    atol=1e-5)
         np.testing.assert_allclose(np.asarray(o1), np.asarray(on),
                                    atol=1e-5)
+
+
+class TestExpertRowsOnChip:
+    """PR 36: `moe_rows_in` and `moe_rows_out` compiled by Mosaic at the
+    two served cells' prefill shapes (8,192 tokens; 2,048 columns with
+    ~8,960 pairs of 10 choices, 4,096 columns with ~7,168 of 8), with
+    every token on held experts (every choice a pair), and at a decode
+    step's, against the rows themselves and the float64 dense sum."""
+
+    @pytest.mark.parametrize("t,d,k,n_pairs,chunk", [
+        (8192, 2048, 10, 8960, 12800),      # qwen3-next-80b-a3b-ep8
+        (8192, 4096, 8, 7168, 10240),       # command-a-plus-ep8
+        (1024, 2048, 10, 10240, 10240),     # every choice a pair
+        (1024, 4096, 8, 8192, 8192),
+        (64, 2048, 10, 75, 256),            # a decode step
+    ])
+    def test_rows_in_and_out_match_the_dense_sum(self, t, d, k, n_pairs,
+                                                 chunk):
+        import jax
+        import jax.numpy as jnp
+
+        from deeplearning4j_tpu.models import moe_rows
+
+        assert jax.devices()[0].platform == "tpu"
+        rng = np.random.RandomState(3)
+        h = jnp.asarray(rng.randn(t, d), jnp.bfloat16)
+        # n_pairs distinct (token, choice) pairs, in a sorted order of
+        # their own: row i of the sorted buffers is pair flat[i]
+        flat = rng.permutation(t * k)[:n_pairs]
+        tok = np.zeros((chunk,), np.int32)
+        tok[:n_pairs] = flat // k
+        pos = np.full((t * k,), -1, np.int32)
+        pos[flat] = np.arange(n_pairs)
+        w = rng.rand(t, k).astype(np.float32)
+        y = jnp.asarray(rng.randn(chunk, d), jnp.float32)
+
+        fn_in = jax.jit(moe_rows.rows_in)
+        assert "moe_rows_in" in fn_in.lower(
+            h, jnp.asarray(tok), jnp.int32(n_pairs)).as_text()
+        x = fn_in(h, jnp.asarray(tok), jnp.int32(n_pairs))
+        assert x.shape == (chunk, d) and x.dtype == jnp.bfloat16
+        assert np.array_equal(
+            np.asarray(x[:n_pairs].astype(jnp.float32)),
+            np.asarray(h.astype(jnp.float32))[tok[:n_pairs]])
+
+        fn_out = jax.jit(moe_rows.rows_out)
+        assert "moe_rows_out" in fn_out.lower(
+            y, jnp.asarray(pos.reshape(t, k)), jnp.asarray(w)).as_text()
+        out = fn_out(y, jnp.asarray(pos.reshape(t, k)), jnp.asarray(w))
+        dense = np.zeros((t, d), np.float64)
+        np.add.at(dense, flat // k,
+                  w.reshape(-1)[flat].astype(np.float64)[:, None]
+                  * np.asarray(y[:n_pairs], np.float64))
+        np.testing.assert_allclose(np.asarray(out, np.float64), dense,
+                                   atol=1e-5)
+        # tokens with no pair come back as exact zeros
+        none = np.setdiff1d(np.arange(t), flat // k)
+        assert not np.asarray(out)[none].any()
