@@ -374,6 +374,77 @@ def flash_attention(q, k, v, causal: bool = False, q_tile: int = 1024,
     return out.reshape(q.shape)
 
 
+# ------------------------------------------------- a query offset a row
+def _ctx_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, *rest, heads: int,
+                **kw):
+    """`_kernel` with the causal offset read from SMEM: row `bi` of the
+    flattened (batch x heads) belongs to batch row `bi // heads`."""
+    from jax.experimental import pallas as pl
+
+    _kernel(q_ref, k_ref, v_ref, o_ref, *rest,
+            causal_offset=off_ref[pl.program_id(0) // heads], **kw)
+
+
+def flash_attention_ctx(q, k, v, offset, q_tile: int = 1024,
+                        block_k: int = 1024, interpret: bool = False):
+    """Causal attention of a piece of a sequence over everything up to
+    it: q (B, Hq, Tq, d) are the queries at positions `offset[b] + i`,
+    k and v (B, Hkv, Tk, d) hold the keys at positions 0..Tk-1 (what
+    came before the piece AND the piece itself), query row i sees key
+    j iff j <= offset[b] + i. `offset` (B,) int32 is traced: one
+    program serves every context length, KV blocks wholly beyond a
+    tile's last query are neither computed nor fetched (their block
+    index is held at the last one needed, and a block that does not
+    change is not copied again), so no (Tq, Tk) array of scores is ever
+    built and a short context costs what it holds. Forward only.
+    Shapes with no 128-aligned tile take the dense masked read."""
+    from deeplearning4j_tpu.attention.blockwise import masked_attention
+
+    _check_grouped(q, k, True, None)
+    b, hq, t_q, d = q.shape
+    hkv, t_k = k.shape[1], k.shape[2]
+    offset = jnp.asarray(offset, jnp.int32)
+    qt, bk = _fit_tile(t_q, q_tile), _fit_tile(t_k, block_k)
+    if qt is None or bk is None or t_q == 1:
+        seen = jnp.arange(t_k)[None, None, :] <= (
+            offset[:, None, None] + jnp.arange(t_q)[None, :, None])
+        return masked_attention(q, k, v, seen).astype(q.dtype)
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    kv_rows = hq // hkv
+
+    def q_map(bi, qi, ki, off):
+        return bi, qi, 0
+
+    def kv_map(bi, qi, ki, off):
+        last = ((qi + 1) * qt - 1 + off[bi // hq]) // bk
+        return bi // kv_rows, jnp.minimum(ki, last), 0
+
+    out = pl.pallas_call(
+        partial(_ctx_kernel, heads=hq, causal=True, q_tile=qt,
+                block_k=bk, group=1, want_lse=False),
+        out_shape=jax.ShapeDtypeStruct((b * hq, t_q, d), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b * hq, t_q // qt, t_k // bk),
+            in_specs=[
+                pl.BlockSpec((1, qt, d), q_map, memory_space=pltpu.VMEM),
+                pl.BlockSpec((1, bk, d), kv_map, memory_space=pltpu.VMEM),
+                pl.BlockSpec((1, bk, d), kv_map, memory_space=pltpu.VMEM)],
+            out_specs=pl.BlockSpec((1, qt, d), q_map,
+                                   memory_space=pltpu.VMEM),
+            scratch_shapes=[pltpu.VMEM((1, qt, d), jnp.float32),
+                            pltpu.VMEM((1, qt, 1), jnp.float32),
+                            pltpu.VMEM((1, qt, 1), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="prefill_ctx_flash",
+    )(offset, q.reshape(b * hq, t_q, d), k.reshape(b * hkv, t_k, d),
+      v.reshape(b * hkv, t_k, d))
+    return out.reshape(q.shape)
+
+
 # --------------------------------------------------------------- backward
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dq_ref,
                    dq_acc, *, causal: bool, q_tile: int, block_k: int,

@@ -3,7 +3,9 @@ prefill (`gdn_scan`) and the one-token state update of a decode step
 (`gdn_update`).
 
 The recurrence, a value head (state `S` (dk, dv) float32, zero at the
-start of a sequence; q and k already normalised, q scaled):
+start of a sequence; q and k already normalised, q scaled; `beta` the
+write strength, in (0, 1) or, where the model lets `I - beta k k^T` have
+a negative eigenvalue, in (0, 2)):
 
     S' = exp(g_t) S_{t-1}
     S_t = S' + k_t (beta_t (v_t - S'^T k_t))^T
@@ -20,7 +22,12 @@ which is exact.
 
 `gdn_scan` is the chunked form (Gated DeltaNet, arXiv:2412.06464, the
 WY / UT transform): inside a chunk of C tokens everything is products,
-between chunks only the state is carried. With `gc` the running sum of g
+between chunks only the state is carried (the first chunk starts from
+the state the caller hands in: zero at the start of a sequence, a slot's
+kept state where a prompt is prefilled a piece at a time). Neither dk
+nor dv need be a multiple of the chip's 128 lanes, nor alike (96 x 192
+runs as it is, a block spanning the whole of either). With `gc` the
+running sum of g
 inside the chunk, `L[i, j] = exp(gc_i - gc_j)` for i >= j (never above
 1), `A = strictly_lower((k beta) k^T * L)` and `T = (I + A)^-1`:
 
@@ -157,15 +164,15 @@ def _chunk(q, k, v, gc, beta, s, prec):
 
 
 # ------------------------------------------------------------ the scan
-def _scan_kernel(q_ref, k_ref, v_ref, gb_ref, o_ref, s_ref, s_scr, *,
-                 heads: int, prec):
+def _scan_kernel(q_ref, k_ref, v_ref, gb_ref, s0_ref, o_ref, s_ref, s_scr,
+                 *, heads: int, prec):
     from jax.experimental import pallas as pl
 
     ci = pl.program_id(1)
 
     @pl.when(ci == 0)
     def _init():
-        s_scr[...] = jnp.zeros_like(s_scr)
+        s_scr[...] = s0_ref[...]
 
     for h in range(heads):
         gb = gb_ref[h, 0]                                # (2, C) f32
@@ -187,28 +194,29 @@ def _head_block(n: int, most: int) -> int:
 
 
 @partial(jax.jit, static_argnames=("chunk", "interpret", "kernel"))
-def _gdn_scan(q, k, v, g, beta, *, chunk, interpret, kernel):
+def _gdn_scan(q, k, v, g, beta, s0, *, chunk, interpret, kernel):
     b, h, t, dk = q.shape
     dv = v.shape[-1]
     n = t // chunk
     bh = b * h
+    s0 = s0.astype(jnp.float32).reshape(bh, dk, dv)
     gc = jnp.cumsum(g.astype(jnp.float32).reshape(bh, n, chunk), axis=-1)
     gb = jnp.stack([gc, beta.astype(jnp.float32).reshape(bh, n, chunk)],
                    axis=2)                               # (BH, N, 2, C)
     q, k, v = (a.reshape(bh, t, a.shape[-1]) for a in (q, k, v))
     prec = _prec(q.dtype)
     if not kernel:
-        def one_head(q, k, v, gb):
+        def one_head(q, k, v, gb, s0):
             def step(s, x):
                 o, s = _chunk(x[0], x[1], x[2], x[3][0:1], x[3][1:2], s,
                               prec)
                 return s, o
             s, o = jax.lax.scan(
-                step, jnp.zeros((dk, dv), jnp.float32),
+                step, s0,
                 (q.reshape(n, chunk, dk), k.reshape(n, chunk, dk),
                  v.reshape(n, chunk, dv), gb))
             return o.reshape(t, dv), s
-        o, s = jax.vmap(one_head)(q, k, v, gb)
+        o, s = jax.vmap(one_head)(q, k, v, gb, s0)
         return (o.astype(v.dtype).reshape(b, h, t, dv),
                 s.reshape(b, h, dk, dv))
     from jax.experimental import pallas as pl
@@ -220,16 +228,19 @@ def _gdn_scan(q, k, v, g, beta, *, chunk, interpret, kernel):
         return pl.BlockSpec((hb, chunk, d), lambda i, c: (i, c, 0),
                             memory_space=pltpu.VMEM)
 
+    def whole_state():
+        return pl.BlockSpec((hb, dk, dv), lambda i, c: (i, 0, 0),
+                            memory_space=pltpu.VMEM)
+
     o, s = pl.pallas_call(
         partial(_scan_kernel, heads=hb, prec=prec),
         grid=(bh // hb, n),
         in_specs=[rows(dk), rows(dk), rows(dv),
                   pl.BlockSpec((hb, 1, 2, chunk),
                                lambda i, c: (i, c, 0, 0),
-                               memory_space=pltpu.VMEM)],
-        out_specs=(rows(dv),
-                   pl.BlockSpec((hb, dk, dv), lambda i, c: (i, 0, 0),
-                                memory_space=pltpu.VMEM)),
+                               memory_space=pltpu.VMEM),
+                  whole_state()],
+        out_specs=(rows(dv), whole_state()),
         out_shape=(jax.ShapeDtypeStruct((bh, t, dv), v.dtype),
                    jax.ShapeDtypeStruct((bh, dk, dv), jnp.float32)),
         scratch_shapes=[pltpu.VMEM((hb, dk, dv), jnp.float32)],
@@ -237,22 +248,26 @@ def _gdn_scan(q, k, v, g, beta, *, chunk, interpret, kernel):
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="gdn_scan",
-    )(q, k, v, gb)
+    )(q, k, v, gb, s0)
     return o.reshape(b, h, t, dv), s.reshape(b, h, dk, dv)
 
 
-def gdn_scan(q, k, v, g, beta, *, chunk: int = CHUNK,
+def gdn_scan(q, k, v, g, beta, *, state=None, chunk: int = CHUNK,
              interpret: bool = False):
-    """The recurrence over whole sequences from a zero state: q, k
-    (B, H, T, dk) and v (B, H, T, dv) in one type, g (log decay, <= 0)
-    and beta (B, H, T) float32, T a multiple of `chunk`. Returns (o
-    (B, H, T, dv) in v's type, the state after the last token (B, H,
-    dk, dv) float32). Tokens with g = 0 and beta = 0 leave the state as
-    it is (padding)."""
-    t = q.shape[2]
+    """The recurrence over whole sequences: q, k (B, H, T, dk) and v
+    (B, H, T, dv) in one type, g (log decay, <= 0) and beta (B, H, T)
+    float32, T a multiple of `chunk`; `state` (B, H, dk, dv) the state
+    before the first token (None: zero, the start of a sequence).
+    Returns (o (B, H, T, dv) in v's type, the state after the last
+    token (B, H, dk, dv) float32). Tokens with g = 0 and beta = 0 leave
+    the state as it is (padding), so with a padded tail the state
+    returned is the one after the last REAL token."""
+    b, h, t, dk = q.shape
     if t % chunk:
         raise ValueError(f"{t} tokens are no whole chunks of {chunk}")
-    return _gdn_scan(q, k, v, g, beta, chunk=int(chunk),
+    if state is None:
+        state = jnp.zeros((b, h, dk, v.shape[-1]), jnp.float32)
+    return _gdn_scan(q, k, v, g, beta, state, chunk=int(chunk),
                      interpret=bool(interpret),
                      kernel=bool(interpret)
                      or jax.default_backend() == "tpu")
@@ -265,7 +280,7 @@ def _update_kernel(eg_ref, beta_ref, kq_ref, v_ref, s_in, o_ref, s_out, *,
 
     si, bi = pl.program_id(0), pl.program_id(1)
     kq = kq_ref[0, 0]                                  # (dk, 128)
-    v = v_ref[0]                                       # (heads, dv)
+    v = v_ref[0, 0]                                    # (heads, dv)
     lane = jax.lax.broadcasted_iota(jnp.int32, (kq.shape[1], v.shape[-1]),
                                     0)
 
@@ -284,7 +299,7 @@ def _update_kernel(eg_ref, beta_ref, kq_ref, v_ref, s_in, o_ref, s_out, *,
         delta = (v[h:h + 1].astype(jnp.float32) - mem) * beta_ref[at]
         s = s + k_col * delta
         s_out[0, h] = s
-        o_ref[0, h:h + 1] = jnp.sum(s * q_col, axis=0, keepdims=True)
+        o_ref[0, 0, h:h + 1] = jnp.sum(s * q_col, axis=0, keepdims=True)
 
 
 @partial(jax.jit, static_argnames=("interpret", "kernel"))
@@ -305,6 +320,9 @@ def _gdn_update(state, q, k, v, g, beta, *, interpret, kernel):
 
     hb = _head_block(h, 16)
     nb = h // hb
+    # v and o go in and out as (N, blocks, hb, dv), a block spanning
+    # the whole of its last two dimensions: hb need then be no multiple
+    # of 8 sublanes (30 heads run as two blocks of 15)
     # positions along sublanes, heads along lanes: lane j < hb is head
     # j's k, lane hb + j its q, the rest of the 128 lanes zero
     kq = jnp.concatenate([k.reshape(n, nb, hb, dk),
@@ -319,18 +337,20 @@ def _gdn_update(state, q, k, v, g, beta, *, interpret, kernel):
                 pl.BlockSpec((1, 1, dk, 128),
                              lambda s, b, *_: (s, b, 0, 0),
                              memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, hb, dv), lambda s, b, *_: (s, b, 0),
+                pl.BlockSpec((1, 1, hb, dv),
+                             lambda s, b, *_: (s, b, 0, 0),
                              memory_space=pltpu.VMEM),
                 pl.BlockSpec((1, hb, dk, dv),
                              lambda s, b, *_: (s, b, 0, 0),
                              memory_space=pltpu.VMEM)],
             out_specs=(
-                pl.BlockSpec((1, hb, dv), lambda s, b, *_: (s, b, 0),
+                pl.BlockSpec((1, 1, hb, dv),
+                             lambda s, b, *_: (s, b, 0, 0),
                              memory_space=pltpu.VMEM),
                 pl.BlockSpec((1, hb, dk, dv),
                              lambda s, b, *_: (s, b, 0, 0),
                              memory_space=pltpu.VMEM))),
-        out_shape=(jax.ShapeDtypeStruct((n, h, dv), f32),
+        out_shape=(jax.ShapeDtypeStruct((n, nb, hb, dv), f32),
                    jax.ShapeDtypeStruct(state.shape, f32)),
         # operand 4 (after the two scalar operands, kq_t and v) is the
         # state: its buffer is the new state's
@@ -339,8 +359,9 @@ def _gdn_update(state, q, k, v, g, beta, *, interpret, kernel):
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="gdn_update",
-    )(eg.reshape(-1), beta.reshape(-1), kq_t, v32, state)
-    return o, state
+    )(eg.reshape(-1), beta.reshape(-1), kq_t, v32.reshape(n, nb, hb, dv),
+      state)
+    return o.reshape(n, h, dv), state
 
 
 def gdn_update(state, q, k, v, g, beta, *, interpret: bool = False):

@@ -1,31 +1,39 @@
 """A third language-model block: layers that keep a recurrent STATE
-beside layers that keep K/V, one expert layer after either.
+beside layers that keep K/V, a feed-forward layer after either.
 
-Sequential pre-norm residuals with RMSNorm, two norms a layer:
-`x = x + mixer(n_1(x))`, then `x = x + moe(n_2(x))`. Layer `l` is one of
+Sequential residuals with RMSNorm, two norms a layer, where the
+configuration says (`cfg.norm_place`): "pre", `x = x + mixer(n_1(x))`
+then `x = x + ff(n_2(x))`, or "post", `x = x + n_1(mixer(x))` then `x =
+x + n_2(ff(x))`, the norm on the sublayer's OUTPUT. Layer `l` is one of
 two kinds (`cfg.layer_kinds`, published as "three linear, one full"):
 
 - **full** (`KIND_FULL`): `n_heads` query heads over `n_kv_heads` K/V
-  heads of `head_dim`; `Wq` gives each head its query AND an output
-  gate (`[q_n | gate_n]` side by side); q and k take an RMSNorm over
-  the head; the first `rotary_dim` dimensions of q and k turn by
-  position in the half-split pairing `(i, i + rotary_dim / 2)`; causal
-  softmax attention; `out = (a * sigmoid(gate)) Wo`.
+  heads of `head_dim`; with `cfg.attn_gate`, `Wq` gives each head its
+  query AND an output gate (`[q_n | gate_n]` side by side) and `out =
+  (a * sigmoid(gate)) Wo`, without it `out = a Wo`; q and k take an
+  RMSNorm over the head (`cfg.qk_norm = "head"`) or over all the
+  heads' columns at once ("width"); the first `rotary_dim` dimensions
+  of q and k turn by position in the half-split pairing `(i, i +
+  rotary_dim / 2)` (`rotary_dim = 0`: no rotation, order comes from
+  the linear layers); causal softmax attention.
 - **linear** (`KIND_LINEAR`, the gated delta rule): `[q | k | v | z] =
   h W_qkvz`, `[b | a] = h W_ba`; a short causal depthwise convolution
   and SiLU over `q | k | v`; q and k L2-normalised a head, q scaled by
-  `1 / sqrt(dk)`; `beta = sigmoid(b)`, `g = -exp(A_log) * softplus(a +
+  `1 / sqrt(dk)`; `beta = sigmoid(b)`, doubled where
+  `cfg.allow_neg_eigval` (a write strength in (0, 2): `I - beta k k^T`
+  may then turn a direction round), `g = -exp(A_log) * softplus(a +
   dt_bias)` a value head, in float32; the recurrence of
   `attention/gdn_pallas.py` over a (dk, dv) state a value head (value
   head n reads the q and k of key head `n // (Hv / Hk)`); `y =
   RMSNorm_dv(o) * silu(z)`, `out = y W_out`.
 
-The expert layer is `models/moe_transformer.expert_layer`, the one
-there is: this configuration says `router_score = "softmax"` and
+The feed-forward layer is `models/moe_transformer.expert_layer`, the
+one there is (this configuration says `router_score = "softmax"` and
 `shared_combine = "sigmoid_gate"` where that module's own says sigmoid
-and average. What this chip holds of the experts and the vocabulary is
-stated as there (`n_held`, `held_first`, rows `[0, vocab_size)`); the
-head is untied.
+and average), or, where the configuration has no experts (`n_experts =
+0`), one dense gated-SiLU product of width `d_ff`. What this chip holds
+of the experts and the vocabulary is stated as there (`n_held`,
+`held_first`, rows `[0, vocab_size)`); the head is untied.
 
 **The block stands once.** A full layer leaves "write these K/V rows,
 read what is visible" to its `attend` callback as every model does
@@ -38,7 +46,8 @@ every callback with what its cache holds: nothing (the uncached
 forward: zero state, the whole scan), a row's real length (the paged
 prefill: padding must not move the state, and the convolution keeps
 the last three REAL columns), or a slot's kept columns and state (the
-decode step: one token, updated in place).
+decode step: one token, updated in place; a later piece of a prompt
+that is prefilled in pieces: the scan from the kept state).
 """
 
 from __future__ import annotations
@@ -90,6 +99,15 @@ class HybridConfig(NamedTuple):
     #: how `expert_layer` scores and how it adds the shared experts
     router_score: str = "softmax"
     shared_combine: str = "sigmoid_gate"
+    #: where a layer's two norms stand: "pre" on a sublayer's input,
+    #: "post" on its output
+    norm_place: str = "pre"
+    #: beta = 2 sigmoid(b): `I - beta k k^T` may have the eigenvalue -1
+    allow_neg_eigval: bool = False
+    #: a full layer's output gate beside each query head in `Wq`
+    attn_gate: bool = True
+    #: the norm of q and k: "head" by head, "width" over all heads
+    qk_norm: str = "head"
 
     @property
     def n_layers(self) -> int:
@@ -125,6 +143,16 @@ class HybridConfig(NamedTuple):
                              f"{self.lin_v_heads} value heads")
         if self.rotary_dim % 2 or self.rotary_dim > self.head_dim:
             raise ValueError("rotary_dim is even and at most head_dim")
+        if self.norm_place not in ("pre", "post"):
+            raise ValueError(f"norm_place is 'pre' or 'post', got "
+                             f"{self.norm_place!r}")
+        if self.qk_norm not in ("head", "width"):
+            raise ValueError(f"qk_norm is 'head' or 'width', got "
+                             f"{self.qk_norm!r}")
+        if not self.n_experts and (self.n_held or self.n_shared
+                                   or self.experts_per_token):
+            raise ValueError("a dense feed-forward (n_experts = 0) has "
+                             "no held, shared or chosen experts")
         bad = [k for k in self.layer_kinds
                if k not in (KIND_FULL, KIND_LINEAR)]
         if bad or not self.layer_kinds:
@@ -160,21 +188,31 @@ def init_hybrid_params(key, cfg: HybridConfig):
     blocks = []
     for i, kind in enumerate(cfg.layer_kinds):
         k = jax.random.split(keys[2 + i], 16)
-        p = {"ln1": gain(d), "ln2": gain(d),
-             "router": normal(k[0], (d, cfg.n_experts)),
-             "experts": {"gate": normal(k[1], (cfg.n_held, d, f)),
-                         "up": normal(k[2], (cfg.n_held, d, f)),
-                         "down": normal(k[3], (cfg.n_held, f, d))},
-             "shared": {"gate": normal(k[4], (cfg.n_shared, d, f)),
-                        "up": normal(k[5], (cfg.n_shared, d, f)),
-                        "down": normal(k[6], (cfg.n_shared, f, d))},
-             "shared_gate": normal(k[7], (d, cfg.n_shared))}
+        p = {"ln1": gain(d), "ln2": gain(d)}
+        if cfg.n_experts:
+            p.update({
+                "router": normal(k[0], (d, cfg.n_experts)),
+                "experts": {"gate": normal(k[1], (cfg.n_held, d, f)),
+                            "up": normal(k[2], (cfg.n_held, d, f)),
+                            "down": normal(k[3], (cfg.n_held, f, d))},
+                "shared": {"gate": normal(k[4], (cfg.n_shared, d, f)),
+                           "up": normal(k[5], (cfg.n_shared, d, f)),
+                           "down": normal(k[6], (cfg.n_shared, f, d))},
+                "shared_gate": normal(k[7], (d, cfg.n_shared))})
+        else:
+            p.update({"W_gate": normal(k[1], (d, f)),
+                      "W_up": normal(k[2], (d, f)),
+                      "W_down": normal(k[3], (f, d))})
         if kind == KIND_FULL:
-            p.update({"Wq": normal(k[8], (d, 2 * cfg.n_heads * hd)),
+            wide = cfg.qk_norm == "width"
+            p.update({"Wq": normal(k[8], (d, (1 + cfg.attn_gate)
+                                          * cfg.n_heads * hd)),
                       "Wk": normal(k[9], (d, cfg.n_kv_heads * hd)),
                       "Wv": normal(k[10], (d, cfg.n_kv_heads * hd)),
                       "Wo": normal(k[11], (cfg.n_heads * hd, d)),
-                      "q_norm": gain(hd), "k_norm": gain(hd)})
+                      "q_norm": gain(cfg.n_heads * hd if wide else hd),
+                      "k_norm": gain(cfg.n_kv_heads * hd if wide
+                                     else hd)})
         else:
             p.update({"W_qkvz": normal(k[8], (d, 2 * hk * dk
                                               + 2 * hv * dv)),
@@ -234,8 +272,8 @@ def linear_mix(cfg: HybridConfig, u, gates, conv_w, *, prev=None,
     padded past it, which must move neither the state (beta = g = 0
     there) nor the kept columns (the last REAL ones). One token with a
     `state` is the decode step's update in place; anything else is the
-    chunked scan from a zero state. Returns (o (B, T, Hv, dv), {"state",
-    "conv"} after the last real token)."""
+    chunked scan, from `state` or from zero. Returns (o (B, T, Hv, dv),
+    {"state", "conv"} after the last real token)."""
     g, beta = gates
     b, t, c = u.shape
     kk = cfg.conv_kernel - 1
@@ -279,10 +317,6 @@ def linear_mix(cfg: HybridConfig, u, gates, conv_w, *, prev=None,
                                   interpret=cfg.interpret)
             o = o[:, None]
     else:
-        if state is not None:
-            raise NotImplementedError(
-                "the chunked scan starts from a zero state: several "
-                "tokens on top of a kept state are not written")
         with jax.named_scope("gdn_scan"):
             pad = -t % CHUNK
 
@@ -296,7 +330,8 @@ def linear_mix(cfg: HybridConfig, u, gates, conv_w, *, prev=None,
 
             o, state = gdn_scan(time_major(q), time_major(k),
                                 time_major(v), time_major(g),
-                                time_major(beta), interpret=cfg.interpret)
+                                time_major(beta), state=state,
+                                interpret=cfg.interpret)
             o = jnp.moveaxis(o[:, :, :t], 1, 2)
     return o, {"state": state, "conv": kept.reshape(b, kk * c)}
 
@@ -310,6 +345,8 @@ def _linear_layer(p, h, cfg: HybridConfig, attend: Attend, layer: int):
         ba = jnp.dot(h, p["W_ba"],
                      preferred_element_type=jnp.float32)
         beta = jax.nn.sigmoid(ba[..., :hv])
+        if cfg.allow_neg_eigval:
+            beta = 2.0 * beta
         g = -jnp.exp(p["A_log"].astype(jnp.float32)) * jax.nn.softplus(
             ba[..., hv:] + p["dt_bias"].astype(jnp.float32))
     o, entry = attend(layer, KIND_LINEAR, u, (g, beta), p["conv"])
@@ -324,20 +361,40 @@ def _full_layer(p, h, positions, cfg: HybridConfig, attend: Attend,
                 layer: int):
     b, t, _ = h.shape
     hq, hd = cfg.n_heads, cfg.head_dim
-    qg = (h @ p["Wq"]).reshape(b, t, hq, 2 * hd)
-    q, gate = qg[..., :hd], qg[..., hd:]
-    k = (h @ p["Wk"]).reshape(b, t, cfg.n_kv_heads, hd)
+    q, k, gate = h @ p["Wq"], h @ p["Wk"], None
+    if cfg.attn_gate:
+        qg = q.reshape(b, t, hq, 2 * hd)
+        q, gate = qg[..., :hd], qg[..., hd:]
+    if cfg.qk_norm == "width":
+        q = _rms_norm(p["q_norm"], q.reshape(b, t, hq * hd), cfg.rms_eps)
+        k = _rms_norm(p["k_norm"], k, cfg.rms_eps)
+    q = q.reshape(b, t, hq, hd)
+    k = k.reshape(b, t, cfg.n_kv_heads, hd)
     v = (h @ p["Wv"]).reshape(b, t, cfg.n_kv_heads, hd)
-    q = rope_half(_rms_norm(p["q_norm"], q, cfg.rms_eps), positions,
-                  cfg.rope_theta, cfg.rotary_dim)
-    k = rope_half(_rms_norm(p["k_norm"], k, cfg.rms_eps), positions,
-                  cfg.rope_theta, cfg.rotary_dim)
+    if cfg.qk_norm == "head":
+        q = _rms_norm(p["q_norm"], q, cfg.rms_eps)
+        k = _rms_norm(p["k_norm"], k, cfg.rms_eps)
+    if cfg.rotary_dim:
+        q = rope_half(q, positions, cfg.rope_theta, cfg.rotary_dim)
+        k = rope_half(k, positions, cfg.rope_theta, cfg.rotary_dim)
     att, entry = attend(layer, KIND_FULL, q.transpose(0, 2, 1, 3),
                         k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
-    with jax.named_scope("attn_gate"):
-        att = att.astype(jnp.float32).transpose(0, 2, 1, 3) \
-            * jax.nn.sigmoid(gate.astype(jnp.float32))
+    att = att.transpose(0, 2, 1, 3)
+    if gate is not None:
+        with jax.named_scope("attn_gate"):
+            att = att.astype(jnp.float32) \
+                * jax.nn.sigmoid(gate.astype(jnp.float32))
     return att.astype(h.dtype).reshape(b, t, hq * hd) @ p["Wo"], entry
+
+
+def _dense_ff(p, h):
+    """(silu(h W_gate) * (h W_up)) W_down, float32 out."""
+    with jax.named_scope("dense_ff"):
+        act = jax.nn.silu(jnp.dot(h, p["W_gate"],
+                                  preferred_element_type=jnp.float32)) \
+            * jnp.dot(h, p["W_up"], preferred_element_type=jnp.float32)
+        return jnp.dot(act.astype(h.dtype), p["W_down"],
+                       preferred_element_type=jnp.float32)
 
 
 # ------------------------------------------------------------- the block
@@ -347,17 +404,26 @@ def block(p, x, positions, layer: int, cfg: HybridConfig, attend: Attend,
     written. Returns (x', the cache's new entry for this layer, pairs
     by held expert)."""
     b, t, d = x.shape
-    h = _rms_norm(p["ln1"], x, cfg.rms_eps)
+    pre = cfg.norm_place == "pre"
+    h = _rms_norm(p["ln1"], x, cfg.rms_eps) if pre else x
     if cfg.layer_kinds[layer] == KIND_LINEAR:
         mix, entry = _linear_layer(p, h, cfg, attend, layer)
     else:
         mix, entry = _full_layer(p, h, positions, cfg, attend, layer)
+    if not pre:
+        mix = _rms_norm(p["ln1"], mix, cfg.rms_eps)
     x = x + mix.astype(x.dtype)
-    h = _rms_norm(p["ln2"], x, cfg.rms_eps)
-    moe, pairs = expert_layer(
-        p, h.reshape(b * t, d), cfg,
-        None if valid is None else valid.reshape(b * t))
-    x = (x.astype(jnp.float32) + moe.reshape(b, t, d)).astype(x.dtype)
+    h = _rms_norm(p["ln2"], x, cfg.rms_eps) if pre else x
+    if cfg.n_experts:
+        ff, pairs = expert_layer(
+            p, h.reshape(b * t, d), cfg,
+            None if valid is None else valid.reshape(b * t))
+        ff = ff.reshape(b, t, d)
+    else:
+        ff, pairs = _dense_ff(p, h), None
+    if not pre:
+        ff = _rms_norm(p["ln2"], ff, cfg.rms_eps)
+    x = (x.astype(jnp.float32) + ff).astype(x.dtype)
     return x, entry, pairs
 
 
@@ -366,14 +432,14 @@ def forward(params, tokens, positions, cfg: HybridConfig, attend: Attend,
     """Every block over tokens (B, T) at `positions` (B, T), or (T,)
     where every row stands at the same ones. Returns (hidden (B, T, d)
     before the final norm, the cache entries a layer, pairs (layers,
-    n_held) int32)."""
+    n_held) int32, or () where the feed-forward is dense)."""
     x = params["embed"][tokens]
     entries, pairs = [], []
     for i, p in enumerate(params["blocks"]):
         x, entry, n = block(p, x, positions, i, cfg, attend, valid)
         entries.append(entry)
         pairs.append(n)
-    return x, tuple(entries), jnp.stack(pairs)
+    return x, tuple(entries), jnp.stack(pairs) if cfg.n_experts else ()
 
 
 def head(params, x, cfg: HybridConfig):

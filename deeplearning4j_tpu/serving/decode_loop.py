@@ -261,12 +261,15 @@ table and no free list: admission is bounded by slots, and by the full
 kind's pages as ever. A cold prefill is told each row's slot beside its
 pages (`page_ids["linear"]`) and overwrites that slot's state whole, so
 a retired slot's state never reaches the next request; an idle slot's
-update is masked in the step. Whatever reuses a page would need the
-state at that page's end, which nothing keeps: prefix sharing (and with
-it copy-on-write and `/kv/export`), speculation, a horizon above 1 and
-the prefill role are refused by name. Preemption retires the slot and
-the router re-admits prompt + delivered: an honest second scan.
-`snapshot()["state"]` says what the kind holds.
+update is masked in the step. The slot keeps ONE state, the newest: a
+later piece of a prompt that is prefilled in pieces starts from it
+(below), but whatever reuses a page would need the state at that page's
+end, which nothing keeps (snapshots at a page boundary are not
+written): prefix sharing (and with it copy-on-write and `/kv/export`),
+speculation, a horizon above 1 and the prefill role are refused by
+name. Preemption retires the slot and the router re-admits prompt +
+delivered: an honest second scan. `snapshot()["state"]` says what the
+kind holds.
 
 **A bound on the tokens a pass prefills** (`prefill_tokens_per_pass`,
 None = no bound): a pass stops claiming queued requests once the rows it
@@ -276,14 +279,39 @@ A burst of long prompts then neither needs the memory of one program
 over all of them nor holds every running stream for the sum of their
 prefills.
 
+**A prompt longer than the bound is prefilled a piece a pass**
+(`_continue_prefills`; where pages are not shared by content and no
+layer has a window, else prompts stay whole). The piece is the largest
+bucket within the bound. Admission claims the slot and ALL the prompt's
+pages and prefills the first piece by the cold program; the slot then
+rides no decode step (no table row, stop 0: the step masks it and its
+state is its own). Every later pass first enqueues the next piece of
+every such prompt, oldest first, as far as the bound allows (always
+one), through ONE program a bucket whatever the context's length
+(`prefill_chunk_fn`, `paged_kinds.prefill_ctx`): a linear layer scans on
+from the state and the columns its slot kept, a full layer writes the
+piece's K/V to its pages and attends over the pages of the pieces
+before it and its own through the flash kernel with a query offset
+(the loop's kernel lane), no array of scores. Then the pass admits what
+the bound still leaves and enqueues the decode step: the running
+streams wait for one piece between two of their tokens, never for the
+whole prompt. The last piece (its bucket no narrower than a quarter of
+a piece: three programs) gives the first token, and the slot joins the
+next step. `snapshot()["prefill_chunks"]` counts the pieces of such
+prompts, `first` from a zero state and `carried` on a kept one, and
+their tokens (dl4j_prefill_chunks{loop,carried},
+dl4j_prefill_chunk_tokens{loop}).
+
 **Spans** (`telemetry.span`, names in `PHASES`): every scheduler pass
 is one `decode.tick` whose children are the phases of the pass, so each
 nanosecond of a pass lies in exactly one child or in the tick's self
 time. Their seconds and counts are always on
 (dl4j_decode_phase_seconds{loop,phase}, `snapshot()["phases"]`), the
 longest pass of each of the last `SLOW_TICKS_KEPT` intervals is kept
-with what it was made of (`snapshot()["slow_ticks"]`), and every span is
-a TraceMe of the same name, so a `jax.profiler` window holds the
+with what it was made of (`snapshot()["slow_ticks"]`;
+`decode.prefill_dispatch` carries `bb`, `tb`, `rows`, `tokens`, the
+context pages it reads `ctx` and `carried`, whether it starts from a
+kept state), and every span is a TraceMe of the same name, so a `jax.profiler` window holds the
 scheduler's phases on the device trace's clock. A request carries four
 stamps of its own life (`GenerationStream.timeline()`); the wait in the
 queue also feeds dl4j_decode_queue_wait_seconds.
@@ -383,8 +411,10 @@ _TIER_ITEM_MS = {TIER_INTERACTIVE: 50.0, TIER_BATCH: 250.0}
 #: stands in the way and by what was asked. A window kind gives its
 #: pages back as the cursor moves (and an expert layer's counts are read
 #: back from the plain step and the cold prefill only); a linear kind's
-#: state is no page: whatever reuses a page would need the state at that
-#: page's end, and snapshots of state are not written
+#: state is no page: a slot keeps the newest state alone (enough to go
+#: on from, as a prompt prefilled in pieces does), so whatever reuses a
+#: page would need the state at that page's end, and snapshots of state
+#: at a page boundary are not written
 _REFUSALS = {
     paged_kinds.KIND_WINDOW: {
         "prefix_cache":
@@ -408,7 +438,9 @@ _REFUSALS = {
             "prefix sharing (and with it copy-on-write forks and "
             "/kv/export) is not written for a model with linear "
             "layers (a shared prefix's pages say nothing of the "
-            "recurrent state at its end): pass prefix_cache=False",
+            "recurrent state at its end, and a slot keeps only its "
+            "newest state, no snapshot at a page boundary): pass "
+            "prefix_cache=False",
         "speculation":
             "speculation is not written for a model with linear "
             "layers (a rejected draft has already moved the "
@@ -686,12 +718,17 @@ class _WindowPages:
 
 class _Slot:
     __slots__ = ("stream", "pages", "awaiting_first", "emitted",
-                 "stop_len", "no_cache")
+                 "stop_len", "no_cache", "prefilled")
 
     def __init__(self, stream: GenerationStream, pages: List[int],
-                 stop_len: int):
+                 stop_len: int, prefilled: Optional[int] = None):
         self.stream = stream
         self.pages = pages        # physical page ids, in logical order
+        #: prompt tokens whose K/V and state the cache holds: the whole
+        #: prompt, or less while a long prompt is prefilled a piece a
+        #: pass (the slot then rides no decode step yet)
+        self.prefilled = (len(stream.prompt) if prefilled is None
+                          else prefilled)
         #: prefill's first token is still ON DEVICE (in a group batch —
         #: DecodeLoop._deferred); admission never blocks on a D2H
         self.awaiting_first = True
@@ -700,6 +737,10 @@ class _Slot:
         #: pages whose bytes diverged from the pure prompt sequence
         #: (CoW forks) — they must never seed the prefix cache
         self.no_cache: set = set()
+
+    @property
+    def in_prefill(self) -> bool:
+        return self.prefilled < len(self.stream.prompt)
 
 
 class _Step:
@@ -813,6 +854,19 @@ class DecodeLoop:
         #: racily)
         self._tier_waiting = {t: 0 for t in TIERS}
         self._buckets = prompt_buckets(cfg, self.page_size)
+        #: the most tokens of ONE prompt a pass prefills: the largest
+        #: bucket within the bound. A longer prompt keeps its slot and
+        #: pages and is prefilled a piece a pass (`_continue_prefills`).
+        #: None: prompts are prefilled whole, as where there is no
+        #: bound, where pages are shared by content (a piece would have
+        #: to start behind a cached prefix) and where a window kind
+        #: claims only the pages a prompt's END can still see
+        self._piece: Optional[int] = None
+        if (self.prefill_tokens_per_pass is not None and not prefix_cache
+                and paged_kinds.KIND_WINDOW not in cfg.layer_kinds):
+            self._piece = max(
+                (b for b in self._buckets
+                 if b <= self.prefill_tokens_per_pass), default=None)
 
         # device state ------------------------------------------------
         #: the window kind's pages (None: every layer keeps all keys)
@@ -981,6 +1035,17 @@ class DecodeLoop:
                 ctx_len, cfg)
             return jnp.argmax(logits, axis=-1).astype(jnp.int32), pool
 
+        def prefill_chunk_fn(params, tokens, true_len, pool, page_ids,
+                             ctx_table, ctx_len):
+            """A later piece of a prompt prefilled in pieces: the
+            context read through the loop's kernel lane, the linear
+            kind's state taken from the row's slot and put back."""
+            logits, pool, aux = paged_kinds.prefill_ctx(
+                params, tokens, true_len, pool, page_ids, ctx_table,
+                ctx_len, cfg, kernel=self.decode_kernel)
+            return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
+                    aux), pool
+
         def verify_fn(params, tokens, pool, table, lengths, widths):
             """ONE widened step over (S, W) tokens: every real column
             writes K/V at `lengths + j` and the returned argmax row is
@@ -1006,6 +1071,8 @@ class DecodeLoop:
         self._prefill = jax.jit(prefill_fn, donate_argnums=donate_pre)
         self._prefill_ctx = jax.jit(prefill_ctx_fn,
                                     donate_argnums=donate_pre)
+        self._prefill_chunk = jax.jit(prefill_chunk_fn,
+                                      donate_argnums=donate_pre)
         # the one compiled surface sharing adds: scalar src/dst are
         # traced, so every CoW fork for the life of the server is ONE
         # program
@@ -1028,6 +1095,8 @@ class DecodeLoop:
                                        self.cache_key + "|prefill")
         self._prefill_ctx = _cc.maybe_wrap(
             self._prefill_ctx, self.cache_key + "|prefill_ctx")
+        self._prefill_chunk = _cc.maybe_wrap(
+            self._prefill_chunk, self.cache_key + "|prefill_chunk")
         self._copy = _cc.maybe_wrap(self._copy, self.cache_key + "|copy")
         #: program-usage record for plan_fragment(): (bb, tb) /
         #: (bb, cb, tb) prefill groups actually dispatched, plus flags
@@ -1036,6 +1105,7 @@ class DecodeLoop:
         #: replay would add programs the record run never had
         self._plan_prefill: set = set()
         self._plan_prefill_ctx: set = set()
+        self._plan_prefill_chunk: set = set()
         self._plan_step = False
         self._plan_verify = False
         self._plan_copy = False
@@ -1167,6 +1237,18 @@ class DecodeLoop:
             "scheduler passes that enqueued a decode dispatch and at "
             "least one prefill: the token gaps a prefill lengthened"
         ).labels(**lab)
+        _chunks = reg.counter(
+            "dl4j_prefill_chunks",
+            "pieces of prompts that are prefilled a piece a pass "
+            "(prompts longer than prefill_tokens_per_pass): "
+            "carried=\"0\" the first piece of such a prompt, from a "
+            "zero state, carried=\"1\" a later one, on the state and "
+            "the pages its slot kept")
+        self._m_chunks = {c: _chunks.labels(carried=str(int(c)), **lab)
+                          for c in (False, True)}
+        self._m_chunk_tokens = reg.counter(
+            "dl4j_prefill_chunk_tokens",
+            "prompt tokens prefilled as such pieces").labels(**lab)
         self._request_ids = itertools.count()
         #: ring of the longest pass per interval (snapshot()
         #: ["slow_ticks"]); slot k holds interval number k mod its size
@@ -1691,8 +1773,11 @@ class DecodeLoop:
 
     def prefill_programs(self) -> int:
         """Compiled prefill programs — bounded by the prompt bucket
-        ladder (one per bucket hit)."""
-        return jit_cache_size(self._prefill)
+        ladder (one per bucket hit; the later pieces of long prompts
+        one more a bucket, whatever the context's length)."""
+        n, m = (jit_cache_size(self._prefill),
+                jit_cache_size(self._prefill_chunk))
+        return -1 if n < 0 or m < 0 else n + m
 
     # ---- warmup plans (docs/WARMUP.md)
     def plan_fragment(self) -> dict:
@@ -1710,6 +1795,8 @@ class DecodeLoop:
             "prefill": sorted(list(g) for g in self._plan_prefill),
             "prefill_ctx": sorted(list(g)
                                   for g in self._plan_prefill_ctx),
+            "prefill_chunk": sorted(list(g)
+                                    for g in self._plan_prefill_chunk),
         }
         if (self._drafter is not None
                 and getattr(self._drafter, "kind", None) == "model"):
@@ -1768,6 +1855,10 @@ class DecodeLoop:
             n += self._prefill_ctx.warm(
                 params_spec, ints(bb, tb), ints(bb), pool_spec,
                 by_kind(bb, tb // ps), by_kind(bb, cb), ints(bb))
+        for bb, cb, tb in frag.get("prefill_chunk", ()):
+            n += self._prefill_chunk.warm(
+                params_spec, ints(bb, tb), ints(bb), pool_spec,
+                rows_of(bb, tb // ps), by_kind(bb, cb), ints(bb))
         draft = frag.get("draft")
         if (draft and self._drafter is not None
                 and hasattr(self._drafter, "warm")):
@@ -2159,6 +2250,10 @@ class DecodeLoop:
                 "prefill_programs": self.prefill_programs(),
                 "prefill_ctx_programs": jit_cache_size(self._prefill_ctx),
                 "prefill_tokens": self._prefill_token_count,
+                "prefill_chunks": {
+                    "first": int(self._m_chunks[False].value),
+                    "carried": int(self._m_chunks[True].value),
+                    "tokens": int(self._m_chunk_tokens.value)},
                 "prefix_cache": {
                     "enabled": self.prefix_cache_enabled,
                     "hits": int(self._m_hits.value),
@@ -2352,7 +2447,9 @@ class DecodeLoop:
         chaos.hit("decode.step")
         with span("decode.admit", self._phases) as admit:
             admit.args["admitted"] = self._admit()
-        ran = self._dispatch()
+        # a piece of a long prompt is work done, step or no step
+        ran = bool(self._phases.pass_ns[PREFILL_DISPATCH])
+        ran = self._dispatch() or ran
         if self._deferred:
             # no step was read in this pass (the first of a busy spell,
             # or every admitted request has max_tokens=1): the firsts
@@ -2369,7 +2466,8 @@ class DecodeLoop:
                               or (self._win is not None
                                   and not self._win.free))
                          and all(s is None
-                                 or self._stop[i] <= self._lengths[i]
+                                 or (not s.in_prefill
+                                     and self._stop[i] <= self._lengths[i])
                                  for i, s in enumerate(self._slot_state)))
             if stuck:
                 self._fail_all(RuntimeError(
@@ -2444,13 +2542,16 @@ class DecodeLoop:
         return True
 
     def _admit(self) -> int:
-        """Claim slots and pages for what fits, then prefill it by
-        groups. Returns the number of requests admitted."""
+        """Prefill the next piece of the long prompts that hold a slot,
+        claim slots and pages for what fits beside them, then prefill
+        that by groups. Returns the number of requests admitted."""
         ps = self.page_size
         # claim everything that fits in one lock pass
         admitted = []  # (slot_idx, stream, pages, plen, covered)
         now = None  # one clock read for the requests this pass admits
-        budget = self.prefill_tokens_per_pass  # bucket tokens left
+        # bucket tokens left: the pieces of prompts already admitted go
+        # first, what they leave of the bound is for new requests
+        budget, continued = self._continue_prefills()
         win = self._win
         with self._cond:
             used = {i for i, s in enumerate(self._slot_state)
@@ -2501,8 +2602,9 @@ class DecodeLoop:
                 if budget is not None:
                     # the bound on what one pass prefills: rows count at
                     # their bucket's width, and a pass always takes one
-                    tb = next(b for b in self._buckets if b >= plen)
-                    if admitted and tb > budget:
+                    tb = next(b for b in self._buckets
+                              if b >= self._first_piece(plen))
+                    if (admitted or continued) and tb > budget:
                         break
                     budget -= tb
                 idx = next((i for i in range(self.slots)
@@ -2572,6 +2674,9 @@ class DecodeLoop:
                 self._note_peak()
         if not admitted:
             return 0
+        # what this pass prefills of each: the whole prompt, or the
+        # first piece of one that is longer than a piece
+        admitted = [a + (self._first_piece(a[3]),) for a in admitted]
         cold = [a for a in admitted if a[4] == 0]
         warm = [a for a in admitted if 0 < a[4] < a[3]]
         full = [a for a in admitted if a[4] >= a[3]]
@@ -2580,7 +2685,7 @@ class DecodeLoop:
         # first compiled decode dispatch recomputes position plen-1 —
         # its K/V write re-enters the last shared page, which the CoW
         # guard forks before the dispatch — and emits the first token.
-        for idx, stream, pages, plen, covered in full:
+        for idx, stream, pages, plen, covered, _upto in full:
             slot = _Slot(stream, pages,
                          stop_len=plen + stream.max_tokens - 1)
             slot.awaiting_first = False
@@ -2598,27 +2703,88 @@ class DecodeLoop:
         # FULL chunks match) and ride the ctx-aware prefill.
         groups: dict = {}
         for item in cold + warm:
-            _idx, _stream, _pages, plen, covered = item
+            _idx, _stream, _pages, _plen, covered, upto = item
             cb = min(_pow2(covered // ps), self._pps) if covered else 0
-            tb = next(b for b in self._buckets if b >= plen - covered)
+            tb = next(b for b in self._buckets if b >= upto - covered)
             groups.setdefault((cb, tb), []).append(item)
         for (cb, tb), group in groups.items():
-            with self._prefill_span(group, _pow2(len(group)), tb, ctx=cb):
-                first = self._prefill_rows(
-                    [(stream.prompt, pages, cov)
-                     for _, stream, pages, _, cov in group], cb, tb,
-                    slots=[item[0] for item in group])
-            self._install_prefilled(group, first)
+            self._prefill_group(group, cb, tb)
         return len(admitted)
 
-    def _prefill_rows(self, rows, cb: int, tb: int, slots=None):
+    def _first_piece(self, plen: int) -> int:
+        """Tokens of a prompt of `plen` that its admission's pass
+        prefills: all of them, or one piece of a longer prompt."""
+        return plen if self._piece is None else min(plen, self._piece)
+
+    def _continue_prefills(self):
+        """The next piece of every prompt that holds a slot and is not
+        prefilled to its end, oldest admission first, as far as the
+        bound on a pass's prefill allows (one is always taken): the
+        piece's K/V goes to the pages the slot already holds, its
+        linear layers start from the state and columns the slot kept,
+        its full layers read the earlier pieces through the slot's
+        pages. A piece is a whole bucket of pages but the last, whose
+        bucket is no narrower than a quarter of a piece (three programs,
+        one width of context table, whatever the prompt). The decode
+        step of the streams that are running is enqueued behind it, in
+        this pass, and the prompt's next piece in the next: a long
+        prompt holds them for one piece a step, never for its whole
+        length. Returns (what is left of the bound, pieces enqueued)."""
+        budget, n = self.prefill_tokens_per_pass, 0
+        if self._piece is None:
+            return budget, n
+        with self._cond:
+            todo = sorted(
+                ((i, s) for i, s in enumerate(self._slot_state)
+                 if s is not None and s.in_prefill),
+                key=lambda item: item[1].stream.admitted)
+        for idx, slot in todo:
+            plen, at = len(slot.stream.prompt), slot.prefilled
+            upto = min(plen, at + self._piece)
+            tb = next(b for b in self._buckets
+                      if b >= max(upto - at, self._piece // 4))
+            if n and tb > budget:
+                break
+            budget -= tb
+            n += 1
+            self._prefill_group(
+                [(idx, slot.stream, slot.pages, plen, at, upto)],
+                self._pps, tb, slot=slot)
+        return budget, n
+
+    def _prefill_group(self, group, cb: int, tb: int, slot=None) -> None:
+        """Enqueue one prefill program for `group`, items of (slot
+        index, stream, pages, prompt length, tokens the cache already
+        covers, tokens it covers after this program), and install what
+        it leaves. `slot`: the group is the next piece of that slot's
+        prompt (one row over a context table of `cb` columns)."""
+        ps = self.page_size
+        carried = slot is not None
+        with self._prefill_span(group, _pow2(len(group)), tb, ctx=cb,
+                                carried=carried):
+            first = self._prefill_rows(
+                [(stream.prompt[:upto],
+                  pages[:pages_for_tokens(upto, ps)], cov)
+                 for _, stream, pages, _, cov, upto in group], cb, tb,
+                slots=[item[0] for item in group], piece=carried)
+        for *_, plen, cov, upto in group:
+            if upto < plen or carried:
+                self._m_chunks[carried].inc()
+                self._m_chunk_tokens.inc(upto - cov)
+        self._install_prefilled(group, first, slot)
+
+    def _prefill_rows(self, rows, cb: int, tb: int, slots=None,
+                      piece: bool = False):
         """Pack one prefill group and enqueue its program: `rows` of
         (a prompt's tokens, its pages in logical order, how many of the
         tokens cached pages cover), padded to a power of two of rows of
         `tb` tokens. `cb` is the width of the table of cached pages a
         warm group reads (`prefill_ctx`); 0 is a cold group (`prefill`),
         whose `slots` say where a window kind claimed pages for the
-        rows. The program is dispatched but NOT synced — back-to-back
+        rows and where a linear kind's state goes. `piece`: the rows
+        are later pieces of prompts prefilled in pieces, the context
+        the slot's own pages (`prefill_chunk_fn`, which also reads the
+        slot's state). The program is dispatched but NOT synced — back-to-back
         groups queue without a host round trip between them. Returns
         its (first tokens, aux), still on the device."""
         import jax.numpy as jnp
@@ -2639,6 +2805,21 @@ class DecodeLoop:
             clen[row] = cov
             self._prefill_token_count += tl
         d_pids = {paged_kinds.KIND_FULL: jnp.asarray(pids)}
+        if self._linear_layers and slots is not None:
+            # where each row's state is (a piece) and goes: its slot; a
+            # padding row names a slot past the last, and its write is
+            # dropped
+            at = np.full((bb,), self.slots, np.int32)
+            at[:len(slots)] = slots
+            d_pids[paged_kinds.KIND_LINEAR] = jnp.asarray(at)
+        if piece:
+            self._plan_prefill_chunk.add((bb, cb, tb))
+            first, self._pool = self._prefill_chunk(
+                self.params, jnp.asarray(padded), jnp.asarray(lens),
+                self._pool, d_pids,
+                {paged_kinds.KIND_FULL: jnp.asarray(ctab)},
+                jnp.asarray(clen))
+            return first
         if cb:
             self._plan_prefill_ctx.add((bb, cb, tb))
             first, self._pool = self._prefill_ctx(
@@ -2656,46 +2837,58 @@ class DecodeLoop:
                 lo, hi = int(win.lo[idx]), int(win.hi[idx])
                 wids[row, lo:hi] = win.table[idx, lo:hi]
             d_pids[paged_kinds.KIND_WINDOW] = jnp.asarray(wids)
-        if self._linear_layers:
-            # where each row's state goes: its slot; a padding row names
-            # a slot past the last, and its write is dropped
-            at = np.full((bb,), self.slots, np.int32)
-            at[:len(slots)] = slots
-            d_pids[paged_kinds.KIND_LINEAR] = jnp.asarray(at)
         self._plan_prefill.add((bb, tb))
         first, self._pool = self._prefill(
             self.params, jnp.asarray(padded), jnp.asarray(lens),
             self._pool, d_pids)
         return first
 
-    def _prefill_span(self, group, bb: int, tb: int, ctx: int) -> span:
+    def _prefill_span(self, group, bb: int, tb: int, ctx: int,
+                      carried: bool = False) -> span:
         """The span of one prefill group, host packing and enqueue: the
         program's batch and token buckets, its real rows and tokens, the
-        context pages it reads (0 = a cold prefill) and the requests in
-        it."""
+        context pages it reads (0 = a cold prefill), whether it starts
+        from the state a slot kept (`carried`: a later piece of a long
+        prompt) and the requests in it."""
         return span(PREFILL_DISPATCH, self._phases, bb=bb, tb=tb,
-                    rows=len(group), ctx=ctx,
-                    tokens=sum(plen - cov for *_, plen, cov in group),
+                    rows=len(group), ctx=ctx, carried=carried,
+                    tokens=sum(upto - cov for *_, cov, upto in group),
                     requests=[a[1].request_id for a in group])
 
-    def _install_prefilled(self, group, first) -> None:
-        """Install slots for one prefill group. `first` is the program's
-        (first tokens, aux): both stay on the device until the next
-        flush (`self._deferred`) and come back in one read."""
+    def _install_prefilled(self, group, first, slot=None) -> None:
+        """Install slots for one prefill group (`slot`: the group is
+        the next piece of that slot's prompt, installed already).
+        `first` is the program's (first tokens, aux): both stay on the
+        device until the next flush (`self._deferred`) and come back in
+        one read. A prompt whose end this program did not reach keeps
+        its slot out of the decode step (no table row, length and stop
+        0: the step masks it, and its state is its own to move) and has
+        no first token yet."""
         members = []
-        for row, (idx, stream, pages, plen, _cov) in enumerate(group):
-            slot = _Slot(stream, pages,
-                         stop_len=plen + stream.max_tokens - 1)
-            members.append((row, idx))
+        for row, (idx, stream, pages, plen, _cov, upto) in enumerate(group):
             with self._cond:
-                self._slot_state[idx] = slot
+                if slot is None:
+                    self._slot_state[idx] = _Slot(
+                        stream, pages,
+                        stop_len=plen + stream.max_tokens - 1,
+                        prefilled=upto)
+                else:
+                    slot.prefilled = upto
+                if upto < plen:
+                    continue
+                members.append((row, idx))
                 self._table[idx, :len(pages)] = pages
                 self._lengths[idx] = plen
                 self._pending[idx] = 0  # real value still on device
                 self._stop[idx] = 0  # set by _grant_pages
                 self._dirty = self._host_rows[idx] = True
+        # a piece that ends no prompt is read back like any other: the
+        # tokens of the step before it are emitted once it is over, so
+        # the running streams' gaps are one piece and one step each,
+        # not none and then two (`itl_p98_ms` 381 for 205 on
+        # `olmohyb7b-docs-chunked`; PERF.md section 6, PR 37)
         self._deferred.append(
-            (first, members, sum(plen - cov for *_, plen, cov in group)))
+            (first, members, sum(upto - cov for *_, cov, upto in group)))
 
     # ---- page granting
     def _grant_pages(self) -> None:
@@ -2712,8 +2905,8 @@ class DecodeLoop:
         adv = (self.spec_k + 1) if self.spec_k else self.horizon
         with span("decode.grant_pages", self._phases), self._cond:
             for i, slot in enumerate(self._slot_state):
-                if slot is None:
-                    continue
+                if slot is None or slot.in_prefill:
+                    continue  # a prompt not prefilled to its end: no step
                 length = int(self._lengths[i])
                 target = min(length + adv, slot.stop_len)
                 want = pages_for_tokens(target, self.page_size)
@@ -2856,6 +3049,8 @@ class DecodeLoop:
                 # device-resident) into the feedback array — ONE scatter
                 # per prefill group, no sync
                 for (arr, _aux), group, _n in self._deferred:
+                    if not group:
+                        continue  # a piece that ended no prompt
                     rows = jnp.asarray([r for r, _ in group])
                     idxs = jnp.asarray([i for _, i in group])
                     self._d_tokens = self._d_tokens.at[idxs].set(

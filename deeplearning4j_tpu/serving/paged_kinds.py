@@ -25,8 +25,11 @@ layers are all full (`models/transformer.py`) is the case of one kind:
   callbacks, which differ in what attention reads and where K/V is
   written and in nothing else: whole-page scatter then the flash kernel;
   whole-page scatter then a dense read of [gathered prefix pages ‖
-  tail]; `_write_rows` at the cursor then the paged kernel or the dense
-  gather; `_write_rows` at W columns then the same two reads a column.
+  tail] or, for a piece of a prompt that is prefilled in pieces, the
+  flash kernel with a query offset over the row's pages up to the
+  piece's end; `_write_rows` at the cursor then the paged kernel or the
+  dense gather; `_write_rows` at W columns then the same two reads a
+  column.
   Each returns, after the logits and the pool, what the model's layers
   count (`aux`: the pairs by held expert, or `()`).
 
@@ -39,10 +42,14 @@ the mixer's rows back; the lanes call the model's one `linear_mix` with
 what the cache holds: `prefill` a row's real length (the state at the
 row's last REAL token goes to `page_ids["linear"][row]`, the row's
 slot; a padding row names a slot past the last and is dropped),
-`decode_step` the slot's kept columns and state (an inactive slot's
-update is masked: g = beta = 0 leave its state bit for bit). It needs no
-table, no grant and no trash row. `prefill_ctx` and `verify_step` are
-not written for it (`DecodeLoop._check_refusals` keeps them away).
+`prefill_ctx` the slot's kept columns and state AND the row's real
+length (a later piece of a prompt prefilled in pieces: read from the
+row's slot, scanned on, written back), `decode_step` the slot's kept
+columns and state (an inactive slot's update is masked: g = beta = 0
+leave its state bit for bit). It needs no table, no grant and no trash
+row. `verify_step` is not written for it, nor is anything that would
+start from the state at a position the slot has left behind
+(`DecodeLoop._check_refusals` keeps them away).
 
 Shapes are fixed for the life of a server: a step is ONE program over S
 slots (tables, lengths and the active mask are traced arrays: requests
@@ -64,6 +71,7 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.attention.blockwise import masked_attention
+from deeplearning4j_tpu.attention.flash_pallas import flash_attention_ctx
 from deeplearning4j_tpu.attention.paged_pallas import paged_attention
 from deeplearning4j_tpu.models import model_of
 from deeplearning4j_tpu.models.transformer import (KIND_FULL, KIND_LINEAR,
@@ -148,6 +156,15 @@ def _write_rows(arr, dest, offset, rows):
         rows.astype(arr.dtype))
 
 
+def _write_slots(held, slots, entry):
+    """A prefill's write for the linear kind: row r of each array of
+    `entry` (state, kept columns) to slot `slots[r]`; a padding row
+    names a slot past the last and is dropped."""
+    return {name: held[name].at[slots].set(rows.astype(held[name].dtype),
+                                           mode="drop")
+            for name, rows in entry.items()}
+
+
 def _row_dest(pool: PagedKVPool, cfg, tables, pos, live):
     """By kind, the physical page the row at cursor `pos` goes to: the
     table's, or the kind's trash page where the row is not `live` or
@@ -195,8 +212,9 @@ def _no_linear(kind: str, what: str) -> None:
     if kind == KIND_LINEAR:
         raise NotImplementedError(
             f"{what} is not written for a layer of the linear kind: it "
-            f"would need the recurrent state at a position the cache "
-            f"does not keep (snapshots of state)")
+            f"would need the recurrent state at a position the slot "
+            f"has left behind, and the cache keeps only the newest "
+            f"(snapshots of state at a page boundary are not written)")
 
 
 def _last_logits(model, params, x, true_len, cfg):
@@ -231,9 +249,7 @@ def prefill(params, tokens, true_len, pool: PagedKVPool,
         held = pool.layers[layer]
         if kind == KIND_LINEAR:
             o, entry = model.linear_mix(cfg, q, k, v, true_len=true_len)
-            return o, {name: held[name].at[flat[kind]].set(
-                rows.astype(held[name].dtype), mode="drop")
-                for name, rows in entry.items()}
+            return o, _write_slots(held, flat[kind], entry)
         att = causal_attention(cfg, kind, q, k, v)
         return att, _write_pages(held, flat[kind], k, v)
 
@@ -243,18 +259,48 @@ def prefill(params, tokens, true_len, pool: PagedKVPool,
             PagedKVPool(layers), aux)
 
 
+def _logical_table(ctx_table, page_ids, ctx_len, ps: int):
+    """A row's pages in logical order with no gap: the `ctx_len // ps`
+    pages of `ctx_table` (B, cb) that hold its context, then the pages
+    its piece goes to, `page_ids` (B, n). (B, cb + n); the columns past
+    the piece's end repeat the last, and no query sees them."""
+    cb, n = ctx_table.shape[1], page_ids.shape[1]
+    held = (ctx_len // ps)[:, None]
+    j = jnp.arange(cb + n)[None, :]
+    src = jnp.minimum(jnp.where(j < held, j, cb + j - held), cb + n - 1)
+    return jnp.take_along_axis(
+        jnp.concatenate([ctx_table, page_ids], axis=1), src, axis=1)
+
+
 def prefill_ctx(params, tokens, true_len, pool: PagedKVPool,
                 page_ids: Dict[str, jax.Array],
-                ctx_tables: Dict[str, jax.Array], ctx_len, cfg):
+                ctx_tables: Dict[str, jax.Array], ctx_len, cfg,
+                kernel: str = "gather"):
     """Prefill a batch of prompt TAILS whose prefix K/V already sits in
-    pool pages (the prefix cache's warm path): row b's tokens are prompt
-    positions `[ctx_len[b], ctx_len[b] + true_len[b])`, its cached
-    prefix occupies the pages in `ctx_tables[kind][b]` (trash-padded,
+    pool pages (the prefix cache's warm path, and every piece after the
+    first of a prompt that is prefilled in pieces): row b's tokens are
+    prompt positions `[ctx_len[b], ctx_len[b] + true_len[b])`, its
+    context occupies the pages in `ctx_tables[kind][b]` (trash-padded,
     masked by `ctx_len`), and its tail K/V goes to `page_ids` exactly as
     in `prefill` (tails start on a page boundary: admission only reuses
-    FULL cached chunks). Attention is the dense read over [gathered
-    prefix pages ‖ tail], not the flash kernel; shared prefix pages are
-    only READ. Returns what `prefill` returns."""
+    FULL cached chunks, and a piece is a whole number of pages). Pages
+    of the context are only READ.
+
+    `kernel` picks the full kind's read. "gather" is the dense read
+    over [gathered context pages ‖ tail]: a (Tb, context + Tb) array of
+    scores a head, for the short tails of the prefix cache. "pallas"
+    writes the tail first and runs `flash_attention_ctx` over the row's
+    pages in logical order, context and tail, query row i seeing the
+    keys up to `ctx_len + i`: no array of scores, blocks past a tile's
+    last query neither computed nor fetched, ONE program whatever the
+    context's length (`cfg.interpret` runs it on the CPU). A window
+    layer takes the dense read in either lane.
+
+    A linear layer reads the kept columns and the state of the row's
+    slot (`page_ids["linear"]`), scans the tail on top of them and
+    writes both back as they stand after the row's last REAL token.
+    Returns what `prefill` returns."""
+    _check_kernel(kernel)
     model = model_of(cfg)
     b, tb = tokens.shape
     ps = pool.page_size
@@ -263,8 +309,23 @@ def prefill_ctx(params, tokens, true_len, pool: PagedKVPool,
     flat = {kind: ids.reshape(-1) for kind, ids in page_ids.items()}
 
     def attend(layer, kind, q, k, v):
-        _no_linear(kind, "a prefill on top of cached prefix pages")
         held = pool.layers[layer]
+        if kind == KIND_LINEAR:
+            at = flat[kind]
+            o, entry = model.linear_mix(
+                cfg, q, k, v, prev=held["conv"][at],
+                state=held["state"][at], true_len=true_len)
+            return o, _write_slots(held, at, entry)
+        if kernel == "pallas" and kind == KIND_FULL:
+            held = _write_pages(held, flat[kind], k, v)
+            table = _logical_table(ctx_tables[kind], page_ids[kind],
+                                   ctx_len, ps)
+            with jax.named_scope("prefill_ctx_flash"):
+                att = flash_attention_ctx(
+                    q, _gathered(held["k"], table),
+                    _gathered(held["v"], table), ctx_len,
+                    interpret=cfg.interpret)
+            return att, held
         table = ctx_tables[kind]
         ctx_pos = jnp.broadcast_to(jnp.arange(table.shape[1] * ps),
                                    (b, table.shape[1] * ps))

@@ -162,6 +162,15 @@ def test_a_row_padded_to_its_bucket_keeps_the_last_real_columns():
     for name in ("state", "conv"):
         np.testing.assert_allclose(np.asarray(got[name]),
                                    np.asarray(want[name]), atol=1e-5)
-    with pytest.raises(NotImplementedError, match="zero state"):
-        hybrid.linear_mix(cfg, u[:, :4], tuple(x[:, :4] for x in gates),
-                          conv_w, state=entry["state"])
+    # several tokens on top of what was kept (a later piece of a
+    # prompt prefilled in pieces; PR 37, which took the refusal that
+    # stood here away): tokens 530..533 are the whole scan's
+    hybrid._linear_layer(p, h[:, :534], cfg, attend_with(), 0)
+    u, gates, conv_w = seen["call"]
+    _, got = hybrid.linear_mix(
+        cfg, u[:, 530:534], tuple(x[:, 530:534] for x in gates), conv_w,
+        prev=entry["conv"], state=entry["state"])
+    for name in ("state", "conv"):
+        np.testing.assert_allclose(np.asarray(got[name]),
+                                   np.asarray(seen["entry"][name]),
+                                   atol=1e-5)
