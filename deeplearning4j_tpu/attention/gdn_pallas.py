@@ -41,21 +41,44 @@ blocks of 16 by the finite Neumann product (their strictly lower part
 is nilpotent of order 16, so intermediate powers grow by at most
 C(15, 7) = 6,435 even where every key is the same), the blocks below
 them by the exact block identity `(D + E)^-1 = (I + D^-1 E)^-1 D^-1`,
-whose Neumann product has two factors. Grid `(rows x heads / block,
-chunks)`: the chunk axis is sequential and carries the state in a VMEM
-scratch; `block` heads a step are independent chains of small products
-for the four MXUs to interleave. Operands of the products are in the
-type the call came in (bfloat16 when serving, float32 accumulation; in
-float32 every product asks for the full contraction); decays, the
+whose Neumann product has two factors. Operands of the products are in
+the type the call came in (bfloat16 when serving, float32 accumulation;
+in float32 every product asks for the full contraction); decays, the
 inverse's sums and the state are float32.
+
+Only the last three lines meet the state, so a chunk is two halves,
+one form that the kernel and the plain path both run:
+
+- `_wy`, the state-free half: the decay mask, `A`, `T`, `u` in float32,
+  and in the call's type `[w; q exp(gc)]` stacked by rows (2C, dk) and
+  `[lower((q k^T) * L); k_d^T]` (C + dk, C), `k_d = k exp(gc_C - gc)`.
+  `u` stays float32 because `v_new = u - w S` is the difference of two
+  terms of the value's size: rounded to bfloat16 first, it would lose
+  what the one-pass chunk kept.
+- `_carry`, the state half: two products, `[w; q exp(gc)] S`, then
+  `[att; k_d^T] v_new`.
+
+Both take a batch of chunks, and every product runs over the whole
+batch before the next: Mosaic keeps a product's order on its MXU, so
+chains written one after another serialise (PR 38: a grid step of 8
+heads, each chunk's ~18 products written head after head, had a
+critical path of ~15,000 cycles, ~125 of them a product's latency).
+`gdn_scan` is ONE kernel: grid `(heads / block, chunks)`, the chunk
+axis sequential, the state resident in VMEM in its own output block
+from the first chunk to the last; a grid step runs `_wy` and then
+`_carry` over its block of heads, so nothing but q, k, v, g and beta
+comes in from HBM and nothing but o and the state goes out. The block
+is as many heads as `_HEADS` and VMEM (`_VMEM_LIMIT`) allow, from the
+widths and the type alone.
 
 Padding must not move the state: past a row's real length the caller
 passes beta = 0 and g = 0, under which a token leaves S as it is, so
 the state after the last chunk is the state at the last real token.
 
 On a TPU, or with `interpret=True` (the CPU test lane), the kernels;
-elsewhere the same chunk arithmetic (`_chunk`) under `vmap` and
-`lax.scan`, and the update as plain products.
+elsewhere the same two halves, `_wy` over every chunk of every head at
+once and `_carry` over every head under `lax.scan`, and the update as
+plain products.
 """
 
 from __future__ import annotations
@@ -81,31 +104,29 @@ def _prec(dtype):
     return _HIGHEST if jnp.dtype(dtype).itemsize >= 4 else None
 
 
-def _mm(a, b, prec):
-    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+def _dot(a, b, ca, cb, prec):
+    """a . b over a's axis `ca` and b's axis `cb`, float32 accumulation;
+    where both are 3-D, the leading axis is a batch of products."""
+    batch = ((0,), (0,)) if a.ndim == 3 else ((), ())
+    return jax.lax.dot_general(a, b, (((ca,), (cb,)), batch),
                                preferred_element_type=jnp.float32,
                                precision=prec)
+
+
+def _mm(a, b, prec):
+    return _dot(a, b, a.ndim - 1, b.ndim - 2, prec)
 
 
 def _mm_nt(a, b, prec):
     """a @ b^T."""
-    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
-                               preferred_element_type=jnp.float32,
-                               precision=prec)
-
-
-def _mm_tn(a, b, prec):
-    """a^T @ b."""
-    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
-                               preferred_element_type=jnp.float32,
-                               precision=prec)
+    return _dot(a, b, a.ndim - 1, b.ndim - 1, prec)
 
 
 def _unit_lower_inverse(a, row, col, cd, prec):
-    """(I + a)^-1 for a strictly lower (C, C) float32, by products
-    alone (the module's doc-string has the identities). `cd` is the
-    type the products' operands take."""
-    c = a.shape[0]
+    """(I + a)^-1 for a strictly lower (C, C) float32, or a batch of
+    them (B, C, C), by products alone (the module's doc-string has the
+    identities). `cd` is the type the products' operands take."""
+    c = a.shape[-1]
     eye = (row == col).astype(jnp.float32)
 
     def mm(x, y):
@@ -129,19 +150,21 @@ def _unit_lower_inverse(a, row, col, cd, prec):
     return mm(neumann(-n, c // _INV_BLOCK), d_inv)
 
 
-def _chunk(q, k, v, gc, beta, s, prec):
-    """One chunk of one head: q, k (C, dk) and v (C, dv) in the call's
-    type, gc and beta (1, C) float32 (gc the running sum of g inside
-    the chunk), s (dk, dv) float32. Returns (o (C, dv) float32, the
-    state after the chunk)."""
-    c, cd, f32 = q.shape[0], q.dtype, jnp.float32
+def _wy(q, k, v, gc, beta, prec):
+    """The state-free half of a batch of chunks (a chunk of a head
+    each): q, k (B, C, dk) and v (B, C, dv) in the call's type, gc and
+    beta (B, 1, C) float32 (gc the running sum of g inside the chunk).
+    Returns u (B, C, dv) float32 and, in the call's type, the operands
+    that meet the state, `[w; q e^gc]` (B, 2C, dk), and those that meet
+    `v_new`, `[lower(q k^T) * L; k_d^T]` (B, C + dk, C)."""
+    c, cd, f32 = q.shape[1], q.dtype, jnp.float32
     row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
 
     def column(r):
-        """(1, C) -> (C, 1): the diagonal of the row spread over rows,
-        summed along lanes."""
-        return jnp.sum(jnp.where(row == col, r, 0.0), axis=1,
+        """(B, 1, C) -> (B, C, 1): the diagonal of the row spread over
+        rows, summed along lanes."""
+        return jnp.sum(jnp.where(row == col, r, 0.0), axis=-1,
                        keepdims=True)
 
     g_col, b_col = column(gc), column(beta)
@@ -152,45 +175,64 @@ def _chunk(q, k, v, gc, beta, s, prec):
     e_gc = jnp.exp(g_col)
     u = _mm(t, (v.astype(f32) * b_col).astype(cd), prec)
     w = _mm(t, (kb * e_gc).astype(cd), prec)
-    s_cd = s.astype(cd)
-    v_new = u - _mm(w.astype(cd), s_cd, prec)
-    v_cd = v_new.astype(cd)
     att = (_mm_nt(q, k, prec) * decay).astype(cd)
-    o = _mm((q.astype(f32) * e_gc).astype(cd), s_cd, prec) \
-        + _mm(att, v_cd, prec)
-    g_last = jnp.min(gc, axis=1, keepdims=True)      # g <= 0: the last
-    kd = (k.astype(f32) * jnp.exp(g_last - g_col)).astype(cd)
-    return o, s * jnp.exp(g_last) + _mm_tn(kd, v_cd, prec)
+    qg = (q.astype(f32) * e_gc).astype(cd)
+    g_last = jnp.min(gc, axis=-1, keepdims=True)     # g <= 0: the last
+    kd_t = jnp.swapaxes(k.astype(f32) * jnp.exp(g_last - g_col), 1, 2)
+    return (u, jnp.concatenate([w.astype(cd), qg], axis=1),
+            jnp.concatenate([att, kd_t.astype(cd)], axis=1))
+
+
+def _carry(u, wq, ak, gc, s, prec):
+    """The state half of a batch of chunks, one a head, on `_wy`'s three
+    outputs, gc (B, 1, C) and s (B, dk, dv) float32: two products, the
+    state's and then `v_new`'s, each over the whole batch. Returns (o
+    (B, C, dv) float32, the states after the chunks)."""
+    c, cd = u.shape[1], wq.dtype
+    ws = _mm(wq, s.astype(cd), prec)                 # [w S; q e^gc S]
+    av = _mm(ak, (u - ws[:, :c]).astype(cd), prec)   # on v_new
+    g_last = jnp.min(gc, axis=-1, keepdims=True)
+    return ws[:, c:] + av[:, :c], s * jnp.exp(g_last) + av[:, c:]
 
 
 # ------------------------------------------------------------ the scan
-def _scan_kernel(q_ref, k_ref, v_ref, gb_ref, s0_ref, o_ref, s_ref, s_scr,
-                 *, heads: int, prec):
-    from jax.experimental import pallas as pl
-
-    ci = pl.program_id(1)
-
-    @pl.when(ci == 0)
-    def _init():
-        s_scr[...] = s0_ref[...]
-
-    for h in range(heads):
-        gb = gb_ref[h, 0]                                # (2, C) f32
-        o, s = _chunk(q_ref[h], k_ref[h], v_ref[h], gb[0:1], gb[1:2],
-                      s_scr[h], prec)
-        o_ref[h] = o.astype(o_ref.dtype)
-        s_scr[h] = s
-
-    @pl.when(ci == pl.num_programs(1) - 1)
-    def _last():
-        s_ref[...] = s_scr[...]
+#: the scan's scoped VMEM (the default scope is 16 MiB, which a float32
+#: step of 16 heads at 128 x 128 outgrows; a v5e has 128 MiB); a step's
+#: blocks and temporaries are held to three quarters of it
+_VMEM_LIMIT = 32 << 20
+#: heads a grid step runs side by side, each a chain of products
+_HEADS = 16
 
 
-def _head_block(n: int, most: int) -> int:
-    b = min(n, most)
+def _vmem(rows: int, cols: int, dtype) -> int:
+    """Bytes of a (rows, cols) block in VMEM, padded to whole tiles."""
+    item = jnp.dtype(dtype).itemsize
+    sub = 8 * 4 // item
+    return -(-rows // sub) * sub * -(-cols // 128) * 128 * item
+
+
+def _fit(n: int, most: int) -> int:
+    """The largest divisor of n that is at most `most` (and at least 1)."""
+    b = max(1, min(n, most))
     while n % b:
         b -= 1
     return b
+
+
+def _scan_kernel(q_ref, k_ref, v_ref, gb_ref, s0_ref, o_ref, s_ref, *,
+                 prec):
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        s_ref[...] = s0_ref[...]
+
+    gb = gb_ref[:, 0]                                    # (hb, 2, C) f32
+    u, wq, ak = _wy(q_ref[:, 0], k_ref[:, 0], v_ref[:, 0], gb[:, 0:1],
+                    gb[:, 1:2], prec)
+    o, s = _carry(u, wq, ak, gb[:, 0:1], s_ref[...], prec)
+    o_ref[:, 0] = o.astype(o_ref.dtype)
+    s_ref[...] = s                      # resident over the chunk axis
 
 
 @partial(jax.jit, static_argnames=("chunk", "interpret", "kernel"))
@@ -199,33 +241,48 @@ def _gdn_scan(q, k, v, g, beta, s0, *, chunk, interpret, kernel):
     dv = v.shape[-1]
     n = t // chunk
     bh = b * h
-    s0 = s0.astype(jnp.float32).reshape(bh, dk, dv)
-    gc = jnp.cumsum(g.astype(jnp.float32).reshape(bh, n, chunk), axis=-1)
-    gb = jnp.stack([gc, beta.astype(jnp.float32).reshape(bh, n, chunk)],
+    cd, f32 = q.dtype, jnp.float32
+    s0 = s0.astype(f32).reshape(bh, dk, dv)
+    gc = jnp.cumsum(g.astype(f32).reshape(bh, n, chunk), axis=-1)
+    gb = jnp.stack([gc, beta.astype(f32).reshape(bh, n, chunk)],
                    axis=2)                               # (BH, N, 2, C)
-    q, k, v = (a.reshape(bh, t, a.shape[-1]) for a in (q, k, v))
-    prec = _prec(q.dtype)
+    q, k, v = (a.reshape(bh, n, chunk, a.shape[-1]) for a in (q, k, v))
+    prec = _prec(cd)
     if not kernel:
-        def one_head(q, k, v, gb, s0):
-            def step(s, x):
-                o, s = _chunk(x[0], x[1], x[2], x[3][0:1], x[3][1:2], s,
-                              prec)
-                return s, o
-            s, o = jax.lax.scan(
-                step, s0,
-                (q.reshape(n, chunk, dk), k.reshape(n, chunk, dk),
-                 v.reshape(n, chunk, dv), gb))
-            return o.reshape(t, dv), s
-        o, s = jax.vmap(one_head)(q, k, v, gb, s0)
-        return (o.astype(v.dtype).reshape(b, h, t, dv),
+        # every chunk's state-free half at once, then the chunks in
+        # order, each over every head
+        flat = gb.reshape(bh * n, 2, chunk)
+        u, wq, ak = (x.reshape((bh, n) + x.shape[1:]).swapaxes(0, 1)
+                     for x in _wy(*(a.reshape((bh * n,) + a.shape[2:])
+                                    for a in (q, k, v)),
+                                  flat[:, 0:1], flat[:, 1:2], prec))
+
+        def step(s, x):
+            o, s = _carry(*x[:3], x[3][:, 0:1], s, prec)
+            return s, o
+        s, o = jax.lax.scan(step, s0, (u, wq, ak, gb.swapaxes(0, 1)))
+        return (o.swapaxes(0, 1).astype(v.dtype).reshape(b, h, t, dv),
                 s.reshape(b, h, dk, dv))
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    hb = _head_block(bh, 8)
+    # a head's blocks, each double-buffered: q, k, v and gb in, o out,
+    # and the state in and out (resident over the chunks); then its
+    # temporaries: the inverse's (C, C) terms, u, [w S; q e^gc S],
+    # [att; k_d^T] v_new and the new state in float32, and T,
+    # [w; q e^gc] and [att; k_d^T] in the call's type
+    head = 2 * (2 * _vmem(chunk, dk, cd) + _vmem(chunk, dv, cd)
+                + _vmem(2, chunk, f32) + _vmem(chunk, dv, v.dtype)
+                + 2 * _vmem(dk, dv, f32)) \
+        + 4 * _vmem(chunk, chunk, f32) + _vmem(chunk, dv, f32) \
+        + _vmem(2 * chunk, dv, f32) + _vmem(chunk + dk, dv, f32) \
+        + _vmem(dk, dv, f32) + _vmem(chunk, chunk, cd) \
+        + _vmem(2 * chunk, dk, cd) + _vmem(chunk + dk, chunk, cd)
+    hb = _fit(bh, min(_HEADS, _VMEM_LIMIT * 3 // 4 // head))
 
-    def rows(d):
-        return pl.BlockSpec((hb, chunk, d), lambda i, c: (i, c, 0),
+    def per_chunk(*tail):
+        return pl.BlockSpec((hb, 1) + tail,
+                            lambda i, c: (i, c) + (0,) * len(tail),
                             memory_space=pltpu.VMEM)
 
     def whole_state():
@@ -233,19 +290,16 @@ def _gdn_scan(q, k, v, g, beta, s0, *, chunk, interpret, kernel):
                             memory_space=pltpu.VMEM)
 
     o, s = pl.pallas_call(
-        partial(_scan_kernel, heads=hb, prec=prec),
+        partial(_scan_kernel, prec=prec),
         grid=(bh // hb, n),
-        in_specs=[rows(dk), rows(dk), rows(dv),
-                  pl.BlockSpec((hb, 1, 2, chunk),
-                               lambda i, c: (i, c, 0, 0),
-                               memory_space=pltpu.VMEM),
-                  whole_state()],
-        out_specs=(rows(dv), whole_state()),
-        out_shape=(jax.ShapeDtypeStruct((bh, t, dv), v.dtype),
-                   jax.ShapeDtypeStruct((bh, dk, dv), jnp.float32)),
-        scratch_shapes=[pltpu.VMEM((hb, dk, dv), jnp.float32)],
+        in_specs=[per_chunk(chunk, dk), per_chunk(chunk, dk),
+                  per_chunk(chunk, dv), per_chunk(2, chunk), whole_state()],
+        out_specs=(per_chunk(chunk, dv), whole_state()),
+        out_shape=(jax.ShapeDtypeStruct((bh, n, chunk, dv), v.dtype),
+                   jax.ShapeDtypeStruct((bh, dk, dv), f32)),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="gdn_scan",
     )(q, k, v, gb, s0)
@@ -318,7 +372,7 @@ def _gdn_update(state, q, k, v, g, beta, *, interpret, kernel):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    hb = _head_block(h, 16)
+    hb = _fit(h, 16)
     nb = h // hb
     # v and o go in and out as (N, blocks, hb, dv), a block spanning
     # the whole of its last two dimensions: hb need then be no multiple

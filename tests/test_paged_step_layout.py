@@ -385,9 +385,10 @@ def test_hybrid_decode_step_updates_state_in_place(one_chip,
 def test_hybrid_prefill_writes_the_slot_s_state_in_place(one_chip,
                                                          no_compile_cache):
     """One row of the 8,192 bucket, as `DecodeLoop`'s `prefill_fn` jits
-    it: the chunked scan a linear layer, grouped-head flash at heads of
-    256 in the full one, the row's final state scattered to its slot of
-    the donated arrays."""
+    it: the chunked scan a linear layer (one kernel, `gdn_scan`, that
+    since PR 38 does a chunk's state-free half and its state half in
+    one grid step), grouped-head flash at heads of 256 in the full one,
+    the row's final state scattered to its slot of the donated arrays."""
     import jax.numpy as jnp
 
     from deeplearning4j_tpu.serving import paged_kinds
