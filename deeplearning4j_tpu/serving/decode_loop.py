@@ -306,15 +306,25 @@ dl4j_prefill_chunk_tokens{loop}).
 is one `decode.tick` whose children are the phases of the pass, so each
 nanosecond of a pass lies in exactly one child or in the tick's self
 time. Their seconds and counts are always on
-(dl4j_decode_phase_seconds{loop,phase}, `snapshot()["phases"]`), the
-longest pass of each of the last `SLOW_TICKS_KEPT` intervals is kept
-with what it was made of (`snapshot()["slow_ticks"]`;
-`decode.prefill_dispatch` carries `bb`, `tb`, `rows`, `tokens`, the
-context pages it reads `ctx` and `carried`, whether it starts from a
-kept state), and every span is a TraceMe of the same name, so a `jax.profiler` window holds the
-scheduler's phases on the device trace's clock. A request carries four
-stamps of its own life (`GenerationStream.timeline()`); the wait in the
-queue also feeds dl4j_decode_queue_wait_seconds.
+(dl4j_decode_phase_seconds{loop,phase}, `snapshot()["phases"]`), and
+`decode.tick` and `decode.d2h` also read the scheduler thread's own CPU
+clock (`cpu_seconds` beside `seconds`; dl4j_decode_phase_cpu_seconds):
+wall less CPU is time the thread did not run. The longest pass of each
+of the last `SLOW_TICKS_KEPT` intervals is kept with what it was made of
+(`snapshot()["slow_ticks"]`: its phases, `cpu_ms` and `d2h_cpu_ms`,
+`offcpu_ms` = `dur_ms` - `d2h_ms` - (`cpu_ms` - `d2h_cpu_ms`), the time the
+pass neither ran nor waited on the device, and from the process's
+`telemetry.host` monitor the collector's `gc_ms` and highest `gc_gen`,
+the heartbeat's worst delay `lag_ms`, the thread's context switches
+`vcsw`, `ivcsw` and major faults `majflt`; `decode.prefill_dispatch`
+carries `bb`, `tb`, `rows`, `tokens`, the context pages it reads `ctx`
+and `carried`, whether it starts from a kept state), and every span is a
+TraceMe of the same name, so a `jax.profiler` window holds the
+scheduler's phases on the device trace's clock, beside the collector's
+`host.gc.gen*`. `snapshot()["host"]` is the monitor's own record. A
+request carries four stamps of its own life
+(`GenerationStream.timeline()`); the wait in the queue also feeds
+dl4j_decode_queue_wait_seconds.
 """
 
 from __future__ import annotations
@@ -347,8 +357,11 @@ from deeplearning4j_tpu.serving.paged_kv import (copy_page, extract_page,
                                                  prompt_buckets)
 from deeplearning4j_tpu.serving.prefix_cache import PrefixIndex
 from deeplearning4j_tpu.serving.speculation import build_drafter
-from deeplearning4j_tpu.telemetry.trace import (PhaseTotals, active_tracer,
-                                               span)
+from deeplearning4j_tpu.telemetry import host
+from deeplearning4j_tpu.telemetry.trace import (SLOWEST_INTERVAL_S,
+                                               SLOWEST_KEPT, PhaseTotals,
+                                               active_tracer, slowest,
+                                               slowest_slot, span)
 from deeplearning4j_tpu.testing import chaos
 from deeplearning4j_tpu.utils.jitcache import jit_cache_size
 
@@ -393,12 +406,14 @@ PHASES = (IDLE_WAIT, TICK, "decode.reap", "decode.kv_jobs", "decode.admit",
           "decode.upload",
           "decode.draft", "decode.step_dispatch", "decode.d2h",
           "decode.account", "decode.flush_first", "decode.emit")
+#: the phases that also count the scheduler thread's CPU time
+CPU_PHASES = (TICK, "decode.d2h")
 
 #: `snapshot()["slow_ticks"]` holds the longest pass of each of the last
 #: SLOW_TICKS_KEPT intervals of SLOW_TICK_INTERVAL_S seconds: about the
 #: last minute of service, however long start-up's passes were
-SLOW_TICKS_KEPT = 8
-SLOW_TICK_INTERVAL_S = 8
+SLOW_TICKS_KEPT = SLOWEST_KEPT
+SLOW_TICK_INTERVAL_S = SLOWEST_INTERVAL_S
 
 #: per-queued-item service estimate feeding the backlog-derived
 #: Retry-After on a tier shed: interactive items are short user turns,
@@ -1226,7 +1241,12 @@ class DecodeLoop:
             "wall time of the scheduler's spans by phase: decode.tick "
             "is one pass, the other decode.* are what a pass is made "
             "of, decode.idle_wait is the scheduler asleep between "
-            "passes"), PHASES, **lab)
+            "passes"), PHASES, cpu_family=reg.counter(
+                "dl4j_decode_phase_cpu_seconds",
+                "CPU seconds of the scheduler's thread in decode.tick and "
+                "decode.d2h: the phase's wall seconds less these are the "
+                "time the thread did not run"), cpu_names=CPU_PHASES,
+            **lab)
         self._m_queue_wait = reg.histogram(
             "dl4j_decode_queue_wait_seconds",
             "time a generate request waited in the admission queue, "
@@ -1253,6 +1273,8 @@ class DecodeLoop:
         #: ring of the longest pass per interval (snapshot()
         #: ["slow_ticks"]); slot k holds interval number k mod its size
         self._slow_ticks: List[Optional[dict]] = [None] * SLOW_TICKS_KEPT
+        #: the process's collector and heartbeat record
+        self._host = host.start_host_monitor()
         reg.gauge(
             "dl4j_kv_pages_total",
             "usable KV pages in the block pool").labels(**lab).set(
@@ -2231,9 +2253,8 @@ class DecodeLoop:
                 "phases": self._phases.totals(),
                 "queue_wait": {"seconds": self._m_queue_wait.sum,
                                "count": self._m_queue_wait.count},
-                "slow_ticks": sorted(
-                    (t for t in self._slow_ticks if t is not None),
-                    key=lambda t: t["start_s"]),
+                "slow_ticks": slowest(self._slow_ticks),
+                "host": self._host.snapshot(),
                 "decode_kernel": {
                     "requested": self.kernel_requested,
                     "selected": self.decode_kernel,
@@ -2408,30 +2429,38 @@ class DecodeLoop:
         deterministically."""
         phases = self._phases
         phases.begin_pass()
+        gc_ns, usage = self._host.gc_ns, host.thread_usage()
         with span(TICK, phases) as tick:
             ran = tick.args["dispatched"] = self._pass()
-        self._keep_if_slowest(tick)
+        self._keep_if_slowest(tick, gc_ns, usage)
         return ran
 
-    def _keep_if_slowest(self, tick) -> None:
+    def _keep_if_slowest(self, tick, gc_ns: Optional[int] = None,
+                         usage=None) -> None:
         """Keep this pass if it is the longest of its interval so far:
-        its start on `time.perf_counter`, its length, and the
-        milliseconds of each phase that ran in it (what they leave of
-        the length is the tick's self time). A pass that is not
-        allocates nothing."""
+        its start on `time.perf_counter`, its length, the milliseconds
+        of each phase that ran in it (what they leave of the length is
+        the tick's self time), its CPU time and what the process did
+        meanwhile (`host.HostMonitor.during`, given the collector's
+        total and the thread's usage as read at the pass's start). A
+        pass that is not kept allocates nothing."""
         start_s, dur_ms = tick.start_ns / 1e9, tick.dur_ns / 1e6
-        interval = int(start_s // SLOW_TICK_INTERVAL_S)
-        at = interval % SLOW_TICKS_KEPT
-        kept = self._slow_ticks[at]
-        if (kept is not None and kept["dur_ms"] >= dur_ms
-                and int(kept["start_s"] // SLOW_TICK_INTERVAL_S)
-                == interval):
+        at = slowest_slot(self._slow_ticks, start_s, dur_ms,
+                          SLOW_TICK_INTERVAL_S)
+        if at is None:
             return
+        cpu = self._phases.pass_cpu_ns
+        cpu_ms, d2h_cpu_ms = cpu[TICK] / 1e6, cpu["decode.d2h"] / 1e6
+        d2h_ms = self._phases.pass_ns["decode.d2h"] / 1e6
         self._slow_ticks[at] = {
             "start_s": start_s, "dur_ms": dur_ms,
             "phases": {n: ns / 1e6
                        for n, ns in self._phases.pass_ns.items()
-                       if ns and n != TICK}}
+                       if ns and n != TICK},
+            "cpu_ms": cpu_ms, "d2h_cpu_ms": d2h_cpu_ms,
+            "offcpu_ms": dur_ms - d2h_ms - (cpu_ms - d2h_cpu_ms),
+            **self._host.during(tick.start_ns, tick.start_ns + tick.dur_ns,
+                                gc_ns, usage)}
 
     def _pass(self) -> bool:
         """The body of `tick()`."""

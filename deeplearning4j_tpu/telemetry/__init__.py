@@ -22,6 +22,10 @@ process-global `MetricsRegistry`:
   `dl4j_batcher_*` + queue depth;
 - device: `dl4j_device_memory_bytes{device=,stat=}`,
   `dl4j_jit_programs{cache=}` recompile counters;
+- host process (`telemetry/host.py`, started by the first decode
+  loop): `dl4j_host_gc_seconds{generation=}`,
+  `dl4j_host_gc_collections{generation=}` (the collector's pauses) and
+  `dl4j_host_lag_seconds_max` (an interpreter heartbeat's delay);
 - checkpoint: `dl4j_ckpt_saves/bytes_written/errors`,
   `dl4j_ckpt_snapshot_seconds` (step-loop stall) /
   `dl4j_ckpt_write_seconds`, in-flight + last-committed-step gauges,

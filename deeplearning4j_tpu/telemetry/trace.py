@@ -21,7 +21,14 @@ What a closed span leaves behind depends on what is listening:
 - **Phase totals**, for a span given `phases=`: seconds and count by
   span name, always on. These are counters like any other of the
   registry (`PhaseTotals` keeps them in one histogram family), so
-  `/metrics` has them with no tracer and no profiler.
+  `/metrics` has them with no tracer and no profiler. For the names the
+  totals call `cpu_names`, the span also reads the thread's own CPU
+  clock (`time.thread_time_ns`) at both ends: wall time less CPU time is
+  the time the thread did not run, waiting or not scheduled.
+
+`slowest_slot` keeps "the longest of each interval" in a ring of a few
+slots: the longest scheduler pass, collector pause or heartbeat delay of
+each of the last minute's intervals, however long start-up's were.
 
 A span with no `phases=` runs only while tracing is on: until then it
 is an object and two attribute checks, cheap enough for the fit and
@@ -42,7 +49,13 @@ from typing import Dict, Iterable, List, NamedTuple, Optional
 __all__ = [
     "SpanRecord", "Tracer", "PhaseTotals", "span", "start_tracing",
     "stop_tracing", "active_tracer", "chrome_trace", "save_chrome_trace",
+    "slowest_slot", "slowest", "SLOWEST_KEPT", "SLOWEST_INTERVAL_S",
 ]
+
+#: a ring of "the longest of each interval" holds SLOWEST_KEPT intervals
+#: of SLOWEST_INTERVAL_S seconds: about the last minute
+SLOWEST_KEPT = 8
+SLOWEST_INTERVAL_S = 8
 
 
 class SpanRecord(NamedTuple):
@@ -134,24 +147,62 @@ class PhaseTotals:
     `phase`, plus the owner's labels), so a scrape has them; `pass_ns`
     holds the nanoseconds since the owner last called `begin_pass()`,
     which is how a scheduler says what one of its passes was made of.
+    The spans named in `cpu_names` also count the thread's CPU seconds,
+    into the counter family `cpu_family` and `pass_cpu_ns`.
     One thread closes an owner's spans; any thread may read."""
 
-    def __init__(self, family, names: Iterable[str], **labels):
+    def __init__(self, family, names: Iterable[str], cpu_family=None,
+                 cpu_names: Iterable[str] = (), **labels):
         self._cells = {n: family.labels(phase=n, **labels) for n in names}
         self.pass_ns: Dict[str, int] = dict.fromkeys(self._cells, 0)
+        self.cpu = frozenset(cpu_names)
+        self._cpu_cells = {n: cpu_family.labels(phase=n, **labels)
+                           for n in self.cpu}
+        self.pass_cpu_ns: Dict[str, int] = dict.fromkeys(self.cpu, 0)
 
     def begin_pass(self) -> None:
-        ns = self.pass_ns
-        for name in ns:
-            ns[name] = 0
+        for ns in (self.pass_ns, self.pass_cpu_ns):
+            for name in ns:
+                ns[name] = 0
 
-    def add(self, name: str, dur_ns: int) -> None:
+    def add(self, name: str, dur_ns: int,
+            cpu_ns: Optional[int] = None) -> None:
         self._cells[name].observe(dur_ns * 1e-9)
         self.pass_ns[name] += dur_ns
+        if cpu_ns is not None:
+            self._cpu_cells[name].inc(cpu_ns * 1e-9)
+            self.pass_cpu_ns[name] += cpu_ns
 
     def totals(self) -> Dict[str, dict]:
-        return {n: {"seconds": c.sum, "count": c.count}
-                for n, c in self._cells.items()}
+        out = {n: {"seconds": c.sum, "count": c.count}
+               for n, c in self._cells.items()}
+        for n, c in self._cpu_cells.items():
+            out[n]["cpu_seconds"] = c.value
+        return out
+
+
+def slowest_slot(ring: list, start_s: float, dur_ms: float,
+                 interval_s: float = SLOWEST_INTERVAL_S) -> Optional[int]:
+    """The slot of `ring` in which to keep an event that started at
+    `start_s` (seconds) and lasted `dur_ms`, or None where the slot
+    already holds a longer event of the same interval. Slot k holds
+    interval number k mod len(ring), so an interval that comes round
+    replaces the one it laps. Entries are dicts with `start_s` and
+    `dur_ms`. Takes no lock and allocates nothing: the collector's
+    callback calls it."""
+    interval = int(start_s // interval_s)
+    at = interval % len(ring)
+    kept = ring[at]
+    if (kept is not None and kept["dur_ms"] >= dur_ms
+            and int(kept["start_s"] // interval_s) == interval):
+        return None
+    return at
+
+
+def slowest(ring: list) -> List[dict]:
+    """The entries a ring holds, oldest first."""
+    return sorted((e for e in ring if e is not None),
+                  key=lambda e: e["start_s"])
 
 
 _active: Optional[Tracer] = None
@@ -195,10 +246,13 @@ class span:  # noqa: N801 - a context manager called like a function
     not anybody traces; `parent_id=` names the span that caused this one
     where that is not the enclosing span of the thread. `args` may be
     added to until the span closes (`with span(...) as s: s.args["n"] =
-    n`); `start_ns` and `dur_ns` can be read once it has."""
+    n`); `start_ns` and `dur_ns` can be read once it has, and `cpu_ns`,
+    the thread's CPU time inside the span, where `phases` names it among
+    its `cpu_names` (None otherwise)."""
 
     __slots__ = ("name", "args", "phases", "parent_id", "span_id",
-                 "start_ns", "dur_ns", "_tracer", "_stack", "_ann")
+                 "start_ns", "dur_ns", "cpu_ns", "_tracer", "_stack",
+                 "_ann")
 
     def __init__(self, name: str, phases: Optional[PhaseTotals] = None,
                  parent_id: Optional[int] = None, **args):
@@ -208,6 +262,7 @@ class span:  # noqa: N801 - a context manager called like a function
         self.parent_id = parent_id
         self.span_id = None
         self.start_ns = self.dur_ns = 0
+        self.cpu_ns = None
         self._tracer = None
         self._ann = None
 
@@ -224,16 +279,21 @@ class span:  # noqa: N801 - a context manager called like a function
         ann = self._ann = _trace_annotation()(self.name)
         ann.__enter__()
         self.start_ns = time.perf_counter_ns()
+        # the CPU clock is read inside the wall clock's two reads
+        if self.phases is not None and self.name in self.phases.cpu:
+            self.cpu_ns = time.thread_time_ns()
         return self
 
     def __exit__(self, *exc) -> None:
         ann = self._ann
         if ann is None:
             return
+        if self.cpu_ns is not None:
+            self.cpu_ns = time.thread_time_ns() - self.cpu_ns
         self.dur_ns = time.perf_counter_ns() - self.start_ns
         ann.__exit__(*exc)
         if self.phases is not None:
-            self.phases.add(self.name, self.dur_ns)
+            self.phases.add(self.name, self.dur_ns, self.cpu_ns)
         tracer = self._tracer
         if tracer is not None:
             self._stack.pop()
