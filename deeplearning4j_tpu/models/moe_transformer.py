@@ -53,7 +53,7 @@ import jax.numpy as jnp
 from deeplearning4j_tpu.models.moe_rows import rows_in, rows_out
 from deeplearning4j_tpu.models.transformer import (KIND_FULL,  # noqa: F401
                                                    KIND_WINDOW, KINDS,
-                                                   Attend,
+                                                   Attend, _project,
                                                    causal_attention)
 
 __all__ = ["MoEConfig", "KINDS", "KIND_FULL", "KIND_WINDOW",
@@ -329,9 +329,9 @@ def block(p, x, positions, layer: int, cfg: MoEConfig, attend: Attend,
     b, t, d = x.shape
     kind = cfg.layer_kinds[layer]
     h = _gain_norm(p["ln"], x, cfg.ln_eps)
-    q = (h @ p["Wq"]).reshape(b, t, cfg.n_heads, cfg.head_dim)
-    k = (h @ p["Wk"]).reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
-    v = (h @ p["Wv"]).reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
+    q = _project(h, p["Wq"]).reshape(b, t, cfg.n_heads, cfg.head_dim)
+    k = _project(h, p["Wk"]).reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
+    v = _project(h, p["Wv"]).reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
     if kind == KIND_WINDOW:
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)

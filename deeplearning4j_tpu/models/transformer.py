@@ -22,6 +22,7 @@ from typing import Any, Callable, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from deeplearning4j_tpu.attention.flash_pallas import flash_attention
 
@@ -133,6 +134,20 @@ def _layer_norm(p, x, eps=1e-5):
             * p["g"] + p["b"])
 
 
+def _project(h, w):
+    """h (B, T, d) @ w (d, n). Where a row holds one position (T == 1, a
+    decode step) the product is fixed row-major before the caller splits
+    it into heads: left free, the compiler meets the heads-major layout
+    the attention reads by re-laying out the WEIGHT, a whole matrix
+    copied a layer a step, instead of the few rows of the product
+    (PERF.md section 6). Longer rows compile as they always have."""
+    y = h @ w
+    if h.shape[1] == 1:
+        y = with_layout_constraint(
+            y, Layout(major_to_minor=tuple(range(y.ndim))))
+    return y
+
+
 def causal_attention(cfg, kind: str, q, k, v):
     """Whole rows at once, nothing cached: q (B, Hq, T, hd) over k, v
     (B, Hkv, T, hd), causal, windowed in a window layer. The flash
@@ -162,8 +177,8 @@ def block(p, x, cfg: TransformerConfig, attend: Attend, layer: int):
     h = _layer_norm(p["ln1"], x)
 
     def heads(w):
-        return (h @ w).reshape(b, t, cfg.n_heads,
-                               cfg.head_dim).transpose(0, 2, 1, 3)
+        return _project(h, w).reshape(b, t, cfg.n_heads,
+                                      cfg.head_dim).transpose(0, 2, 1, 3)
 
     att, state = attend(layer, KIND_FULL, heads(p["Wq"]), heads(p["Wk"]),
                         heads(p["Wv"]))

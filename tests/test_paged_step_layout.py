@@ -14,7 +14,9 @@ layout changes of a whole 168 MB pool per layer for K and for V each,
 chip time. Since PR 31 the kernel at these shapes sweeps blocks of 8
 pages that it copies out of the pool itself (the pool is an operand
 left in HBM): each call must still be handed the pool as it lies,
-row-major, whatever the block.
+row-major, whatever the block. And the decode steps may hold no copy of
+a `Wq`, `Wk` or `Wv` weight (the compiler had met the attention's
+heads-major layout by re-laying out the weights).
 
 The topology is described inside a module fixture, never at import, and
 the tests skip where it cannot be described (the same set-up as
@@ -133,11 +135,11 @@ def _assert_kernel_reads_the_pool_as_it_lies(text, shapes, calls):
         assert len(set(pools)) == 1 and pools[0] in wanted, constraints
 
 
-def test_decode_step_holds_no_pool_shaped_copy(one_chip,
-                                               no_compile_cache):
+@pytest.fixture(scope="module")
+def gpt_step_text(one_chip, no_compile_cache):
     """The step as `DecodeLoop` jits it: `paged_kinds.decode_step` on
     the paged lane with the argmax fed back, under a `lax.scan` of length
-    1 (horizon 1), pool donated."""
+    1 (horizon 1), pool donated; its optimized HLO."""
     import jax
     import jax.numpy as jnp
 
@@ -162,11 +164,15 @@ def test_decode_step_holds_no_pool_shaped_copy(one_chip,
             inner, (tokens, lengths, pool), None, length=1)
         return toks, tokens, lengths, pool
 
-    text = jax.jit(step_fn, donate_argnums=(2,)).lower(
+    return jax.jit(step_fn, donate_argnums=(2,)).lower(
         params, vec(SLOTS), pool, table, vec(SLOTS),
         vec(SLOTS)).compile().as_text()
-    _assert_kernel_reads_the_pool_as_it_lies(text, [POOL_SHAPE], N_LAYERS)
-    _assert_pool_updated_in_place(text)
+
+
+def test_decode_step_holds_no_pool_shaped_copy(gpt_step_text):
+    _assert_kernel_reads_the_pool_as_it_lies(gpt_step_text, [POOL_SHAPE],
+                                             N_LAYERS)
+    _assert_pool_updated_in_place(gpt_step_text)
 
 
 def test_verify_step_holds_no_pool_shaped_copy(one_chip,
@@ -196,8 +202,11 @@ def test_verify_step_holds_no_pool_shaped_copy(one_chip,
     _assert_pool_updated_in_place(text)
 
 
-def test_two_kind_decode_step_holds_no_pool_shaped_copy(one_chip,
-                                                        no_compile_cache):
+TWO_KIND_PAGES = {"full": 2048, "window": 1056}
+
+
+@pytest.fixture(scope="module")
+def two_kind_step_text(one_chip, no_compile_cache):
     """The decode step of the block with grouped K/V heads, window and
     full layers and a held-expert layer (`paged_kinds.decode_step`, as
     `DecodeLoop` jits it for a model with kinds of layer) at the served
@@ -206,7 +215,8 @@ def test_two_kind_decode_step_holds_no_pool_shaped_copy(one_chip,
     and one full layer: what the compiler does to a pool it does to
     each; the same `lax.scan` of length 1 as every model's step. The
     grouped expert products are the megablox kernel, as on the chip (the
-    backend here is the CPU, so the test says "tpu")."""
+    backend here is the CPU, so the test says "tpu"). Its optimized
+    HLO."""
     import jax
     import jax.numpy as jnp
 
@@ -219,7 +229,7 @@ def test_two_kind_decode_step_holds_no_pool_shaped_copy(one_chip,
         window=4096, n_experts=128, experts_per_token=8, n_shared=4,
         n_held=16, rope_theta=50000.0, max_len=8192,
         dtype=jnp.bfloat16).check()
-    pages = {"full": 2048, "window": 1056}
+    pages = TWO_KIND_PAGES
 
     def on_chip(tree):
         return jax.tree_util.tree_map(
@@ -253,15 +263,19 @@ def test_two_kind_decode_step_holds_no_pool_shaped_copy(one_chip,
     backend = jax.default_backend
     jax.default_backend = lambda: "tpu"
     try:
-        text = jax.jit(step_fn, donate_argnums=(2,)).lower(
+        return jax.jit(step_fn, donate_argnums=(2,)).lower(
             params, vec(32), pool, tables, vec(32),
             vec(32)).compile().as_text()
     finally:
         jax.default_backend = backend
+
+
+def test_two_kind_decode_step_holds_no_pool_shaped_copy(two_kind_step_text):
+    text = two_kind_step_text
     assert "%gmm" in text
     _assert_kernel_reads_the_pool_as_it_lies(
-        text, [(n + 1, 8, 128, 128) for n in pages.values()], 2)
-    for n in pages.values():
+        text, [(n + 1, 8, 128, 128) for n in TWO_KIND_PAGES.values()], 2)
+    for n in TWO_KIND_PAGES.values():
         copies = re.findall(
             rf"^.*= bf16\[{n + 1},8,128,128\]\{{[^}}]*\}} copy\(.*$",
             text, re.M)
@@ -269,6 +283,66 @@ def test_two_kind_decode_step_holds_no_pool_shaped_copy(one_chip,
     alias = re.search(r"input_output_alias=\{(.*?)\}, entry", text, re.S)
     assert alias, "the compiled module aliases no input to an output"
     assert len(re.findall(r"(?:may|must)-alias", alias.group(1))) == 4
+
+
+# ---------------------------------- the projections' weights as they lie
+#: what hands a value on unchanged, or moves it between memories: a
+#: weight reached through these is the weight itself
+_CARRIERS = ("bitcast", "copy-start", "copy-done", "slice-start",
+             "slice-done", "get-tuple-element")
+
+
+def _instructions(text):
+    """{name: (opcode, operand names, line)} of every instruction."""
+    found = {}
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", line)
+        op = m and re.search(r"\s([a-z][\w\-]*)\(", line[m.end():])
+        if op:
+            args = line[m.end() + op.end():]
+            found[m.group(1)] = (op.group(1), re.findall(
+                r"%([\w.\-]+)", args[:args.find(")")]), line)
+    return found
+
+
+def _projection_weight_copies(text):
+    """The `copy` instructions whose operand is a parameter `Wq`, `Wk`
+    or `Wv`, reached through bitcasts, the prefetch's async copies and
+    slices, and the custom call that joins prefetched slices."""
+    found = _instructions(text)
+
+    def weights(name, depth=0):
+        if name not in found or depth > 16:
+            return set()
+        op, args, line = found[name]
+        if op == "parameter":
+            return {name} if re.match(r"\s*%params__\S*___W[qkv]__",
+                                      line) else set()
+        if op in _CARRIERS or (op == "custom-call"
+                               and "ConcatBitcast" in line):
+            return set().union(*(weights(a, depth + 1) for a in args))
+        return set()
+
+    return [(name, sorted(weights(args[0])))
+            for name, (op, args, _) in found.items()
+            if op == "copy" and args and weights(args[0])]
+
+
+@pytest.mark.parametrize("step", ["gpt_step_text", "two_kind_step_text"])
+def test_decode_step_copies_no_projection_weight(step, request):
+    """cgpt-1.3b's step (16 heads of 128 at d 2048) and command-a-plus
+    ep8's (128 query heads over 8 K/V heads at d 4096): given the
+    products of `Wq`, `Wk` and `Wv` free to take the heads-major layout
+    the attention reads, the compiler re-laid out each WEIGHT, a whole
+    matrix copied a layer a step (72 `bf16[2048,2048]` copies a step of
+    24 layers, 4 of `bf16[16384,4096]` a step of ep8's four; PERF.md
+    section 6). A step leaves its products row-major and
+    splits the rows: no copy of a projection's weight."""
+    text = request.getfixturevalue(step)
+    assert re.search(r"%params__\S*___Wq__\S* = \S+ parameter\(", text), \
+        "the step takes no Wq: the walk would find nothing to refuse"
+    copies = _projection_weight_copies(text)
+    assert not copies, f"{len(copies)} weight copies, first {copies[0]}"
 
 
 # ------------------------------------- a kind that is not pages (PR 35)
