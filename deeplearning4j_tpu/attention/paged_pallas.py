@@ -65,17 +65,19 @@ DECODE_KERNELS = ("auto", "pallas", "gather")
 
 
 def _softmax_update(q, k, v, page, page_size, pos, first,
-                    acc_ref, m_ref, s_ref):
+                    acc_ref, m_ref, s_ref, transposed: bool = False):
     """One online-softmax update of the (H, rows, .) state with the
-    keys of `k`/`v` (H, keys, hd), the first of them the first of
-    logical page `page`: positions past `pos` (and before `first`,
-    where it is given) are masked to NEG_INF and weigh exactly 0."""
+    keys of `k`/`v` (H, keys, hd), or (H, hd, keys) where `transposed`,
+    the first of them the first of logical page `page`: positions past
+    `pos` (and before `first`, where it is given) are masked to NEG_INF
+    and weigh exactly 0."""
     hd = q.shape[-1]
+    keys_at = 2 if transposed else 1
     # base-2 softmax state, scores prescaled by log2(e)/sqrt(hd):
     # the transcendental is a bare exp2 (flash_pallas._kernel)
     scale2 = jnp.float32(LOG2E) / jnp.float32(hd) ** 0.5
     scores = jax.lax.dot_general(
-        q, k, (((2,), (2,)), ((0,), (0,))),
+        q, k, (((2,), (3 - keys_at,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
         precision=_dot_precision(q.dtype)) * scale2   # (H, rows, keys)
     k_pos = page * page_size \
@@ -94,13 +96,13 @@ def _softmax_update(q, k, v, page, page_size, pos, first,
     # P in V's storage dtype for the MXU dot, f32 accumulation —
     # same rounding story as the flash forward
     acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+        p.astype(v.dtype), v, (((2,), (keys_at,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
         precision=_dot_precision(v.dtype))
 
 
 def _decode_kernel(pt_ref, len_ref, *refs, page_size: int,
-                   windowed: bool = False):
+                   windowed: bool = False, transposed: bool = False):
     """One (slot, page) grid step: the kernel of a block of ONE page.
     `pt_ref`/`len_ref` (and `first_ref` where `windowed`) are the
     scalar-prefetch operands (the same arrays the BlockSpec index maps
@@ -113,7 +115,8 @@ def _decode_kernel(pt_ref, len_ref, *refs, page_size: int,
 
     `windowed`: the slot sees positions [first, pos] only, and grid
     step j stands for logical page first // page_size + j, so the sweep
-    starts at the first page that still holds a visible key."""
+    starts at the first page that still holds a visible key.
+    `transposed`: a page of K and V arrives as (H, hd, page_size)."""
     from jax.experimental import pallas as pl
 
     if windowed:
@@ -139,7 +142,7 @@ def _decode_kernel(pt_ref, len_ref, *refs, page_size: int,
     @pl.when(page * page_size <= pos)
     def _tile():
         _softmax_update(q_ref[0], k_ref[0], v_ref[0], page, page_size,
-                        pos, first, acc_ref, m_ref, s_ref)
+                        pos, first, acc_ref, m_ref, s_ref, transposed)
 
     @pl.when(j == n_j - 1)
     def _finalize():
@@ -362,6 +365,14 @@ def _paged_attention(q, k_pool, v_pool, page_table, lengths, first, *,
         pltpu.VMEM((h, rows, 1), jnp.float32),    # running max (base-2)
         pltpu.VMEM((h, rows, 1), jnp.float32),    # running sum
     ]
+    # heads narrower than a lane tile in pages of whole lane tiles: the
+    # TPU lays such a pool out with its positions minor ({2,3,1,0}, no
+    # padding), and a row-major operand would copy both pools whole at
+    # every call; read as it lies, each page (H, hd, page_size), it is
+    # the transposed pool's row-major layout and no copy at all
+    transposed = bool(hd % 128) and ps % 128 == 0
+    if transposed:
+        k_pool, v_pool = (jnp.swapaxes(a, 2, 3) for a in (k_pool, v_pool))
     if n_block == 1:
         # a block of one page is one contiguous piece of the pool:
         # Pallas's own pipeline fetches it by a block spec
@@ -370,9 +381,10 @@ def _paged_attention(q, k_pool, v_pool, page_table, lengths, first, *,
                 pt[si, jnp.minimum(fs[si] // ps + j, n_p - 1)], 0, 0, 0)
         else:
             kv_map = lambda si, j, pt, ln: (pt[si, j], 0, 0, 0)  # noqa: E731
-        kv_spec = pl.BlockSpec((1, h, ps, hd), kv_map,
+        kv_spec = pl.BlockSpec((1, *k_pool.shape[1:]), kv_map,
                                memory_space=pltpu.VMEM)
-        kernel = partial(_decode_kernel, page_size=ps, windowed=windowed)
+        kernel = partial(_decode_kernel, page_size=ps, windowed=windowed,
+                         transposed=transposed)
         grid, scratch = (s, n_j), state
         # slots are independent (scratch init/finalize is per-row);
         # only the page sweep carries the online-softmax state

@@ -1,11 +1,13 @@
 """A third language-model block: layers that keep a recurrent STATE
-beside layers that keep K/V, a feed-forward layer after either.
+or a few convolution columns beside layers that keep K/V, a
+feed-forward layer after either.
 
 Sequential residuals with RMSNorm, two norms a layer, where the
 configuration says (`cfg.norm_place`): "pre", `x = x + mixer(n_1(x))`
 then `x = x + ff(n_2(x))`, or "post", `x = x + n_1(mixer(x))` then `x =
 x + n_2(ff(x))`, the norm on the sublayer's OUTPUT. Layer `l` is one of
-two kinds (`cfg.layer_kinds`, published as "three linear, one full"):
+three kinds (`cfg.layer_kinds`, published as "three linear, one full"
+or "conv, conv, full, conv, ..."):
 
 - **full** (`KIND_FULL`): `n_heads` query heads over `n_kv_heads` K/V
   heads of `head_dim`; with `cfg.attn_gate`, `Wq` gives each head its
@@ -26,14 +28,23 @@ two kinds (`cfg.layer_kinds`, published as "three linear, one full"):
   `attention/gdn_pallas.py` over a (dk, dv) state a value head (value
   head n reads the q and k of key head `n // (Hv / Hk)`); `y =
   RMSNorm_dv(o) * silu(z)`, `out = y W_out`.
+- **conv** (`KIND_CONV`, the gated short convolution): `[b | c | x] = h
+  W_in`, three blocks of `d_model`; `u = b * x`; a causal depthwise
+  convolution of `conv_kernel` taps, no bias, zeros before the
+  sequence, NO activation: `z_t = sum_j w_j u_{t - K + 1 + j}`; `out =
+  (c * z) W_out`. A slot keeps the last `conv_kernel - 1` columns of u.
 
 The feed-forward layer is `models/moe_transformer.expert_layer`, the
 one there is (this configuration says `router_score = "softmax"` and
 `shared_combine = "sigmoid_gate"` where that module's own says sigmoid
 and average), or, where the configuration has no experts (`n_experts =
-0`), one dense gated-SiLU product of width `d_ff`. What this chip holds
-of the experts and the vocabulary is stated as there (`n_held`,
-`held_first`, rows `[0, vocab_size)`); the head is untied.
+0`), one dense gated-SiLU product of width `d_ff`; or dense in the
+first `n_dense_layers` layers (width `d_ff_dense`) and experts after
+them. With `router_bias` the router chooses by its scores plus
+`p["expert_bias"]` and weights by the scores alone. What this chip
+holds of the experts and the vocabulary is stated as there (`n_held`,
+`held_first`, rows `[0, vocab_size)`); the head is its own matrix, or
+the embedding's transpose where `tied_head`.
 
 **The block stands once.** A full layer leaves "write these K/V rows,
 read what is visible" to its `attend` callback as every model does
@@ -41,8 +52,10 @@ read what is visible" to its `attend` callback as every model does
 the callback the pre-convolution columns `u` (B, T, C), the two gates
 `(g, beta)` (B, T, Hv) and the convolution's weights, and gets the
 mixer's rows `o` (B, T, Hv, dv) and the layer's new cache entry back.
-What lies between is `linear_mix`, written here once and called by
-every callback with what its cache holds: nothing (the uncached
+A conv layer hands it the columns `u` (B, T, d) and the convolution's
+weights and gets `z` back. What lies between is `slot_mix` (`linear_mix`
+or `conv_mix`, both over the one `causal_conv`), written here once and
+called by every callback with what its cache holds: nothing (the uncached
 forward: zero state, the whole scan), a row's real length (the paged
 prefill: padding must not move the state, and the convolution keeps
 the last three REAL columns), or a slot's kept columns and state (the
@@ -60,13 +73,16 @@ import jax.numpy as jnp
 from deeplearning4j_tpu.attention.gdn_pallas import (CHUNK, gdn_scan,
                                                      gdn_update)
 from deeplearning4j_tpu.models.moe_transformer import expert_layer
-from deeplearning4j_tpu.models.transformer import (KIND_FULL, KIND_LINEAR,
-                                                   Attend,
+from deeplearning4j_tpu.models.transformer import (KIND_CONV, KIND_FULL,
+                                                   KIND_LINEAR, Attend,
                                                    causal_attention)
 
-__all__ = ["HybridConfig", "KIND_FULL", "KIND_LINEAR",
-           "init_hybrid_params", "rope_half", "linear_mix", "block",
-           "forward", "head", "logits", "causal_attention"]
+__all__ = ["HybridConfig", "KIND_FULL", "KIND_LINEAR", "KIND_CONV",
+           "init_hybrid_params", "rope_half", "causal_conv",
+           "linear_mix", "conv_mix", "slot_mix", "block", "forward",
+           "head", "logits", "causal_attention"]
+
+KINDS = (KIND_FULL, KIND_LINEAR, KIND_CONV)
 
 
 class HybridConfig(NamedTuple):
@@ -78,7 +94,7 @@ class HybridConfig(NamedTuple):
     n_kv_heads: int
     head_dim: int
     d_ff: int                  # an expert's width, routed and shared
-    layer_kinds: Tuple[str, ...]   # KIND_LINEAR or KIND_FULL a layer
+    layer_kinds: Tuple[str, ...]   # one of KINDS a layer
     n_experts: int             # the router's width
     experts_per_token: int
     n_shared: int
@@ -88,7 +104,7 @@ class HybridConfig(NamedTuple):
     lin_v_heads: int = 32      # value heads,
     lin_k_dim: int = 128       # their widths
     lin_v_dim: int = 128
-    conv_kernel: int = 4
+    conv_kernel: int = 4       # taps of either kind's convolution
     rotary_dim: int = 64       # of head_dim, the part that turns
     rope_theta: float = 1e7
     max_len: int = 256
@@ -108,6 +124,15 @@ class HybridConfig(NamedTuple):
     attn_gate: bool = True
     #: the norm of q and k: "head" by head, "width" over all heads
     qk_norm: str = "head"
+    #: the first `n_dense_layers` layers have a dense feed-forward of
+    #: width `d_ff_dense` where the later ones have the expert layer
+    n_dense_layers: int = 0
+    d_ff_dense: int = 0
+    #: the router chooses by score + `p["expert_bias"]` and weights the
+    #: chosen by their scores alone, over their sum + 1e-6
+    router_bias: bool = False
+    #: the head is the embedding's transpose
+    tied_head: bool = False
 
     @property
     def n_layers(self) -> int:
@@ -134,6 +159,27 @@ class HybridConfig(NamedTuple):
                 "conv": (((self.conv_kernel - 1) * self.conv_channels,),
                          self.dtype)}
 
+    @property
+    def slot_state(self) -> dict:
+        """By kind held by slot that this model has, a layer's cache
+        entry a slot (`linear_state`; a conv layer's `conv`, the last
+        `conv_kernel - 1` columns of u side by side)."""
+        out = {}
+        if KIND_LINEAR in self.layer_kinds:
+            out[KIND_LINEAR] = self.linear_state
+        if KIND_CONV in self.layer_kinds:
+            out[KIND_CONV] = {"conv": (((self.conv_kernel - 1)
+                                        * self.d_model,), self.dtype)}
+        return out
+
+    def dense_at(self, layer: int) -> bool:
+        """Whether layer `layer` has the dense feed-forward."""
+        return not self.n_experts or layer < self.n_dense_layers
+
+    @property
+    def dense_width(self) -> int:
+        return self.d_ff_dense if self.n_experts else self.d_ff
+
     def check(self) -> "HybridConfig":
         if self.n_heads % self.n_kv_heads:
             raise ValueError(f"{self.n_kv_heads} K/V heads do not divide "
@@ -153,12 +199,15 @@ class HybridConfig(NamedTuple):
                                    or self.experts_per_token):
             raise ValueError("a dense feed-forward (n_experts = 0) has "
                              "no held, shared or chosen experts")
-        bad = [k for k in self.layer_kinds
-               if k not in (KIND_FULL, KIND_LINEAR)]
+        bad = [k for k in self.layer_kinds if k not in KINDS]
         if bad or not self.layer_kinds:
-            raise ValueError(f"layer_kinds must be of "
-                             f"{(KIND_LINEAR, KIND_FULL)}, got "
+            raise ValueError(f"layer_kinds must be of {KINDS}, got "
                              f"{self.layer_kinds}")
+        if self.n_dense_layers and not (
+                self.n_experts and self.d_ff_dense
+                and self.n_dense_layers <= self.n_layers):
+            raise ValueError("leading dense layers need experts after "
+                             "them, a width and at most n_layers")
         if not 0 <= self.held_first <= self.n_experts - self.n_held:
             raise ValueError(
                 f"held experts [{self.held_first}, "
@@ -174,6 +223,7 @@ def init_hybrid_params(key, cfg: HybridConfig):
     decay near 0.5 a token; a test that means a slow decay sets
     `dt_bias` itself."""
     d, f, hd = cfg.d_model, cfg.d_ff, cfg.head_dim
+    fd = cfg.dense_width
     hk, hv = cfg.lin_k_heads, cfg.lin_v_heads
     dk, dv = cfg.lin_k_dim, cfg.lin_v_dim
 
@@ -189,7 +239,7 @@ def init_hybrid_params(key, cfg: HybridConfig):
     for i, kind in enumerate(cfg.layer_kinds):
         k = jax.random.split(keys[2 + i], 16)
         p = {"ln1": gain(d), "ln2": gain(d)}
-        if cfg.n_experts:
+        if not cfg.dense_at(i):
             p.update({
                 "router": normal(k[0], (d, cfg.n_experts)),
                 "experts": {"gate": normal(k[1], (cfg.n_held, d, f)),
@@ -199,10 +249,12 @@ def init_hybrid_params(key, cfg: HybridConfig):
                            "up": normal(k[5], (cfg.n_shared, d, f)),
                            "down": normal(k[6], (cfg.n_shared, f, d))},
                 "shared_gate": normal(k[7], (d, cfg.n_shared))})
+            if cfg.router_bias:
+                p["expert_bias"] = normal(k[14], (cfg.n_experts,))
         else:
-            p.update({"W_gate": normal(k[1], (d, f)),
-                      "W_up": normal(k[2], (d, f)),
-                      "W_down": normal(k[3], (f, d))})
+            p.update({"W_gate": normal(k[1], (d, fd)),
+                      "W_up": normal(k[2], (d, fd)),
+                      "W_down": normal(k[3], (fd, d))})
         if kind == KIND_FULL:
             wide = cfg.qk_norm == "width"
             p.update({"Wq": normal(k[8], (d, (1 + cfg.attn_gate)
@@ -213,6 +265,10 @@ def init_hybrid_params(key, cfg: HybridConfig):
                       "q_norm": gain(cfg.n_heads * hd if wide else hd),
                       "k_norm": gain(cfg.n_kv_heads * hd if wide
                                      else hd)})
+        elif kind == KIND_CONV:
+            p.update({"W_in": normal(k[8], (d, 3 * d)),
+                      "conv": normal(k[10], (cfg.conv_kernel, d)),
+                      "W_out": normal(k[13], (d, d))})
         else:
             p.update({"W_qkvz": normal(k[8], (d, 2 * hk * dk
                                               + 2 * hv * dv)),
@@ -224,9 +280,12 @@ def init_hybrid_params(key, cfg: HybridConfig):
                       "norm": gain(dv),
                       "W_out": normal(k[13], (hv * dv, d))})
         blocks.append(p)
-    return {"embed": normal(keys[0], (cfg.vocab_size, d)),
-            "head": normal(keys[1], (d, cfg.vocab_size)),
-            "ln_f": gain(d), "blocks": blocks}
+    out = {"embed": normal(keys[0], (cfg.vocab_size, d)),
+           "head": normal(keys[1], (d, cfg.vocab_size)),
+           "ln_f": gain(d), "blocks": blocks}
+    if cfg.tied_head:
+        del out["head"]
+    return out
 
 
 def _rms_norm(p, x, eps: float):
@@ -260,6 +319,34 @@ def rope_half(x, positions, theta: float, rotary_dim: int):
             + turned.astype(jnp.float32) * jnp.sin(ang)).astype(x.dtype)
 
 
+# ------------------------------------------ the convolution over kept columns
+def causal_conv(u, conv_w, act=None, *, prev=None, true_len=None):
+    """The causal depthwise convolution of a layer held by slot: u (B,
+    T, C), `conv_w` (K, C), output column t = sum_j w_j u_{t - K + 1 +
+    j} in float32, then `act` and u's type (`act` None: no activation,
+    float32 out). `prev` (B, (K - 1) * C) the columns kept before this
+    call (zeros where None: the start of a sequence). `true_len` (B,):
+    rows are padded past it, and the columns kept are the last K - 1
+    REAL ones. Returns (output (B, T, C), kept (B, K - 1, C))."""
+    b, t, c = u.shape
+    kk = conv_w.shape[0] - 1
+    prev = (jnp.zeros((b, kk, c), u.dtype) if prev is None
+            else prev.reshape(b, kk, c))
+    ext = jnp.concatenate([prev, u], axis=1)              # (B, kk + T, C)
+    w = conv_w.astype(jnp.float32)
+    acc = sum(ext[:, j:j + t].astype(jnp.float32) * w[j]
+              for j in range(kk + 1))
+    out = acc if act is None else act(acc).astype(u.dtype)
+    if true_len is None:
+        kept = ext[:, t:]
+    else:
+        # u's rows true_len - kk .. true_len - 1 are ext's rows
+        # true_len .. true_len + kk - 1
+        rows = true_len[:, None] + jnp.arange(kk)[None, :]
+        kept = jnp.take_along_axis(ext, rows[:, :, None], axis=1)
+    return out, kept
+
+
 # ------------------------------------------------------ the linear mixer
 def linear_mix(cfg: HybridConfig, u, gates, conv_w, *, prev=None,
                state=None, true_len=None):
@@ -280,20 +367,9 @@ def linear_mix(cfg: HybridConfig, u, gates, conv_w, *, prev=None,
     hk, hv, dk, dv = (cfg.lin_k_heads, cfg.lin_v_heads, cfg.lin_k_dim,
                       cfg.lin_v_dim)
     with jax.named_scope("gdn_conv"):
-        prev = (jnp.zeros((b, kk, c), u.dtype) if prev is None
-                else prev.reshape(b, kk, c))
-        ext = jnp.concatenate([prev, u], axis=1)          # (B, kk + T, C)
-        w = conv_w.astype(jnp.float32)
-        acc = sum(ext[:, j:j + t].astype(jnp.float32) * w[j]
-                  for j in range(kk + 1))
-        mixed = jax.nn.silu(acc).astype(u.dtype)
-        if true_len is None:
-            kept = ext[:, t:]
-        else:
-            # u's rows true_len - kk .. true_len - 1 are ext's rows
-            # true_len .. true_len + kk - 1
-            rows = true_len[:, None] + jnp.arange(kk)[None, :]
-            kept = jnp.take_along_axis(ext, rows[:, :, None], axis=1)
+        mixed, kept = causal_conv(u, conv_w, jax.nn.silu, prev=prev,
+                                  true_len=true_len)
+        if true_len is not None:
             real = jnp.arange(t)[None, :, None] < true_len[:, None, None]
             g, beta = jnp.where(real, g, 0.0), jnp.where(real, beta, 0.0)
         q = mixed[..., :hk * dk].reshape(b, t, hk, dk)
@@ -334,6 +410,42 @@ def linear_mix(cfg: HybridConfig, u, gates, conv_w, *, prev=None,
                                 interpret=cfg.interpret)
             o = jnp.moveaxis(o[:, :, :t], 1, 2)
     return o, {"state": state, "conv": kept.reshape(b, kk * c)}
+
+
+def conv_mix(u, conv_w, *, prev=None, true_len=None):
+    """A conv layer between its projections: the convolution of u (B,
+    T, d) over the columns its slot kept (`prev`, zeros where None),
+    with no activation. Returns (z (B, T, d) float32, {"conv": the last
+    `conv_kernel - 1` REAL columns of u})."""
+    b = u.shape[0]
+    z, kept = causal_conv(u, conv_w, prev=prev, true_len=true_len)
+    return z, {"conv": kept.reshape(b, -1)}
+
+
+def slot_mix(cfg: HybridConfig, kind: str, a, b, c, *, prev=None,
+             state=None, true_len=None):
+    """A layer of a kind held by slot between its projections, called
+    with what the `attend` callback got (`linear_mix`'s columns, gates
+    and weights; `conv_mix`'s columns, None and weights) and with what
+    the cache holds: the kept columns `prev`, a linear layer's `state`,
+    the rows' real lengths. Returns (the mixer's rows, the new entry)."""
+    if kind == KIND_LINEAR:
+        return linear_mix(cfg, a, b, c, prev=prev, state=state,
+                          true_len=true_len)
+    return conv_mix(a, c, prev=prev, true_len=true_len)
+
+
+def _conv_layer(p, h, cfg: HybridConfig, attend: Attend, layer: int):
+    """The gated short convolution: `[b | c | x] = h W_in`, `u = b *
+    x`, the convolution z of u, `(c * z) W_out`."""
+    d = cfg.d_model
+    with jax.named_scope("short_conv"):
+        proj = h @ p["W_in"]
+        u = (proj[..., :d].astype(jnp.float32)
+             * proj[..., 2 * d:].astype(jnp.float32)).astype(h.dtype)
+        z, entry = attend(layer, KIND_CONV, u, None, p["conv"])
+        y = proj[..., d:2 * d].astype(jnp.float32) * z
+        return y.astype(h.dtype) @ p["W_out"], entry
 
 
 def _linear_layer(p, h, cfg: HybridConfig, attend: Attend, layer: int):
@@ -406,21 +518,27 @@ def block(p, x, positions, layer: int, cfg: HybridConfig, attend: Attend,
     b, t, d = x.shape
     pre = cfg.norm_place == "pre"
     h = _rms_norm(p["ln1"], x, cfg.rms_eps) if pre else x
-    if cfg.layer_kinds[layer] == KIND_LINEAR:
+    kind = cfg.layer_kinds[layer]
+    if kind == KIND_LINEAR:
         mix, entry = _linear_layer(p, h, cfg, attend, layer)
+    elif kind == KIND_CONV:
+        mix, entry = _conv_layer(p, h, cfg, attend, layer)
     else:
         mix, entry = _full_layer(p, h, positions, cfg, attend, layer)
     if not pre:
         mix = _rms_norm(p["ln1"], mix, cfg.rms_eps)
     x = x + mix.astype(x.dtype)
     h = _rms_norm(p["ln2"], x, cfg.rms_eps) if pre else x
-    if cfg.n_experts:
+    if not cfg.dense_at(layer):
         ff, pairs = expert_layer(
             p, h.reshape(b * t, d), cfg,
             None if valid is None else valid.reshape(b * t))
         ff = ff.reshape(b, t, d)
     else:
-        ff, pairs = _dense_ff(p, h), None
+        # a dense layer of a model with experts counts no pairs
+        ff = _dense_ff(p, h)
+        pairs = jnp.zeros((cfg.n_held,), jnp.int32) if cfg.n_experts \
+            else None
     if not pre:
         ff = _rms_norm(p["ln2"], ff, cfg.rms_eps)
     x = (x.astype(jnp.float32) + ff).astype(x.dtype)
@@ -444,17 +562,18 @@ def forward(params, tokens, positions, cfg: HybridConfig, attend: Attend,
 
 def head(params, x, cfg: HybridConfig):
     """x (..., d) -> logits over the vocabulary rows held, f32; the
-    head is its own matrix."""
-    return jnp.dot(_rms_norm(params["ln_f"], x, cfg.rms_eps),
-                   params["head"], preferred_element_type=jnp.float32)
+    head is its own matrix, or the embedding's transpose."""
+    w = params["embed"].T if cfg.tied_head else params["head"]
+    return jnp.dot(_rms_norm(params["ln_f"], x, cfg.rms_eps), w,
+                   preferred_element_type=jnp.float32)
 
 
 def uncached(cfg: HybridConfig) -> Attend:
     """The callback of a forward that keeps nothing: causal attention
     over the rows as they come, the whole scan from a zero state."""
     def attend(_layer, kind, a, b, c):
-        if kind == KIND_LINEAR:
-            o, _ = linear_mix(cfg, a, b, c)
+        if kind in (KIND_LINEAR, KIND_CONV):
+            o, _ = slot_mix(cfg, kind, a, b, c)
             return o, None
         return causal_attention(cfg, kind, a, b, c), None
     return attend

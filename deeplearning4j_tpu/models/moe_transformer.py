@@ -90,6 +90,8 @@ class MoEConfig(NamedTuple):
     #: "sigmoid_gate": each behind `sigmoid(h w_s)`, `p["shared_gate"]`)
     router_score: str = "sigmoid"
     shared_combine: str = "average"
+    #: no selection bias: the k largest scores are chosen
+    router_bias: bool = False
 
     @property
     def n_layers(self) -> int:
@@ -233,7 +235,12 @@ def expert_layer(p, h, cfg, valid=None):
     the configuration says. The one expert layer of every model that
     has one: `cfg` is any configuration with the counts (`n_experts`,
     `experts_per_token`, `n_held`, `held_first`, `n_shared`) and the
-    two choices `router_score` and `shared_combine`.
+    three choices `router_score`, `shared_combine` and `router_bias`.
+    With `router_bias` the k chosen are the largest of score +
+    `p["expert_bias"]` (n_experts,), and their weights their scores
+    alone over the scores' sum + 1e-6 (the published `lfm2_moe`
+    router); without it the k largest scores over their sum. With no
+    shared expert (`n_shared` 0) the routed sum is the whole output.
 
     h: (T, d) normed activations; `valid` (T,) bool marks the tokens
     that are real (padding rows and idle slots route nowhere and count
@@ -247,8 +254,14 @@ def expert_layer(p, h, cfg, valid=None):
         scores = ROUTER_SCORES[cfg.router_score](jnp.dot(
             h.astype(jnp.float32), p["router"].astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST))          # (T, E) f32
-        top, chosen = jax.lax.top_k(scores, k)
-        weight = top / jnp.sum(top, axis=-1, keepdims=True)
+        if cfg.router_bias:
+            _, chosen = jax.lax.top_k(
+                scores + p["expert_bias"].astype(jnp.float32), k)
+            top = jnp.take_along_axis(scores, chosen, axis=-1)
+            weight = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-6)
+        else:
+            top, chosen = jax.lax.top_k(scores, k)
+            weight = top / jnp.sum(top, axis=-1, keepdims=True)
         local = chosen - cfg.held_first
         here = (local >= 0) & (local < held) & valid[:, None]
         # group `held` = "not here": sorts behind every held expert
@@ -298,6 +311,8 @@ def expert_layer(p, h, cfg, valid=None):
                 0, -(-n_pairs // chunk),
                 lambda c, out: out + take(c * chunk),
                 jnp.zeros((t, d), jnp.float32))
+    if not cfg.n_shared:
+        return routed, counts
     with jax.named_scope("moe_shared"):
         if cfg.shared_combine not in SHARED_COMBINES:
             raise ValueError(f"shared_combine must be one of "

@@ -37,6 +37,12 @@ KINDS = (KIND_FULL, KIND_WINDOW)
 #: columns a SEQUENCE, whatever its length (models/hybrid_transformer.py).
 #: Not among `KINDS`, which are the kinds of PAGE a cache hands out
 KIND_LINEAR = "linear"
+#: a layer that keeps only the last few columns of a gated short
+#: convolution a SEQUENCE (models/hybrid_transformer.py)
+KIND_CONV = "conv"
+#: the kinds a cache holds by SLOT, no pages: each names its own arrays
+#: (`cfg.slot_state[kind]`)
+SLOT_KINDS = (KIND_LINEAR, KIND_CONV)
 
 #: `attend(layer, kind, q, k, v) -> (att, the cache's new state for this
 #: layer)`: the one thing a block leaves to its caller. q is (B, Hq, T,
@@ -49,7 +55,9 @@ KIND_LINEAR = "linear"
 #: their callbacks. A `KIND_LINEAR` layer has no K/V rows: it hands the
 #: callback its pre-convolution columns (B, T, C), its two gates and the
 #: convolution's weights in the three places, and gets its mixer's rows
-#: (B, T, Hv, dv) and the layer's new cache entry back.
+#: (B, T, Hv, dv) and the layer's new cache entry back; a `KIND_CONV`
+#: layer hands it the columns (B, T, d), None and the convolution's
+#: weights, and gets the convolved columns (B, T, d) and its entry back.
 Attend = Callable[[int, str, Any, Any, Any], Tuple[Any, Any]]
 
 
@@ -327,7 +335,7 @@ def generate(params, prompt, cfg: TransformerConfig, n_tokens: int,
 
 
 __all__ = ["TransformerConfig", "KINDS", "KIND_FULL", "KIND_WINDOW",
-           "KIND_LINEAR",
+           "KIND_LINEAR", "KIND_CONV", "SLOT_KINDS",
            "init_transformer_params", "causal_attention", "visible",
            "block", "forward", "head", "transformer_logits", "lm_loss",
            "make_train_step", "init_velocity", "fit_scan", "generate"]

@@ -251,15 +251,17 @@ the tokens make anyway. What the host's side cannot do yet for what a
 model has is an error at construction that names it (`_check_refusals`):
 prefix sharing, speculation, a horizon above 1 and the prefill role, for
 a window kind (it gives pages back), an expert layer's counts, or a
-linear kind.
+kind held by slot.
 
-**A kind that is not pages.** A `linear` layer
-(`models/hybrid_transformer.py`) keeps a recurrent state and a few
-convolution columns a sequence, however long: arrays indexed by SLOT in
-the same donated pool, `(slots, ...)` a layer. It needs no grant, no
-table and no free list: admission is bounded by slots, and by the full
-kind's pages as ever. A cold prefill is told each row's slot beside its
-pages (`page_ids["linear"]`) and overwrites that slot's state whole, so
+**Kinds that are not pages** (`paged_kinds.SLOT_KINDS`). A `linear`
+layer (`models/hybrid_transformer.py`) keeps a recurrent state and a few
+convolution columns a sequence, however long, a `conv` layer only the
+columns of its short convolution: arrays indexed by SLOT in the same
+donated pool, `(slots, ...)` a layer, each kind its own
+(`cfg.slot_state`). They need no grant, no table and no free list:
+admission is bounded by slots, and by the full kind's pages as ever. A
+cold prefill is told each row's slot beside its pages
+(`page_ids[kind]`) and overwrites that slot's entry whole, so
 a retired slot's state never reaches the next request; an idle slot's
 update is masked in the step. The slot keeps ONE state, the newest: a
 later piece of a prompt that is prefilled in pieces starts from it
@@ -269,7 +271,7 @@ written): prefix sharing (and with it copy-on-write and `/kv/export`),
 speculation, a horizon above 1 and the prefill role are refused by
 name. Preemption retires the slot and the router re-admits prompt +
 delivered: an honest second scan. `snapshot()["state"]` says what the
-kind holds.
+kinds hold together, `snapshot()["state_by_kind"]` each kind's share.
 
 **A bound on the tokens a pass prefills** (`prefill_tokens_per_pass`,
 None = no bound): a pass stops claiming queued requests once the rows it
@@ -425,10 +427,10 @@ _TIER_ITEM_MS = {TIER_INTERACTIVE: 50.0, TIER_BATCH: 250.0}
 #: what `DecodeLoop._check_refusals` says, by the kind of layer that
 #: stands in the way and by what was asked. A window kind gives its
 #: pages back as the cursor moves (and an expert layer's counts are read
-#: back from the plain step and the cold prefill only); a linear kind's
-#: state is no page: a slot keeps the newest state alone (enough to go
-#: on from, as a prompt prefilled in pieces does), so whatever reuses a
-#: page would need the state at that page's end, and snapshots of state
+#: back from the plain step and the cold prefill only); the state of a
+#: kind held by slot is no page: a slot keeps the newest alone (enough to
+#: go on from, as a prompt prefilled in pieces does), so whatever reuses
+#: a page would need the state at that page's end, and snapshots of state
 #: at a page boundary are not written
 _REFUSALS = {
     paged_kinds.KIND_WINDOW: {
@@ -448,28 +450,28 @@ _REFUSALS = {
         "role":
             "a prefill-role loop ships pages over /kv/export, which "
             "is not written for a model with window layers"},
-    paged_kinds.KIND_LINEAR: {
+    **{kind: {
         "prefix_cache":
-            "prefix sharing (and with it copy-on-write forks and "
-            "/kv/export) is not written for a model with linear "
-            "layers (a shared prefix's pages say nothing of the "
-            "recurrent state at its end, and a slot keeps only its "
-            "newest state, no snapshot at a page boundary): pass "
-            "prefix_cache=False",
+            f"prefix sharing (and with it copy-on-write forks and "
+            f"/kv/export) is not written for a model with {kind} "
+            f"layers (a shared prefix's pages say nothing of the "
+            f"{what} at its end, and a slot keeps only its newest, no "
+            f"snapshot at a page boundary): pass prefix_cache=False",
         "speculation":
-            "speculation is not written for a model with linear "
-            "layers (a rejected draft has already moved the "
-            "slot's state, and there is no snapshot to go back "
-            "to): pass speculation=0",
+            f"speculation is not written for a model with {kind} "
+            f"layers (a rejected draft has already moved the slot's "
+            f"{what}, and there is no snapshot to go back to): pass "
+            f"speculation=0",
         "horizon":
-            "horizon > 1 is not written for a model with linear "
-            "layers (the chained step has not been checked "
-            "against a state that is updated in place): pass "
-            "horizon=1",
+            f"horizon > 1 is not written for a model with {kind} "
+            f"layers (the chained step has not been checked against "
+            f"{what} updated in place): pass horizon=1",
         "role":
-            "a prefill-role loop ships pages over /kv/export, "
-            "which is not written for a model with linear layers "
-            "(a page list says nothing of the state)"},
+            f"a prefill-role loop ships pages over /kv/export, which "
+            f"is not written for a model with {kind} layers (a page "
+            f"list says nothing of the {what})"}
+       for kind, what in ((paged_kinds.KIND_LINEAR, "recurrent state"),
+                          (paged_kinds.KIND_CONV, "kept columns"))},
 }
 
 
@@ -899,10 +901,9 @@ class DecodeLoop:
             pages[paged_kinds.KIND_WINDOW] = self._win.n_pages
         self._kind_pages = pages
         self._kind_layers = paged_kinds.layers_of(cfg)
-        #: layers of the `linear` kind: a state a SLOT, no pages (0:
-        #: every layer keeps keys)
-        self._linear_layers = cfg.layer_kinds.count(
-            paged_kinds.KIND_LINEAR)
+        #: layers of each kind held by SLOT, no pages (empty: every
+        #: layer keeps keys)
+        self._slot_layers = paged_kinds.slot_kinds(cfg)
         #: what a page of a kind counts for in `pages_total` and its
         #: like: the kind's layers where kinds have to be summed (a page
         #: of a kind spans every layer of that kind), 1 where there is
@@ -1344,14 +1345,15 @@ class DecodeLoop:
         error here, by name, never a silent wrong answer: a window kind
         gives its pages back as the cursor moves, what an expert layer
         counts is read back from the plain step and the cold prefill
-        only, and a linear kind's state is no page (`_REFUSALS` has the
-        words)."""
+        only, and the state of a kind held by slot is no page
+        (`_REFUSALS` has the words)."""
         if paged_kinds.KIND_FULL not in cfg.layer_kinds:
             raise ValueError(
                 "layer_kinds needs a full layer: a request's token "
                 "budget rides the full kind's page table")
-        if paged_kinds.KIND_LINEAR in cfg.layer_kinds:
-            why = _REFUSALS[paged_kinds.KIND_LINEAR]
+        held = paged_kinds.slot_kinds(cfg)
+        if held:
+            why = _REFUSALS[next(iter(held))]
         elif paged_kinds.KIND_WINDOW in cfg.layer_kinds or cfg.n_held:
             why = _REFUSALS[paged_kinds.KIND_WINDOW]
         else:
@@ -1401,14 +1403,15 @@ class DecodeLoop:
                 "dl4j_kv_window_pages_released",
                 "window-layer KV pages returned to their free list "
                 "because their last key left the window").labels(**lab)
-        if self._linear_layers:
-            reg.gauge(
+        if self._slot_layers:
+            state = reg.gauge(
                 "dl4j_state_bytes",
                 "bytes of per-slot state the cache holds for layers "
                 "that keep no pages, by kind of layer (a linear layer's "
-                "recurrent state and kept convolution columns, every "
-                "slot)").labels(kind=paged_kinds.KIND_LINEAR,
-                                **lab).set(self.state_bytes())
+                "recurrent state and kept convolution columns, a conv "
+                "layer's kept columns, every slot)")
+            for kind in self._slot_layers:
+                state.labels(kind=kind, **lab).set(self.state_bytes(kind))
             reg.gauge(
                 "dl4j_state_slots_live",
                 "slots whose per-slot state belongs to an in-flight "
@@ -1770,11 +1773,14 @@ class DecodeLoop:
         return paged_kinds.pool_bytes(self.cfg, self._kind_pages,
                                       self.page_size)
 
-    def state_bytes(self) -> int:
-        """HBM the `linear` kind's per-slot state pins: every slot of
-        every such layer. 0 for a model whose layers all keep pages."""
-        return (self.slots * self._linear_layers
-                * paged_kinds.state_bytes_per_slot(self.cfg))
+    def state_bytes(self, kind: Optional[str] = None) -> int:
+        """HBM the per-slot state of `kind` pins (None: of every kind
+        held by slot): every slot of every such layer. 0 for a model
+        whose layers all keep pages."""
+        return sum(self.slots * n
+                   * paged_kinds.state_bytes_per_slot(self.cfg, k)
+                   for k, n in self._slot_layers.items()
+                   if kind in (None, k))
 
     def decode_step_programs(self) -> int:
         """Compiled-program count for the decode lane — the
@@ -1856,8 +1862,8 @@ class DecodeLoop:
             """A cold prefill's `page_ids`: pages by kind, and the
             rows' slots where a kind keeps its state by slot."""
             ids = by_kind(bb, columns)
-            if self._linear_layers:
-                ids[paged_kinds.KIND_LINEAR] = ints(bb)
+            for kind in self._slot_layers:
+                ids[kind] = ints(bb)
             return ids
 
         n = 0
@@ -2312,8 +2318,9 @@ class DecodeLoop:
         """`pages_total`, `pages_in_use` and `peak_pages_in_use`: pages
         of the one kind, or, where the model's layers are of several,
         sums over kinds weighted by the kind's layers (`_kind_weight`);
-        each kind's own pages under `pages_by_kind`, what the `linear`
-        kind holds by slot under `state`, and the pairs of a model with
+        each kind's own pages under `pages_by_kind`, what the kinds
+        held by slot hold under `state` (summed) and `state_by_kind`,
+        and the pairs of a model with
         an expert layer under `moe`. Caller holds the lock."""
         by_kind = {}
         for kind, n in self._kind_pages.items():
@@ -2333,13 +2340,19 @@ class DecodeLoop:
             "pages_in_use": self._weighted_in_use(),
             "peak_pages_in_use": self._peak_pages,
             "pages_by_kind": by_kind}
-        if self._linear_layers:
-            per_slot = paged_kinds.state_bytes_per_slot(self.cfg)
+        if self._slot_layers:
+            by = {kind: {"bytes": self.state_bytes(kind),
+                         "bytes_per_slot": n * paged_kinds.
+                         state_bytes_per_slot(self.cfg, kind),
+                         "layers": n}
+                  for kind, n in self._slot_layers.items()}
             pages["state"] = {
                 "bytes": self.state_bytes(),
-                "bytes_per_slot": self._linear_layers * per_slot,
-                "layers": self._linear_layers,
+                "bytes_per_slot": sum(v["bytes_per_slot"]
+                                      for v in by.values()),
+                "layers": sum(self._slot_layers.values()),
                 "slots_live": self.occupied_slots}
+            pages["state_by_kind"] = by
         moe = self._moe
         if moe is not None:
             pages["moe"] = {
@@ -2834,13 +2847,14 @@ class DecodeLoop:
             clen[row] = cov
             self._prefill_token_count += tl
         d_pids = {paged_kinds.KIND_FULL: jnp.asarray(pids)}
-        if self._linear_layers and slots is not None:
+        if self._slot_layers and slots is not None:
             # where each row's state is (a piece) and goes: its slot; a
             # padding row names a slot past the last, and its write is
             # dropped
             at = np.full((bb,), self.slots, np.int32)
             at[:len(slots)] = slots
-            d_pids[paged_kinds.KIND_LINEAR] = jnp.asarray(at)
+            for kind in self._slot_layers:
+                d_pids[kind] = jnp.asarray(at)
         if piece:
             self._plan_prefill_chunk.add((bb, cb, tb))
             first, self._pool = self._prefill_chunk(

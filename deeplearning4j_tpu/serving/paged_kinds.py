@@ -33,23 +33,26 @@ layers are all full (`models/transformer.py`) is the case of one kind:
   Each returns, after the logits and the pool, what the model's layers
   count (`aux`: the pairs by held expert, or `()`).
 
-A third kind keeps no pages at all. A `linear` layer
+Two more kinds keep no pages at all (`SLOT_KINDS`). A `linear` layer
 (`models/hybrid_transformer.py`) holds a recurrent state and the last
-few pre-convolution columns a SEQUENCE, however long: its arrays are
+few pre-convolution columns a SEQUENCE, however long, a `conv` layer
+only the last few columns of its short convolution: their arrays are
 indexed by SLOT, `(slots, ...)`, donated and updated in place like the
-pools. The block hands its callback the columns and the gates and gets
-the mixer's rows back; the lanes call the model's one `linear_mix` with
-what the cache holds: `prefill` a row's real length (the state at the
-row's last REAL token goes to `page_ids["linear"][row]`, the row's
-slot; a padding row names a slot past the last and is dropped),
+pools. The block hands its callback the columns (and a linear layer's
+gates) and gets the mixer's rows back; the lanes call the model's one
+`slot_mix` with what the cache holds: `prefill` a row's real length
+(what the row leaves after its last REAL token goes to
+`page_ids[kind][row]`, the row's slot; a padding row names a slot past
+the last and is dropped),
 `prefill_ctx` the slot's kept columns and state AND the row's real
 length (a later piece of a prompt prefilled in pieces: read from the
 row's slot, scanned on, written back), `decode_step` the slot's kept
 columns and state (an inactive slot's update is masked: g = beta = 0
-leave its state bit for bit). It needs no table, no grant and no trash
-row. `verify_step` is not written for it, nor is anything that would
-start from the state at a position the slot has left behind
-(`DecodeLoop._check_refusals` keeps them away).
+leave its state bit for bit, and its kept columns are kept as they
+were). They need no table, no grant and no trash row. `verify_step` is
+not written for them, nor is anything that would start from the state
+at a position the slot has left behind (`DecodeLoop._check_refusals`
+keeps them away).
 
 Shapes are fixed for the life of a server: a step is ONE program over S
 slots (tables, lengths and the active mask are traced arrays: requests
@@ -74,24 +77,26 @@ from deeplearning4j_tpu.attention.blockwise import masked_attention
 from deeplearning4j_tpu.attention.flash_pallas import flash_attention_ctx
 from deeplearning4j_tpu.attention.paged_pallas import paged_attention
 from deeplearning4j_tpu.models import model_of
-from deeplearning4j_tpu.models.transformer import (KIND_FULL, KIND_LINEAR,
-                                                   KIND_WINDOW,
+from deeplearning4j_tpu.models.transformer import (KIND_CONV, KIND_FULL,
+                                                   KIND_LINEAR,
+                                                   KIND_WINDOW, SLOT_KINDS,
                                                    causal_attention,
                                                    visible)
 from deeplearning4j_tpu.serving.paged_kv import (PagedKVPool,  # noqa: F401
                                                  init_pool, page_bytes,
-                                                 pool_bytes,
+                                                 pool_bytes, slot_kinds,
                                                  state_bytes_per_slot)
 
-__all__ = ["KIND_FULL", "KIND_WINDOW", "KIND_LINEAR", "kinds_of",
+__all__ = ["KIND_FULL", "KIND_WINDOW", "KIND_LINEAR", "KIND_CONV",
+           "SLOT_KINDS", "kinds_of", "slot_kinds",
            "layers_of", "window_table_pages", "first_visible", "init_pool",
            "pool_bytes", "page_bytes", "state_bytes_per_slot", "prefill",
            "prefill_ctx", "decode_step", "verify_step"]
 
 
 def kinds_of(cfg):
-    """The kinds of PAGE this model has, full first (the `linear` kind
-    keeps no pages and is not among them)."""
+    """The kinds of PAGE this model has, full first (the kinds held by
+    slot keep no pages and are not among them)."""
     return tuple(k for k in (KIND_FULL, KIND_WINDOW)
                  if k in cfg.layer_kinds)
 
@@ -157,8 +162,8 @@ def _write_rows(arr, dest, offset, rows):
 
 
 def _write_slots(held, slots, entry):
-    """A prefill's write for the linear kind: row r of each array of
-    `entry` (state, kept columns) to slot `slots[r]`; a padding row
+    """A prefill's write for a kind held by slot: row r of each array
+    of `entry` (state, kept columns) to slot `slots[r]`; a padding row
     names a slot past the last and is dropped."""
     return {name: held[name].at[slots].set(rows.astype(held[name].dtype),
                                            mode="drop")
@@ -209,11 +214,11 @@ def _check_kernel(kernel: str) -> None:
 
 
 def _no_linear(kind: str, what: str) -> None:
-    if kind == KIND_LINEAR:
+    if kind in SLOT_KINDS:
         raise NotImplementedError(
-            f"{what} is not written for a layer of the linear kind: it "
-            f"would need the recurrent state at a position the slot "
-            f"has left behind, and the cache keeps only the newest "
+            f"{what} is not written for a layer of the {kind} kind: it "
+            f"would need the state or kept columns at a position the "
+            f"slot has left behind, and the cache keeps only the newest "
             f"(snapshots of state at a page boundary are not written)")
 
 
@@ -247,8 +252,8 @@ def prefill(params, tokens, true_len, pool: PagedKVPool,
 
     def attend(layer, kind, q, k, v):
         held = pool.layers[layer]
-        if kind == KIND_LINEAR:
-            o, entry = model.linear_mix(cfg, q, k, v, true_len=true_len)
+        if kind in SLOT_KINDS:
+            o, entry = model.slot_mix(cfg, kind, q, k, v, true_len=true_len)
             return o, _write_slots(held, flat[kind], entry)
         att = causal_attention(cfg, kind, q, k, v)
         return att, _write_pages(held, flat[kind], k, v)
@@ -296,9 +301,10 @@ def prefill_ctx(params, tokens, true_len, pool: PagedKVPool,
     context's length (`cfg.interpret` runs it on the CPU). A window
     layer takes the dense read in either lane.
 
-    A linear layer reads the kept columns and the state of the row's
-    slot (`page_ids["linear"]`), scans the tail on top of them and
-    writes both back as they stand after the row's last REAL token.
+    A layer held by slot reads the kept columns (and a linear layer the
+    state) of the row's slot (`page_ids[kind]`), runs the tail on top
+    of them and writes them back as they stand after the row's last
+    REAL token.
     Returns what `prefill` returns."""
     _check_kernel(kernel)
     model = model_of(cfg)
@@ -310,11 +316,12 @@ def prefill_ctx(params, tokens, true_len, pool: PagedKVPool,
 
     def attend(layer, kind, q, k, v):
         held = pool.layers[layer]
-        if kind == KIND_LINEAR:
+        if kind in SLOT_KINDS:
             at = flat[kind]
-            o, entry = model.linear_mix(
-                cfg, q, k, v, prev=held["conv"][at],
-                state=held["state"][at], true_len=true_len)
+            o, entry = model.slot_mix(
+                cfg, kind, q, k, v, prev=held["conv"][at],
+                state=held["state"][at] if kind == KIND_LINEAR else None,
+                true_len=true_len)
             return o, _write_slots(held, at, entry)
         if kernel == "pallas" and kind == KIND_FULL:
             held = _write_pages(held, flat[kind], k, v)
@@ -373,11 +380,12 @@ def decode_step(params, tokens, pool: PagedKVPool,
 
     def attend(layer, kind, q, k, v):
         held = pool.layers[layer]
-        if kind == KIND_LINEAR:
-            live = active[:, None, None]
-            o, entry = model.linear_mix(
-                cfg, q, tuple(jnp.where(live, gate, 0.0) for gate in k), v,
-                prev=held["conv"], state=held["state"])
+        if kind in SLOT_KINDS:
+            if kind == KIND_LINEAR:
+                live = active[:, None, None]
+                k = tuple(jnp.where(live, gate, 0.0) for gate in k)
+            o, entry = model.slot_mix(cfg, kind, q, k, v, prev=held["conv"],
+                                      state=held.get("state"))
             entry["conv"] = jnp.where(active[:, None], entry["conv"],
                                       held["conv"])
             return o, entry

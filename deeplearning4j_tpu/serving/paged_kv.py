@@ -35,10 +35,10 @@ from typing import Any, Dict, NamedTuple, Tuple
 
 import jax.numpy as jnp
 
-from deeplearning4j_tpu.models.transformer import KIND_LINEAR
+from deeplearning4j_tpu.models.transformer import KIND_LINEAR, SLOT_KINDS
 
 __all__ = ["PagedKVPool", "init_pool", "page_bytes", "pool_bytes",
-           "state_bytes_per_slot",
+           "slot_kinds", "state_bytes_per_slot",
            "init_paged_pool", "paged_kv_bytes",
            "pages_per_slot", "pages_for_tokens", "prompt_buckets",
            "copy_page", "extract_page", "install_page"]
@@ -48,10 +48,11 @@ class PagedKVPool(NamedTuple):
     """What the cache holds a layer. `layers`: tuple (one per block). A
     layer that keeps keys holds {"k", "v"} arrays of shape (n_pages + 1,
     n_kv_heads, page_size, head_dim), n_pages the layer's kind's; the
-    last page is the trash page for masked writes. A layer of the
-    `linear` kind holds no pages: its arrays are indexed by SLOT,
-    `cfg.linear_state` says which (`{"state": (slots, Hv, dk, dv)
-    float32, "conv": (slots, columns kept)}`). `page_size`, `n_pages`
+    last page is the trash page for masked writes. A layer of a kind
+    held by slot (`linear`, `conv`) holds no pages: its arrays are
+    indexed by SLOT, `cfg.slot_state[kind]` says which (linear:
+    `{"state": (slots, Hv, dk, dv) float32, "conv": (slots, columns
+    kept)}`; conv: `{"conv": (slots, columns kept)}`). `page_size`, `n_pages`
     and `trash_page` are those of the first layer that has pages: the
     pool's, where there is one kind of page."""
 
@@ -98,19 +99,26 @@ def prompt_buckets(cfg, page_size: int) -> Tuple[int, ...]:
     return tuple(buckets)
 
 
+def slot_kinds(cfg) -> Dict[str, int]:
+    """The kinds this model holds by SLOT, no pages, and the layers of
+    each (empty for a model whose layers all keep keys)."""
+    return {k: cfg.layer_kinds.count(k) for k in SLOT_KINDS
+            if k in cfg.layer_kinds}
+
+
 def init_pool(cfg, pages: Dict[str, int], page_size: int,
               slots: int = 0) -> PagedKVPool:
     """Allocate the block pools: `pages[kind]` usable pages and the
-    trash page for every layer of that kind, and for every layer of the
-    `linear` kind (which has no entry in `pages`) its state, a row a
-    slot of `slots`. Pool HBM is fixed at construction — per-request
+    trash page for every layer of that kind, and for every layer of a
+    kind held by slot (which has no entry in `pages`) its arrays, a row
+    a slot of `slots`. Pool HBM is fixed at construction — per-request
     cost is page-table bookkeeping, not allocation."""
     layers = []
     for kind in cfg.layer_kinds:
         if kind not in pages:
             layers.append({name: jnp.zeros((int(slots),) + shape, dtype)
                            for name, (shape, dtype)
-                           in cfg.linear_state.items()})
+                           in cfg.slot_state[kind].items()})
             continue
         shape = (int(pages[kind]) + 1, cfg.n_kv_heads, page_size,
                  cfg.head_dim)
@@ -119,11 +127,11 @@ def init_pool(cfg, pages: Dict[str, int], page_size: int,
     return PagedKVPool(tuple(layers))
 
 
-def state_bytes_per_slot(cfg) -> int:
-    """What ONE slot holds in ONE layer of the `linear` kind: its
-    recurrent state and the convolution's kept columns. 0 for a model
-    that has no such layer."""
-    entry = getattr(cfg, "linear_state", None)
+def state_bytes_per_slot(cfg, kind: str = KIND_LINEAR) -> int:
+    """What ONE slot holds in ONE layer of `kind`, a kind held by slot:
+    a linear layer's recurrent state and kept columns, a conv layer's
+    kept columns. 0 for a model that has no such layer."""
+    entry = getattr(cfg, "slot_state", {}).get(kind)
     if not entry:
         return 0
     return sum(math.prod(shape) * jnp.dtype(dtype).itemsize
@@ -150,8 +158,8 @@ def _same_for_every_kind(cfg, n_pages: int, page_size: int):
         raise ValueError(f"n_pages must be >= 1, got {n_pages}")
     if page_size < 1:
         raise ValueError(f"page_size must be >= 1, got {page_size}")
-    return dict.fromkeys((k for k in cfg.layer_kinds if k != KIND_LINEAR),
-                         n_pages)
+    return dict.fromkeys((k for k in cfg.layer_kinds
+                          if k not in SLOT_KINDS), n_pages)
 
 
 def init_paged_pool(cfg, n_pages: int, page_size: int) -> PagedKVPool:

@@ -257,6 +257,28 @@ class TestBlocksOfPages:
         tol = 1e-5 if dtype == jnp.float32 else 2e-2
         assert np.abs(np.asarray(today, np.float64) - want).max() < tol
 
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_narrow_heads_in_pages_of_128_read_transposed(self, dtype):
+        """Heads of 64 in pages of 128 (lfm2's, groups of 4): the
+        kernel reads each page as (H, hd, page_size), the pool's layout
+        on the TPU, and the swapped contractions of scores and values
+        still give the dense sums; a cursor at 0, inside, at and past a
+        page's edge, at the table's end, a slot of trash and a shared
+        prefix."""
+        q, k, v, table, lengths = _block_case(
+            128, 3, [0, 200, 127, 128, 383, 384, 5, 300], hq=8, hkv=2,
+            hd=64, dtype=dtype)
+        args = (q, k, v, jnp.asarray(table), jnp.asarray(lengths))
+        text = str(jax.make_jaxpr(
+            lambda *a: paged_attention(*a, interpret=True))(*args))
+        assert "permutation=(0, 1, 3, 2)" in text
+        got = paged_attention(*args, interpret=True)
+        want = _dense(q, k, v, table, lengths, np.zeros(8, np.int32), 128)
+        # f32: the sums in another order; bf16: P rounded to bf16 for
+        # the value product, as on every lane
+        tol = 1e-5 if dtype == jnp.float32 else 2e-2
+        assert np.abs(np.asarray(got, np.float64) - want).max() < tol
+
     def test_loop_reports_the_block_it_was_built_with(self):
         """`snapshot()["paged_block_pages"]` and the gauge: the rule's
         answer for the pool the loop built, 0 on the gather lane."""

@@ -593,3 +593,70 @@ def test_decode_step_expert_layer_takes_the_kernels_too(one_chip,
     assert len(_calls(text, "moe_rows_in")) == 1
     assert len(_calls(text, "moe_rows_out")) == 1
     assert not re.findall(r"^.* sort\(.*moe_experts.*$", text, re.M)
+
+
+# ------------------------ heads narrower than a lane tile (lfm2_moe's)
+NARROW_SLOTS, NARROW_PAGES = 64, 2560
+
+
+def test_narrow_head_decode_step_holds_no_pool_shaped_copy(
+        one_chip, no_compile_cache):
+    """lfm2's step: 32 query heads over 8 K/V heads of 64 in pages of
+    128 (a conv layer beside, dense, to keep the compile short). The
+    TPU lays such a pool out with its positions minor, so a kernel that
+    took it row-major copied the K and V pools of every attention layer
+    at every step (1.34 GB a step at the cell's 2,560 pages); the kernel
+    reads it as it lies, and the step holds no copy of either form of
+    the pool's shape and updates it in place."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.models import hybrid_transformer as hybrid
+    from deeplearning4j_tpu.serving import paged_kinds
+
+    cfg = hybrid.HybridConfig(
+        vocab_size=1024, d_model=2048, n_heads=32, n_kv_heads=8,
+        head_dim=64, d_ff=512, layer_kinds=("conv", "full"), n_experts=0,
+        experts_per_token=0, n_shared=0, n_held=0, conv_kernel=3,
+        max_len=8192, rms_eps=1e-5, attn_gate=False, tied_head=True,
+        dtype=jnp.bfloat16).check()
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    def vec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = on_chip(jax.eval_shape(
+        lambda: hybrid.init_hybrid_params(jax.random.PRNGKey(0), cfg)))
+    pool = on_chip(jax.eval_shape(
+        lambda: paged_kinds.init_pool(cfg, {"full": NARROW_PAGES}, 128,
+                                      slots=NARROW_SLOTS)))
+    pool_shape = (NARROW_PAGES + 1, 8, 128, 64)
+    assert pool.layers[1]["k"].shape == pool_shape
+    s = NARROW_SLOTS
+
+    def step_fn(params, tokens, pool, table, lengths, stop):
+        act = lengths < stop
+        logits, pool, _ = paged_kinds.decode_step(
+            params, tokens, pool, table, lengths, act, cfg,
+            kernel="pallas")
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), pool
+
+    text = _compiled_as_on_the_chip(
+        step_fn, (2,), params, vec(s), pool, {"full": vec(s, 64)}, vec(s),
+        vec(s))
+    assert len(re.findall(r"^.*paged_decode_attention.*custom-call\(",
+                          text, re.M)) == 1
+    n, h, ps, hd = pool_shape
+    for dims in ((n, h, ps, hd), (n, h, hd, ps)):
+        shape = ",".join(str(x) for x in dims)
+        copies = re.findall(rf"^.*= bf16\[{shape}\]\{{[^}}]*\}} "
+                            r"(?:copy|transpose)\(.*$", text, re.M)
+        assert not copies, f"{len(copies)} pool copies: {copies[0][:200]}"
+    alias = re.search(r"input_output_alias=\{(.*?)\}, entry", text, re.S)
+    assert alias, "the compiled module aliases no input to an output"
+    # K and V of the full layer, the conv layer's kept columns
+    assert len(re.findall(r"(?:may|must)-alias", alias.group(1))) == 3
