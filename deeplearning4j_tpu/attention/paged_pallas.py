@@ -13,20 +13,22 @@ kernel-with-interpret portability pattern from
   consecutive page-table columns. The page table and per-slot lengths
   ride `PrefetchScalarGridSpec` scalar prefetch and pick the PHYSICAL
   page of each column — the pool is the kernel operand and no dense
-  window is ever materialized. A block is as many pages as hold 128
-  keys, the size at which pages of 128 tokens read 77% of the kernel's
-  roofline on a v5e and pages of 16, one a step, read 24% (a grid step
-  costs ~0.4 us whatever it moves, and a page of 16 x 16 x 128 bf16 is
-  0.08 us of bandwidth):
-  - pages of 128 tokens and more, and pages that are no whole
-    (sublane, lane) tiles, are a block each: grid `(S, P)`, the K/V
+  window is ever materialized. A block is as many whole pages as hold
+  `BLOCK_BYTES` = 512 KiB of K, whatever their tokens: a grid step
+  costs ~0.3-0.4 us whatever it moves, and one page of 128 KiB of K and
+  as much of V is ~0.32 us of bandwidth (`paged_attention`'s doc-string
+  has the sweeps on a v5e):
+  - a page that fills a block alone, and a page that is no whole
+    (sublane, lane) tiles, is a block of its own: grid `(S, P)`, the K/V
     BlockSpec index map reads `pt[s, j]`, Pallas's own pipeline
     fetches (`_decode_kernel`, the program this kernel always was);
   - smaller pages are swept several a step (`_block_kernel`): grid
     `(S,)`, the pools left in HBM, each page of a block copied by a
-    DMA of its own to its rows of one `(H, N * page_size, hd)` buffer,
-    the next block in flight while this one computes. The scores of a
-    block are one lane-dense `(H, rows, N * page_size)` tile;
+    DMA of its own to its rows of one `(H, N * page_size, hd)` buffer
+    (to its lanes of an `(H, hd, N * page_size)` one where pages are
+    read transposed), the next block in flight while this one
+    computes. The scores of a block are one lane-dense
+    `(H, rows, N * page_size)` tile;
 - online softmax across a slot's blocks: f32 scratch (acc, m, s),
   base-2 state (`exp2`, scores prescaled by log2(e)/sqrt(hd)) exactly
   like the flash kernels;
@@ -127,6 +129,11 @@ def _decode_kernel(pt_ref, len_ref, *refs, page_size: int,
     j = pl.program_id(1)
     n_j = pl.num_programs(1)
     pos = len_ref[si]   # this slot's cursor: positions [0, pos] visible
+    if windowed:
+        # a cursor past the table's end sees the table (as in
+        # `_block_kernel`): the index map fetches the last column again
+        # for steps past it, and those must not count
+        pos = jnp.minimum(pos, pt_ref.shape[1] * page_size - 1)
     first = first_ref[si] if windowed else None
     page = first // page_size + j if windowed else j
 
@@ -151,15 +158,17 @@ def _decode_kernel(pt_ref, len_ref, *refs, page_size: int,
 
 
 def _block_kernel(pt_ref, len_ref, *refs, page_size: int, n_block: int,
-                  n_sweep: int, windowed: bool = False):
+                  n_sweep: int, windowed: bool = False,
+                  transposed: bool = False):
     """One slot a grid step, a block of `n_block` pages a loop step.
 
     The pools stay in HBM (`memory_space=pl.ANY`). A block is the K
     (and V) of `n_block` consecutive table columns, each page one DMA
     from where it lies in the pool to its `page_size` rows of a
-    (H, n_block * page_size, hd) buffer: the pages arrive side by side
-    along the key dimension, so the scores are one lane-dense
-    (H, rows, n_block * page_size) tile and the block is one
+    (H, n_block * page_size, hd) buffer, or to its `page_size` lanes of
+    a (H, hd, n_block * page_size) one where `transposed`: the pages
+    arrive side by side along the key dimension, so the scores are one
+    lane-dense (H, rows, n_block * page_size) tile and the block is one
     online-softmax update; nothing is relaid in VMEM. Two buffers: the
     next block (or the next slot's first) is in flight while this one
     computes; which of the two is next is carried from slot to slot in
@@ -200,11 +209,13 @@ def _block_kernel(pt_ref, len_ref, *refs, page_size: int, n_block: int,
 
         def page_dma(i):
             page = pt_ref[s, col0 + b * n_block + i]
+            keys = pl.ds(i * ps, ps)
             for kv, (hbm, vmem) in enumerate(((k_hbm, k_buf),
                                               (v_hbm, v_buf))):
-                copy = pltpu.make_async_copy(
-                    hbm.at[page], vmem.at[buf, :, pl.ds(i * ps, ps)],
-                    sem.at[buf, kv])
+                dst = (vmem.at[buf, :, :, keys] if transposed
+                       else vmem.at[buf, :, keys])
+                copy = pltpu.make_async_copy(hbm.at[page], dst,
+                                             sem.at[buf, kv])
                 getattr(copy, act)()
 
         page_dma(0)             # a block that runs holds its first page
@@ -244,7 +255,7 @@ def _block_kernel(pt_ref, len_ref, *refs, page_size: int, n_block: int,
         block_dma("wait", si, span, b, buf)
         _softmax_update(q_ref[0], k_buf[buf], v_buf[buf],
                         col0 + b * n_block, ps, pos, first,
-                        acc_ref, m_ref, s_ref)
+                        acc_ref, m_ref, s_ref, transposed)
         return carry
 
     jax.lax.fori_loop(0, n_blocks, block, None)
@@ -300,17 +311,34 @@ def paged_attention(q, k_pool, v_pool, page_table, lengths, *,
     `window_pages` is at least the most columns a window can straddle.
     A step of the sweep covers a BLOCK of N = `block_pages(...)`
     consecutive table columns, N derived from the call's shapes and
-    from nothing else: as many pages as hold `BLOCK_KEYS` = 128 keys,
-    no more than `BLOCK_BYTES` of K and no more than the columns swept;
-    1 for pages of 128 tokens and more and for pages that are no whole
-    (sublane, lane) tiles. On a v5e, 24 calls at `cgpt13b-decode-sat`'s
+    from nothing else: as many whole pages as hold `BLOCK_BYTES` =
+    512 KiB of K, at least one and no more than the columns swept,
+    whatever the page's tokens; 1 for pages that are no whole (sublane,
+    lane) tiles. In bf16 that is 8 pages of 16 tokens at 16 heads of
+    128 (64 KiB of K a page), 4 pages of 128 at 2 K/V heads of 256 or
+    8 of 64 (128 KiB), 2 at 8 heads of 128 (256 KiB), 1 at 30 heads of
+    128 (960 KiB). On a v5e, 24 calls at `cgpt13b-decode-sat`'s
     shapes (16 slots at 1,024-1,472 keys, 16 heads of 128, pages of 16,
     bf16; the bytes of their written pages take 4.86 ms at 819 GB/s)
     took 20.98 ms with one page a step, 11.69 / 7.76 / 6.22 / 6.15 ms
     with blocks of 2 / 4 / 8 / 16 pages (23 / 42 / 63 / 78 / 79% of
-    that least), and with 5 of the 16 slots live 8.68 ms against 3.26
-    / 2.42 / 2.15 / 2.31: 128 keys it is, 256 pay nothing more (PERF.md
-    section 6, PR 31). With N = 1 the call is the program it always
+    that least; 128 KiB, 256 KiB, 512 KiB and 1 MiB of K a block), and
+    with 5 of the 16 slots live 8.68 ms against 3.26 / 2.42 / 2.15 /
+    2.31 (PERF.md section 6). Blocks of 128-token pages, one call after
+    another (host clock over 20 calls, the best of 3), against the
+    least time of each slot's written pages of K and V at 819 GB/s: at
+    `qwen3-next-80b-a3b`'s shapes (64 slots at 7,168-8,191 keys, 16
+    query heads over 2 K/V heads of 256, bf16; least 1.242 ms) 2.370 /
+    1.610 / 1.406 / 1.394 ms with 128 KiB / 256 KiB / 512 KiB / 1 MiB
+    of K a block (52 / 77 / 88 / 89%); at `lfm2-24b-a2b`'s (64 slots at
+    1,536-5,119 keys, 32 over 8 heads of 64 read transposed; least
+    0.549 ms) 1.293 / 0.744 / 0.653 / 0.637 ms (42 / 74 / 84 / 86%); at
+    `command-a-plus`'s (32 slots, 128 over 8 heads of 128; least
+    1.239 ms) 1.651 / 1.385 / 1.384 ms with 256 KiB / 512 KiB / 1 MiB
+    (75 / 89 / 90%), its window over 33 columns 74 / 86 / 85%; sat's
+    shapes again 63 / 80 / 79% at 256 KiB / 512 KiB / 1 MiB. The knee
+    lies at 512 KiB: a MiB buys a point or less and would double sat's
+    block for nothing. With N = 1 the call is the program it always
     was, grid `(S, columns)` with the pool under a block spec; with
     N > 1 the grid is `(S,)`, the pools stay in HBM and the kernel
     copies each page of a block to its place in a VMEM buffer itself
@@ -370,7 +398,7 @@ def _paged_attention(q, k_pool, v_pool, page_table, lengths, first, *,
     # padding), and a row-major operand would copy both pools whole at
     # every call; read as it lies, each page (H, hd, page_size), it is
     # the transposed pool's row-major layout and no copy at all
-    transposed = bool(hd % 128) and ps % 128 == 0
+    transposed = _transposed(ps, hd)
     if transposed:
         k_pool, v_pool = (jnp.swapaxes(a, 2, 3) for a in (k_pool, v_pool))
     if n_block == 1:
@@ -392,8 +420,11 @@ def _paged_attention(q, k_pool, v_pool, page_table, lengths, first, *,
     else:
         kv_spec = pl.BlockSpec(memory_space=pl.ANY)
         kernel = partial(_block_kernel, page_size=ps, n_block=n_block,
-                         n_sweep=n_j, windowed=windowed)
-        block = pltpu.VMEM((2, h, n_block * ps, hd), k_pool.dtype)
+                         n_sweep=n_j, windowed=windowed,
+                         transposed=transposed)
+        keys = n_block * ps
+        block = pltpu.VMEM((2, h, hd, keys) if transposed
+                           else (2, h, keys, hd), k_pool.dtype)
         grid = (s,)
         scratch = [block, block, pltpu.SemaphoreType.DMA((2, 2)), *state,
                    pltpu.SMEM((1,), jnp.int32)]
@@ -416,30 +447,42 @@ def _paged_attention(q, k_pool, v_pool, page_table, lengths, first, *,
     return out[:, :, :hq // h, :].reshape(s, hq, hd)
 
 
-#: keys a block holds: what a page of 128 tokens holds, at which the
-#: kernel reads 77% of its roofline (`paged_attention`'s doc-string
-#: has the sweep of 32, 64, 128 and 256 keys)
-BLOCK_KEYS = 128
-#: most bytes of K a block holds: K and V, two buffers each, stay
-#: inside a quarter of the 16 MiB of VMEM a kernel may use
-BLOCK_BYTES = 1 << 20
+#: bytes of K a block holds (and as many of V), whatever the page's
+#: tokens: the knee of the sweeps in `paged_attention`'s doc-string.
+#: K and V, two buffers each, take 2 MiB of the 16 MiB of VMEM a
+#: kernel may use
+BLOCK_BYTES = 512 << 10
+
+
+def _transposed(page_size: int, head_dim: int) -> bool:
+    """Whether the kernel reads a page as (H, hd, page_size): heads
+    narrower than a lane tile in pages of whole lane tiles, which the
+    TPU lays out with their positions minor."""
+    return bool(head_dim % 128) and page_size % 128 == 0
 
 
 def block_pages(page_size: int, kv_heads: int, head_dim: int, dtype,
                 n_columns: int) -> int:
     """Pages of a block of `paged_attention`'s sweep, from the shapes
-    of the call alone (see its doc-string): 8 for `cerebras-gpt-1.3b`'s
-    pages of 16 tokens, 1 for `command-a-plus-ep8`'s pages of 128.
-    `n_columns`: the table columns a slot's sweep covers."""
+    of the call alone (see its doc-string): as many whole pages as hold
+    `BLOCK_BYTES` of K, at least one and no more than `n_columns`, the
+    table columns a slot's sweep covers. In bf16: 8 for
+    `cerebras-gpt-1.3b`'s pages of 16 tokens (64 KiB of K), 4 for
+    `qwen3-next-80b-a3b`'s and `lfm2-24b-a2b`'s pages of 128 (128 KiB),
+    2 for `command-a-plus`'s (256 KiB), 1 for `olmo-hybrid-7b`'s
+    (960 KiB)."""
     itemsize = jnp.dtype(dtype).itemsize
-    if page_size % (32 // itemsize) or head_dim % 128:
-        # a page whose rows are no whole (sublane, lane) tiles has no
-        # aligned place inside a block (Mosaic refuses the DMA): it is
-        # fetched alone, padded, as ever
+    # a page as the kernel reads it: (rows, lanes) a head, the keys
+    # along the rows, or along the lanes where it is read transposed
+    rows, lanes = ((head_dim, page_size) if _transposed(page_size, head_dim)
+                   else (page_size, head_dim))
+    if rows % (32 // itemsize) or lanes % 128:
+        # a page that is no whole (sublane, lane) tiles has no aligned
+        # place inside a block (Mosaic refuses the DMA): it is fetched
+        # alone, padded, as ever
         return 1
     page_bytes = kv_heads * page_size * head_dim * itemsize
-    return max(1, min(BLOCK_KEYS // page_size, BLOCK_BYTES // page_bytes,
-                      n_columns))
+    return max(1, min(BLOCK_BYTES // page_bytes, n_columns))
 
 
 def resolve_decode_kernel(kernel: str, cfg, page_size: int) -> str:
@@ -464,9 +507,11 @@ def resolve_decode_kernel(kernel: str, cfg, page_size: int) -> str:
       a window over 33 columns (2e-2 / 1e-4 against the float64
       reference, cursors on every edge of a page, a block and the
       table); PR 35: S64 x 16 query heads over 2 K/V heads of 256 (8
-      rows a K/V head, one 128 KB K page a block), pages of 128 over 64
-      columns, bf16 and f32 (2e-2 / 1e-4 against the float64 reference,
-      cursors on the edges of a page and the table): where the dense
+      rows a K/V head, four 128 KiB K pages a block in bf16 and two in
+      f32), pages of 128 over 64 columns, bf16 and f32 (2e-2 / 1e-4
+      against the float64 reference, cursors on the edges of a page
+      and the table), and pages of 128 in blocks at heads of 64 read
+      transposed and in a window over 33 columns: where the dense
       gather of 64 slots x 64 columns would move 1.1 GB a full layer a
       step. The chip refused nothing in it. Outside it the kernel
       also compiles (hd 16..256, page sizes 1..128 were compiled for

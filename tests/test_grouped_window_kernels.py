@@ -5,7 +5,7 @@ of the decode step; the flash forward with groups and a window against
 `attention/blockwise.py`. And what they were is what they are: with as
 many K/V heads as query heads and nothing windowed the new arguments
 change no bit. Since PR 31 pages that are whole tiles are swept a block
-of several a step: the same cases at a head size that engages it."""
+of several a step: the same cases at a page size that engages it."""
 
 import jax
 import jax.numpy as jnp
@@ -74,11 +74,12 @@ def test_paged_kernel_grouped_heads_and_a_first_position(hq, hkv):
 @pytest.mark.parametrize("window", [150, 40, None])
 @pytest.mark.parametrize("hq,hkv", [(8, 2), (16, 1), (4, 4)])
 def test_paged_kernel_blocks_of_pages_grouped_and_windowed(hq, hkv, window):
-    """Pages of 16 at head size 128: 8 pages a block. A window of 150
-    keys straddles 11 columns (no multiple of the block) from a first
-    position in the middle of a page and of the table's blocks; one of
-    40 keys straddles 4, fewer than a block holds."""
-    s, ps, hd, n_p, pages = 4, 16, 128, 20, 80
+    """Pages of 16 that hold 64 KiB of K (K/V heads x head size 1,024
+    in float32): 8 pages a block. A window of 150 keys straddles 11
+    columns (no multiple of the block) from a first position in the
+    middle of a page and of the table's blocks; one of 40 keys
+    straddles 4, fewer than a block holds."""
+    s, ps, hd, n_p, pages = 4, 16, 1024 // hkv, 20, 80
     ks = jax.random.split(jax.random.PRNGKey(3), 3)
     q = jax.random.normal(ks[0], (s, hq, hd))
     k = jax.random.normal(ks[1], (pages + 1, hkv, ps, hd))

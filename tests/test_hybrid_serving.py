@@ -194,14 +194,16 @@ def test_what_counts_on_page_reuse_is_refused_by_name(kw, what):
 def test_the_paged_kernel_and_flash_take_heads_of_256():
     """16 query heads over 2 K/V heads of 256, pages of 128, through the
     interpreter: the paged kernel against the dense gather's masked
-    softmax, the flash forward against blockwise attention."""
+    softmax (float32 pages of 256 KiB of K, 2 a block over 3 columns),
+    the flash forward against blockwise attention."""
     from deeplearning4j_tpu.attention.blockwise import (blockwise_attention,
                                                         masked_attention)
     from deeplearning4j_tpu.attention.flash_pallas import flash_attention
     from deeplearning4j_tpu.attention.paged_pallas import (block_pages,
                                                            paged_attention)
 
-    assert block_pages(128, 2, 256, jnp.bfloat16, 64) == 1
+    assert block_pages(128, 2, 256, jnp.bfloat16, 64) == 4
+    assert block_pages(128, 2, 256, jnp.float32, 3) == 2
     ks = jax.random.split(jax.random.PRNGKey(0), 6)
     s, n_p, ps = 3, 3, 128
     q = jax.random.normal(ks[0], (s, 16, 256))

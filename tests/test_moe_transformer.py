@@ -321,8 +321,9 @@ def test_counters_of_pages_by_kind_and_of_pairs_and_the_release_span():
 
 def test_paged_kernel_block_by_kind_follows_each_kind_s_columns():
     """At a head size that engages the block (pages of 16, 2 K/V heads
-    of 128): the full kind sweeps 64 columns 8 a block, the window kind
-    `window_table_pages` = 5 columns in one block of 5."""
+    of 128 in float32, 16 KiB of K a page): the full kind sweeps 64
+    columns 32 a block, the window kind `window_table_pages` = 5
+    columns in one block of 5."""
     from deeplearning4j_tpu.attention.paged_pallas import block_pages
 
     cfg = _model(_config(), head_dim=128, window=50, max_len=1024,
@@ -334,10 +335,10 @@ def test_paged_kernel_block_by_kind_follows_each_kind_s_columns():
         assert loop.snapshot()["paged_block_pages"] == {
             "full": block_pages(16, cfg.n_kv_heads, 128, cfg.dtype, 64),
             "window": 5}
-        assert loop.snapshot()["paged_block_pages"]["full"] == 8
+        assert loop.snapshot()["paged_block_pages"]["full"] == 32
     text = exposition.render_prometheus()
     assert ('dl4j_paged_kernel_block_pages{kind="full",'
-            'loop="moe-blocks"} 8') in text
+            'loop="moe-blocks"} 32') in text
     assert ('dl4j_paged_kernel_block_pages{kind="window",'
             'loop="moe-blocks"} 5') in text
 

@@ -23,15 +23,18 @@ docs/SERVING.md "Decode kernel"):
 5. **flash q_len=1** (satellite): `_fit_tile` admits the decode-shaped
    single-row query tile instead of demoting it to the dense fallback.
 6. **Blocks of pages** (ISSUE 31): pages that are whole (sublane, lane)
-   tiles and hold fewer than 128 keys are swept several a step
-   (`block_pages`: 8 for sat's pages of 16). Against a dense float64
-   reference and the gather lane with the block engaged: cursors in a
-   block's first, middle and last page and on its edges, widths the
-   block does not divide, a table narrower than a block, shared and
-   trash columns inside a block, the cursor at max_len; pages of 128
-   tokens stay one a grid step, bit for bit. The count the loop
-   reports (`snapshot()["paged_block_pages"]`,
-   `dl4j_paged_kernel_block_pages`) is that rule's.
+   tiles are swept several a step, as many as hold 512 KiB of K
+   (`block_pages`: 8 for sat's pages of 16, 4 for q3n's and lfm2's
+   pages of 128, 2 for ep8's). Against a dense float64 reference and
+   the gather lane with the block engaged: cursors in a block's first,
+   middle and last page and on its edges, widths the block does not
+   divide, a table narrower than a block, shared and trash columns
+   inside a block, the cursor at max_len; pages of 128 tokens in blocks
+   at q3n's grouping, in ep8's windowed sweep and read transposed at
+   lfm2's heads of 64; a page that fills a block alone stays one a
+   grid step, bit for bit. The count the loop reports
+   (`snapshot()["paged_block_pages"]`, `dl4j_paged_kernel_block_pages`)
+   is that rule's.
 """
 
 from __future__ import annotations
@@ -155,42 +158,55 @@ def _block_case(ps, n_p, lengths, hq=2, hkv=2, hd=128, dtype=jnp.float32,
 
 
 class TestBlocksOfPages:
-    # (page size, dtype, table width, pages a block): widths the block
-    # does not divide and one narrower than a block
-    SHAPES = [(16, "float32", 20, 8), (8, "float32", 37, 16),
-              (16, "bfloat16", 19, 8), (32, "float32", 9, 4),
-              (16, "float32", 5, 5)]
+    # (page size, dtype, K/V heads, table width, pages a block): pages of
+    # 64 KiB (32 KiB, 128 KiB) of K, widths the block does not divide and
+    # one narrower than a block
+    SHAPES = [(16, "float32", 8, 20, 8), (8, "float32", 8, 37, 16),
+              (16, "bfloat16", 16, 19, 8), (32, "float32", 8, 9, 4),
+              (16, "float32", 8, 5, 5)]
 
     def test_the_rule(self):
-        """From the call's shapes alone: pages that are whole tiles,
-        as many as hold 128 keys, no more than the columns swept nor
-        than a MiB of K; sat's shapes take 8, ep8's 1."""
+        """From the call's shapes alone: whole pages that are whole
+        tiles, as many as hold 512 KiB of K, at least one and no more
+        than the columns swept. The cells' shapes (bf16): sat and p80
+        8, q3n 4, lfm2 4 (read transposed), ep8 2 for both kinds, olm
+        1."""
         assert block_pages(16, 16, 128, jnp.bfloat16, 128) == 8
-        assert block_pages(16, 16, 128, jnp.float32, 128) == 8
-        assert block_pages(128, 8, 128, jnp.bfloat16, 64) == 1
-        assert block_pages(128, 8, 128, jnp.bfloat16, 33) == 1
-        assert block_pages(256, 8, 128, jnp.bfloat16, 33) == 1
+        assert block_pages(128, 2, 256, jnp.bfloat16, 64) == 4
+        assert block_pages(128, 8, 64, jnp.bfloat16, 64) == 4
+        assert block_pages(128, 8, 128, jnp.bfloat16, 64) == 2
+        assert block_pages(128, 8, 128, jnp.bfloat16, 33) == 2
+        assert block_pages(128, 30, 128, jnp.bfloat16, 128) == 1
+        # the same pages in float32 hold twice the bytes
+        assert block_pages(16, 16, 128, jnp.float32, 128) == 4
+        assert block_pages(128, 2, 256, jnp.float32, 64) == 2
+        assert block_pages(128, 8, 128, jnp.float32, 64) == 1
+        # no wider than the columns swept; a page past the bytes alone
         assert block_pages(16, 16, 128, jnp.bfloat16, 3) == 3
-        assert block_pages(16, 32, 128, jnp.float32, 128) == 4
+        assert block_pages(128, 2, 256, jnp.bfloat16, 3) == 3
+        assert block_pages(256, 8, 128, jnp.bfloat16, 33) == 1
+        assert block_pages(16, 32, 128, jnp.float32, 128) == 2
         # no aligned place inside a block: half a bf16 tile, half a lane
-        # tile, a page of 4
+        # tile, a page of 4, heads of 64 in pages of 64 (not transposed)
         assert block_pages(8, 8, 128, jnp.bfloat16, 32) == 1
         assert block_pages(16, 16, 64, jnp.bfloat16, 32) == 1
         assert block_pages(4, 2, 16, jnp.float32, 6) == 1
+        assert block_pages(64, 8, 64, jnp.bfloat16, 32) == 1
 
-    @pytest.mark.parametrize("ps,dtype,n_p,pages", SHAPES)
-    def test_kernel_against_dense_float64(self, ps, dtype, n_p, pages):
+    @pytest.mark.parametrize("ps,dtype,hkv,n_p,pages", SHAPES)
+    def test_kernel_against_dense_float64(self, ps, dtype, hkv, n_p, pages):
         """Cursors at 0, in the first, a middle and the last page of a
         block, on a block's last lane and the next block's first, at
         the table's end and AT max_len; a slot of nothing but trash
         (cursor 0) and one that shares another's pages."""
-        assert block_pages(ps, 2, 128, dtype, n_p) == pages
+        assert block_pages(ps, hkv, 128, dtype, n_p) == pages
         span, end = pages * ps, n_p * ps
         lengths = [3, min(span + ps + 3, end - 1), span - 1,
                    min(span, end - 1), end - 1, end,
                    min(span + span // 2, end - 2), 0,
                    min(span + 2 * ps + 1, end - 1)]
-        q, k, v, table, lengths = _block_case(ps, n_p, lengths,
+        q, k, v, table, lengths = _block_case(ps, n_p, lengths, hq=hkv,
+                                              hkv=hkv,
                                               dtype=jnp.dtype(dtype))
         got = paged_attention(q, k, v, jnp.asarray(table),
                               jnp.asarray(lengths), interpret=True)
@@ -199,16 +215,66 @@ class TestBlocksOfPages:
         tol = 1e-5 if dtype == "float32" else 2e-2
         assert np.abs(np.asarray(got, np.float64) - want).max() < tol
 
+    # (query heads, K/V heads, head size, dtype, window keys, pages a
+    # block): q3n's grouping (16 over 2 K/V heads of 256), ep8's
+    # windowed sweep over 5 columns (and its float32 pages, a block
+    # each), lfm2's heads of 64 read transposed
+    PAGES_OF_128 = [(16, 2, 256, "bfloat16", None, 4),
+                    (16, 2, 256, "float32", None, 2),
+                    (32, 8, 128, "bfloat16", 513, 2),
+                    (16, 4, 128, "float32", 513, 2),
+                    (32, 8, 128, "float32", 513, 1),
+                    (32, 8, 64, "bfloat16", None, 4),
+                    (16, 4, 64, "float32", None, 4)]
+
+    @pytest.mark.parametrize("hq,hkv,hd,dtype,window,pages", PAGES_OF_128)
+    def test_pages_of_128_in_blocks_against_dense_float64(
+            self, hq, hkv, hd, dtype, window, pages):
+        """Pages of 128 tokens swept several a step: cursors at 0, on a
+        block's last lane and the next block's first, in a later block,
+        at the table's end and past it, a slot of nothing but trash and
+        one that shares another's pages; where a window is given, its
+        first position in the middle of a page and of a block, the
+        pages before it given back, and a sweep of 5 columns that
+        blocks of 2 do not divide."""
+        ps = 128
+        n_p = 12 if window else 9
+        span, end = pages * ps, n_p * ps
+        lengths = [0, span - 1, span, 2 * span + 5, end - 1, end, 300, 0,
+                   span + 130]
+        q, k, v, table, lengths = _block_case(
+            ps, n_p, lengths, hq=hq, hkv=hkv, hd=hd, dtype=jnp.dtype(dtype))
+        if window is None:
+            assert block_pages(ps, hkv, hd, dtype, n_p) == pages
+            first = np.zeros(len(lengths), np.int32)
+            got = paged_attention(q, k, v, jnp.asarray(table),
+                                  jnp.asarray(lengths), interpret=True)
+        else:
+            columns = -(-(window - 1) // ps) + 1
+            assert block_pages(ps, hkv, hd, dtype, columns) == pages
+            first = np.maximum(lengths - window + 1, 0).astype(np.int32)
+            held = table.copy()
+            for i in range(len(lengths)):
+                held[i, :first[i] // ps] = len(k) - 1
+            got = paged_attention(q, k, v, jnp.asarray(held),
+                                  jnp.asarray(lengths),
+                                  first=jnp.asarray(first),
+                                  window_pages=columns, interpret=True)
+        want = _dense(q, k, v, table, lengths, first, ps)
+        tol = 1e-5 if dtype == "float32" else 2e-2
+        assert np.abs(np.asarray(got, np.float64) - want).max() < tol
+
     def test_step_kernel_lane_equals_the_gather_lane_with_blocks(self):
         """`paged_decode_step` at a head size that engages the block
-        (hd 128, pages of 8: 16 a block over a 20-column table),
-        teacher-forced from cursors on both sides of a block's edge."""
-        cfg = TransformerConfig(vocab_size=17, d_model=256, n_heads=2,
+        (8 heads of 128, pages of 8 of 32 KiB: 16 a block over a
+        20-column table), teacher-forced from cursors on both sides of
+        a block's edge."""
+        cfg = TransformerConfig(vocab_size=17, d_model=1024, n_heads=8,
                                 n_layers=1, d_ff=32, max_len=160,
                                 interpret=True)
         ps = 8
         n_p = pages_per_slot(cfg, ps)
-        assert block_pages(ps, 2, 128, cfg.dtype, n_p) == 16
+        assert block_pages(ps, 8, 128, cfg.dtype, n_p) == 16
         p = init_transformer_params(jax.random.PRNGKey(0), cfg)
         rng = np.random.RandomState(0)
         pool = init_paged_pool(cfg, 3 * n_p, ps)
@@ -235,12 +301,16 @@ class TestBlocksOfPages:
 
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_pages_of_128_are_one_a_grid_step_bit_for_bit(self, dtype):
-        """ep8's page: a block of one, fetched by Pallas's own pipeline
-        over grid (slot, column) as ever: no copy by hand in the call,
-        and the sums of `test_paged_kernel_at_today_s_shapes_is_bit_
-        for_bit` (tests/test_grouped_window_kernels.py) bit for bit."""
+        """A page of 128 tokens that holds a block's 512 KiB of K alone
+        (olm's, 30 heads of 128 in bf16, is 960 KiB): a block of one,
+        fetched by Pallas's own pipeline over grid (slot, column) as
+        ever: no copy by hand in the call, and the sums of
+        `test_paged_kernel_at_today_s_shapes_is_bit_for_bit`
+        (tests/test_grouped_window_kernels.py) bit for bit."""
+        hkv = 32 // jnp.dtype(dtype).itemsize
+        assert block_pages(128, hkv, 128, dtype, 3) == 1
         q, k, v, table, lengths = _block_case(
-            128, 3, [0, 200, 127, 128, 383, 384, 5], hq=4, hkv=2,
+            128, 3, [0, 200, 127, 128, 383, 384, 5], hq=2 * hkv, hkv=hkv,
             dtype=dtype)
         args = (q, k, v, jnp.asarray(table), jnp.asarray(lengths))
         text = str(jax.make_jaxpr(
@@ -288,7 +358,10 @@ class TestBlocksOfPages:
                                 n_layers=1, d_ff=32, max_len=512,
                                 interpret=True)
         p = init_transformer_params(jax.random.PRNGKey(0), cfg)
-        for ps, kernel, want in ((16, "pallas", 8), (128, "pallas", 1),
+        # 2 heads of 128 in float32: pages of 16 hold 16 KiB of K, 32
+        # a block over 32 columns; pages of 128 hold 128 KiB, 4 a block
+        # over 4 columns
+        for ps, kernel, want in ((16, "pallas", 32), (128, "pallas", 4),
                                  (16, "gather", 0)):
             name = f"blk-{kernel}-{ps}"
             with DecodeLoop(p, cfg, slots=2, page_size=ps, kernel=kernel,

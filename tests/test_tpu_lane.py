@@ -279,7 +279,8 @@ class TestPagedKernelOnChip:
     def test_kernel_at_the_served_shape_sweeps_blocks(self, dtype, atol):
         """`cgpt13b-decode-sat`'s call: 16 slots, 16 heads of 128, pages
         of 16 over a 128-column table. Since PR 31 a block of 8 pages a
-        step, each page copied from where it lies in the pool."""
+        step (4 in float32: a block holds 512 KiB of K), each page
+        copied from where it lies in the pool."""
         import jax
         import jax.numpy as jnp
 
@@ -287,13 +288,58 @@ class TestPagedKernelOnChip:
             block_pages, paged_attention)
 
         assert jax.devices()[0].platform == "tpu"
-        assert block_pages(16, 16, 128, jnp.dtype(dtype), 128) == 8
+        assert block_pages(16, 16, 128, jnp.dtype(dtype), 128) == {
+            "float32": 4, "bfloat16": 8}[dtype]
         case = _paged_case(np.random.default_rng(1), 16, 16, 128, 16,
                            128, jnp.dtype(dtype))
         out = jax.jit(paged_attention)(*case)
         ref = _dense_paged_reference(*case)
         np.testing.assert_allclose(
             np.asarray(out.astype(jnp.float32), np.float64), ref,
+            atol=atol)
+
+    @pytest.mark.parametrize("dtype,atol", [("float32", 1e-4),
+                                            ("bfloat16", 2e-2)])
+    @pytest.mark.parametrize("hq,hkv,hd,window", [(32, 8, 64, None),
+                                                  (128, 8, 128, 4097)])
+    def test_pages_of_128_in_blocks_match_dense_reference(
+            self, hq, hkv, hd, window, dtype, atol):
+        """Pages of 128 tokens swept several a step: lfm2's
+        heads of 64, read transposed, 4 a block in bf16 (2 in f32), and
+        ep8's window of 4,097 keys over 33 columns, 2 a block in bf16
+        (1 in f32); cursors on a block's edges, at and past the table's
+        end, a slot of trash and a shared prefix."""
+        import jax
+        import jax.numpy as jnp
+
+        from deeplearning4j_tpu.attention.paged_pallas import (
+            block_pages, paged_attention)
+        from tests.test_grouped_window_kernels import _dense
+        from tests.test_paged_kernel import _block_case
+
+        assert jax.devices()[0].platform == "tpu"
+        ps, n_p = 128, 40
+        columns = -(-(window - 1) // ps) + 1 if window else n_p
+        pages = block_pages(ps, hkv, hd, jnp.dtype(dtype), columns)
+        assert pages == {(None, "bfloat16"): 4, (None, "float32"): 2,
+                         (4097, "bfloat16"): 2,
+                         (4097, "float32"): 1}[window, dtype]
+        span, end = max(pages, 2) * ps, n_p * ps
+        lengths = [0, span - 1, span, 3 * span + 5, end - 1, end, 4500,
+                   0, span + 130]
+        q, k, v, table, lengths = _block_case(
+            ps, n_p, lengths, hq=hq, hkv=hkv, hd=hd, dtype=jnp.dtype(dtype))
+        first = (np.maximum(lengths - window + 1, 0).astype(np.int32)
+                 if window else np.zeros(len(lengths), np.int32))
+        fn = jax.jit(lambda *a: paged_attention(
+            *a[:5], first=a[5] if window else None,
+            window_pages=columns if window else None))
+        out = fn(q, k, v, jnp.asarray(table), jnp.asarray(lengths),
+                 jnp.asarray(first))
+        want = _dense(q.astype(jnp.float32), k.astype(jnp.float32),
+                      v.astype(jnp.float32), table, lengths, first, ps)
+        np.testing.assert_allclose(
+            np.asarray(out.astype(jnp.float32), np.float64), want,
             atol=atol)
 
     @pytest.mark.parametrize("dtype,rtol", [("float32", 1e-4),
@@ -438,8 +484,9 @@ class TestFlashHd128OnChip:
 class TestHeadsOf256OnChip:
     """PR 35: what lets `resolve_decode_kernel("auto")` take heads of
     256 (`qwen3-next-80b-a3b-ep8`'s full layers: 16 query heads over 2
-    K/V heads, 8 query rows a K/V head, pages of 128 tokens, one page a
-    block), and the flash forward with the same heads."""
+    K/V heads, 8 query rows a K/V head, pages of 128 tokens, 4 pages a
+    block in bf16 and 2 in f32), and the flash forward with the same
+    heads."""
 
     @pytest.mark.parametrize("dtype,atol", [("float32", 1e-4),
                                             ("bfloat16", 2e-2)])
@@ -453,7 +500,8 @@ class TestHeadsOf256OnChip:
             HybridConfig
 
         assert jax.devices()[0].platform == "tpu"
-        assert block_pages(128, 2, 256, jnp.dtype(dtype), 64) == 1
+        assert block_pages(128, 2, 256, jnp.dtype(dtype), 64) == {
+            "float32": 2, "bfloat16": 4}[dtype]
         cfg = HybridConfig(
             vocab_size=8, d_model=2048, n_heads=16, n_kv_heads=2,
             head_dim=256, d_ff=512, layer_kinds=("linear", "full"),
